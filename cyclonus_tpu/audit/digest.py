@@ -161,7 +161,7 @@ def epoch_digest(
 ) -> Dict[str, Any]:
     """The full per-epoch digest record exported on /audit and state().
     `digest` is the comparison primitive; `epoch` and `seconds` ride
-    along for display and perfobs but are not hashed."""
+    along for display but are not hashed."""
     t0 = time.perf_counter()
     canon = canonical_state(pods, namespaces, netpols, anps, banp)
     state_hex = state_digest(canon)

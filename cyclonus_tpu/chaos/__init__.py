@@ -8,7 +8,8 @@ Two halves:
     `stall(point)` sleeps — armed ONLY via CYCLONUS_CHAOS, so the
     hooks are two dict reads when disarmed.  Points today:
 
-        backend_init       bench.py's overlapped attach thread
+        backend_init       the harness's own retry-envelope scenario
+                           (no production path retries an attach)
         delta_apply        VerdictService.apply_pending, AFTER the
                            authoritative dicts mutated (exercises the
                            rollback + rebuild-to-snapshot path)
@@ -28,7 +29,8 @@ Two halves:
     batch mid-apply — each asserting the system degrades exactly as
     designed (fresh compile / retry / rollback; incremental == rebuild
     == oracle parity after every injected fault).  `make chaos` runs
-    them all; bench.py's detail.chaos leg runs the kill/restart one.
+    them all, on the CPU: the serve scenarios start children that need
+    the backend, so their parent must not hold a chip.
 
 Spec grammar (CYCLONUS_CHAOS): comma-separated `point[:count[:arg]]` —
 `count` faults fire at that point then the hook disarms (default 1);

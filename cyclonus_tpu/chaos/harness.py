@@ -37,6 +37,20 @@ def _ttfv_bound_s() -> float:
         return DEFAULT_TTFV_BOUND_S
 
 
+def _held_accelerator() -> Optional[str]:
+    """The non-CPU platform this process has already initialised, or
+    None — asked without importing or initialising JAX."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return None
+    platform = jax.default_backend()
+    return None if platform == "cpu" else platform
+
+
 class _Serve:
     """A real `cyclonus-tpu serve` subprocess on the JSON-lines wire
     (stderr to a file so a chatty child can never deadlock the pipe)."""
@@ -44,12 +58,18 @@ class _Serve:
     def __init__(self, n_pods: int, n_ns: int, seed: int, workdir: str,
                  tag: str, env: Optional[Dict[str, str]] = None):
         self.stderr_path = os.path.join(workdir, f"serve-{tag}.stderr")
-        # children INHERIT the caller's backend: `make chaos` and the
-        # test suite export JAX_PLATFORMS=cpu themselves, while the
-        # bench's TPU-only chaos leg exists precisely to measure a TPU
-        # replica's restart (a forced-CPU child would record a CPU
-        # ttfv and could not adopt the TPU AOT entries — platform
-        # stamp mismatch)
+        # children INHERIT the caller's platform choice (`make chaos`
+        # and the test suite export JAX_PLATFORMS=cpu themselves), so a
+        # parent that already holds an accelerator would start children
+        # that cannot attach to it: a chip belongs to one process
+        held = _held_accelerator()
+        if held is not None:
+            raise RuntimeError(
+                f"this process holds the {held} backend; a serve child "
+                "could not attach to it.  Drive serve children from a "
+                "process that has not initialised JAX, or pin "
+                "JAX_PLATFORMS=cpu"
+            )
         full_env = dict(os.environ)
         full_env.update(env or {})
         self._stderr = open(self.stderr_path, "w")
@@ -319,11 +339,10 @@ def scenario_poisoned_caches(
 
 
 def scenario_backend_init_flake(seed: int = 0, failures: int = 2) -> Dict:
-    """Arm the `backend_init` point for N failures and drive the
-    bench-shaped retry envelope (same jittered backoff helper): the
-    attach must recover on attempt N+1 with the structured last-error
-    retained — the exact forensics bench.py ships in
-    detail.cold_start."""
+    """Arm the `backend_init` point for N failures and drive a retry
+    envelope over it (the utils/retry jittered backoff helper): the
+    call must recover on attempt N+1 with the structured last-error
+    retained."""
     from ..utils.retry import full_jitter_pause
     from . import fire
 

@@ -187,10 +187,16 @@ def run_serve(args) -> int:
         print(
             f"serve: prewarmed {pw['programs']} pair buckets in "
             f"{pw['seconds']}s (aot adopted={aot.get('adopted')} "
-            f"compiles={aot.get('compiles')})"
-            + (f" — prewarm error: {pw['error']}" if pw.get("error") else ""),
+            f"compiles={aot.get('compiles')})",
             file=sys.stderr,
         )
+        if pw.get("error"):
+            # a replica whose query programs cannot initialise, compile
+            # or run is dead, not degraded: answering from the scalar
+            # oracle is a stated behaviour WHILE warming, never a way
+            # to look healthy without the device
+            print(f"serve: prewarm failed: {pw['error']}", file=sys.stderr)
+            return 1
     st = service.state()
     tier_note = ""
     if st["tiers"]["active"]:
@@ -204,10 +210,14 @@ def run_serve(args) -> int:
             f", audit armed (rate {service.audit.rate:g}, "
             f"seed {service.audit.seed})"
         )
+    from ..engine import device_identity
+
+    dev = device_identity()
     print(
-        f"serve: engine ready — {st['pods']} pods, {st['policies']} "
-        f"policies{tier_note} (epoch {st['epoch']}){audit_note}; "
-        f"reading batches from stdin",
+        f"serve: engine ready on {dev['platform']} "
+        f"({dev['kind']} x{dev['count']}) — {st['pods']} pods, "
+        f"{st['policies']} policies{tier_note} (epoch {st['epoch']})"
+        f"{audit_note}; reading batches from stdin",
         file=sys.stderr,
     )
     run_stdio(service, sys.stdin, sys.stdout, max_lines=args.max_lines)

@@ -11,61 +11,97 @@ Pipeline:
   TpuPolicyEngine - the user-facing facade
 """
 
+import logging as _logging
 import os as _os
 
 _cache_configured = False
 
 
+def cache_root() -> str:  # never-raises
+    """The one directory every compile artefact lives under: JAX's
+    persistent compilation cache directly in it, the AOT executable
+    cache in `aot/` (aot_cache.py) and the autotune winners in
+    `autotune.json` (autotune.py).  $JAX_COMPILATION_CACHE_DIR when set
+    — a cache placed from outside the process — else the fixed
+    `.cache/jax` of the checkout this package was imported from.  The
+    path is part of JAX's cache key, so it is a function of the
+    package location alone: the same from every process and working
+    directory."""
+    placed = _os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    if placed:
+        return placed
+    checkout = _os.path.dirname(
+        _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
+    )
+    return _os.path.join(checkout, ".cache", "jax")
+
+
+def device_identity() -> dict:
+    """The devices of the default backend as JAX reports them —
+    {"platform", "kind", "count"} — for every line, banner and result
+    that has to say what answered.  Initialises the backend."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+
 def ensure_persistent_compile_cache() -> None:
     """Cache compiled XLA executables across processes: a CLI invocation
-    pays 10-20s of TPU compile for the verdict kernels; with the cache a
-    repeat run with the same tensor shapes skips it entirely.  Opt out
-    with CYCLONUS_JAX_CACHE=0, redirect with CYCLONUS_JAX_CACHE=<dir>.
+    pays seconds of TPU compile for the verdict kernels; with the cache
+    a repeat run with the same tensor shapes skips it.  The directory is
+    cache_root(); CYCLONUS_JAX_CACHE=0 opts out and CYCLONUS_JAX_CACHE=
+    <dir> redirects (neither applies when JAX_COMPILATION_CACHE_DIR
+    already placed the cache: the code then sets no directory at all).
 
     Called lazily from the first jax-using engine path (NOT at import
-    time - the oracle/native engines never pay the jax import), and
-    defers to any cache the user already configured via JAX's own knobs."""
+    time - the oracle/native engines never pay the jax import).  A
+    cache that cannot be configured is a warning, never an error and
+    never silent."""
     global _cache_configured
     if _cache_configured:
         return
     _cache_configured = True
-    try:
-        import jax
+    import jax
 
-        # Full-traceback locations leak CALLER line numbers into the
-        # Mosaic custom-call payload, where the cache key's
-        # strip-debuginfo pass cannot reach (the payload is an opaque
-        # serialized module): editing ANY file on the pallas call stack
-        # — even a benchmark script — minted a fresh key for an
-        # unchanged kernel and re-paid the 20-40s TPU compile.  Frame-
-        # free locations keep the key a function of the program alone.
-        # Applied for user-configured caches too (it is key hygiene, not
-        # cache placement); CYCLONUS_FULL_LOCATIONS=1 restores the
-        # debug-friendly full frames.  Own try: a jax without this flag
-        # must not knock out the cache configuration below.
-        if _os.environ.get("CYCLONUS_FULL_LOCATIONS", "") != "1":
-            try:
-                jax.config.update(
-                    "jax_include_full_tracebacks_in_locations", False
-                )
-            except Exception:
-                pass
+    # Full-traceback locations leak CALLER line numbers into the
+    # Mosaic custom-call payload, where the cache key's
+    # strip-debuginfo pass cannot reach (the payload is an opaque
+    # serialized module): editing ANY file on the pallas call stack
+    # — even a benchmark script — minted a fresh key for an
+    # unchanged kernel and re-paid the TPU compile.  Frame-free
+    # locations keep the key a function of the program alone.  It is
+    # key hygiene, not cache placement, so it applies wherever the
+    # cache lives; CYCLONUS_FULL_LOCATIONS=1 restores the
+    # debug-friendly full frames.
+    if _os.environ.get("CYCLONUS_FULL_LOCATIONS", "") != "1":
+        jax.config.update("jax_include_full_tracebacks_in_locations", False)
 
-        setting = _os.environ.get("CYCLONUS_JAX_CACHE", "")
-        if setting == "0" or _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    setting = _os.environ.get("CYCLONUS_JAX_CACHE", "")
+    if not jax.config.jax_compilation_cache_dir:
+        # nothing placed the cache from outside (the env var and a
+        # caller's own jax.config.update both land in this option)
+        if setting == "0":
             return
-        if jax.config.jax_compilation_cache_dir:
-            return  # the user configured their own cache; leave it alone
-        path = setting or _os.path.join(
-            _os.path.expanduser("~"), ".cache", "cyclonus-tpu", "jax"
-        )
-        _os.makedirs(path, exist_ok=True)
+        path = setting or cache_root()
+        try:
+            _os.makedirs(path, exist_ok=True)
+        except OSError as e:
+            _logging.getLogger(__name__).warning(
+                "persistent compile cache disabled: cannot create %s (%s)",
+                path,
+                e,
+            )
+            return
         jax.config.update("jax_compilation_cache_dir", path)
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in _os.environ:
         # the verdict kernels at CLI-typical cluster sizes compile in
         # ~0.2-1s each; the default 1s floor would cache none of them
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-    except Exception:  # cache is an optimization, never a requirement
-        pass
 
 
 from .encoding import ClusterEncoding, PolicyEncoding, encode_cluster, encode_policy

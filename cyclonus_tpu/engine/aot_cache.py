@@ -29,12 +29,14 @@ different device kind silently invalidates instead of loading an
 executable the runtime cannot run.
 
 Security note: entries are pickles (the serialize_executable payload
-format), loaded only from the user's own cache directory — the same
+format), loaded only from the operator's own cache directory — the same
 trust boundary as the autotune cache and JAX's own compilation cache.
 
 CYCLONUS_AOT_CACHE: cache directory; "0"/"" disables entirely (the test
 suite default — tests/conftest.py — so suites never share executables
-through the developer's home); unset -> the per-user default below.
+through the checkout's cache); unset -> `aot/` under the engine's
+cache_root() ($JAX_COMPILATION_CACHE_DIR, else the checkout's fixed
+.cache/jax).
 """
 
 from __future__ import annotations
@@ -55,18 +57,18 @@ log = logging.getLogger(__name__)
 #: (fresh compile), never migrated
 CACHE_VERSION = 1
 
-_DEFAULT_DIR = os.path.join("~", ".cache", "cyclonus_tpu", "aot")
-
-
 def cache_dir() -> Optional[str]:  # never-raises
     """Resolved cache directory, or None when persistence is disabled."""
     raw = os.environ.get("CYCLONUS_AOT_CACHE")
     if raw is None:
-        raw = _DEFAULT_DIR
+        from . import cache_root
+
+        # cache_root is checked as never-raising in its own module
+        return os.path.join(cache_root(), "aot")  # cachelint: ignore[CC005]
     raw = raw.strip()
     if raw in ("", "0"):
         return None
-    return os.path.expanduser(raw)
+    return raw
 
 
 def platform_stamp() -> str:
@@ -226,7 +228,7 @@ def _count(outcome: str) -> None:
 
 def counters() -> Dict[str, Any]:
     """The per-process AOT cache forensics bench.py records as
-    detail.cold_start.aot_cache: hits (executables adopted from disk —
+    detail.aot_cache: hits (executables adopted from disk —
     `adopted` aliases it for the acceptance schema), misses, stores,
     and fresh compiles actually paid (the restart gate's flat line)."""
     from ..telemetry import instruments as ti

@@ -13,16 +13,17 @@ tests/test_engine_packed.py).
 
 Robustness contract: the cache is advisory.  A corrupt, truncated,
 version-skewed, or otherwise surprising file degrades to a fresh search
-— load_winner never raises (the tunnel_wait truncated-JSON discipline)
-— and a failed write is a logged warning, never an error.  Writes are
-atomic (tmp + os.replace) and read-merge-write so concurrent processes
-tuning different buckets don't clobber each other (last writer wins per
-key, which is fine: both wrote a measured winner).
+— load_winner never raises — and a failed write is a logged warning,
+never an error.  Writes are atomic (tmp + os.replace) and
+read-merge-write so concurrent processes tuning different buckets don't
+clobber each other (last writer wins per key, which is fine: both wrote
+a measured winner).
 
 CYCLONUS_AUTOTUNE_CACHE: cache file path; "0"/"" disables persistence
 entirely (the test suite default — tests/conftest.py — so suites never
-share state through the user's home); unset -> the per-user default
-below.
+share state through the checkout's cache); unset -> `autotune.json`
+under the engine's cache_root() ($JAX_COMPILATION_CACHE_DIR, else the
+checkout's fixed .cache/jax).
 """
 
 from __future__ import annotations
@@ -46,20 +47,18 @@ CACHE_VERSION = 1
 #: reader — it re-searches instead)
 KNOWN_KERNELS = ("default", "slab", "packed")
 
-_DEFAULT_PATH = os.path.join(
-    "~", ".cache", "cyclonus_tpu", "autotune.json"
-)
-
-
 def cache_path() -> Optional[str]:  # never-raises
     """Resolved cache file path, or None when persistence is disabled."""
     raw = os.environ.get("CYCLONUS_AUTOTUNE_CACHE")
     if raw is None:
-        raw = _DEFAULT_PATH
+        from . import cache_root
+
+        # cache_root is checked as never-raising in its own module
+        return os.path.join(cache_root(), "autotune.json")  # cachelint: ignore[CC005]
     raw = raw.strip()
     if raw in ("", "0"):
         return None
-    return os.path.expanduser(raw)
+    return raw
 
 
 def make_key(

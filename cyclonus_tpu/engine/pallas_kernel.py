@@ -670,9 +670,10 @@ PACKED_BD = 512
 #: re-checked at call time
 PACKED_TILE_CANDIDATES = ((512, 512), (1024, 512), (2048, 1024))
 
-#: fused-tier unroll ceiling: the min-key loop unrolls statically over
-#: the bucketed rule rows, so a pathological ANP set must fall back to
-#: the XLA tile loop instead of tracing an unbounded program
+#: fused-tier rule-row ceiling: the min-key loop is a rolled fori_loop
+#: (one [BS, BD] body whatever the row count), so the ceiling bounds
+#: only the per-step loop trips; past it tiered counts route to the XLA
+#: tile loop
 PACKED_TIER_MAX_ROWS = 1024
 
 
@@ -681,9 +682,61 @@ def _sub8(n: int) -> int:
     return -(-max(int(n), 1) // 8) * 8
 
 
-def _sub32(n: int) -> int:
-    """Round up to the int8 sublane tile (32)."""
-    return -(-max(int(n), 1) // 32) * 32
+#: Mosaic's default scoped-VMEM limit on the v5e; a kernel whose own
+#: arithmetic needs more asks for it (CompilerParams.vmem_limit_bytes)
+_SCOPED_VMEM_DEFAULT = 16 * 2**20
+
+
+def _packed_compiler_params(blocks, bs: int, bd: int, tiered: bool):
+    """CompilerParams raising the scoped-VMEM limit to what the packed
+    kernel at this tile needs, or None when the default holds it.  The
+    need is the double-buffered input blocks plus the live [BS, BD]
+    int32 planes: two OR-accumulators and two epilogue temporaries,
+    and with tiers two carried key planes and two loop temporaries
+    more.  The (2048, 1024) tile needs 25.08 MiB by the compiler's own
+    count (this bound says 36 MiB); the smaller tiles stay under the
+    default and pass no params."""
+    planes = 8 if tiered else 4
+    need = 2 * sum(4 * math.prod(shape) for shape, _ in blocks) + (
+        planes * 4 * bs * bd
+    )
+    if need <= _SCOPED_VMEM_DEFAULT:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=need)
+
+
+def _tier_min_keys(src_words, dst_row, anp_ref, banp_ref, rows: int, shape):
+    """([BS, BD], [BS, BD]) int32 min ANP / BANP key over the `rows`
+    tier rule rows matching each (src, dst) cell — the kernel-local
+    twin of kernel.tier_first_match_keys.  The rule axis arrives
+    bit-packed 32-per-word on BOTH sides: `src_words` [BS, Wl] holds
+    the src pods' scope bits with words on the lane axis; `dst_row(w)`
+    loads word w of the dst pods' bits as a [1, BD] row.  One rolled
+    loop: a static unroll gives every rule row its own [BS, BD]
+    temporaries on Mosaic's scoped-VMEM stack (45 MiB at 64 rows
+    against the 16 MiB limit) and compile time grows with it."""
+    from .encoding import PACK_BITS, TIER_KEY_NONE
+
+    none = jnp.int32(TIER_KEY_NONE)
+    lane = jax.lax.broadcasted_iota(jnp.int32, src_words.shape, 1)
+
+    def body(g, keys):
+        anp, banp = keys
+        w = g // PACK_BITS
+        b = g % PACK_BITS
+        # word w of every src pod: a one-hot lane reduce, because a
+        # dynamic LANE slice does not lower (the dst side indexes the
+        # sublane axis, which does)
+        col = jnp.sum(
+            jnp.where(lane == w, src_words, 0), axis=1, keepdims=True
+        )  # [BS, 1]
+        m = (((col >> b) & 1) & ((dst_row(w) >> b) & 1)) != 0  # [BS, BD]
+        anp = jnp.minimum(anp, jnp.where(m, anp_ref[g], none))
+        banp = jnp.minimum(banp, jnp.where(m, banp_ref[g], none))
+        return anp, banp
+
+    init = jnp.full(shape, none, dtype=jnp.int32)
+    return jax.lax.fori_loop(0, rows, body, (init, init))
 
 
 def _make_packed_kernel(
@@ -691,14 +744,13 @@ def _make_packed_kernel(
 ):
     """Packed single-chunk kernel body, specialized on the per-direction
     word depths, the tier rule-row counts, and the epilogue variant.
-    Word and rule loops unroll statically (n_w <= ~33; g bounded by
-    PACKED_TIER_MAX_ROWS at the eligibility gate)."""
+    The word loops unroll statically (n_w <= ~33); the tier rule loop
+    is rolled (_tier_min_keys)."""
     ti.KERNEL_TRACES.inc(
         kernel="counts_packed"
         + ("_tiered" if tiered else "")
         + ("_weighted" if weighted else "")
     )
-    from .encoding import TIER_KEY_NONE
 
     def _kernel(*refs):
         idx = 0
@@ -711,10 +763,11 @@ def _make_packed_kernel(
         a_i_ref = refs[idx + 3]  # [Wi_s, BD] i32 — tmatch_i words + flags row
         idx += 4
         if tiered:
-            subj_e_ref = refs[idx]  # [BS, Ge_l] i8
-            peerq_e_ref = refs[idx + 1]  # [1, Ge_s, BD] i8
-            subj_i_ref = refs[idx + 2]  # [Gi_s, BD] i8
-            peerq_i_ref = refs[idx + 3]  # [1, BS, Gi_l] i8
+            # tier scope bits, rule axis packed 32-per-word
+            subj_e_ref = refs[idx]  # [BS, Ge_l] i32
+            peerq_e_ref = refs[idx + 1]  # [1, Ge_s, BD] i32
+            subj_i_ref = refs[idx + 2]  # [Gi_s, BD] i32
+            peerq_i_ref = refs[idx + 3]  # [1, BS, Gi_l] i32
             idx += 4
         if weighted:
             w_ref = refs[idx]  # [8, BD] f32 (row 0 real)
@@ -759,22 +812,19 @@ def _make_packed_kernel(
             # fused tier min-key first-match epilogue: the same fold as
             # kernel.tier_first_match_keys, with rule keys read from
             # scalar prefetch and the [g, BS, BD] intermediates never
-            # leaving registers (the HBM round trip this fusion kills)
-            none = jnp.int32(TIER_KEY_NONE)
-            anp_e = jnp.full(egress.shape, none, dtype=jnp.int32)
-            banp_e = jnp.full(egress.shape, none, dtype=jnp.int32)
-            for g in range(g_e):
-                m = (subj_e_ref[:, g : g + 1] & peerq_e_ref[0, g : g + 1, :]) != 0
-                anp_e = jnp.minimum(anp_e, jnp.where(m, anp_e_ref[g], none))
-                banp_e = jnp.minimum(banp_e, jnp.where(m, banp_e_ref[g], none))
+            # leaving VMEM (the HBM round trip this fusion kills)
+            anp_e, banp_e = _tier_min_keys(
+                subj_e_ref[:],
+                lambda w: peerq_e_ref[0, pl.ds(w, 1), :],
+                anp_e_ref, banp_e_ref, g_e, egress.shape,
+            )
             egress = resolve_tier_lattice_packed(egress, has_s, anp_e, banp_e)
-            anp_i = jnp.full(ingress.shape, none, dtype=jnp.int32)
-            banp_i = jnp.full(ingress.shape, none, dtype=jnp.int32)
-            for g in range(g_i):
-                # ingress subjects are the DST pods, peers the SRC pods
-                m = (peerq_i_ref[0, :, g : g + 1] & subj_i_ref[g : g + 1, :]) != 0
-                anp_i = jnp.minimum(anp_i, jnp.where(m, anp_i_ref[g], none))
-                banp_i = jnp.minimum(banp_i, jnp.where(m, banp_i_ref[g], none))
+            # ingress subjects are the DST pods, peers the SRC pods
+            anp_i, banp_i = _tier_min_keys(
+                peerq_i_ref[0],
+                lambda w: subj_i_ref[pl.ds(w, 1), :],
+                anp_i_ref, banp_i_ref, g_i, ingress.shape,
+            )
             ingress = resolve_tier_lattice_packed(ingress, has_d, anp_i, banp_i)
 
         combined = egress & ingress
@@ -846,18 +896,13 @@ def resolve_tier_lattice_packed(np_allowed, has_b, anp_min, banp_min):
 
     anp_act = jnp.where(anp_min < TIER_KEY_NONE, anp_min % 4, TIER_ACT_NONE)
     banp_act = jnp.where(banp_min < TIER_KEY_NONE, banp_min % 4, TIER_ACT_NONE)
-    below = jnp.where(
-        has_b,
-        np_allowed,
-        jnp.where(
-            banp_act == TIER_ACT_NONE, True, banp_act == TIER_ACT_ALLOW
-        ),
+    # boolean algebra, not jnp.where: Mosaic has no select over i1
+    # VALUES (it widens them to i8 and cannot truncate back)
+    below = (has_b & np_allowed) | (
+        ~has_b & ((banp_act == TIER_ACT_NONE) | (banp_act == TIER_ACT_ALLOW))
     )
-    return jnp.where(
-        (anp_act == TIER_ACT_NONE) | (anp_act == TIER_ACT_PASS),
-        below,
-        anp_act == TIER_ACT_ALLOW,
-    )
+    defer = (anp_act == TIER_ACT_NONE) | (anp_act == TIER_ACT_PASS)
+    return (defer & below) | (~defer & (anp_act == TIER_ACT_ALLOW))
 
 
 def packed_tier_eligible(tensors: Dict) -> bool:
@@ -990,22 +1035,23 @@ def _verdict_counts_pallas_packed(
     ]
     prefetch = []
     if tiered:  # jaxlint: ignore[JX002] — static structure branch
+        from .encoding import packed_words
+        from .kernel import pack_bool_words_jnp
+
         te, ti_ = tier["egress"], tier["ingress"]
-        ge_l = lane_round_up(g_e)  # tile: 128
-        ge_s = _sub32(g_e)
-        gi_l = lane_round_up(g_i)  # tile: 128
-        gi_s = _sub32(g_i)
+        # the rule axis packs 32-per-word like the target axis: SRC-side
+        # bits put the words on the lane axis, DST-side on the sublanes
+        ge_l = lane_round_up(packed_words(g_e))  # tile: 128
+        ge_s = _sub8(packed_words(g_e))
+        gi_l = lane_round_up(packed_words(g_i))  # tile: 128
+        gi_s = _sub8(packed_words(g_i))
         subj_e = _pad_to(
-            _pad_to(
-                jnp.where(vs, te["subj"], False).T.astype(jnp.int8), 1, ge_l
-            ),
-            0,
-            bs,
+            _pad_to(pack_bool_words_jnp(te["subj"] & vs).T, 1, ge_l), 0, bs
         )  # [Ns', Ge_l]
         peerq_e = _pad_to(
             _pad_to(
                 jnp.moveaxis(
-                    (te["peerq"] & vd[:, :, None]).astype(jnp.int8), 2, 0
+                    pack_bool_words_jnp(te["peerq"] & vd[:, :, None]), 2, 0
                 ),
                 1,
                 ge_s,
@@ -1014,16 +1060,12 @@ def _verdict_counts_pallas_packed(
             bd,
         )  # [Q, Ge_s, Nd']
         subj_i = _pad_to(
-            _pad_to(
-                jnp.where(vd, ti_["subj"], False).astype(jnp.int8), 0, gi_s
-            ),
-            1,
-            bd,
+            _pad_to(pack_bool_words_jnp(ti_["subj"] & vd), 0, gi_s), 1, bd
         )  # [Gi_s, Nd']
         peerq_i = _pad_to(
             _pad_to(
                 jnp.transpose(
-                    (ti_["peerq"] & vs[:, :, None]).astype(jnp.int8),
+                    pack_bool_words_jnp(ti_["peerq"] & vs[:, :, None]),
                     (2, 1, 0),
                 ),
                 1,
@@ -1066,6 +1108,7 @@ def _verdict_counts_pallas_packed(
         + 4 * q * n_i * bs * wi_l,
         transcendentals=0,
     )
+    params = _packed_compiler_params(blocks, bs, bd, tiered)
     if tiered:  # jaxlint: ignore[JX002] — static structure branch
 
         def _with_prefetch(m):
@@ -1087,6 +1130,7 @@ def _verdict_counts_pallas_packed(
             grid_spec=grid_spec,
             out_shape=out_shape,
             cost_estimate=cost,
+            compiler_params=params,
             interpret=interpret,
         )(*prefetch, *operands)
     else:
@@ -1098,6 +1142,7 @@ def _verdict_counts_pallas_packed(
             scratch_shapes=scratch,
             out_shape=out_shape,
             cost_estimate=cost,
+            compiler_params=params,
             interpret=interpret,
         )(*operands)
     return out[:, :, :3]
@@ -1269,7 +1314,8 @@ def verdict_counts_pallas_slab(
     caller gates on the byte estimate).  This composed form rebuilds
     them per dispatch; steady-state callers should build them once with
     slab_operands and dispatch verdict_counts_pallas_slab_from_ops
-    (r5 measured the rebuild at more than the depth cut's savings).
+    (rebuild cost vs the depth cut's savings: not measured on the
+    current machine).
     The alternative (scalar-prefetch block maps into the original
     arrays, like the general kernel's nz redirects) avoids the copies
     and the cap, but block index maps are w-ALIGNED, so covering an
@@ -1294,10 +1340,8 @@ def slab_operands(
     """The slab path's gathered operands — {a_e, b_e, b_i, a_i} — as a
     SEPARATE traceable stage: they depend only on the precompute and the
     (fixed) window starts, so a steady-state caller can materialize them
-    ONCE and cache them device-resident next to the precompute.  Round-5
-    measurement: rebuilding these per dispatch (the original fused form)
-    cost more than the slab's depth cut saved, flipping the kernel from
-    a ~2x device-time win to a 22% loss."""
+    ONCE and cache them device-resident next to the precompute instead
+    of rebuilding them per dispatch (the original fused form)."""
     return _slab_operands(
         tmatch_e, has_e, tallow_e, tmatch_i, has_i, tallow_i,
         t0_e, t0_i, n_pods,

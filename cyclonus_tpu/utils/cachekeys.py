@@ -91,7 +91,7 @@ def registered() -> Dict[str, RegisteredCache]:
 
 def registered_count() -> int:  # never-raises
     """How many cache families have registered (0 when inactive) — the
-    number bench.py records as detail.cold_start.key_audit."""
+    number bench.py records as detail.key_audit."""
     try:
         with _LOCK:
             return len(_REG)

@@ -65,8 +65,9 @@ _FLAGS = [
     Flag("CYCLONUS_FULL_LOCATIONS", "bool", False, "engine",
          "Keep full jaxpr source locations (debug; bigger traces)."),
     Flag("CYCLONUS_JAX_CACHE", "path", "", "engine",
-         "JAX persistent compilation cache dir; '0' disables, '' picks "
-         "the default dir."),
+         "JAX persistent compilation cache dir; '0' disables, unset "
+         "picks the compile-cache root ($JAX_COMPILATION_CACHE_DIR, "
+         "else .cache/jax in the checkout)."),
     # --- engine: kernels and autotune ---------------------------------
     Flag("CYCLONUS_PALLAS_DTYPE", "enum", "int8", "engine",
          "Pallas counts-kernel operand dtype.",
@@ -91,11 +92,11 @@ _FLAGS = [
     Flag("CYCLONUS_AUTOTUNE_DRAIN_S", "float", 5.0, "engine",
          "Grace period for an orphaned autotune candidate thread."),
     Flag("CYCLONUS_AUTOTUNE_CACHE", "path", "", "engine",
-         "Autotune result cache file; '0' disables, '' picks the "
-         "default path."),
+         "Autotune result cache file; '0' disables, unset picks "
+         "autotune.json under the compile-cache root."),
     Flag("CYCLONUS_AOT_CACHE", "path", "", "engine",
-         "Persistent AOT executable cache dir; '0' disables, '' picks "
-         "the default dir."),
+         "Persistent AOT executable cache dir; '0' disables, unset "
+         "picks aot/ under the compile-cache root."),
     # --- engine: CIDR pre-classification ------------------------------
     Flag("CYCLONUS_CIDR_TSS", "enum", "auto", "engine",
          "TSS/LPM CIDR pre-classification: 'auto' (spec floor), '1', "
@@ -135,9 +136,6 @@ _FLAGS = [
          "Probe with native sockets instead of agnhost exec."),
     Flag("CYCLONUS_SOURCE_IP", "str", "", "worker",
          "Source IP override for native probes."),
-    # --- probe ----------------------------------------------------------
-    Flag("CYCLONUS_BACKEND_TIMEOUT_S", "float", 75.0, "probe",
-         "Probe-backend request timeout."),
     # --- chaos ----------------------------------------------------------
     Flag("CYCLONUS_CHAOS", "str", "", "chaos",
          "Fault-injection spec armed for the chaos harness."),

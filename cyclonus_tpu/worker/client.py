@@ -4,9 +4,8 @@ batch, kubectl-exec the in-pod worker, parse its stdout.
 Wire robustness (docs/DESIGN.md "Cold start & chaos"): each batch issue
 is BOUNDED (CYCLONUS_WORKER_TIMEOUT_S; a worker pod that dies mid-exec
 must cost a timeout, never a wedged driver thread) and RETRIED with the
-one canonical full-jitter backoff (utils/retry.py — the same envelope
-the backend-init and tunnel probes use), CYCLONUS_WORKER_RETRIES extra
-attempts.  Probes are idempotent connection attempts, so a re-issued
+one canonical full-jitter backoff (utils/retry.py),
+CYCLONUS_WORKER_RETRIES extra attempts.  Probes are idempotent connection attempts, so a re-issued
 batch re-measures, it never double-commits.  Every retry counts into
 cyclonus_tpu_worker_retries_total; the final failure raises KubeError
 carrying the last error.  The chaos layer's `worker_wire` /

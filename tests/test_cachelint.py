@@ -663,7 +663,7 @@ class TestCC005NeverRaise:
 
 CACHE_PACKAGES = [
     os.path.join(REPO, "cyclonus_tpu", p)
-    for p in ("engine", "serve", "perfobs", "chaos")
+    for p in ("engine", "serve", "chaos")
 ]
 
 
@@ -873,7 +873,7 @@ class TestLintBudget:
             os.path.join(REPO, "cyclonus_tpu", p)
             for p in (
                 "engine", "telemetry", "worker", "analysis", "probe",
-                "perfobs", "serve", "tiers", "chaos", "linter", "recipes",
+                "serve", "tiers", "chaos", "linter", "recipes",
             )
         ]
         for f in jaxlint.iter_py_files(jax_paths):
@@ -884,7 +884,7 @@ class TestLintBudget:
                 os.path.join(REPO, "cyclonus_tpu", p)
                 for p in (
                     "engine", "analysis", os.path.join("worker", "model.py"),
-                    "perfobs", "serve", "tiers", "chaos", "linter", "recipes",
+                    "serve", "tiers", "chaos", "linter", "recipes",
                 )
             ]
         )

@@ -174,6 +174,26 @@ class TestServeWarmup:
         assert svc.ready
         assert "compile exploded" in (pw["error"] or "")
 
+    def test_serve_cli_exits_nonzero_when_prewarm_fails(
+        self, monkeypatch, capsys
+    ):
+        """A replica whose query programs cannot initialise, compile or
+        run is dead, not degraded: `cyclonus-tpu serve` reports the
+        prewarm error and exits 1 before it reads a single batch."""
+        import cyclonus_tpu.cli
+
+        def boom(self, *a, **k):
+            raise RuntimeError("Unable to initialize backend 'tpu'")
+
+        monkeypatch.setattr(
+            "cyclonus_tpu.engine.TpuPolicyEngine.evaluate_pairs", boom
+        )
+        rc = cyclonus_tpu.cli.main(["serve", "--synthetic-pods", "8"])
+        err = capsys.readouterr().err
+        assert rc == 1
+        assert "serve: prewarm failed: RuntimeError: Unable to initialize" in err
+        assert "engine ready" not in err
+
     def test_state_counts_degraded_queries(self):
         from cyclonus_tpu.serve import VerdictService
         from cyclonus_tpu.worker.model import FlowQuery

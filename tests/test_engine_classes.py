@@ -599,80 +599,6 @@ class TestModeSelection:
         assert evals.get((("path", "counts"),), 0) >= 1
 
 
-class TestPerfobsClassRatio:
-    """Satellite: class_compression_ratio rides every bench line into
-    the ledger, surfaces in the report, and the sentinel WARNS (never
-    fails) on a >2x degradation."""
-
-    def test_ledger_parses_ratio(self, tmp_path):
-        from cyclonus_tpu.perfobs.ledger import ingest_bench
-
-        p = tmp_path / "BENCH_r90.json"
-        p.write_text(
-            json.dumps(
-                {
-                    "metric": "m",
-                    "value": 1000,
-                    "unit": "cells/sec",
-                    "failure_class": "ok",
-                    "detail": {"class_compression": {"ratio": 12.5}},
-                }
-            )
-        )
-        run = ingest_bench(str(p))
-        assert run.class_compression_ratio == 12.5
-        assert run.to_dict()["class_compression_ratio"] == 12.5
-
-    def test_sentinel_warns_not_fails_on_degradation(self):
-        from cyclonus_tpu.perfobs.ledger import Ledger
-        from cyclonus_tpu.perfobs.schema import PerfRun
-        from cyclonus_tpu.perfobs.sentinel import gate
-
-        def run(i, ratio):
-            return PerfRun(
-                run_id=f"r{i:02d}", kind="bench", source="x",
-                failure_class="ok", ok=True, n=i,
-                cells_per_sec=1e9, warmup_s=5.0,
-                class_compression_ratio=ratio,
-            )
-
-        led = Ledger([run(1, 20.0), run(2, 22.0), run(3, 5.0)])
-        result = gate(led)
-        assert result.status == "pass"  # warn, never fail
-        assert any(
-            "class_compression_ratio degraded" in n for n in result.notes
-        )
-        # no degradation, no warning
-        led2 = Ledger([run(1, 20.0), run(2, 22.0), run(3, 19.0)])
-        r2 = gate(led2)
-        assert not any(
-            "class_compression_ratio" in n for n in r2.notes
-        )
-
-    def test_report_surfaces_ratio(self):
-        from cyclonus_tpu.perfobs import report as perf_report
-        from cyclonus_tpu.perfobs.ledger import Ledger
-        from cyclonus_tpu.perfobs.schema import PerfRun
-
-        led = Ledger(
-            [
-                PerfRun(
-                    run_id="r01", kind="bench", source="x",
-                    failure_class="ok", ok=True, n=1,
-                    cells_per_sec=1e9, class_compression_ratio=25.0,
-                )
-            ]
-        )
-        md = perf_report.render_markdown(led)
-        assert "25x" in md
-        doc = perf_report.trend(led)
-        assert doc["class_compression"] == [{"run": "r01", "ratio": 25.0}]
-        perf_report.publish(led)
-        snap = perf_report.REGISTRY.snapshot()
-        fam = snap["cyclonus_tpu_perf_class_compression_ratio"]
-        assert any(s["value"] == 25.0 for s in fam["samples"])
-
-
 class TestCompressedEvaluatorCoverage:
     """The sharded grid/counts compressed routes agree with dense (the
     xla parity lives in TestCompressedParity; this pins the mesh legs +

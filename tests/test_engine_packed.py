@@ -454,7 +454,7 @@ class TestPersistedAutotune:
         cache, policy, pods, namespaces = self._tuned_engine(
             monkeypatch, tmp_path, seed=36
         )
-        # truncated JSON — the tunnel_wait discipline: degrade, don't die
+        # truncated JSON: degrade, do not die
         cache.write_text('{"v": 1, "entries": {"x": {"winn')
         engine = TpuPolicyEngine(policy, pods, namespaces)
         want = engine.evaluate_grid_counts(CASES, block=8, backend="xla")

@@ -51,6 +51,39 @@ def synthetic_engine(n_pods, n_pols=6, seed=3, **kw):
     return TpuPolicyEngine(policy, pods, namespaces, **kw), policy, pods
 
 
+class TestDefaultMesh:
+    def test_never_cpu_devices_when_the_default_backend_is_not_the_cpu(
+        self, monkeypatch
+    ):
+        """A one-chip accelerator gets a one-device mesh of ITS device:
+        the virtual CPU mesh this suite runs on is never substituted, or
+        `--engine tpu-sharded` would compute on the host and say
+        nothing."""
+        import jax
+
+        class Chip:
+            platform, device_kind, id, process_index = "tpu", "fake", 0, 0
+
+        chip = Chip()
+        cpu_devices = jax.devices("cpu")
+        assert len(cpu_devices) > 1  # the substitution was on offer
+
+        def devices(backend=None):
+            return cpu_devices if backend == "cpu" else [chip]
+
+        monkeypatch.setattr(sharded_mod.jax, "devices", devices)
+        monkeypatch.setattr(
+            sharded_mod, "Mesh", lambda devs, axes: (list(devs.flat), axes)
+        )
+        assert sharded_mod.default_mesh() == ([chip], ("x",))
+
+    def test_all_devices_of_the_default_backend(self):
+        import jax
+
+        mesh = sharded_mod.default_mesh()
+        assert list(mesh.devices.flat) == list(jax.devices())
+
+
 class TestRingParity:
     @pytest.mark.parametrize("n_dev", [1, 2, 4, 8])
     @pytest.mark.parametrize("seed", [0, 3])

@@ -74,7 +74,7 @@ as tools/jaxlint.py / locklint.py / shapelint.py).
 
 Usage: python tools/cachelint.py [paths...]
        (default: cyclonus_tpu/engine cyclonus_tpu/serve
-        cyclonus_tpu/perfobs cyclonus_tpu/chaos)
+        cyclonus_tpu/chaos)
 Exit status 1 iff findings remain.
 """
 
@@ -1466,7 +1466,6 @@ def lint_paths(paths: List[str]) -> Tuple[List[Finding], Dict[str, int]]:
 DEFAULT_PATHS = [
     "cyclonus_tpu/engine",
     "cyclonus_tpu/serve",
-    "cyclonus_tpu/perfobs",
     "cyclonus_tpu/chaos",
 ]
 

@@ -28,7 +28,7 @@ LEGS: Tuple[Tuple[str, List[str], List[str]], ...] = (
         "jaxlint",
         ["cyclonus_tpu/engine", "cyclonus_tpu/telemetry",
          "cyclonus_tpu/worker", "cyclonus_tpu/analysis",
-         "cyclonus_tpu/probe", "cyclonus_tpu/perfobs",
+         "cyclonus_tpu/probe",
          "cyclonus_tpu/serve", "cyclonus_tpu/tiers", "cyclonus_tpu/chaos",
          "cyclonus_tpu/linter", "cyclonus_tpu/recipes", "cyclonus_tpu/slo",
          "cyclonus_tpu/audit"],
@@ -38,12 +38,12 @@ LEGS: Tuple[Tuple[str, List[str], List[str]], ...] = (
     (
         "shapelint",
         ["cyclonus_tpu/engine", "cyclonus_tpu/analysis",
-         "cyclonus_tpu/worker/model.py", "cyclonus_tpu/perfobs",
+         "cyclonus_tpu/worker/model.py",
          "cyclonus_tpu/serve", "cyclonus_tpu/tiers", "cyclonus_tpu/chaos",
          "cyclonus_tpu/linter", "cyclonus_tpu/recipes", "cyclonus_tpu/slo",
          "cyclonus_tpu/audit"],
         ["cyclonus_tpu/engine", "cyclonus_tpu/analysis",
-         "cyclonus_tpu/worker/model.py", "cyclonus_tpu/perfobs",
+         "cyclonus_tpu/worker/model.py",
          "cyclonus_tpu/serve", "cyclonus_tpu/tiers", "cyclonus_tpu/chaos",
          "cyclonus_tpu/linter", "cyclonus_tpu/recipes", "cyclonus_tpu/slo",
          "cyclonus_tpu/audit"],
@@ -51,11 +51,9 @@ LEGS: Tuple[Tuple[str, List[str], List[str]], ...] = (
     (
         "cachelint",
         ["cyclonus_tpu/engine", "cyclonus_tpu/serve",
-         "cyclonus_tpu/perfobs", "cyclonus_tpu/chaos",
-         "cyclonus_tpu/audit"],
+         "cyclonus_tpu/chaos", "cyclonus_tpu/audit"],
         ["cyclonus_tpu/engine", "cyclonus_tpu/serve",
-         "cyclonus_tpu/perfobs", "cyclonus_tpu/chaos",
-         "cyclonus_tpu/audit"],
+         "cyclonus_tpu/chaos", "cyclonus_tpu/audit"],
     ),
     (
         "planlint",
