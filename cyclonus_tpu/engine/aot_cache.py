@@ -49,6 +49,7 @@ import pickle
 import tempfile
 from typing import Any, Dict, Optional, Tuple
 
+from ..telemetry.spans import span
 from ..utils import cachekeys
 
 log = logging.getLogger(__name__)
@@ -335,9 +336,12 @@ class AotProgram:
         Adopted executables never trace, so they never count."""
         return self._jitted._cache_size()
 
-    def __call__(self, *args, **kwargs):
-        if cache_dir() is None:
-            return self._jitted(*args, **kwargs)
+    def _program(self, args, kwargs):
+        """(key, dynamic kwargs, the executable or None=fallback) for
+        this call's signature, obtaining the executable first where the
+        signature is new: span `engine.program`, attr `how` = adopted
+        (from the persistent cache), built (lowered and compiled here)
+        or fallback."""
         statics = tuple(
             (k, kwargs[k]) for k in self._static_argnames if k in kwargs
         )
@@ -347,8 +351,22 @@ class AotProgram:
         key = (call_key(args, dyn_kwargs), statics)
         if key not in self._programs:
             sig = signature_string(key[0]) + "|" + repr(statics)
-            self._programs[key] = self._resolve(sig, args, kwargs)
-        compiled = self._programs[key]
+            with span("engine.program", program=self._name) as sp:
+                self._programs[key] = self._resolve(sig, args, kwargs, sp)
+        return key, dyn_kwargs, self._programs[key]
+
+    def resolve(self, *args, **kwargs) -> None:
+        """Obtain the executable for these arguments without running it,
+        so that a caller can time getting the program apart from the
+        dispatch that follows.  With the persistent cache off there is
+        nothing to obtain: the plain jit traces at its first call."""
+        if cache_dir() is not None:
+            self._program(args, kwargs)
+
+    def __call__(self, *args, **kwargs):
+        if cache_dir() is None:
+            return self._jitted(*args, **kwargs)
+        key, dyn_kwargs, compiled = self._program(args, kwargs)
         if compiled is None:
             return self._jitted(*args, **kwargs)
         try:
@@ -360,7 +378,7 @@ class AotProgram:
             self._programs[key] = None
             return self._jitted(*args, **kwargs)
 
-    def _resolve(self, sig: str, args, kwargs):
+    def _resolve(self, sig: str, args, kwargs, sp):
         from ..telemetry import instruments as ti
 
         key = make_key(
@@ -372,8 +390,10 @@ class AotProgram:
             compiled = None
         if compiled is not None:
             ti.AOT_CACHE.inc(outcome="hit")
+            sp.set(how="adopted")
             return compiled
         ti.AOT_CACHE.inc(outcome="miss")
+        sp.set(how="built")
         try:
             compiled = self._jitted.lower(*args, **kwargs).compile()
             ti.AOT_COMPILES.inc()
@@ -383,6 +403,7 @@ class AotProgram:
             # here on for this signature
             log.info("aot lower/compile fallback for %s: %s", self._name, e)
             ti.AOT_CACHE.inc(outcome="fallback")
+            sp.set(how="fallback")
             return None
         store(key, compiled)
         return compiled

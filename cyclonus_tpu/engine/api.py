@@ -19,8 +19,9 @@ import numpy as np
 from ..kube.ipaddr import is_ip_address_match_for_ip_block
 from ..matcher.core import Policy
 from ..telemetry import instruments as ti
+from ..telemetry.spans import evaluation
 from ..utils import guards
-from ..utils.tracing import phase
+from ..utils.tracing import detail, phase
 from . import aot_cache, planspec
 from .encoding import (
     PEER_IP,
@@ -50,9 +51,15 @@ class GridVerdict:
     views materialize lazily on first access,
     and `gather` fetches individual cells with one device-side take."""
 
-    def __init__(self, pod_keys, port_cases, ingress_dev, egress_dev, combined_dev):
+    def __init__(
+        self, pod_keys, port_cases, ingress_dev, egress_dev, combined_dev,
+        eval_id: Optional[int] = None,
+    ):
         self.pod_keys: List[str] = pod_keys
         self.port_cases: List[PortCase] = port_cases
+        # the evaluation's number (instruments.eval_flight): the fetch
+        # spans run after evaluate_grid has returned and carry it too
+        self.eval_id = eval_id
         # device arrays: ingress [Q, N_dst, N_src]; egress/combined
         # [Q, N_src, N_dst]
         self.ingress_dev = ingress_dev
@@ -61,17 +68,26 @@ class GridVerdict:
         self._np: Dict[str, np.ndarray] = {}
 
     def block_until_ready(self) -> "GridVerdict":
-        for a in (self.ingress_dev, self.egress_dev, self.combined_dev):
-            if hasattr(a, "block_until_ready"):
-                a.block_until_ready()
+        with evaluation(self.eval_id), phase("grid.wait"):
+            for a in (self.ingress_dev, self.egress_dev, self.combined_dev):
+                if hasattr(a, "block_until_ready"):
+                    a.block_until_ready()
         return self
 
     def _materialize(self, name: str) -> np.ndarray:
         if name not in self._np:
-            # NB: JAX dispatch is async, so this fetch phase also absorbs
-            # any still-running device execution time (see engine.dispatch)
-            with phase("grid.fetch"):
-                self._np[name] = np.asarray(getattr(self, name + "_dev"))
+            dev = getattr(self, name + "_dev")
+            with evaluation(self.eval_id), phase("grid.fetch", table=name):
+                # JAX dispatch is async: grid.wait is what the device
+                # still had to run, grid.copy the transfer and the
+                # host's own work on the buffer, timed apart (np.asarray
+                # alone would wait first and copy then, all the same)
+                with phase("grid.wait"):
+                    if hasattr(dev, "block_until_ready"):
+                        dev.block_until_ready()
+                with phase("grid.copy") as sp:
+                    out = self._np[name] = np.asarray(dev)
+                    sp.set(bytes=out.nbytes, dtype=str(out.dtype))
         return self._np[name]
 
     @property
@@ -663,8 +679,9 @@ def _np_leaves(tree):
         yield tree
 
 
-def _pack_tensors(tree):
-    """Pack a numpy pytree into one int32 buffer + an unpack function.
+def _pack_tensors(tree, name: str = "unpack"):
+    """Pack a numpy pytree into one int32 buffer + an unpack function
+    called `name` (a jit of it reads `jit_<name>` in a profile).
 
     Every device_put pays a fixed per-buffer overhead (not measured on
     the current machine), and the tensor dict has ~57 leaves of a few MB
@@ -740,6 +757,7 @@ def _pack_tensors(tree):
         return jtu2.tree_unflatten(treedef, outs)
 
     unpack.metas_by_path = dict(zip(paths, metas))
+    unpack.__name__ = unpack.__qualname__ = name
     return packed, unpack
 
 
@@ -812,10 +830,12 @@ class TpuPolicyEngine:
         if self.tiers is not None:
             self.tiers.validate()
         with phase("engine.encode"):
-            self.encoding: PolicyEncoding = encode_policy(
-                policy, pods, namespaces, tiers=self.tiers
-            )
-            self._tensors = self._build_tensors()
+            with phase("engine.encode_policy", pods=len(pods)):
+                self.encoding: PolicyEncoding = encode_policy(
+                    policy, pods, namespaces, tiers=self.tiers
+                )
+            with phase("engine.build_tensors"):
+                self._tensors = self._build_tensors()
             # one O(S*N) host selector pass serves both consumers: dead-
             # target compaction here and the slab-window plan later
             # (selector and pod axes are unchanged by compaction, only
@@ -857,16 +877,20 @@ class TpuPolicyEngine:
                         self._tensors[direction] = nd
                     self._partition_stats = pstats
                 self._maybe_build_class_state(mode)
-            self._tensors = _bucket_tensors(
-                _sort_targets_by_ns(self._tensors),
-                headroom=self._slab_headroom,
-            )
-            if self._class_state is not None:
-                st = self._class_state
-                st["ctensors"] = _bucket_tensors(
-                    _sort_targets_by_ns(st.pop("ctensors_raw")),
+            with phase("engine.class_tensors"):
+                self._tensors = _bucket_tensors(
+                    _sort_targets_by_ns(self._tensors),
                     headroom=self._slab_headroom,
                 )
+                if self._class_state is not None:
+                    self._class_state["ctensors"] = _bucket_tensors(
+                        _sort_targets_by_ns(
+                            self._class_state.pop("ctensors_raw")
+                        ),
+                        headroom=self._slab_headroom,
+                    )
+            if self._class_state is not None:
+                st = self._class_state
                 # the gather/index tensors the compressed path pins on
                 # device: class map + weights + the compressed tensor
                 # buffer — counted against CYCLONUS_SLAB_MAX_BYTES by
@@ -1122,17 +1146,23 @@ class TpuPolicyEngine:
         # to the pre-TSS signature.
         from . import cidrspace
 
-        space = cidrspace.resolve(
-            self._tensors, mode=self._opt_cidr_tss, n_pods=n
-        )
-        with phase("engine.classify"):
+        with phase("engine.cidrspace"):
+            space = cidrspace.resolve(
+                self._tensors, mode=self._opt_cidr_tss, n_pods=n
+            )
+        with phase("engine.classify") as sp:
             pc = compute_pod_classes(self._tensors, selpod, cidr=space)
+            sp.set(classes=pc.n_classes)
         if mode != "1" and pc.n_classes > int(0.9 * n):
             return  # no real reduction: the second tensor set isn't worth it
+        # engine.class_tensors: the second tensor set (one row a class),
+        # then in __init__ the padding of both sets to their shape buckets
+        with phase("engine.class_tensors"):
+            ctensors_raw = gather_class_pod_rows(self._tensors, pc.class_rep)
         self._class_state = {
             "classes": pc,
             "ratio": n / max(pc.n_classes, 1),
-            "ctensors_raw": gather_class_pod_rows(self._tensors, pc.class_rep),
+            "ctensors_raw": ctensors_raw,
             "aux_bytes": 0,  # finalized after bucketing (engine __init__)
             "last_gather_s": None,
             "cidr": space,
@@ -1243,30 +1273,36 @@ class TpuPolicyEngine:
         """Compressed-tensor twin of _tensors_with_cases: the class-
         representative tensor set + port-case arrays, optionally through
         its own single-buffer device transfer."""
-        q_port, q_name, q_proto = self._port_case_arrays(cases)
         st = self._class_state
-        if device:
-            import jax
+        with detail("engine.case_tensors", device=device):
+            q_port, q_name, q_proto = self._port_case_arrays(cases)
+            if device:
+                import jax
 
-            if self._class_device_tensors is None:
-                buf = self._packed_transfer(
-                    "_class_packed_buf", "_class_unpack", st["ctensors"]
-                )
-                if self._class_unpack_jit is None:
-                    self._class_unpack_jit = aot_cache.AotProgram(
-                        "unpack.classes",
-                        jax.jit(self._class_unpack),
-                        plan=self._aot_plan(
-                            self._metas_digest(self._class_unpack)
-                        ),
+                if self._class_device_tensors is None:
+                    buf = self._packed_transfer(
+                        "_class_packed_buf", "_class_unpack", st["ctensors"],
+                        name="unpack_classes",
                     )
-                self._class_device_tensors = self._class_unpack_jit(buf)
-            tensors = dict(self._class_device_tensors)
-        else:
-            tensors = dict(st["ctensors"])
-        tensors["q_port"] = q_port
-        tensors["q_name"] = q_name
-        tensors["q_proto"] = q_proto
+                    if self._class_unpack_jit is None:
+                        self._class_unpack_jit = aot_cache.AotProgram(
+                            "unpack.classes",
+                            jax.jit(self._class_unpack),
+                            plan=self._aot_plan(
+                                self._metas_digest(self._class_unpack)
+                            ),
+                        )
+                        self._class_unpack_jit.resolve(buf)
+                    with detail("engine.unpack"):
+                        self._class_device_tensors = self._class_unpack_jit(
+                            buf
+                        )
+                tensors = dict(self._class_device_tensors)
+            else:
+                tensors = dict(st["ctensors"])
+            tensors["q_port"] = q_port
+            tensors["q_name"] = q_name
+            tensors["q_proto"] = q_proto
         return tensors
 
     def _class_counts_eligible(self, q: int) -> bool:
@@ -1331,15 +1367,18 @@ class TpuPolicyEngine:
             planspec.record("counts.classes")
             from .tiled import evaluate_grid_counts_classes
 
-            counts, gather_s = evaluate_grid_counts_classes(
-                self._ctensors_with_cases(cases, device=True),
-                pc.n_classes,
-                pc.class_size,
-                n,
-                pack=self._pack,
-            )
+            with ti.eval_flight(
+                "counts.classes", n, len(cases), classes=pc.n_classes
+            ) as fl:
+                counts, gather_s = evaluate_grid_counts_classes(
+                    fl,
+                    self._ctensors_with_cases(cases, device=True),
+                    pc.n_classes,
+                    pc.class_size,
+                    n,
+                    pack=self._pack,
+                )
         st["last_gather_s"] = gather_s
-        ti.CLASS_GATHER_SECONDS.set(gather_s)
         ti.CLASS_EVALS.inc(path="sharded" if sharded else "counts")
         return counts
 
@@ -1361,7 +1400,7 @@ class TpuPolicyEngine:
             len(cases),
             classes=st["classes"].n_classes,
             dispatch_only=True,
-        ):
+        ) as fl:
             tensors = self._ctensors_with_cases(cases, device=True)
             if self._class_of_dev is None:
                 with phase("engine.device_put"):
@@ -1370,15 +1409,18 @@ class TpuPolicyEngine:
                     )
             if self._class_grid_jit is None:
                 pack = self._pack
+
+                def grid_classes(t, co):
+                    return gather_class_grids(
+                        evaluate_grid_kernel(t, pack=pack), co
+                    )
+
                 self._class_grid_jit = aot_cache.AotProgram(
-                    "grid.classes",
-                    jax.jit(
-                        lambda t, co: gather_class_grids(
-                            evaluate_grid_kernel(t, pack=pack), co
-                        )
-                    ),
-                    plan=self._aot_plan(),
+                    "grid.classes", jax.jit(grid_classes), plan=self._aot_plan()
                 )
+                # the executable is obtained here, so that engine.dispatch
+                # below is the call that enqueues and nothing else
+                self._class_grid_jit.resolve(tensors, self._class_of_dev)
             t0 = time.perf_counter()
             with phase("engine.dispatch"):
                 out = self._class_grid_jit(tensors, self._class_of_dev)
@@ -1391,6 +1433,7 @@ class TpuPolicyEngine:
             out["ingress"],
             out["egress"],
             out["combined"],
+            eval_id=fl.eval_id,
         )
 
     def _evaluate_grid_sharded_classes(
@@ -1454,7 +1497,6 @@ class TpuPolicyEngine:
             rs, pc.class_size, pc.n_classes, len(cases), n
         )
         if dt > 0:
-            ti.EVAL_DEVICE_SECONDS.set(dt)
             ti.EVAL_PIPELINED_CELLS_PER_SEC.set(counts["cells"] / dt)
         return dt, counts
 
@@ -1495,17 +1537,18 @@ class TpuPolicyEngine:
             return self._evaluate_grid_classes(cases)
         planspec.record("grid.dense")
         n = self.encoding.cluster.n_pods
-        if self._grid_aot is None:
-            self._grid_aot = aot_cache.AotProgram(
-                "grid",
-                evaluate_grid_kernel,
-                plan=self._aot_plan(),
-                static_argnames=("pack",),
-            )
-        with ti.eval_flight("grid", n, len(cases), dispatch_only=True):
+        with ti.eval_flight("grid", n, len(cases), dispatch_only=True) as fl:
             tensors = self._tensors_with_cases(cases, device=True)
+            if self._grid_aot is None:
+                self._grid_aot = aot_cache.AotProgram(
+                    "grid",
+                    evaluate_grid_kernel,
+                    plan=self._aot_plan(),
+                    static_argnames=("pack",),
+                )
+                self._grid_aot.resolve(tensors, pack=self._pack)
             # dispatch-only timing: jit calls return once enqueued (async);
-            # device execution time lands in grid.fetch / allow_stats
+            # device execution time lands in grid.wait / allow_stats
             t0 = time.perf_counter()
             with phase("engine.dispatch"):
                 out = self._grid_aot(tensors, pack=self._pack)
@@ -1520,48 +1563,59 @@ class TpuPolicyEngine:
             out["ingress"][:, :n, :n],
             out["egress"][:, :n, :n],
             out["combined"][:, :n, :n],
+            eval_id=fl.eval_id,
         )
 
-    def _packed_transfer(self, buf_attr: str, unpack_attr: str, tensors: Dict):
+    def _packed_transfer(
+        self, buf_attr: str, unpack_attr: str, tensors: Dict, name: str
+    ):
         """Single-buffer device copy with per-engine caching (one
         transfer instead of one per leaf — see _pack_tensors)."""
         if getattr(self, buf_attr) is None:
             import jax
 
-            with phase("engine.device_put"):
-                packed, unpack = _pack_tensors(tensors)
+            with phase("engine.device_put") as sp:
+                packed, unpack = _pack_tensors(tensors, name=name)
                 setattr(self, buf_attr, jax.device_put(packed))
                 setattr(self, unpack_attr, unpack)
+                sp.set(bytes=packed.nbytes)
         return getattr(self, buf_attr)
 
     def _ensure_packed(self):
         """Packed device buffer of the caller-order tensors (grid paths)."""
-        return self._packed_transfer("_packed_buf", "_unpack", self._tensors)
+        return self._packed_transfer(
+            "_packed_buf", "_unpack", self._tensors, name="unpack_tensors"
+        )
 
     def _tensors_with_cases(
         self, cases: Sequence[PortCase], device: bool = False
     ) -> Dict:
         """Tensors + port-case arrays.  device=True reuses the packed
         device buffer (paths that don't re-pad the pod axis host-side)."""
-        q_port, q_name, q_proto = self._port_case_arrays(cases)
-        if device:
-            import jax
+        with detail("engine.case_tensors", device=device):
+            q_port, q_name, q_proto = self._port_case_arrays(cases)
+            if device:
+                import jax
 
-            if self._device_tensors is None:
-                buf = self._ensure_packed()
-                if self._unpack_jit is None:
-                    self._unpack_jit = aot_cache.AotProgram(
-                        "unpack",
-                        jax.jit(self._unpack),
-                        plan=self._aot_plan(self._metas_digest(self._unpack)),
-                    )
-                self._device_tensors = self._unpack_jit(buf)
-            tensors = dict(self._device_tensors)
-        else:
-            tensors = dict(self._tensors)
-        tensors["q_port"] = q_port
-        tensors["q_name"] = q_name
-        tensors["q_proto"] = q_proto
+                if self._device_tensors is None:
+                    buf = self._ensure_packed()
+                    if self._unpack_jit is None:
+                        self._unpack_jit = aot_cache.AotProgram(
+                            "unpack",
+                            jax.jit(self._unpack),
+                            plan=self._aot_plan(
+                                self._metas_digest(self._unpack)
+                            ),
+                        )
+                        self._unpack_jit.resolve(buf)
+                    with detail("engine.unpack"):
+                        self._device_tensors = self._unpack_jit(buf)
+                tensors = dict(self._device_tensors)
+            else:
+                tensors = dict(self._tensors)
+            tensors["q_port"] = q_port
+            tensors["q_name"] = q_name
+            tensors["q_proto"] = q_proto
         return tensors
 
     def evaluate_grid_counts(
@@ -2456,8 +2510,6 @@ class TpuPolicyEngine:
         key, slab_ok, slab_args, (q_port, q_name, q_proto), choice = (
             self._steady_state_args(cases)
         )
-        t_dispatch = time.perf_counter()
-        autotuned = False
         if self._pre_cache is not None and self._pre_cache[0] == key:
             # steady state: only the pallas counts kernel runs
             self._pre_cache_misses = 0
@@ -2473,7 +2525,6 @@ class TpuPolicyEngine:
                 and (self._pack or slab_ok)
             )
             if tune_pending:
-                autotuned = True
                 # autotune at the first steady-state call: every
                 # candidate runs from the SAME pinned precompute, so
                 # this times exactly what every later call will execute
@@ -2544,17 +2595,11 @@ class TpuPolicyEngine:
                     buf, self._pod_perm_dev, q_port, q_name, q_proto,
                     np.int32(n), *slab_args,
                 )
-        if not autotuned:
-            # the autotune branch runs synchronous timed executions of
-            # both candidate programs — recording that window as "async
-            # dispatch" would poison the dispatch-vs-device split
-            ti.EVAL_DISPATCH_SECONDS.set(time.perf_counter() - t_dispatch)
         # the [Q, n_tiles, 3] readback is the execution barrier: device
-        # run time lands here, not in the async dispatch above
-        t_execute = time.perf_counter()
+        # run time lands here, not in the async dispatch above (nor in
+        # engine.autotune, whose candidates run synchronously, timed)
         with phase("engine.execute"):
             partials = np.asarray(partials)
-        ti.EVAL_EXECUTE_SECONDS.set(time.perf_counter() - t_execute)
         return sum_partials(partials, len(cases), n)
 
     def _steady_state_args(self, cases: Sequence[PortCase]):
@@ -2714,7 +2759,6 @@ class TpuPolicyEngine:
         # the pipelined rate as a REAL gauge: what a batched caller
         # sustains, vs the sync eval's per-dispatch-round-trip number
         if dt > 0:
-            ti.EVAL_DEVICE_SECONDS.set(dt)
             ti.EVAL_PIPELINED_CELLS_PER_SEC.set(counts["cells"] / dt)
         return dt, counts
 

@@ -51,7 +51,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..telemetry import instruments as ti
 from ..utils import cachekeys
-from ..utils.tracing import phase
+from ..utils.tracing import detail, phase
 from .encoding import TIER_KEY_NONE, pack_enabled
 from .kernel import (
     direction_precompute,
@@ -587,32 +587,14 @@ def _class_rowsums_fused_kernel(
     return jnp.moveaxis(rs[:, :cb, :], 0, 1)  # [Cb, Q, 3]
 
 
-def evaluate_grid_counts_classes(
-    tensors: Dict,
-    n_classes: int,
-    class_size: np.ndarray,
-    n_pods: int,
-    block: int = 1024,
-    pack: bool = None,
-    kernel: str = None,
-) -> Tuple[Dict[str, int], float]:
-    """Allow counts over the FULL N x N x Q grid, evaluated on the
-    compressed C x C class grid and weighted back exactly.  Returns
-    (counts, gather_s) where gather_s is the broadcast-back epilogue
-    (the host weighting) — the cheap gather the compression trades the
-    dense grid for.
-
-    kernel="pallas" (the TPU default when `pack` is on) runs the FUSED
-    packed kernel — contraction + tier lattice + the dst-weighted gather
-    epilogue in one Pallas program; kernel="xla" keeps the fori_loop
-    tile body.  Identical row sums by construction (the fused-vs-split
-    parity test pins them)."""
-    import time as _time
-
+def _class_counts_kernel_choice(tensors: Dict, pack: bool, kernel: str) -> str:
+    """Which class row-sum program runs: "pallas" (the TPU default when
+    `pack` is on) is the FUSED packed kernel — contraction + tier lattice
+    + the dst-weighted gather epilogue in one Pallas program; "xla" keeps
+    the fori_loop tile body.  Identical row sums by construction (the
+    fused-vs-split parity test pins them)."""
     from .pallas_kernel import packed_tier_eligible
 
-    if pack is None:
-        pack = pack_enabled()
     if kernel is None:
         # the same static-unroll ceiling the dense counts route
         # enforces (api._packed_tier_ok): an oversized tier rule axis
@@ -634,27 +616,55 @@ def evaluate_grid_counts_classes(
             "past the static-unroll ceiling; use kernel='xla' or "
             "kernel=None (auto)"
         )
-    q = int(tensors["q_port"].shape[0])
-    w, block, n_tiles = class_rowsums_plan(tensors, n_classes, class_size, block)
-    with ti.eval_flight(
-        "counts.classes", n_pods, q, classes=n_classes, block=block
-    ) as fl:
-        with phase("engine.dispatch"):
-            if kernel == "pallas":
-                from .pallas_kernel import _should_interpret
+    return kernel
 
-                out = _class_rowsums_fused_kernel(
-                    tensors, w, interpret=_should_interpret()
-                )
-            else:
-                out = _class_rowsums_kernel(tensors, w, block, n_tiles, pack)
-        # the readback is the execution barrier (dispatch is async)
-        with phase("engine.execute"):
-            rs = np.asarray(out)
-        t0 = _time.perf_counter()
+
+def evaluate_grid_counts_classes(
+    fl,
+    tensors: Dict,
+    n_classes: int,
+    class_size: np.ndarray,
+    n_pods: int,
+    block: int = 1024,
+    pack: bool = None,
+    kernel: str = None,
+) -> Tuple[Dict[str, int], float]:
+    """Allow counts over the FULL N x N x Q grid, evaluated on the
+    compressed C x C class grid and weighted back exactly, inside the
+    caller's eval_flight `fl` (the engine opens it before it builds
+    `tensors`, so that the flight's `engine.eval` span covers the whole
+    request): plan, dispatch, the readback barrier, the exact host
+    finish.  Returns (counts, gather_s) where gather_s is that finish
+    (the host weighting) — the cheap gather the compression trades the
+    dense grid for.  `kernel`: see _class_counts_kernel_choice."""
+    import time as _time
+
+    if pack is None:
+        pack = pack_enabled()
+    kernel = _class_counts_kernel_choice(tensors, pack, kernel)
+    q = int(tensors["q_port"].shape[0])
+    with detail("engine.plan"):
+        w, block, n_tiles = class_rowsums_plan(
+            tensors, n_classes, class_size, block
+        )
+    fl.set(block=block)
+    with phase("engine.dispatch"):
+        if kernel == "pallas":
+            from .pallas_kernel import _should_interpret
+
+            out = _class_rowsums_fused_kernel(
+                tensors, w, interpret=_should_interpret()
+            )
+        else:
+            out = _class_rowsums_kernel(tensors, w, block, n_tiles, pack)
+    # the readback is the execution barrier (dispatch is async)
+    with phase("engine.execute"):
+        rs = np.asarray(out)
+    t0 = _time.perf_counter()
+    with detail("engine.finish"):
         counts = class_counts_finish(rs, class_size, n_classes, q, n_pods)
-        gather_s = _time.perf_counter() - t0
-        fl.set(cells=counts["cells"])
+    gather_s = _time.perf_counter() - t0
+    fl.set(cells=counts["cells"])
     return counts, gather_s
 
 
@@ -885,8 +895,9 @@ def evaluate_grid_counts_ring(
 #
 # The sync ring path re-transfers the host tensors and re-derives the
 # peer-side bundle every eval; at N chips the per-dispatch overhead is
-# what the single-chip pipelined path already amortizes away (BENCH_r05:
-# dispatch_overhead_s 0.09).  This twin splits the program in two:
+# what the single-chip pipelined path already amortizes away (its size
+# on the mesh is not measured: no cell runs this route yet).  This twin
+# splits the program in two:
 #
 #   seed(tensors) -> (src, ring)   one host->device transfer + the
 #                                  per-shard precompute, device-resident

@@ -13,6 +13,7 @@ from ..kube.netpol import (
     POLICY_TYPE_INGRESS,
     PROTOCOL_TCP,
 )
+from ..telemetry.spans import span
 from .core import (
     ALL_PEERS_PORTS,
     AllNamespaceMatcher,
@@ -40,15 +41,17 @@ def build_network_policies(
     simplify: bool, netpols: List[NetworkPolicy]
 ) -> Policy:
     """builder.go:11-26."""
-    policy = Policy()
-    for netpol in netpols:
-        ingress, egress = build_target(netpol)
-        if ingress is not None:
-            policy.add_target(True, ingress)
-        if egress is not None:
-            policy.add_target(False, egress)
-    if simplify:
-        policy.simplify()
+    with span("matcher.build", policies=len(netpols)) as s:
+        policy = Policy()
+        for netpol in netpols:
+            ingress, egress = build_target(netpol)
+            if ingress is not None:
+                policy.add_target(True, ingress)
+            if egress is not None:
+                policy.add_target(False, egress)
+        if simplify:
+            policy.simplify()
+        s.set(targets=len(policy.ingress) + len(policy.egress))
     return policy
 
 

@@ -23,6 +23,7 @@ from __future__ import annotations
 import json
 from typing import IO, Optional
 
+from ..telemetry.spans import span
 from ..worker.model import Batch
 from .service import AdmissionRejected, VerdictService
 
@@ -45,7 +46,9 @@ def run_stdio(
             reply = handle_line(service, line)
         except Exception as e:  # a bad line must answer, not kill the loop
             reply = {"Error": f"{type(e).__name__}: {e}"}  # wire-emit: Reply
-        out_stream.write(json.dumps(reply) + "\n")
+        with span("serve.codec", side="encode"):
+            encoded = json.dumps(reply) + "\n"
+        out_stream.write(encoded)
         out_stream.flush()
         if max_lines is not None and handled >= max_lines:
             break
@@ -53,7 +56,8 @@ def run_stdio(
 
 
 def handle_line(service: VerdictService, line: str) -> dict:
-    batch = Batch.from_json(line)  # wire-read: Batch
+    with span("serve.codec", side="decode", bytes=len(line)):
+        batch = Batch.from_json(line)  # wire-read: Batch
     reply: dict = {}  # wire-emit: Reply
     if batch.deltas:
         try:

@@ -84,6 +84,31 @@ def _prewarm_pair_cap() -> int:
     return envflags.get_int("CYCLONUS_SERVE_PREWARM_PAIRS")
 
 
+class _lock_wait:
+    """The `serve.lock_wait` span: from asking for the service lock to
+    holding it (an apply holds the lock for its whole batch).
+    `with _lock_wait() as waited, self._lock:` opens it before the lock
+    is asked for and `waited.held()`, first thing inside, closes it; the
+    block's end closes it where the lock was never held, so the thread's
+    span path is restored whatever interrupts the acquisition.  The lock
+    itself stays a plain `with` item for locklint and statelint."""
+
+    __slots__ = ("_span",)
+
+    def __enter__(self) -> "_lock_wait":
+        self._span = phase("serve.lock_wait")
+        self._span.__enter__()
+        return self
+
+    def held(self) -> None:
+        span, self._span = self._span, None
+        if span is not None:
+            span.__exit__(None, None, None)
+
+    def __exit__(self, *exc) -> None:
+        self.held()
+
+
 class AdmissionRejected(Exception):
     """submit() refusal under freshness-budget admission control
     (CYCLONUS_SLO_ENFORCE): the delta batch was NOT enqueued; str(e) is
@@ -515,7 +540,8 @@ class VerdictService:
         """Drain the queue and bring the engine up to date.  Returns a
         report: {applied, mode, seconds, epoch, ...}."""
         t0 = time.perf_counter()
-        with self._lock:
+        with _lock_wait() as waited, self._lock:
+            waited.held()
             deltas, self._queue = self._queue, []
             self._pending_since = None
             ti.SERVE_PENDING.set(0)
@@ -823,9 +849,10 @@ class VerdictService:
             return out
         planspec.record("serve.query.live")
         t0 = time.perf_counter()
-        with self._lock:
-            # host-side span only (serve.query): no device sync inside
-            with phase("serve.query"):
+        # host-side spans only: no device sync inside
+        with phase("serve.query", flows=len(queries)):
+            with _lock_wait() as waited, self._lock:
+                waited.held()
                 out = self._query_locked(queries)
         dt = time.perf_counter() - t0
         nq = max(len(queries), 1)

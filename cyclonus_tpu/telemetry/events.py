@@ -30,8 +30,19 @@ Recording is OFF by default — aggregates are always cheap, events are
 per-occurrence — and costs one module-attribute read per span when off.
 Enable with `enable()` (the --trace-out flags do this) or
 CYCLONUS_TRACE_EVENTS=1 at process start; the ring holds the newest
-CYCLONUS_TRACE_EVENTS_N events (default 8192), so an unbounded run keeps
+CYCLONUS_TRACE_EVENTS_N events (default 32768), so an unbounded run keeps
 a bounded, newest-wins window.
+
+A JAX profiler CAPTURE turns recording on by itself, for as long as it
+runs and only then: spans.span sees the capture, numbers it
+(`begin_capture`) and records its B/E pair tagged with that `capture`
+number and the evaluation's number as `eval_id`, the E event carrying
+`dur_s` (the perf_counter difference the span registry got).
+`capture_spans()` pairs one capture's events back into completed spans —
+the list the benchmark's per-layer readers read, since the same spans
+lie in the capture's own trace as `cyclonus.<name>` annotations.  The
+default capacity holds the benchmark's largest traced window (500
+requests of the counts route at 12 events each) five times over.
 """
 
 from __future__ import annotations
@@ -46,11 +57,17 @@ from ..utils.bounded import BoundedRing
 from . import state
 
 
+_DEFAULT_CAPACITY = 32768
+
+
 def _default_capacity() -> int:
     try:
-        return max(1, int(os.environ.get("CYCLONUS_TRACE_EVENTS_N", "8192")))
+        return max(
+            1,
+            int(os.environ.get("CYCLONUS_TRACE_EVENTS_N", _DEFAULT_CAPACITY)),
+        )
     except ValueError:
-        return 8192
+        return _DEFAULT_CAPACITY
 
 
 RING = BoundedRing(_default_capacity())
@@ -66,6 +83,17 @@ _PID = os.getpid()
 ACTIVE: bool = False
 
 _TRACE: Dict[str, Optional[str]] = {"id": None, "role": "driver"}
+
+# Profiler captures seen so far.  CAPTURE is the number of the capture
+# that is recording NOW (0: none), read and written by the span() hot
+# path through begin_capture/end_capture; _CAPTURE_STARTS keeps, for the
+# newest few captures, the ring's lifetime append count when each was
+# first seen, which is how capture_spans tells a wrapped ring.
+CAPTURE: int = 0
+_CAPTURES_KEPT = 8
+_capture_lock = threading.Lock()
+_capture_seen = 0  # guarded-by: _capture_lock
+_CAPTURE_STARTS: Dict[int, int] = {}  # guarded-by: _capture_lock
 
 
 def enable(trace_id: Optional[str] = None, role: str = "driver") -> str:
@@ -93,11 +121,43 @@ def trace_id() -> Optional[str]:
     return _TRACE["id"]
 
 
+def begin_capture() -> int:
+    """A span found a profiler capture recording and CAPTURE at 0: this
+    is a capture not seen before, and it gets the next number.  (Only
+    spans look, so two captures with no span and no end_capture between
+    them count as one.)"""
+    global CAPTURE, _capture_seen
+    with _capture_lock:
+        if not CAPTURE:
+            _capture_seen += 1
+            _CAPTURE_STARTS[_capture_seen] = RING.appended
+            for old in sorted(_CAPTURE_STARTS)[:-_CAPTURES_KEPT]:
+                del _CAPTURE_STARTS[old]
+            CAPTURE = _capture_seen
+        return CAPTURE
+
+
+def end_capture() -> None:
+    """No capture is recording any more (a span saw none, or the code
+    that stopped one says so): the next one seen is a new capture."""
+    global CAPTURE
+    CAPTURE = 0
+
+
 def record(
-    ph: str, name: str, path: str, attrs: Optional[Dict[str, Any]] = None
+    ph: str,
+    name: str,
+    path: str,
+    attrs: Optional[Dict[str, Any]] = None,
+    *,
+    capture: int = 0,
+    eval_id: Optional[int] = None,
+    dur_s: Optional[float] = None,
 ) -> None:
-    """Append one B/E event (called by spans.span on enter/exit)."""
-    if not (ACTIVE and state.ENABLED):
+    """Append one B/E event (called by spans.span on enter/exit).  With
+    `capture` (the number of the profiler capture the span runs in) the
+    event is kept even while no trace is ACTIVE."""
+    if not ((ACTIVE or capture) and state.ENABLED):
         return
     event: Dict[str, Any] = {
         "ph": ph,
@@ -111,6 +171,12 @@ def record(
     }
     if attrs:
         event["args"] = dict(attrs)
+    if capture:
+        event["capture"] = capture
+    if eval_id is not None:
+        event["eval_id"] = eval_id
+    if dur_s is not None:
+        event["dur_s"] = dur_s
     RING.append(event)
 
 
@@ -156,10 +222,62 @@ def since(marker: int) -> List[Dict[str, Any]]:
     return snap[-min(new, len(snap)):]
 
 
+def capture_spans(capture: Optional[int] = None) -> Dict[str, Any]:
+    """The completed spans of one profiler capture (default: the newest
+    one seen), oldest first:
+
+        {"capture": n, "wrapped": bool,
+         "spans": [{"name", "path", "start_s", "dur_s", "eval_id",
+                    "attrs"}, ...]}
+
+    `start_s` is the B event's epoch time, `dur_s` the E event's
+    perf_counter length, `attrs` the span's final attributes.  A span
+    still open when this is called is left out.  `wrapped` says that the
+    ring dropped events of this capture (its first event is no longer in
+    the window), so sums over `spans` would be short.  Capture 0 (none
+    seen yet) is an empty list."""
+    snap, appended = RING.snapshot_with_count()
+    with _capture_lock:
+        if capture is None:
+            capture = _capture_seen
+        started = _CAPTURE_STARTS.get(capture)
+    spans: List[Dict[str, Any]] = []
+    if not capture:
+        return {"capture": 0, "wrapped": False, "spans": spans}
+    wrapped = started is None or started < appended - len(snap)
+    open_by_thread: Dict[Any, List[Dict[str, Any]]] = {}
+    for e in snap:
+        if e.get("capture") != capture:
+            continue
+        stack = open_by_thread.setdefault((e.get("pid"), e.get("tid")), [])
+        if e["ph"] == "B":
+            stack.append(e)
+            continue
+        # span() records its E in a `finally`, so a B is missing only
+        # where the ring dropped it: reckon that start from the end
+        begin = (
+            stack.pop() if stack and stack[-1]["path"] == e["path"] else None
+        )
+        dur = e.get("dur_s", 0.0)
+        spans.append({
+            "name": e["name"],
+            "path": e["path"],
+            "start_s": begin["ts"] if begin is not None else e["ts"] - dur,
+            "dur_s": dur,
+            "eval_id": e.get("eval_id"),
+            "attrs": dict(e.get("args") or {}),
+        })
+    spans.sort(key=lambda sp: sp["start_s"])
+    return {"capture": capture, "wrapped": wrapped, "spans": spans}
+
+
 def reset() -> None:
     """Clear the window (the active/trace-id state survives — a reset
-    mid-trace starts an empty timeline, not an untraced one)."""
+    mid-trace starts an empty timeline, not an untraced one).  A capture
+    that is recording is counted as a new one by its next span, so its
+    `wrapped` is reckoned from the cleared ring."""
     RING.clear()
+    end_capture()
 
 
 if os.environ.get("CYCLONUS_TRACE_EVENTS", "") == "1":

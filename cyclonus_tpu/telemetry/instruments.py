@@ -6,20 +6,22 @@ engine call sites stay one-liners.  Unlabeled gauges/counters exist from
 import, so a scrape of a fresh process already shows the full schema.
 
 `eval_flight` is the per-evaluation wrapper the engine hot paths use: it
-times the evaluation, feeds the latency histogram / throughput gauges,
-and appends a flight-recorder entry (including on crash, with the
-exception as the outcome).  Cost per eval when enabled: two
-perf_counter reads, a handful of locked dict updates, one ring append —
-host-side only, never a device sync (pinned by the jaxlint test).
+numbers the evaluation, opens its `engine.eval` span (every span inside
+carries the number as `eval_id`), times it, feeds the latency histogram
+/ throughput gauges, and appends a flight-recorder entry (including on
+crash, with the exception as the outcome).  Cost per eval when enabled:
+one span, a handful of locked dict updates, one ring append — host-side
+only, never a device sync (pinned by the jaxlint test).
 """
 
 from __future__ import annotations
 
 import contextlib
+import sys
 import time
 from typing import Any, Dict, Iterator, Optional
 
-from . import recorder, state
+from . import recorder, spans, state
 from .metrics import REGISTRY
 
 # --- evaluation throughput / latency ------------------------------------
@@ -42,21 +44,6 @@ EVAL_DISPATCHES = REGISTRY.counter(
     "cyclonus_tpu_eval_dispatches_total",
     "Engine evaluations dispatched, by kernel path.",
     labelnames=("path",),
-)
-EVAL_DISPATCH_SECONDS = REGISTRY.gauge(
-    "cyclonus_tpu_eval_dispatch_seconds",
-    "Host time of the most recent async dispatch (enqueue only; the "
-    "device may still be executing).",
-)
-EVAL_EXECUTE_SECONDS = REGISTRY.gauge(
-    "cyclonus_tpu_eval_execute_seconds",
-    "Time of the most recent readback barrier (absorbs device execution "
-    "and the device->host copy).",
-)
-EVAL_DEVICE_SECONDS = REGISTRY.gauge(
-    "cyclonus_tpu_eval_device_seconds",
-    "Steady-state device seconds per evaluation from the pipelined "
-    "timing loop (the dispatch-vs-device split's device half).",
 )
 
 # --- HBM watermarks ------------------------------------------------------
@@ -94,6 +81,41 @@ MESH_RING_STEP_SECONDS = REGISTRY.gauge(
     "budget the bench records as detail.mesh ring_step_s.",
 )
 
+DEVICE_BYTES = REGISTRY.gauge(
+    "cyclonus_tpu_device_bytes",
+    "Device memory of the fullest local device as the runtime reports it "
+    "(memory_stats: stat=in_use now, stat=peak since process start), "
+    "refreshed at scrape time.  Absent until the process has started a "
+    "backend, and on backends that report no memory_stats (the CPU).",
+    labelnames=("stat",),
+)
+
+
+class _DeviceMemory:
+    """Scrape-time refresher of DEVICE_BYTES (the collector registry
+    holds bound methods by weakref, hence an object).  It never starts a
+    backend: a scrape of a process that has not touched the device must
+    not be the thing that claims the chip."""
+
+    def refresh(self) -> None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return
+        from jax._src import xla_bridge
+
+        started = getattr(xla_bridge, "backends_are_initialized", None)
+        if started is None or not started():
+            return
+        stats = [d.memory_stats() or {} for d in jax.local_devices()]
+        for stat, key in (("in_use", "bytes_in_use"), ("peak", "peak_bytes_in_use")):
+            values = [s[key] for s in stats if key in s]
+            if values:
+                DEVICE_BYTES.set(max(values), stat=stat)
+
+
+_DEVICE_MEMORY = _DeviceMemory()
+REGISTRY.register_collector(_DEVICE_MEMORY.refresh)
+
 # --- equivalence-class grid compression ----------------------------------
 
 CLASS_PODS = REGISTRY.gauge(
@@ -110,11 +132,6 @@ CLASS_RATIO = REGISTRY.gauge(
     "cyclonus_tpu_class_compression_ratio",
     "Grid compression: pods / classes (1.0 = no reduction; the grid "
     "work shrinks by ratio^2).",
-)
-CLASS_GATHER_SECONDS = REGISTRY.gauge(
-    "cyclonus_tpu_class_gather_seconds",
-    "Grid compression: last broadcast-back epilogue (gather / class-"
-    "size weighting) wall-clock.",
 )
 CLASS_AUX_BYTES = REGISTRY.gauge(
     "cyclonus_tpu_class_aux_bytes",
@@ -395,6 +412,7 @@ VERDICTS = REGISTRY.counter(
 
 class _NullFlight:
     __slots__ = ()
+    eval_id = None
 
     def set(self, **kw: Any) -> "_NullFlight":
         return self
@@ -405,12 +423,15 @@ _NULL_FLIGHT = _NullFlight()
 
 class Flight:
     """Mutable per-evaluation record; `set(cells=..., **attrs)` enriches
-    the flight entry (and, when cells is set, the throughput gauge)."""
+    the flight entry (and, when cells is set, the throughput gauge).
+    `eval_id` is the evaluation's number: the entry's `seq` and the
+    `eval_id` of every span recorded inside the flight."""
 
-    __slots__ = ("data",)
+    __slots__ = ("data", "eval_id")
 
-    def __init__(self, data: Dict[str, Any]):
+    def __init__(self, data: Dict[str, Any], eval_id: int):
         self.data = data
+        self.eval_id = eval_id
 
     def set(self, **kw: Any) -> "Flight":
         self.data.update(kw)
@@ -419,16 +440,22 @@ class Flight:
 
 @contextlib.contextmanager
 def eval_flight(path: str, n_pods: int, q: int, **attrs: Any) -> Iterator[Flight]:
-    """Wrap one engine evaluation: histogram + dispatch counter + flight
-    record, outcome 'ok' or the exception repr."""
+    """Wrap one engine evaluation: its number, its `engine.eval` span
+    (attr `route` = the PathSpec name), histogram + dispatch counter +
+    flight record, outcome 'ok' or the exception repr."""
     if not state.ENABLED:
         yield _NULL_FLIGHT  # type: ignore[misc]
         return
-    flight = Flight({"path": path, "n_pods": n_pods, "q": q, **attrs})
+    eval_id = recorder.next_seq()
+    flight = Flight(
+        {"path": path, "n_pods": n_pods, "q": q, "seq": eval_id, **attrs},
+        eval_id,
+    )
     outcome = "ok"
     t0 = time.perf_counter()
     try:
-        yield flight
+        with spans.evaluation(eval_id), spans.span("engine.eval", route=path):
+            yield flight
     except BaseException as e:
         outcome = f"{type(e).__name__}: {e}"[:300]
         raise

@@ -44,14 +44,24 @@ _seq = {"n": 0}  # guarded-by: _lock
 _hook = {"installed": False, "previous": None}  # guarded-by: _lock
 
 
+def next_seq() -> int:
+    """The next evaluation's sequence number, handed out when the
+    evaluation STARTS: it is also the `eval_id` its spans carry
+    (instruments.eval_flight), so a flight entry and the spans of the
+    same evaluation name each other."""
+    with _lock:
+        _seq["n"] += 1
+        return _seq["n"]
+
+
 def record(**entry: Any) -> None:
-    """Append one evaluation record (timestamped + sequence-numbered)."""
+    """Append one evaluation record (timestamped; sequence-numbered here
+    unless the caller took its `seq` from next_seq at the start)."""
     if not state.ENABLED:
         return
     _install_crash_hook()
-    with _lock:
-        _seq["n"] += 1
-        entry["seq"] = _seq["n"]
+    if "seq" not in entry:
+        entry["seq"] = next_seq()
     entry["at"] = round(time.time(), 3)
     RING.append(entry)
 

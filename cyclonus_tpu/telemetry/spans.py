@@ -21,12 +21,27 @@ Span names are static strings (phase names, kernel paths), so the
 registry is bounded by the instrumentation sites, not by traffic.  The
 hot-path cost when telemetry is disabled is one module-attribute read;
 when enabled, two perf_counter calls plus one locked dict update.
+
+While a JAX profiler capture is recording (`--jax-profile`,
+`/profile?seconds=N`, the benchmark's `--trace 1` window) a span is
+ALSO a `jax.profiler.TraceAnnotation("cyclonus." + name)`: it lies in
+the capture's `/host:CPU` plane, on the clock the device operations are
+on, so an idle stretch of the device can be laid at the program's own
+span.  For that time, and only then, its B/E events are recorded too
+(events.py `capture_spans`).  With no capture the added cost is one
+`TraceAnnotation.is_enabled()` call.  JAX is never imported from here:
+no capture can run in a process that has not imported it.
+
+`detail(name)` is a span that exists ONLY while a capture or a trace
+records: the steps of a request that take less than a span costs to
+aggregate are visible on a timeline and free otherwise.
 """
 
 from __future__ import annotations
 
 import contextlib
 import logging
+import sys
 import threading
 import time
 from typing import Any, Dict, Iterator, Optional
@@ -39,20 +54,104 @@ logger = logging.getLogger("cyclonus.trace")
 _EMPTY: Dict[str, Any] = {}
 
 
+def _trace_annotation():
+    """`jax.profiler.TraceAnnotation` once the process has imported JAX,
+    else None; looked up through sys.modules so that importing telemetry
+    never starts importing JAX."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        _ANNOTATION = getattr(profiler, "TraceAnnotation", None)
+    return _ANNOTATION
+
+
+_ANNOTATION = None
+# what a trace annotation carries of a span's attributes: the viewer
+# shows them as the event's stats, and a long repr would bloat every event
+_SMALL = (bool, int, float, str)
+
+
+def _small(attrs: Dict[str, Any]) -> Dict[str, Any]:
+    return {
+        k: v for k, v in attrs.items()
+        if isinstance(v, _SMALL) and (not isinstance(v, str) or len(v) <= 64)
+    }
+
+
 class Span:
-    """The in-flight handle yielded by `span()`: attribute sink only —
-    timing and registration happen in the context manager."""
+    """One timed block, the context manager `span()` returns:
 
-    __slots__ = ("name", "path", "attrs")
+        with span("engine.encode", pods=n) as s:
+            ...
+            s.set(targets=t)
 
-    def __init__(self, name: str, path: str, attrs: Optional[Dict[str, Any]]):
+    Entering makes it the current thread's active span (its children
+    nest under its path); leaving records it."""
+
+    __slots__ = (
+        "name", "path", "attrs", "_parent", "_t0", "_capture", "_eval_id",
+        "_annotation", "_shown",
+    )
+
+    def __init__(self, name: str, attrs: Dict[str, Any]):
         self.name = name
-        self.path = path
-        self.attrs = dict(attrs) if attrs else {}
+        self.path = name
+        self.attrs = attrs
 
     def set(self, **attrs: Any) -> "Span":
         self.attrs.update(attrs)
         return self
+
+    def __enter__(self) -> "Span":
+        name = self.name
+        parent = self._parent = getattr(_tls, "path", "")
+        if parent:
+            self.path = f"{parent}/{name}"
+        _tls.path = self.path
+        capture, annotation = 0, _ANNOTATION or _trace_annotation()
+        if annotation is not None and annotation.is_enabled():
+            capture = events.CAPTURE or events.begin_capture()
+        elif events.CAPTURE:
+            events.end_capture()
+        self._capture = capture
+        self._annotation = self._eval_id = None
+        if capture or events.ACTIVE:
+            eval_id = self._eval_id = getattr(_tls, "eval_id", None)
+            events.record(
+                "B", name, self.path, self.attrs,
+                capture=capture, eval_id=eval_id,
+            )
+            if capture:
+                shown = _small(self.attrs)
+                if eval_id is not None:
+                    shown["eval_id"] = eval_id
+                self._shown = tuple(shown)
+                self._annotation = annotation("cyclonus." + name, **shown)
+                self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        dt = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            late = _small(self.attrs)  # what s.set() added inside the block
+            for k in self._shown:
+                late.pop(k, None)
+            if late:
+                self._annotation.set_metadata(**late)
+            self._annotation.__exit__(*exc)
+        _tls.path = self._parent
+        REGISTRY.record(self.path, self.name, dt, self.attrs)
+        if self._capture or events.ACTIVE:
+            # exit carries the FINAL attrs (s.set() calls inside the block)
+            events.record(
+                "E", self.name, self.path, self.attrs,
+                capture=self._capture,
+                eval_id=self._eval_id,
+                dur_s=dt,
+            )
+        logger.debug("phase %s: %.4fs", self.path, dt)
 
 
 class _NullSpan:
@@ -65,6 +164,12 @@ class _NullSpan:
 
     def set(self, **attrs: Any) -> "_NullSpan":
         return self
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        return None
 
 
 _NULL_SPAN = _NullSpan()
@@ -173,26 +278,46 @@ def adopt(path: str) -> Iterator[None]:
         _tls.path = prev
 
 
-@contextlib.contextmanager
-def span(name: str, **attrs: Any) -> Iterator[Span]:
+def span(name: str, **attrs: Any):
     """Time a block as a child of the current thread's active span."""
     if not state.ENABLED:
-        yield _NULL_SPAN  # type: ignore[misc]
-        return
-    parent = getattr(_tls, "path", "")
-    path = f"{parent}/{name}" if parent else name
-    _tls.path = path
-    handle = Span(name, path, attrs)
-    if events.ACTIVE:
-        events.record("B", name, path, attrs)
-    t0 = time.perf_counter()
-    try:
-        yield handle
-    finally:
-        dt = time.perf_counter() - t0
-        _tls.path = parent
-        REGISTRY.record(path, name, dt, handle.attrs)
+        return _NULL_SPAN
+    return Span(name, attrs)
+
+
+def detail(name: str, **attrs: Any):
+    """A span for a step of tens of microseconds inside a request
+    (`engine.case_tensors`, `engine.plan`, `engine.unpack`,
+    `engine.finish`): it exists only while something keeps a timeline —
+    a profiler capture or an ACTIVE trace — and is the shared no-op
+    handle otherwise, so an unobserved request pays one poll for it and
+    the registry's aggregates since process start never hold it."""
+    if state.ENABLED:
         if events.ACTIVE:
-            # exit carries the FINAL attrs (s.set() calls inside the block)
-            events.record("E", name, path, handle.attrs)
-        logger.debug("phase %s: %.4fs", path, dt)
+            return Span(name, attrs)
+        annotation = _ANNOTATION or _trace_annotation()
+        if annotation is not None and annotation.is_enabled():
+            return Span(name, attrs)
+    return _NULL_SPAN
+
+
+class evaluation:
+    """Everything this thread's spans record inside the block belongs to
+    evaluation `eval_id`: `instruments.eval_flight` opens one per
+    evaluation, and `GridVerdict` re-opens its evaluation's round the
+    fetches that run after `evaluate_grid` has returned.  The id lands on
+    the recorded events and the trace annotations, so the spans of one
+    request share an identifier."""
+
+    __slots__ = ("eval_id", "_prev")
+
+    def __init__(self, eval_id: Optional[int]):
+        self.eval_id = eval_id
+
+    def __enter__(self) -> "evaluation":
+        self._prev = getattr(_tls, "eval_id", None)
+        _tls.eval_id = self.eval_id
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        _tls.eval_id = self._prev

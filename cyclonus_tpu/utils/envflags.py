@@ -146,7 +146,7 @@ _FLAGS = [
          "Telemetry counters/gauges master switch."),
     Flag("CYCLONUS_TRACE_EVENTS", "bool", False, "telemetry",
          "Structured event trace emission."),
-    Flag("CYCLONUS_TRACE_EVENTS_N", "int", 8192, "telemetry",
+    Flag("CYCLONUS_TRACE_EVENTS_N", "int", 32768, "telemetry",
          "Event trace ring capacity."),
     Flag("CYCLONUS_TRACE_ID", "str", "", "telemetry",
          "Trace correlation id attached to emitted events."),

@@ -27,7 +27,11 @@ import contextlib
 import logging
 from typing import Dict, Iterator, Optional
 
-from ..telemetry.spans import REGISTRY, span as phase  # noqa: F401 (re-export)
+from ..telemetry.spans import (  # noqa: F401 (re-exports)
+    REGISTRY,
+    detail,
+    span as phase,
+)
 
 logger = logging.getLogger("cyclonus.trace")
 
@@ -60,12 +64,21 @@ def render_stats() -> str:
 
 @contextlib.contextmanager
 def jax_profile(trace_dir: Optional[str]) -> Iterator[None]:
-    """Wrap a block in jax.profiler.trace(trace_dir); no-op when falsy."""
+    """Wrap a block in jax.profiler.trace(trace_dir); no-op when falsy.
+    While it runs every phase/span is also a `cyclonus.<name>` trace
+    annotation in the capture (telemetry/spans.py)."""
     if not trace_dir:
         yield
         return
     import jax
 
-    with jax.profiler.trace(trace_dir):
-        yield
+    from ..telemetry import events
+
+    try:
+        with jax.profiler.trace(trace_dir):
+            yield
+    finally:
+        # spans notice a capture's end only at the next span: say so now,
+        # so that a capture started right after this one is a new one
+        events.end_capture()
     logger.info("jax profiler trace written to %s", trace_dir)

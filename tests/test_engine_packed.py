@@ -23,6 +23,7 @@ from cyclonus_tpu.engine.encoding import (
     packed_words,
 )
 from cyclonus_tpu.matcher import build_network_policies
+from cyclonus_tpu.telemetry.instruments import eval_flight
 
 from test_engine_tiled import CASES, fuzz_problem, full_grids
 
@@ -276,12 +277,14 @@ class TestFusedEpilogues:
         pc = engine._class_state["classes"]
         tensors = engine._ctensors_with_cases(CASES)
         n = len(pods)
-        split, _ = evaluate_grid_counts_classes(
-            tensors, pc.n_classes, pc.class_size, n, kernel="xla"
-        )
-        fused, _ = evaluate_grid_counts_classes(
-            tensors, pc.n_classes, pc.class_size, n, kernel="pallas"
-        )
+        with eval_flight("counts.classes", n, len(CASES)) as fl:
+            split, _ = evaluate_grid_counts_classes(
+                fl, tensors, pc.n_classes, pc.class_size, n, kernel="xla"
+            )
+        with eval_flight("counts.classes", n, len(CASES)) as fl:
+            fused, _ = evaluate_grid_counts_classes(
+                fl, tensors, pc.n_classes, pc.class_size, n, kernel="pallas"
+            )
         assert fused == split
         # and both equal the dense truth
         ing, egr, comb = full_grids(engine, CASES)
@@ -312,15 +315,18 @@ class TestFusedEpilogues:
         pc = engine._class_state["classes"]
         tensors = engine._ctensors_with_cases(fc.cases)
         monkeypatch.setattr(pk, "PACKED_TIER_MAX_ROWS", 1)
+        n, q = len(fc.pods), len(fc.cases)
         with pytest.raises(ValueError, match="static-unroll ceiling"):
-            evaluate_grid_counts_classes(
-                tensors, pc.n_classes, pc.class_size, len(fc.pods),
-                kernel="pallas",
-            )
+            with eval_flight("counts.classes", n, q) as fl:
+                evaluate_grid_counts_classes(
+                    fl, tensors, pc.n_classes, pc.class_size, n,
+                    kernel="pallas",
+                )
         # auto routes to the XLA body and stays correct
-        counts, _ = evaluate_grid_counts_classes(
-            tensors, pc.n_classes, pc.class_size, len(fc.pods)
-        )
+        with eval_flight("counts.classes", n, q) as fl:
+            counts, _ = evaluate_grid_counts_classes(
+                fl, tensors, pc.n_classes, pc.class_size, n
+            )
         want = engine.evaluate_grid_counts(fc.cases, block=8, backend="xla")
         assert counts["combined"] == want["combined"]
 

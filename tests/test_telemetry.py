@@ -457,18 +457,17 @@ class TestOverhead:
     @staticmethod
     def _per_eval_telemetry_ops():
         """Exactly the telemetry call sequence one steady-state counts
-        eval executes (api._counts_pallas_dispatch): the flight wrapper,
-        the branch attrs, the cache counter, the two phase spans, and
-        the dispatch/execute split gauges."""
+        eval executes (api._counts_pallas_dispatch): the flight wrapper
+        (with its engine.eval span), the branch attrs, the cache
+        counter, and the dispatch/execute phase spans that carry the
+        split."""
         with ti.eval_flight("counts.pallas", 512, 1) as fl:
             fl.set(mode="steady", slab=False)
             ti.PRE_CACHE_HITS.inc()
             with span("engine.dispatch"):
                 pass
-            ti.EVAL_DISPATCH_SECONDS.set(0.001)
             with span("engine.execute"):
                 pass
-            ti.EVAL_EXECUTE_SECONDS.set(0.002)
             fl.set(cells=262144)
 
     def test_hot_path_overhead_under_2_percent(self, steady_engine):
@@ -628,9 +627,13 @@ class TestEngineInstrumentation:
         assert modes == ["fused", "split", "steady"]
         assert all(e["outcome"] == "ok" for e in ents)
         assert ents[-1]["cells"] == counts["cells"]
-        # dispatch/execute split gauges moved
-        assert ti.EVAL_DISPATCH_SECONDS.value() > 0
-        assert ti.EVAL_EXECUTE_SECONDS.value() > 0
+        # the dispatch/execute split: one span each an evaluation, both
+        # inside the evaluation's engine.eval
+        stats = telemetry.SPANS.stats()
+        assert stats["engine.eval"]["count"] == 3
+        for name in ("engine.dispatch", "engine.execute"):
+            assert stats[name]["count"] == 3 and stats[name]["total_s"] > 0
+            assert f"engine.eval/{name}" in telemetry.SPANS.tree()
 
 
 class TestWorkerLatency:
