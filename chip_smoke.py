@@ -331,15 +331,6 @@ def oracle_phase(state: dict) -> None:
     say(f"   {ORACLE_PAIRS} pairs x {len(state['cases'])} cases x 2 engines")
 
 
-def grid_sums(grid) -> tuple:
-    import jax.numpy as jnp
-
-    return tuple(
-        int(jnp.sum(getattr(grid, name), dtype=jnp.int32))
-        for name in ("ingress_dev", "egress_dev", "combined_dev")
-    )
-
-
 def tables_phase(state: dict) -> None:
     """The full [Q, N, N] grid: sampled cells against the scalar oracle,
     and its sums against the counts kernels on the same engines."""
@@ -362,7 +353,7 @@ def tables_phase(state: dict) -> None:
             policy, pods, namespaces, cases, grid, ORACLE_CELLS,
             random.Random(SEED + 3),
         )
-        sums = grid_sums(grid)
+        sums = grid.allow_counts()
         counts, rc = routes_of(
             lambda: engine.evaluate_grid_counts(cases, backend=backend)
         )
@@ -370,7 +361,7 @@ def tables_phase(state: dict) -> None:
         check(sums == counts3(counts), f"{name}: grid sums != counts")
         grids[name] = (engine, grid)
     check(
-        grid_sums(grids["default"][1]) == grid_sums(grids["dense"][1]),
+        grids["default"][1].allow_counts() == grids["dense"][1].allow_counts(),
         "default and dense grids differ",
     )
     state.update(table_cases=cases, table_engines=grids)
@@ -408,7 +399,7 @@ def tier_phase() -> None:
         say(f"   class_compress={cc} {r}: {got}")
         check(got == want, f"tiered pallas {got} != xla {want}")
         grid = engine.evaluate_grid(cases)
-        check(grid_sums(grid) == counts3(got), "tiered grid sums != counts")
+        check(grid.allow_counts() == counts3(got), "tiered grid sums != counts")
         combined = np.asarray(grid.combined)
         for _ in range(16):
             qi, si, di = rng.randrange(2), rng.randrange(n), rng.randrange(n)

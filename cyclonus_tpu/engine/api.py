@@ -49,7 +49,17 @@ class GridVerdict:
     """Verdict grids.  The underlying arrays stay DEVICE-RESIDENT (host
     transfer of an N x N x Q grid dominates wall-clock at scale); numpy
     views materialize lazily on first access,
-    and `gather` fetches individual cells with one device-side take."""
+    and `gather` fetches individual cells with one device-side take.
+
+    A table arrives in one of two forms, told apart by its dtype: bool
+    [Q, N, N] (the sharded routes, the native evaluator, the empty case),
+    or uint32 `kernel.cell_words` [Q, >= N, >= ceil(N/4)] (the
+    single-device grid programs): cell c of a row is byte c % 4 of word
+    c // 4, little-endian, each byte 0 or 1.  A one-byte element leaves
+    the device four times slower than a 32-bit one (the runtime un-tiles
+    either on the host), and on the host the words' bytes ARE the
+    boolean table, so `ingress` / `egress` / `combined` are bool
+    [Q, N, N] in either form, with no host copy."""
 
     def __init__(
         self, pod_keys, port_cases, ingress_dev, egress_dev, combined_dev,
@@ -61,7 +71,7 @@ class GridVerdict:
         # spans run after evaluate_grid has returned and carry it too
         self.eval_id = eval_id
         # device arrays: ingress [Q, N_dst, N_src]; egress/combined
-        # [Q, N_src, N_dst]
+        # [Q, N_src, N_dst] (or the words of those, see above)
         self.ingress_dev = ingress_dev
         self.egress_dev = egress_dev
         self.combined_dev = combined_dev
@@ -77,7 +87,10 @@ class GridVerdict:
     def _materialize(self, name: str) -> np.ndarray:
         if name not in self._np:
             dev = getattr(self, name + "_dev")
-            with evaluation(self.eval_id), phase("grid.fetch", table=name):
+            words = dev.dtype == np.uint32
+            with evaluation(self.eval_id), phase(
+                "grid.fetch", table=name, form="words" if words else "bools"
+            ):
                 # JAX dispatch is async: grid.wait is what the device
                 # still had to run, grid.copy the transfer and the
                 # host's own work on the buffer, timed apart (np.asarray
@@ -86,8 +99,13 @@ class GridVerdict:
                     if hasattr(dev, "block_until_ready"):
                         dev.block_until_ready()
                 with phase("grid.copy") as sp:
-                    out = self._np[name] = np.asarray(dev)
+                    out = np.asarray(dev)
                     sp.set(bytes=out.nbytes, dtype=str(out.dtype))
+                if words:
+                    from .kernel import host_cells
+
+                    out = host_cells(out, len(self.pod_keys))
+                self._np[name] = out
         return self._np[name]
 
     @property
@@ -113,37 +131,52 @@ class GridVerdict:
         """Fetch (ingress, egress, combined) for a batch of (q, src, dst)
         triples with one device gather + one tiny transfer — no full-grid
         materialization."""
-        import jax.numpy as jnp
-
         idx = np.array(triples, dtype=np.int32).reshape(-1, 3)
         if idx.shape[0] == 0:
             return np.zeros((0, 3), dtype=bool)
         q, s, d = idx[:, 0], idx[:, 1], idx[:, 2]
-        out = jnp.stack(
-            [
-                self.ingress_dev[q, d, s],
-                self.egress_dev[q, s, d],
-                self.combined_dev[q, s, d],
-            ],
-            axis=1,
-        )
-        return np.asarray(out)
+        if isinstance(self.ingress_dev, np.ndarray):  # host tables already
+            return np.stack(
+                [
+                    self.ingress_dev[q, d, s],
+                    self.egress_dev[q, s, d],
+                    self.combined_dev[q, s, d],
+                ],
+                axis=1,
+            )
+        from .kernel import grid_cells_kernel
 
-    def allow_stats(self) -> Dict[str, float]:
-        """Device-side aggregate: mean allow rate per grid.  One fused
-        execution and one 12-byte transfer — separate readbacks each pay a
+        return np.asarray(
+            grid_cells_kernel(
+                self.ingress_dev, self.egress_dev, self.combined_dev, q, s, d
+            )
+        )
+
+    def allow_counts(self) -> Tuple[int, int, int]:
+        """Allowed cells of (ingress, egress, combined), exact, counted
+        on the device in either form: one fused execution and one small
+        transfer (a row's count each) — separate readbacks each pay a
         full device->host round trip."""
         if self.ingress_dev.shape[0] == 0:
-            return {"ingress": 0.0, "egress": 0.0, "combined": 0.0}
-        from .kernel import grid_stats_kernel
+            return (0, 0, 0)
+        from .kernel import grid_row_counts_kernel
 
-        stats = np.asarray(
-            grid_stats_kernel(self.ingress_dev, self.egress_dev, self.combined_dev)
+        rows = np.asarray(
+            grid_row_counts_kernel(
+                self.ingress_dev, self.egress_dev, self.combined_dev,
+                n=len(self.pod_keys),
+            )
         )
+        return tuple(int(t) for t in rows.sum(axis=(1, 2), dtype=np.int64))
+
+    def allow_stats(self) -> Dict[str, float]:
+        """Mean allow rate per grid (allow_counts over the cells)."""
+        cells = len(self.port_cases) * len(self.pod_keys) ** 2
         return {
-            "ingress": float(stats[0]),
-            "egress": float(stats[1]),
-            "combined": float(stats[2]),
+            name: count / cells if cells else 0.0
+            for name, count in zip(
+                ("ingress", "egress", "combined"), self.allow_counts()
+            )
         }
 
 
@@ -1384,12 +1417,13 @@ class TpuPolicyEngine:
 
     def _evaluate_grid_classes(self, cases: Sequence[PortCase]) -> GridVerdict:
         """Compressed grid path: evaluate the C x C x Q class grid and
-        broadcast back to pod axes with the int32 gather epilogue —
+        broadcast back to pod axes with the int32 gather epilogue, which
+        emits the tables as 32-bit words (kernel.cell_words) —
         kernel + gather trace into ONE jit, so the path keeps the dense
         path's single-execution property."""
         import jax
 
-        from .kernel import evaluate_grid_kernel, gather_class_grids
+        from .kernel import WORD_FORMAT, evaluate_grid_kernel, gather_class_words
 
         planspec.record("grid.classes")
         st = self._class_state
@@ -1411,12 +1445,14 @@ class TpuPolicyEngine:
                 pack = self._pack
 
                 def grid_classes(t, co):
-                    return gather_class_grids(
+                    return gather_class_words(
                         evaluate_grid_kernel(t, pack=pack), co
                     )
 
                 self._class_grid_jit = aot_cache.AotProgram(
-                    "grid.classes", jax.jit(grid_classes), plan=self._aot_plan()
+                    "grid.classes",
+                    jax.jit(grid_classes),
+                    plan=self._aot_plan(WORD_FORMAT),
                 )
                 # the executable is obtained here, so that engine.dispatch
                 # below is the call that enqueues and nothing else
@@ -1526,7 +1562,7 @@ class TpuPolicyEngine:
     def evaluate_grid(self, cases: Sequence[PortCase]) -> GridVerdict:
         """Single-device evaluation of the full N x N x Q verdict grid.
         Results stay on device (see GridVerdict)."""
-        from .kernel import evaluate_grid_kernel
+        from .kernel import WORD_FORMAT, evaluate_grid_words
 
         self._check_ips()
         if not cases:
@@ -1542,8 +1578,8 @@ class TpuPolicyEngine:
             if self._grid_aot is None:
                 self._grid_aot = aot_cache.AotProgram(
                     "grid",
-                    evaluate_grid_kernel,
-                    plan=self._aot_plan(),
+                    evaluate_grid_words,
+                    plan=self._aot_plan(WORD_FORMAT),
                     static_argnames=("pack",),
                 )
                 self._grid_aot.resolve(tensors, pack=self._pack)
@@ -1554,15 +1590,15 @@ class TpuPolicyEngine:
                 out = self._grid_aot(tensors, pack=self._pack)
             if self.tiers is not None:
                 self._tier_resolve_s = time.perf_counter() - t0
-        # kernel emits [q, ...] layout directly: one device execution
-        # total.  Bucketing pads the pod axis; the lazy device slice
-        # strips the pad rows so GridVerdict stays exactly n x n.
+        # kernel emits the words in [q, ...] layout directly: one device
+        # execution total.  Bucketing pads the pod axis; GridVerdict
+        # leaves the pad rows and cells out of every host view and count.
         return GridVerdict(
             self.pod_keys,
             list(cases),
-            out["ingress"][:, :n, :n],
-            out["egress"][:, :n, :n],
-            out["combined"][:, :n, :n],
+            out["ingress"],
+            out["egress"],
+            out["combined"],
             eval_id=fl.eval_id,
         )
 

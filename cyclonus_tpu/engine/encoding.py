@@ -902,7 +902,7 @@ class PolicyEncoding:
 # indistinguishable to every rule and must receive identical verdict rows
 # AND columns.  compute_pod_classes buckets pods by that signature; the
 # evaluators then run the unique (src-class x dst-class x port) grid and
-# broadcast back with an int32 gather (kernel.gather_class_grids) or an
+# broadcast back with an int32 gather (kernel.gather_class_words) or an
 # exact class-size weighting (tiled.evaluate_grid_counts_classes).
 # Soundness is pinned three ways: the property suite hashes signatures
 # against scalar-oracle verdict rows, the parity suite runs compressed vs

@@ -28,6 +28,7 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..utils import contracts
 from .encoding import (
@@ -594,23 +595,99 @@ def evaluate_grid_kernel(tensors: Dict, pack: bool = False) -> Dict[str, jnp.nda
     }
 
 
+#: cells a result word holds, one byte each
+WORD_CELLS = 4
+#: a table of words is padded to whole 32-bit device tiles, rows to 8 and
+#: words to 128: the runtime then keeps the row-major layout (for a shape
+#: that would pad its tiles it picks the axis order that pads least, and
+#: uint32[2, 10000, 2500] came to the host with a strided last axis, which
+#: no boolean view can be laid over: my chip run, PR 27)
+WORD_TILE = (8, 128)
+#: the format's name in the AOT plan of the programs that emit it ("grid",
+#: "grid.classes"): aot_cache.make_key sees nothing of a program's code or
+#: result, so an executable that returns its tables in another form (the
+#: boolean era's, or other tiles) must not be found under the same key
+WORD_FORMAT = f"out=w{8 * WORD_CELLS}.{WORD_TILE[0]}x{WORD_TILE[1]}"
+
+
+def cell_words(lanes) -> jnp.ndarray:
+    """THE result format of the single-device grid programs: the four
+    `lanes` (bool [..., W]; lane k holds cells k, 4+k, 8+k, ... of the
+    last axis) as uint32 [..., W up to a multiple of 128], cell 4w+k in
+    bits 8k..8k+7 of word w, each byte exactly 0 or 1, the pad words 0.
+    On a little-endian host the words' bytes ARE the boolean table
+    (GridVerdict views them, no copy); the runtime un-tiles every
+    fetched buffer on the host, and a 32-bit element four times faster
+    than a one-byte one (PERF.md section 6, PR 27)."""
+    words = lanes[0].astype(jnp.uint32)
+    for k in range(1, WORD_CELLS):
+        words = words | (lanes[k].astype(jnp.uint32) << (8 * k))
+    pad = [(0, 0)] * (words.ndim - 1) + [(0, -words.shape[-1] % WORD_TILE[1])]
+    return jnp.pad(words, pad)
+
+
+def host_cells(words: np.ndarray, n: int) -> np.ndarray:
+    """The host's side of cell_words: bool [Q, n, n] over the fetched
+    words of a table, a view and not a copy (a buffer whose last axis is
+    strided, which WORD_TILE rules out, raises here).  "<u4" is this
+    host's own order wherever JAX runs (a no-op), and still right where
+    it is not."""
+    return words.astype("<u4", copy=False).view(np.bool_)[:, :n, :n]
+
+
+def _real_bytes(n: int, width: int) -> jnp.ndarray:
+    """uint32 [width]: 0x01 in every byte that holds one of the `n` real
+    cells of a row of words, 0 in its pad bytes."""
+    real = np.zeros(width * WORD_CELLS, dtype=np.uint8)
+    real[:n] = 1
+    return jnp.asarray(real.view("<u4").astype(np.uint32))
+
+
+@partial(jax.jit, static_argnames=("pack",))
+def evaluate_grid_words(tensors: Dict, pack: bool = False) -> Dict[str, jnp.ndarray]:
+    """evaluate_grid_kernel with each table as cell_words over the
+    bucketed pod axis (a multiple of eight, so there is no pad byte
+    short of the pad words): uint32 [Q, N, >= N/4].  Kernel and words
+    trace into one program: one device execution."""
+    return {
+        k: cell_words([v[..., i::WORD_CELLS] for i in range(WORD_CELLS)])
+        for k, v in evaluate_grid_kernel(tensors, pack=pack).items()
+    }
+
+
 @contracts.args(class_of="(N,) int32")
-def gather_class_grids(
+def gather_class_words(
     out: Dict[str, jnp.ndarray], class_of: jnp.ndarray
 ) -> Dict[str, jnp.ndarray]:
-    """Broadcast class-grid verdicts back to the full pod x pod grid.
+    """Broadcast class-grid verdicts back to the full pod x pod grid, as
+    cell_words: uint32 [Q, N up to a multiple of 8, ceil(N/4) up to a
+    multiple of 128], every pad byte 0.
 
     out: {ingress, egress, combined} [Q, C*, C*] bool over the (possibly
     bucketing-padded) class axes; class_of: [N] int32 pod -> class map
     (values < the real class count, so pad rows are never gathered).
-    Two chained int32 gathers per grid — cell (q, i, j) copies class
-    cell (q, class_of[i], class_of[j]), which is exact by the class
-    signature's completeness (encoding.compute_pod_classes).  Designed
-    to trace INSIDE the caller's jit so grid + gather stay one device
+    Cell (q, i, j) copies class cell (q, class_of[i], class_of[j]),
+    which is exact by the class signature's completeness
+    (encoding.compute_pod_classes).  The COLUMNS go first, four int32
+    gathers (one a lane) on the small class grid, which build the words
+    of every class row; one row gather then writes the result once, in
+    its final layout (rows first, and four gathers on the full width,
+    took 10.0 ms against 7.7: my chip run, PR 27).  Designed to trace
+    INSIDE the caller's jit so grid + gather stay one device
     execution."""
+    n = class_of.shape[0]
 
     def g(a: jnp.ndarray) -> jnp.ndarray:
-        return jnp.take(jnp.take(a, class_of, axis=1), class_of, axis=2)
+        # the pad cells and the pad rows gather zeros: a column and a row
+        # of them, appended behind the classes
+        zeros = a.shape[1]
+        a = jnp.pad(a, ((0, 0), (0, 1), (0, 1)))
+        cols = jnp.pad(class_of, (0, -n % WORD_CELLS), constant_values=zeros)
+        rows = jnp.pad(class_of, (0, -n % WORD_TILE[0]), constant_values=zeros)
+        words = cell_words(
+            [jnp.take(a, cols[k::WORD_CELLS], axis=2) for k in range(WORD_CELLS)]
+        )
+        return jnp.take(words, rows, axis=1)
 
     return {k: g(v) for k, v in out.items()}
 
@@ -677,10 +754,36 @@ def rule_firing_kernel(shared: Dict, enc: Dict) -> Dict[str, jnp.ndarray]:
     }
 
 
+@partial(jax.jit, static_argnames=("n",))
+def grid_row_counts_kernel(ingress, egress, combined, n: int) -> jnp.ndarray:
+    """int32 [3, Q, n]: the allowed cells of every real row of the three
+    tables, whichever form they are in (bool [Q, >=n, >=n] or cell_words
+    of it) - one execution and one small transfer.  Rows, not totals: a
+    row's count fits int32 at any size, the host adds them in int64."""
+
+    def rows(a: jnp.ndarray) -> jnp.ndarray:
+        if a.dtype == jnp.uint32:
+            # a byte is 0 or 1, so a word's set bits are its allowed cells
+            width = -(-n // WORD_CELLS)
+            a = a[:, :n, :width] & _real_bytes(n, width)
+            return jnp.sum(jax.lax.population_count(a), axis=2, dtype=jnp.int32)
+        return jnp.sum(a[:, :n, :n], axis=2, dtype=jnp.int32)
+
+    return jnp.stack([rows(ingress), rows(egress), rows(combined)])
+
+
 @jax.jit
-def grid_stats_kernel(ingress, egress, combined) -> jnp.ndarray:
-    """[3] f32 mean allow-rates — one execution, one scalar-sized
-    transfer (vs three separate float() readbacks)."""
+def grid_cells_kernel(ingress, egress, combined, q, s, d) -> jnp.ndarray:
+    """bool [K, 3]: (ingress, egress, combined) of K (q, src, dst) cells
+    in either form - one device gather, one tiny transfer."""
+
+    def cells(a: jnp.ndarray, r, c) -> jnp.ndarray:
+        if a.dtype == jnp.uint32:
+            word = a[q, r, c // WORD_CELLS]
+            return ((word >> (8 * (c % WORD_CELLS)).astype(jnp.uint32)) & 1) != 0
+        return a[q, r, c]
+
     return jnp.stack(
-        [jnp.mean(ingress), jnp.mean(egress), jnp.mean(combined)]
+        [cells(ingress, d, s), cells(egress, s, d), cells(combined, s, d)],
+        axis=1,
     )

@@ -267,6 +267,81 @@ class TestCacheModule:
         assert c["adopted"] == c["hits"]
 
 
+class TestGridResultFormat:
+    """make_key sees nothing of a program's code or result, so the grid
+    programs name their result format in the plan: the executable of a
+    build that returned boolean tables lies under another key and is
+    never adopted by one that returns words (kernel.cell_words)."""
+
+    @pytest.mark.parametrize(
+        "name,class_compress", [("grid", "0"), ("grid.classes", "1")]
+    )
+    def test_boolean_era_executable_is_not_adopted(
+        self, tmp_path, monkeypatch, name, class_compress
+    ):
+        import random
+
+        import jax
+        import numpy as np
+
+        from bench import build_synthetic
+        from cyclonus_tpu.engine import PortCase, TpuPolicyEngine
+        from cyclonus_tpu.engine.kernel import evaluate_grid_kernel
+        from cyclonus_tpu.matcher import build_network_policies
+
+        monkeypatch.setenv("CYCLONUS_AOT_CACHE", str(tmp_path))
+        pods, namespaces, policies = build_synthetic(40, 10, random.Random(3))
+        policy = build_network_policies(True, policies)
+        cases = [PortCase(80, "serve-80-tcp", "TCP")]
+
+        def engine():
+            return TpuPolicyEngine(
+                policy, pods, namespaces, class_compress=class_compress
+            )
+
+        def entries():
+            """key -> file of this program's entries in the cache"""
+            found = {}
+            for f in tmp_path.glob("*.aotx"):
+                key = pickle.loads(f.read_bytes())["key"]
+                if json.loads(key)["name"] == name:
+                    found[key] = f
+            return found
+
+        first = engine()
+        want = first.evaluate_grid(cases).combined
+        ((key, path),) = entries().items()
+        parts = json.loads(key)
+        # the parent's key for the same program, shapes, platform, plan
+        assert parts["plan"] == first._aot_plan() + ";out=w32.8x128"
+        old_key = aot_cache.make_key(
+            name, parts["sig"], schedule=parts["schedule"],
+            plan=first._aot_plan(),
+        )
+        assert old_key != key
+        # an executable of that era, same arguments, boolean tables back
+        if name == "grid":
+            args = (first._tensors_with_cases(cases, device=True),)
+            old = evaluate_grid_kernel.lower(*args, pack=first._pack)
+        else:
+            args = (
+                first._ctensors_with_cases(cases, device=True),
+                first._class_of_dev,
+            )
+            pack = first._pack
+            old = jax.jit(
+                lambda t, co: evaluate_grid_kernel(t, pack=pack)
+            ).lower(*args)
+        assert aot_cache.store(old_key, old.compile())
+        assert aot_cache.load(old_key) is not None  # it WOULD load
+        path.unlink()
+        # a new process' engine: finds only the old entry, builds its own
+        grid = engine().evaluate_grid(cases)
+        assert grid.combined_dev.dtype == np.uint32
+        assert np.array_equal(grid.combined, want)
+        assert set(entries()) == {key, old_key}
+
+
 @pytest.mark.slow
 class TestRestartContractSharded:
     def test_sharded_program_adopts_on_restart(self, tmp_path):

@@ -21,6 +21,7 @@ import subprocess
 import sys
 
 import jax
+import numpy as np
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -251,6 +252,9 @@ class TestEvaluationSpans:
                 "engine.eval", "engine.case_tensors", "engine.dispatch",
             ] + ["grid.fetch", "grid.wait", "grid.copy"] * 3
         assert all(sp["eval_id"] in ids for sp in found["spans"])
+        # the counter that says the word format engaged
+        copies = by_name(found, "grid.copy")
+        assert [sp["attrs"]["dtype"] for sp in copies] == ["uint32"] * 6
 
     def test_wait_and_copy_are_the_children_of_fetch(self, engine, tmp_path):
         eng, cases = engine
@@ -263,8 +267,12 @@ class TestEvaluationSpans:
         (copy,) = by_name(found, "grid.copy")
         assert wait["path"] == "grid.fetch/grid.wait"
         assert copy["path"] == "grid.fetch/grid.copy"
-        assert fetch["attrs"] == {"table": "combined"}
-        assert copy["attrs"] == {"bytes": table.nbytes, "dtype": "bool"}
+        # the single-device routes hand over 32-bit words (`form` says so
+        # in any trace); the host's boolean table is a view of them
+        words = np.asarray(out.combined_dev)
+        assert fetch["attrs"] == {"table": "combined", "form": "words"}
+        assert copy["attrs"] == {"bytes": words.nbytes, "dtype": "uint32"}
+        assert table.dtype == np.bool_ and np.shares_memory(table, words)
         assert wait["dur_s"] + copy["dur_s"] <= fetch["dur_s"]
         assert fetch["start_s"] <= wait["start_s"] <= copy["start_s"]
         # block_until_ready is the same wait, outside any fetch
@@ -332,6 +340,9 @@ class TestEvaluationSpans:
         assert "engine.eval/engine.case_tensors/engine.device_put" in paths
         assert "engine.eval/engine.case_tensors/engine.unpack" in paths
         assert "engine.eval/engine.dispatch" in paths
+        # either single-device route hands the host 32-bit words
+        (copy,) = by_name(found, "grid.copy")
+        assert copy["attrs"]["dtype"] == "uint32"
 
 
 class TestServeSpans:
