@@ -99,8 +99,12 @@ class GridVerdict:
                     if hasattr(dev, "block_until_ready"):
                         dev.block_until_ready()
                 with phase("grid.copy") as sp:
-                    out = np.asarray(dev)
-                    sp.set(bytes=out.nbytes, dtype=str(out.dtype))
+                    shards = _shards_of(dev)
+                    out = _copy_shards(dev, shards) if shards else np.asarray(dev)
+                    sp.set(
+                        bytes=out.nbytes, dtype=str(out.dtype),
+                        shards=len(shards) or 1,
+                    )
                 if words:
                     from .kernel import host_cells
 
@@ -178,6 +182,72 @@ class GridVerdict:
                 ("ingress", "egress", "combined"), self.allow_counts()
             )
         }
+
+
+def _shards_of(dev) -> Sequence:
+    """The addressable shards of a table that lies on more than one
+    device (the mesh routes' row-sharded words); () for a table on one
+    device or on the host, which takes the plain copy."""
+    sharding = getattr(dev, "sharding", None)
+    if sharding is None or len(sharding.device_set) <= 1:
+        return ()
+    return dev.addressable_shards
+
+
+#: a shard is laid into its table's host buffer by up to this many
+#: threads, a piece of about _LAY_BYTES each: the buffer's pages are fresh,
+#: and first touching 9.7 GB of them from one thread took 10 s of a 13 s
+#: request on the four-chip host, four threads 4.3 (my chip run, PR 28)
+_LAY_THREADS = 16
+_LAY_BYTES = 32 << 20
+_lay_pool = None
+
+
+def _lay(out: np.ndarray, index, piece: np.ndarray) -> None:
+    """out[index] = piece; a large piece in row blocks on the lay threads
+    (numpy copies with the GIL released)."""
+    global _lay_pool
+    dst = out[index]
+    if piece.nbytes <= _LAY_BYTES:
+        dst[...] = piece
+        return
+    if _lay_pool is None:
+        import os
+        from concurrent.futures import ThreadPoolExecutor
+
+        _lay_pool = ThreadPoolExecutor(
+            max_workers=min(_LAY_THREADS, os.cpu_count() or 1),
+            thread_name_prefix="grid-lay",
+        )
+    rows = max(1, _LAY_BYTES // piece[0, 0].nbytes)
+    jobs = [
+        _lay_pool.submit(np.copyto, dst[q, r : r + rows], piece[q, r : r + rows])
+        for q in range(piece.shape[0])
+        for r in range(0, piece.shape[1], rows)
+    ]
+    for job in jobs:
+        job.result()
+
+
+def _copy_shards(dev, shards) -> np.ndarray:
+    """A sharded table on the host: ONE buffer of the final shape, each
+    shard laid into its place (span `grid.shard_copy`, one a shard: the
+    wait for the shard's own transfer and un-tiling, then _lay).  The
+    shards' transfers are all started first, so the runtime brings the
+    later ones over while the earlier ones are laid in place.  JAX hands
+    a shard over in a buffer of the runtime's, so every byte is written
+    twice on the host; there is no call that names a destination (and
+    JAX keeps each shard's host copy with the shard, so the host holds
+    the table twice until the GridVerdict is dropped)."""
+    for sh in shards:
+        sh.data.copy_to_host_async()
+    out = np.empty(dev.shape, dev.dtype)
+    for sh in shards:
+        with detail("grid.shard_copy", device=sh.device.id) as sp:
+            piece = np.asarray(sh.data)
+            _lay(out, sh.index, piece)
+            sp.set(bytes=piece.nbytes, dtype=str(piece.dtype))
+    return out
 
 
 def _direction_tensors(enc: _DirectionEncoding) -> Dict:
@@ -1477,29 +1547,19 @@ class TpuPolicyEngine:
     ) -> GridVerdict:
         """Compressed mesh path: the shard_map program runs over the
         class axis — with the ring schedule, a C x C ring over class
-        representatives; the gather epilogue broadcasts back to pod
-        axes device-side (sharded.evaluate_class_grid_sharded)."""
-        import jax.numpy as jnp
-
-        from .sharded import evaluate_class_grid_sharded
+        representatives — and broadcasts back to pod rows inside the
+        same program, each device its own rows, as words
+        (sharded.evaluate_grid_sharded with `class_of`)."""
+        from .sharded import evaluate_grid_sharded
 
         planspec.record("grid.sharded.classes")
-        st = self._class_state
-        pc = st["classes"]
-        tensors = self._ctensors_with_cases(cases)
-        with phase("engine.dispatch_sharded"):
-            ingress, egress, combined = evaluate_class_grid_sharded(
-                tensors, pc.n_classes, pc.class_of_pod, mesh=mesh,
-                schedule=schedule,
-            )
-        ti.CLASS_EVALS.inc(path="sharded")
-        return GridVerdict(
-            self.pod_keys,
-            list(cases),
-            jnp.moveaxis(ingress, -1, 0),
-            jnp.moveaxis(egress, -1, 0),
-            jnp.moveaxis(combined, -1, 0),
+        pc = self._class_state["classes"]
+        tables, eval_id = evaluate_grid_sharded(
+            self._ctensors_with_cases(cases), pc.n_classes, mesh=mesh,
+            schedule=schedule, class_of=pc.class_of_pod,
         )
+        ti.CLASS_EVALS.inc(path="sharded")
+        return GridVerdict(self.pod_keys, list(cases), *tables, eval_id=eval_id)
 
     def _pipelined_classes(self, cases: Sequence[PortCase], reps: int):
         """Compressed twin of the pipelined steady-state measurement:
@@ -3010,21 +3070,11 @@ class TpuPolicyEngine:
             planspec.record("grid.sharded.ring")
         else:
             planspec.record("grid.sharded.allgather")
-        tensors = self._tensors_with_cases(cases)
-        import jax.numpy as jnp
-
-        with phase("engine.dispatch_sharded"):
-            ingress, egress, combined = evaluate_grid_sharded(
-                tensors, self.encoding.cluster.n_pods, mesh=mesh,
-                schedule=schedule,
-            )
-        return GridVerdict(
-            self.pod_keys,
-            list(cases),
-            jnp.moveaxis(ingress, -1, 0),
-            jnp.moveaxis(egress, -1, 0),
-            jnp.moveaxis(combined, -1, 0),
+        tables, eval_id = evaluate_grid_sharded(
+            self._tensors_with_cases(cases), self.encoding.cluster.n_pods,
+            mesh=mesh, schedule=schedule,
         )
+        return GridVerdict(self.pod_keys, list(cases), *tables, eval_id=eval_id)
 
 
 def _parseable_ip(ip: str) -> bool:

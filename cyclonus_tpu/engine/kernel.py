@@ -24,7 +24,7 @@ integers, never rounded to zero).
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -657,11 +657,16 @@ def evaluate_grid_words(tensors: Dict, pack: bool = False) -> Dict[str, jnp.ndar
 
 @contracts.args(class_of="(N,) int32")
 def gather_class_words(
-    out: Dict[str, jnp.ndarray], class_of: jnp.ndarray
+    out: Dict[str, jnp.ndarray],
+    class_of: jnp.ndarray,
+    rows: Optional[jnp.ndarray] = None,
 ) -> Dict[str, jnp.ndarray]:
     """Broadcast class-grid verdicts back to the full pod x pod grid, as
     cell_words: uint32 [Q, N up to a multiple of 8, ceil(N/4) up to a
-    multiple of 128], every pad byte 0.
+    multiple of 128], every pad byte 0.  With `rows` (int32 [R], the
+    class of each row to write, -1 for a pad row) only those rows are
+    written, [Q, R, W]: a device of a mesh gathers its own rows of a
+    table and never holds the others (sharded._class_words).
 
     out: {ingress, egress, combined} [Q, C*, C*] bool over the (possibly
     bucketing-padded) class axes; class_of: [N] int32 pod -> class map
@@ -683,11 +688,16 @@ def gather_class_words(
         zeros = a.shape[1]
         a = jnp.pad(a, ((0, 0), (0, 1), (0, 1)))
         cols = jnp.pad(class_of, (0, -n % WORD_CELLS), constant_values=zeros)
-        rows = jnp.pad(class_of, (0, -n % WORD_TILE[0]), constant_values=zeros)
+        if rows is None:
+            own = jnp.pad(
+                class_of, (0, -n % WORD_TILE[0]), constant_values=zeros
+            )
+        else:
+            own = jnp.where(rows < 0, zeros, rows)
         words = cell_words(
             [jnp.take(a, cols[k::WORD_CELLS], axis=2) for k in range(WORD_CELLS)]
         )
-        return jnp.take(words, rows, axis=1)
+        return jnp.take(words, own, axis=1)
 
     return {k: g(v) for k, v in out.items()}
 

@@ -5,7 +5,11 @@ Sharding layout (see SURVEY.md section 2.7 / 5):
     axis 'x'; policy tensors (selectors, targets, peers, port specs) are
     replicated — they are small.
   * each device computes verdict ROWS for its source-pod block.
-  * output [N_src, N_dst, Q] stays row-sharded until fetched.
+  * the three tables leave the program as kernel.cell_words
+    [Q, N_pad, W] in their final [q, row, word] order, the ROW axis
+    sharded over 'x': each device packs (the class route: gathers and
+    packs) only its own rows, no device ever holds a whole table, and
+    GridVerdict copies them to the host shard by shard.
 
 Two schedules produce bit-identical grids (docs/DESIGN.md "Multi-chip
 scale-out"):
@@ -44,10 +48,16 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..telemetry import instruments as ti
 from ..utils import cachekeys
+from ..utils.tracing import phase
 
 from .kernel import (
+    WORD_CELLS,
+    WORD_FORMAT,
+    WORD_TILE,
     _bool_matmul,
+    cell_words,
     direction_precompute,
+    gather_class_words,
     m_tp_onehot,
     port_spec_allows,
     resolve_tier_lattice,
@@ -398,18 +408,79 @@ def peer_buffer_bytes(
     return 2 * bundle
 
 
+def _block_words(block: jnp.ndarray) -> jnp.ndarray:
+    """A device's rows of a table, bool [R, N, Q], as cell_words
+    uint32 [Q, R, W]: the final order, packed where the rows are."""
+    a = jnp.moveaxis(block, -1, 0)
+    return cell_words([a[..., k::WORD_CELLS] for k in range(WORD_CELLS)])
+
+
+def _dense_words(ingress_rows, egress, combined):
+    """The dense routes' epilogue, inside the sharded program: each
+    device's [Sb, N, Q] blocks as words of its own rows.  Ingress is
+    indexed [dst, src]: its blocks change hands in ONE explicit
+    all_to_all (device j gets the columns of its destinations from
+    every source block), N * N * Q / n_dev bytes a device, and are
+    transposed block by block - never a swapaxes of a whole table."""
+    ingress = jnp.swapaxes(
+        jax.lax.all_to_all(
+            ingress_rows, "x", split_axis=1, concat_axis=0, tiled=True
+        ),
+        0,
+        1,
+    )  # [Db, N_src, Q]
+    return _block_words(ingress), _block_words(egress), _block_words(combined)
+
+
+def _class_words(ingress_rows, egress, combined, rows, cols):
+    """The class route's epilogue, inside the sharded program: the
+    C x C x Q class grids are all-gathered (they are small), ingress
+    takes its [dst, src] orientation THERE, and every device gathers
+    and packs the words of its own pod rows (`rows`: its slice of the
+    pod -> class map, -1 on pad rows; `cols`: the whole map).  No
+    [N, N, Q] value exists anywhere."""
+
+    def whole(a, order):
+        return jnp.transpose(
+            jax.lax.all_gather(a, "x", axis=0, tiled=True), order
+        )
+
+    out = gather_class_words(
+        {
+            "ingress": whole(ingress_rows, (2, 1, 0)),
+            "egress": whole(egress, (2, 0, 1)),
+            "combined": whole(combined, (2, 0, 1)),
+        },
+        cols,
+        rows=rows,
+    )
+    return out["ingress"], out["egress"], out["combined"]
+
+
 #: compiled sharded-grid programs, keyed by (mesh devices, schedule,
-#: shard, in_specs structure).  One entry per (mesh, schedule, shape
-#: family) — re-jitting per eval cost a full retrace every call, and a
-#: same-bucket cluster resize must hit this cache (zero-recompile
-#: contract, pinned by tests/test_engine_sharded.py)
-_SHARDED_PROGRAMS: Dict = {}  # cache-key: mesh, schedule, shard, pack, specs
+#: shard, pack, classes, in_specs structure).  One entry per (mesh,
+#: schedule, shape family) — re-jitting per eval cost a full retrace
+#: every call, and a same-bucket cluster resize must hit this cache
+#: (zero-recompile contract, pinned by tests/test_engine_sharded.py)
+_SHARDED_PROGRAMS: Dict = {}  # cache-key: mesh, schedule, shard, pack, classes, specs
 _SHARDED_PROGRAMS_MAX = 64
+
+#: the tables' sharding as they leave the program: [q, row, word], rows over x
+_WORDS_SPEC = P(None, "x", None)
 
 
 def _sharded_program(
-    mesh: Mesh, schedule: str, shard: int, in_specs: Dict, pack: bool = False
+    mesh: Mesh,
+    schedule: str,
+    shard: int,
+    in_specs: Dict,
+    pack: bool = False,
+    classes: bool = False,
 ):
+    """The jitted shard_map program of one (mesh, schedule, shape
+    family): the schedule's verdict blocks, then the word epilogue of
+    the dense routes (fn(tensors)) or of the class route
+    (fn(tensors, rows, cols))."""
     n_dev = int(mesh.devices.size)
     leaves, treedef = jax.tree_util.tree_flatten(in_specs)
     key = (
@@ -418,32 +489,38 @@ def _sharded_program(
         schedule,
         shard,
         pack,
+        classes,
         treedef,
         tuple(leaves),
     )
     fn = _SHARDED_PROGRAMS.get(key)
     if fn is None:
-        out_specs = (
-            P("x", None, None),
-            P("x", None, None),
-            P("x", None, None),
-        )
         if schedule == "ring":
-            def body(t, _n_dev=n_dev, _shard=shard, _pack=pack):
-                return _ring_grid_eval(t, _n_dev, _shard, _pack)
+            def blocks(t):
+                return _ring_grid_eval(t, n_dev, shard, pack)
         else:
-            body = _sharded_eval
+            blocks = _sharded_eval
+        if classes:
+            def body(t, rows, cols):
+                return _class_words(*blocks(t), rows, cols)
+
+            specs = (in_specs, P("x"), P())
+        else:
+            def body(t):
+                return _dense_words(*blocks(t))
+
+            specs = (in_specs,)
         fn = jax.jit(
             shard_map_no_check(
-                body, mesh=mesh, in_specs=(in_specs,), out_specs=out_specs
+                body, mesh=mesh, in_specs=specs, out_specs=(_WORDS_SPEC,) * 3
             )
         )
         # the persistent AOT executable cache covers the cached sharded
         # programs too (engine/aot_cache.py): a restarted process
         # adopts the ring/allgather executables for its mesh without a
-        # retrace.  The partition-spec structure and the shard/pack
-        # statics are program identity the arg shapes can't see, so
-        # they ride in the plan.
+        # retrace.  The partition-spec structure, the shard/pack
+        # statics, the epilogue and the result's form are program
+        # identity the arg shapes can't see, so they ride in the plan.
         from . import aot_cache
 
         spec_digest = aot_cache.digest(
@@ -454,8 +531,9 @@ def _sharded_program(
             fn,
             schedule=schedule,
             plan=(
-                f"shard={shard};pack={pack};"
-                f"mesh={','.join(mesh.axis_names)}x{n_dev};{spec_digest}"
+                f"shard={shard};pack={pack};classes={classes};"
+                f"mesh={','.join(mesh.axis_names)}x{n_dev};{spec_digest};"
+                f"{WORD_FORMAT}"
             ),
         )
         if cachekeys.ACTIVE:
@@ -463,7 +541,7 @@ def _sharded_program(
                 "sharded.programs",
                 kind="program",
                 components=cachekeys.program(
-                    "mesh", "schedule", "shard", "pack", "specs"
+                    "mesh", "schedule", "shard", "pack", "classes", "specs"
                 ),
             )
         if len(_SHARDED_PROGRAMS) >= _SHARDED_PROGRAMS_MAX:
@@ -472,71 +550,70 @@ def _sharded_program(
     return fn
 
 
-def evaluate_class_grid_sharded(
-    tensors: Dict,
-    n_classes: int,
-    class_of: np.ndarray,
-    mesh: Optional[Mesh] = None,
-    schedule: Optional[str] = None,
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Mesh-sharded evaluation over the COMPRESSED class grid + the
-    int32 gather epilogue back to pod axes.
-
-    `tensors` carries class-representative rows on the pod axis
-    (encoding.gather_class_pod_rows); the shard_map program is exactly
-    evaluate_grid_sharded over that axis — with the ring schedule this
-    is the C x C ring over class representatives — and the broadcast
-    back to the full pod x pod grid is two chained jnp.take gathers per
-    verdict tensor — device-resident, lazy, identical in layout to the
-    dense path's outputs."""
-    ingress, egress, combined = evaluate_grid_sharded(
-        tensors, n_classes, mesh=mesh, schedule=schedule
-    )
-
-    def g(a):
-        # a: [C, C, Q] (either orientation) -> [N, N, Q]
-        return jnp.take(jnp.take(a, class_of, axis=0), class_of, axis=1)
-
-    return g(ingress), g(egress), g(combined)
-
-
 def evaluate_grid_sharded(
     tensors: Dict,
     n_pods: int,
     mesh: Optional[Mesh] = None,
     schedule: Optional[str] = None,
-) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Returns (ingress[N_dst, N_src, Q], egress[N_src, N_dst, Q],
-    combined[N_src, N_dst, Q]) as DEVICE-RESIDENT (immutable) jax arrays,
-    pad rows stripped lazily.  `schedule` picks the peer exchange:
-    "ring" (overlapped, default) or "allgather" (replicated reference);
-    both are bit-identical by construction and pinned so by
-    tests/test_engine_sharded.py."""
+    class_of: Optional[np.ndarray] = None,
+) -> Tuple[Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray], Optional[int]]:
+    """((ingress, egress, combined), eval_id): the three tables as
+    DEVICE-RESIDENT kernel.cell_words uint32 [Q, N_pad, W] (ingress
+    [q, dst, src], egress and combined [q, src, dst]), the row axis
+    sharded over the mesh's 'x' and padded to whole WORD_TILE rows on
+    every device (GridVerdict leaves pad rows and cells out of every
+    view), and the evaluation's number for the fetch spans.
+
+    `schedule` picks the peer exchange: "ring" (overlapped, default) or
+    "allgather" (replicated reference); both are bit-identical by
+    construction and pinned so by tests/test_engine_sharded.py.
+
+    With `class_of` (int32 [N], pod -> class) `tensors` carries class-
+    representative rows on the pod axis (encoding.gather_class_pod_rows)
+    and `n_pods` is the class count: the schedule runs over the class
+    axis - with the ring, a C x C ring over class representatives - and
+    the same program broadcasts back to pod rows (_class_words)."""
     from .encoding import pack_enabled
 
     mesh = mesh or default_mesh()
     schedule = mesh_schedule(schedule)
     pack = pack_enabled()
-    n_dev = mesh.devices.size
-    tensors, padded_n = _pad_pod_arrays(tensors, n_pods, n_dev)
+    n_dev = int(mesh.devices.size)
+    classes = class_of is not None
+    # a device's rows of a table are whole word tiles: on the class
+    # route the rows are pods and the evaluated axis only has to divide
+    step = n_dev * WORD_TILE[0]
+    tensors, padded_n = _pad_pod_arrays(
+        tensors, n_pods, n_dev if classes else step
+    )
     shard = padded_n // n_dev
 
     in_specs = pod_sharded_in_specs(tensors)
-    fn = _sharded_program(mesh, schedule, shard, in_specs, pack=pack)
+    fn = _sharded_program(
+        mesh, schedule, shard, in_specs, pack=pack, classes=classes
+    )
+    args = (tensors,)
+    if classes:
+        class_of = np.asarray(class_of, dtype=np.int32)
+        n_pods = class_of.shape[0]
+        rows = np.full(-(-n_pods // step) * step, -1, np.int32)
+        rows[:n_pods] = class_of
+        args = (tensors, rows, class_of)
     ti.MESH_PEER_BYTES.set(
         peer_buffer_bytes(tensors, n_dev, schedule, pack=pack),
         schedule=schedule,
     )
     with ti.eval_flight(
         "grid.sharded", n_pods, int(tensors["q_port"].shape[0]),
-        devices=int(n_dev), schedule=schedule, dispatch_only=True,
-    ):
+        devices=n_dev, schedule=schedule, dispatch_only=True,
+    ) as fl:
         with mesh_device_context(mesh):
-            ingress_rows, egress, combined = fn(tensors)
-            # stay on device: strip pad rows and fix the ingress layout
-            # ([src, dst, q] -> [dst, src, q]) with lazy jnp ops
-            ingress_rows = ingress_rows[:n_pods, :n_pods]
-            egress = egress[:n_pods, :n_pods]
-            combined = combined[:n_pods, :n_pods]
-            ingress = jnp.swapaxes(ingress_rows, 0, 1)
-    return ingress, egress, combined
+            fn.resolve(*args)
+            with phase(
+                "engine.dispatch_sharded",
+                route="classes" if classes else schedule,
+                devices=n_dev,
+                schedule=schedule,
+            ):
+                out = fn(*args)
+    return out, fl.eval_id

@@ -381,3 +381,120 @@ class TestElasticResize:
             assert fn._cache_size() == ring_fns.get(id(fn), 0), (
                 "same-bucket resize retraced a sharded program"
             )
+
+
+class TestMeshWordPrograms:
+    """The sharded programs emit their tables as row-sharded words
+    (kernel.cell_words) from inside the shard_map: what the lowered
+    program holds, and what its persistent key names."""
+
+    @staticmethod
+    def _lowered(monkeypatch, engine, mesh, schedule=None):
+        """The StableHLO text of the sharded program one evaluation
+        runs, read where evaluate_grid_sharded resolves it."""
+        texts = []
+        real = sharded_mod._sharded_program
+
+        def spy(*a, **kw):
+            fn = real(*a, **kw)
+
+            class Spy:
+                def resolve(self, *args):
+                    texts.append(fn._jitted.lower(*args).as_text())
+
+                def __call__(self, *args):
+                    return fn(*args)
+
+            return Spy()
+
+        monkeypatch.setattr(sharded_mod, "_sharded_program", spy)
+        grid = engine.evaluate_grid_sharded(CASES, mesh=mesh, schedule=schedule)
+        (text,) = texts
+        return text, grid
+
+    @pytest.mark.parametrize("n_dev", [2, 4, 8])
+    def test_class_route_holds_no_pod_grid_boolean(self, monkeypatch, n_dev):
+        """The class route broadcasts back to pods INSIDE the sharded
+        program, each device its own rows, as words: no boolean value of
+        the lowered program is as large as an [N, N] table (the parent
+        built three [N, N, Q] booleans with eager gathers outside)."""
+        import re
+
+        engine, _policy, pods = synthetic_engine(
+            600, n_pols=12, class_compress="1"
+        )
+        n = len(pods)
+        classes = engine.pod_classes().n_classes
+        assert classes * classes * len(CASES) < n * n  # a boolean class grid fits
+        text, grid = self._lowered(monkeypatch, engine, cpu_mesh(n_dev))
+        booleans = re.findall(r"tensor<((?:\d+x)+)i1>", text)
+        assert booleans  # the class grid is there
+        largest = max(
+            int(np.prod([int(d) for d in dims.split("x") if d]))
+            for dims in booleans
+        )
+        assert largest < n * n
+        # the words are there instead, a device's rows of them
+        rows = grid.combined_dev.shape[1] // n_dev
+        width = grid.combined_dev.shape[2]
+        assert f"tensor<{len(CASES)}x{rows}x{width}xui32>" in text
+        assert grids_equal(grid, engine.evaluate_grid(CASES))
+
+    @pytest.mark.parametrize("schedule", ["ring", "allgather"])
+    def test_dense_routes_turn_ingress_by_one_all_to_all_of_blocks(
+        self, monkeypatch, schedule
+    ):
+        """Ingress is indexed [dst, src]: its blocks change hands inside
+        the program, and no boolean value spans all rows of a table."""
+        import re
+
+        engine, _policy, pods = synthetic_engine(100, class_compress="0")
+        text, grid = self._lowered(
+            monkeypatch, engine, cpu_mesh(4), schedule=schedule
+        )
+        assert len(re.findall(r"stablehlo\.all_to_all", text)) == 1
+        n_pad = grid.ingress_dev.shape[1]
+        assert n_pad % (4 * 8) == 0
+        # no boolean table over all rows, in either order of its axes
+        q = len(CASES)
+        assert f"tensor<{n_pad // 4}x{n_pad}x{q}xi1>" in text  # a device's block
+        for whole in (f"{n_pad}x{n_pad}x{q}", f"{q}x{n_pad}x{n_pad}"):
+            assert f"tensor<{whole}xi1>" not in text
+        assert grids_equal(grid, engine.evaluate_grid(CASES))
+
+    @pytest.mark.parametrize("class_compress", ["0", "1"])
+    def test_aot_key_of_sharded_grid_changes_with_the_word_format(
+        self, monkeypatch, class_compress
+    ):
+        """aot_cache.make_key sees nothing of a program's result, so the
+        plan of `sharded.grid` names the form (kernel.WORD_FORMAT): an
+        executable that returns another form lies under another key."""
+        from cyclonus_tpu.engine import aot_cache
+        from cyclonus_tpu.engine.kernel import WORD_FORMAT
+
+        engine, _policy, _pods = synthetic_engine(
+            40, class_compress=class_compress
+        )
+        mesh = cpu_mesh(2)
+
+        def program():
+            sharded_mod._SHARDED_PROGRAMS.clear()
+            engine.evaluate_grid_sharded(CASES, mesh=mesh)
+            (fn,) = sharded_mod._SHARDED_PROGRAMS.values()
+            return fn
+
+        now = program()
+        assert now._name == "sharded.grid"
+        assert now._plan.endswith(";" + WORD_FORMAT)
+        assert f"classes={class_compress == '1'}" in now._plan
+        monkeypatch.setattr(sharded_mod, "WORD_FORMAT", "out=bits.1x1")
+        other = program()
+        assert other._plan == now._plan.replace(WORD_FORMAT, "out=bits.1x1")
+        keys = {
+            aot_cache.make_key(
+                "sharded.grid", "sig", schedule=fn._schedule, plan=fn._plan
+            )
+            for fn in (now, other)
+        }
+        assert len(keys) == 2
+        sharded_mod._SHARDED_PROGRAMS.clear()

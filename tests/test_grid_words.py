@@ -1,6 +1,8 @@
 """The finished tables' device format (docs/DESIGN.md "GridVerdict"): the
-single-device grid programs hand `GridVerdict` 32-bit words, four cells a
-word, and the host lays a boolean view over them; every other route keeps
+single-device grid programs and, since PR 28, the mesh routes hand
+`GridVerdict` 32-bit words, four cells a word, and the host lays a boolean
+view over them (the mesh routes' words are row-sharded over the mesh and come
+to the host shard by shard); the native evaluator and the empty case keep
 handing it boolean tables.  Either way the caller sees bool [Q, N, N], held
 here to the scalar oracle."""
 
@@ -125,6 +127,86 @@ def test_word_tables_equal_the_oracle(route, n, q, tiered):
     )
 
 
+#: mesh route -> (the class_compress, the schedule) that take it
+MESH_ROUTES = {
+    "classes": ("1", None), "ring": ("0", "ring"), "allgather": ("0", "allgather"),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def cluster(n: int):
+    """A seeded cluster too large for the scalar oracle, and the tables of
+    the single-device dense route over CASES."""
+    pods, namespaces, policies = build_synthetic(n, 12, random.Random(27 + n))
+    policy = build_network_policies(True, policies)
+    ref = TpuPolicyEngine(policy, pods, namespaces, class_compress="0")
+    grid = ref.evaluate_grid(CASES)
+    return policy, pods, namespaces, {name: getattr(grid, name) for name in TABLES}
+
+
+@pytest.mark.parametrize("n", [9, 130, 1027])
+@pytest.mark.parametrize("n_dev", [2, 4, 8])
+@pytest.mark.parametrize("route", list(MESH_ROUTES))
+def test_mesh_word_tables_are_row_sharded_and_exact(route, n_dev, n):
+    """Every mesh route hands over cell_words [Q, N_pad, W] whose ROW axis
+    is split over the mesh: no device holds more than its share of a table,
+    and the host's tables equal evaluate_grid's bit for bit (and the
+    oracle's, where the oracle is affordable)."""
+    policy, pods, namespaces, single = cluster(n)
+    class_compress, schedule = MESH_ROUTES[route]
+    engine = TpuPolicyEngine(
+        policy, pods, namespaces, class_compress=class_compress
+    )
+    grid = engine.evaluate_grid_sharded(
+        CASES, mesh=cpu_mesh(n_dev), schedule=schedule
+    )
+    tables = []
+    for name in TABLES:
+        dev = getattr(grid, name + "_dev")
+        assert dev.dtype == np.uint32 and dev.shape[0] == len(CASES)
+        rows = dev.shape[1]
+        assert rows >= n and rows % (n_dev * WORD_TILE[0]) == 0
+        assert dev.shape[2] % WORD_TILE[1] == 0
+        assert dev.shape[2] >= -(-n // WORD_CELLS)
+        assert tuple(dev.sharding.spec)[:2] == (None, "x")
+        shards = dev.addressable_shards
+        assert len(shards) == n_dev
+        held = sorted(sh.index[1].indices(rows)[:2] for sh in shards)
+        share = rows // n_dev
+        assert held == [(k * share, (k + 1) * share) for k in range(n_dev)]
+        for sh in shards:
+            assert sh.data.shape == (len(CASES), share, dev.shape[2])
+        table = getattr(grid, name)
+        assert table.dtype == np.bool_ and table.shape == (len(CASES), n, n)
+        assert np.array_equal(table, single[name]), name
+        if n <= 130:
+            assert np.array_equal(table, problem(n, False)[4][name]), name
+        assert getattr(grid, name) is table
+        tables.append(table)
+    assert_same_answers(grid, tables)
+
+
+def test_a_large_shard_is_laid_into_the_table_in_row_blocks(monkeypatch):
+    """Above _LAY_BYTES a shard goes into the table's host buffer in row
+    blocks on the lay threads; the table is the same, bit for bit."""
+    from cyclonus_tpu.engine import api
+
+    policy, pods, namespaces, single = cluster(130)
+    engine = TpuPolicyEngine(policy, pods, namespaces, class_compress="1")
+    blocks = []
+    real = np.copyto
+    monkeypatch.setattr(api, "_LAY_BYTES", 4 << 10)
+    monkeypatch.setattr(
+        api.np, "copyto", lambda dst, src: (blocks.append(dst.shape), real(dst, src))
+    )
+    grid = engine.evaluate_grid_sharded(CASES, mesh=cpu_mesh(4))
+    for name in TABLES:
+        assert np.array_equal(getattr(grid, name), single[name]), name
+    # 3 tables x 4 shards x 3 port cases, a shard's 40 rows of 128 words in
+    # blocks of 8 rows (4 KiB)
+    assert blocks == [(8, 128)] * (3 * 4 * 3 * 5)
+
+
 def boolean_grid(form: str):
     """A GridVerdict as each route that does NOT emit words builds it, and
     the tables it must return."""
@@ -138,24 +220,30 @@ def boolean_grid(form: str):
         import jax.numpy as jnp
 
         return GridVerdict(keys, list(CASES), *map(jnp.asarray, tables)), tables
+    assert form == "empty"
+    engine = TpuPolicyEngine(policy, pods, namespaces, class_compress="0")
+    return engine.evaluate_grid([]), [t[:0] for t in tables]
+
+
+@pytest.mark.parametrize("schedule", ["ring", "allgather"])
+@pytest.mark.parametrize("class_compress", ["0", "1"])
+def test_a_one_device_mesh_hands_over_words_on_one_device(class_compress, schedule):
+    """A table on one device takes the single-device copy (no shard copy)."""
+    policy, pods, namespaces, _, want = problem(13, False)
     engine = TpuPolicyEngine(
-        policy, pods, namespaces,
-        class_compress="1" if form == "sharded.classes" else "0",
+        policy, pods, namespaces, class_compress=class_compress
     )
-    if form == "empty":
-        return engine.evaluate_grid([]), [t[:0] for t in tables]
-    schedule = None if form == "sharded.classes" else form.split(".")[1]
-    return (
-        engine.evaluate_grid_sharded(CASES, mesh=cpu_mesh(8), schedule=schedule),
-        tables,
-    )
+    grid = engine.evaluate_grid_sharded(CASES, mesh=cpu_mesh(1), schedule=schedule)
+    tables = [getattr(grid, name) for name in TABLES]
+    for name, table in zip(TABLES, tables):
+        dev = getattr(grid, name + "_dev")
+        assert dev.dtype == np.uint32 and len(dev.sharding.device_set) == 1
+        assert np.array_equal(table, want[name])
+        assert np.shares_memory(table, np.asarray(dev))  # a view, no assembly
+    assert_same_answers(grid, tables)
 
 
-@pytest.mark.parametrize(
-    "form",
-    ["native", "device", "empty", "sharded.ring", "sharded.allgather",
-     "sharded.classes"],
-)
+@pytest.mark.parametrize("form", ["native", "device", "empty"])
 def test_boolean_tables_behave_as_before(form):
     grid, want = boolean_grid(form)
     assert grid.ingress_dev.dtype == np.bool_

@@ -271,7 +271,11 @@ class TestEvaluationSpans:
         # in any trace); the host's boolean table is a view of them
         words = np.asarray(out.combined_dev)
         assert fetch["attrs"] == {"table": "combined", "form": "words"}
-        assert copy["attrs"] == {"bytes": words.nbytes, "dtype": "uint32"}
+        assert copy["attrs"] == {
+            "bytes": words.nbytes, "dtype": "uint32", "shards": 1,
+        }
+        # a table on one device is copied whole: no shard copies
+        assert by_name(found, "grid.shard_copy") == []
         assert table.dtype == np.bool_ and np.shares_memory(table, words)
         assert wait["dur_s"] + copy["dur_s"] <= fetch["dur_s"]
         assert fetch["start_s"] <= wait["start_s"] <= copy["start_s"]
@@ -343,6 +347,98 @@ class TestEvaluationSpans:
         # either single-device route hands the host 32-bit words
         (copy,) = by_name(found, "grid.copy")
         assert copy["attrs"]["dtype"] == "uint32"
+
+
+class TestMeshSpans:
+    """The mesh routes (`evaluate_grid_sharded`): the dispatch span names
+    the route, the mesh and the schedule, the evaluation's number rides
+    into the fetches as on the one-chip routes, and a row-sharded table
+    comes to the host in one `grid.shard_copy` a shard."""
+
+    @pytest.mark.parametrize(
+        "route,class_compress,schedule",
+        [("classes", "1", None), ("ring", "0", "ring"),
+         ("allgather", "0", "allgather")],
+    )
+    @pytest.mark.parametrize("n_dev", [2, 4])
+    def test_one_shard_copy_a_shard_under_one_eval_id(
+        self, cluster, tmp_path, route, class_compress, schedule, n_dev
+    ):
+        from jax.sharding import Mesh
+
+        from cyclonus_tpu.engine import PortCase, TpuPolicyEngine
+        from cyclonus_tpu.matcher import build_network_policies
+
+        pods, namespaces, policies = cluster
+        eng = TpuPolicyEngine(
+            build_network_policies(True, policies), pods, namespaces,
+            class_compress=class_compress,
+        )
+        cases = [PortCase(80, "serve-80-tcp", "TCP"), PortCase(81, "", "UDP")]
+        mesh = Mesh(np.array(jax.devices("cpu")[:n_dev]), ("x",))
+        eng.evaluate_grid_sharded(cases, mesh=mesh, schedule=schedule)  # warm
+        with capture(tmp_path):
+            out = eng.evaluate_grid_sharded(cases, mesh=mesh, schedule=schedule)
+            tables = out.ingress, out.egress, out.combined
+        found = events.capture_spans()
+        (root,) = by_name(found, "engine.eval")
+        assert root["attrs"]["route"] == "grid.sharded"
+        assert out.eval_id == root["eval_id"] is not None
+        (dispatch,) = by_name(found, "engine.dispatch_sharded")
+        assert dispatch["path"] == "engine.eval/engine.dispatch_sharded"
+        assert dispatch["attrs"] == {
+            "route": route, "devices": n_dev,
+            "schedule": schedule or "ring",
+        }
+        copies = by_name(found, "grid.copy")
+        assert len(copies) == 3
+        words = np.asarray(out.combined_dev)
+        for copy in copies:
+            assert copy["attrs"] == {
+                "bytes": words.nbytes, "dtype": "uint32", "shards": n_dev,
+            }
+        shard_copies = by_name(found, "grid.shard_copy")
+        assert len(shard_copies) == 3 * n_dev
+        assert {sp["path"] for sp in shard_copies} == {
+            "grid.fetch/grid.copy/grid.shard_copy"
+        }
+        devices = sorted(d.id for d in mesh.devices.flat)
+        for k in range(3):
+            of_table = shard_copies[k * n_dev:(k + 1) * n_dev]
+            assert sorted(sp["attrs"]["device"] for sp in of_table) == devices
+            assert sum(sp["attrs"]["bytes"] for sp in of_table) == words.nbytes
+            assert {sp["attrs"]["dtype"] for sp in of_table} == {"uint32"}
+            assert sum(sp["dur_s"] for sp in of_table) <= copies[k]["dur_s"]
+        # the evaluation and its fetches carry the evaluation's number
+        # (the case tensors are made before the sharded evaluation opens)
+        for sp in found["spans"]:
+            if sp["path"].startswith(("engine.eval", "grid.")):
+                assert sp["eval_id"] == root["eval_id"], sp["path"]
+        # one buffer of the final shape a table, viewed and not copied
+        for table in tables:
+            assert table.dtype == np.bool_ and table.base is not None
+
+    def test_outside_a_capture_a_shard_copy_is_no_span(self, cluster):
+        """`grid.shard_copy` is a detail span: it exists only while
+        something keeps a timeline."""
+        from jax.sharding import Mesh
+
+        from cyclonus_tpu.engine import PortCase, TpuPolicyEngine
+        from cyclonus_tpu.matcher import build_network_policies
+        from cyclonus_tpu.utils import tracing
+
+        pods, namespaces, policies = cluster
+        eng = TpuPolicyEngine(
+            build_network_policies(True, policies), pods, namespaces,
+            class_compress="1",
+        )
+        mesh = Mesh(np.array(jax.devices("cpu")[:2]), ("x",))
+        tracing.reset()
+        out = eng.evaluate_grid_sharded([PortCase(80, "", "TCP")], mesh=mesh)
+        assert out.combined.shape == (1, len(pods), len(pods))
+        stats = tracing.stats()
+        assert stats["grid.copy"]["count"] == 1
+        assert "grid.shard_copy" not in stats
 
 
 class TestServeSpans:
