@@ -304,6 +304,10 @@ def scenario_poisoned_caches(
             _poison_file(path, modes[i % len(modes)])
         with open(tune_path, "w") as f:
             f.write('{"v": 1, "entries": {truncated')
+        # the directory's contents were replaced under a live process:
+        # without this the fresh engine shares eng_a's executables and
+        # never opens a poisoned file
+        aot_cache.forget()
         corrupt0 = ti.AOT_CACHE.value(outcome="corrupt") + ti.AOT_CACHE.value(
             outcome="stale"
         )

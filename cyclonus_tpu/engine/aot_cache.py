@@ -32,6 +32,18 @@ Security note: entries are pickles (the serialize_executable payload
 format), loaded only from the operator's own cache directory — the same
 trust boundary as the autotune cache and JAX's own compilation cache.
 
+In front of the files stands a MEMORY TIER of the process (`_SHARED`):
+the executables this process has already adopted or built, under the
+same key string and the directory they belong to.  A new engine of
+shapes the process has loaded resolves its programs there (counter
+outcome `shared`, span attr how="shared"): no file, no unpickle, no
+`deserialize_and_load`.  A LIVE process therefore does nothing with a
+file that changes under it, be it replaced, poisoned or deleted, until
+`forget()` or a restart: it goes on running the executable it holds,
+which is a valid one for that key.  The restart contract and the
+poisoned-entry contract above are about NEW processes, which start with
+an empty memory tier, and are unchanged.
+
 CYCLONUS_AOT_CACHE: cache directory; "0"/"" disables entirely (the test
 suite default — tests/conftest.py — so suites never share executables
 through the checkout's cache); unset -> `aot/` under the engine's
@@ -47,6 +59,7 @@ import logging
 import os
 import pickle
 import tempfile
+import threading
 from typing import Any, Dict, Optional, Tuple
 
 from ..telemetry.spans import span
@@ -57,6 +70,30 @@ log = logging.getLogger(__name__)
 #: bump when the entry layout changes: stale versions are ignored
 #: (fresh compile), never migrated
 CACHE_VERSION = 1
+
+#: the memory tier holds at most this many executables, least recently
+#: resolved out first.  An engine resolves two to six (grid + unpack,
+#: counts, pairs, the sharded grid), a `serve` replica a dozen over its
+#: pair-batch buckets, `generate`'s suite a few shape buckets of each.
+SHARED_MAX = 64
+
+#: (cache directory, persisted key string) -> loaded executable, oldest
+#: first.  The lock covers the mapping's own bookkeeping alone, never a
+#: load or a compile: the worst interleaving loads one key twice, both
+#: valid, and the last is kept.
+_SHARED_LOCK = threading.Lock()
+_SHARED: Dict[Tuple[str, str], Any] = {}  # guarded-by: _SHARED_LOCK  # cache-key: cache_dir, name, signature, platform, schedule, plan
+
+if cachekeys.ACTIVE:
+    # the entry's identity is the file's (make_key) and its directory
+    cachekeys.register(
+        "aot.shared",
+        kind="program",
+        components=cachekeys.program(
+            "cache_dir", "name", "signature", "platform", "schedule", "plan"
+        ),
+    )
+
 
 def cache_dir() -> Optional[str]:  # never-raises
     """Resolved cache directory, or None when persistence is disabled."""
@@ -221,6 +258,42 @@ def store(key: str, compiled) -> bool:  # never-raises
         return False
 
 
+def _shared_get(base: str, key: str):
+    """The memory tier's executable for `key` under directory `base`,
+    made its most recent entry, or None."""
+    with _SHARED_LOCK:
+        compiled = _SHARED.pop((base, key), None)
+        if compiled is not None:
+            _SHARED[(base, key)] = compiled
+    return compiled
+
+
+def _shared_put(base: str, key: str, compiled) -> None:
+    """Keep `compiled` as the most recent entry and drop the oldest
+    beyond SHARED_MAX (an AotProgram that still holds a dropped
+    executable in its own `_programs` keeps it alive)."""
+    with _SHARED_LOCK:
+        _SHARED.pop((base, key), None)
+        while len(_SHARED) >= SHARED_MAX:
+            del _SHARED[next(iter(_SHARED))]
+        _SHARED[(base, key)] = compiled
+
+
+def _shared_drop(base: str, key: str) -> None:
+    """A loaded executable the runtime rejected is not handed on."""
+    with _SHARED_LOCK:
+        _SHARED.pop((base, key), None)
+
+
+def forget() -> None:
+    """Empty the memory tier: the next resolve of every key goes back
+    to the directory.  For tests that stand for a new process, and for
+    a caller that replaced the directory's contents under a live one.
+    Executables that an AotProgram has resolved stay with it."""
+    with _SHARED_LOCK:
+        _SHARED.clear()
+
+
 def _count(outcome: str) -> None:
     from ..telemetry import instruments as ti
 
@@ -230,12 +303,15 @@ def _count(outcome: str) -> None:
 def counters() -> Dict[str, Any]:
     """The per-process AOT cache forensics bench.py records as
     detail.aot_cache: hits (executables adopted from disk —
-    `adopted` aliases it for the acceptance schema), misses, stores,
-    and fresh compiles actually paid (the restart gate's flat line)."""
+    `adopted` aliases it for the acceptance schema), shared (resolved
+    in the memory tier: loaded earlier by this process), misses,
+    stores, and fresh compiles actually paid (the restart gate's flat
+    line)."""
     from ..telemetry import instruments as ti
 
     return {
         "hits": int(ti.AOT_CACHE.value(outcome="hit")),
+        "shared": int(ti.AOT_CACHE.value(outcome="shared")),
         "misses": int(ti.AOT_CACHE.value(outcome="miss")),
         "adopted": int(ti.AOT_CACHE.value(outcome="hit")),
         "stores": int(ti.AOT_CACHE.value(outcome="store")),
@@ -280,11 +356,16 @@ def signature_string(key) -> str:
 class AotProgram:
     """Wrap a jitted callable with the persistent executable cache.
 
-    On the first call per argument signature: try to ADOPT a serialized
-    executable (zero trace, zero compile); otherwise lower+compile via
-    the wrapped jit (counted in AOT_COMPILES) and persist the result.
-    Later calls with the same signature dispatch the resolved
-    executable directly.  Any failure anywhere — an unserializable
+    On the first call per argument signature: take the executable the
+    PROCESS already holds under the same key (the memory tier: another
+    wrapper, usually an earlier engine's, adopted or built it), else
+    try to ADOPT a serialized executable (zero trace, zero compile),
+    else lower+compile via the wrapped jit (counted in AOT_COMPILES)
+    and persist the result; what the last two yield goes into the
+    memory tier.  Later calls with the same signature dispatch the
+    resolved executable directly.  A file that changes under a live
+    process is not looked at again for a key the process holds, until
+    `forget()`.  Any failure anywhere — an unserializable
     program, a runtime that rejects the AOT path, statics the lowering
     chokes on — pins a per-signature FALLBACK to the plain jitted
     callable, so the wrapper can never be less robust than the jit it
@@ -295,6 +376,9 @@ class AotProgram:
     autotune orphan only ever calls through programs resolved earlier
     on the issuing thread (dict reads are atomic under the GIL; the
     worst interleaving resolves the same signature twice, both valid).
+    The memory tier is shared by every thread's engines under its own
+    lock, held for the bookkeeping alone: one key may be loaded twice,
+    and the last is kept.
     """
 
     def __init__(
@@ -339,9 +423,9 @@ class AotProgram:
     def _program(self, args, kwargs):
         """(key, dynamic kwargs, the executable or None=fallback) for
         this call's signature, obtaining the executable first where the
-        signature is new: span `engine.program`, attr `how` = adopted
-        (from the persistent cache), built (lowered and compiled here)
-        or fallback."""
+        signature is new: span `engine.program`, attr `how` = shared
+        (from the memory tier), adopted (from the persistent cache),
+        built (lowered and compiled here) or fallback."""
         statics = tuple(
             (k, kwargs[k]) for k in self._static_argnames if k in kwargs
         )
@@ -350,9 +434,10 @@ class AotProgram:
         }
         key = (call_key(args, dyn_kwargs), statics)
         if key not in self._programs:
-            sig = signature_string(key[0]) + "|" + repr(statics)
             with span("engine.program", program=self._name) as sp:
-                self._programs[key] = self._resolve(sig, args, kwargs, sp)
+                self._programs[key] = self._resolve(
+                    self._persisted_key(key), args, kwargs, sp
+                )
         return key, dyn_kwargs, self._programs[key]
 
     def resolve(self, *args, **kwargs) -> None:
@@ -373,17 +458,30 @@ class AotProgram:
             return compiled(*args, **dyn_kwargs)
         except Exception:
             # a loaded executable the runtime rejects at CALL time
-            # (device moved, donation mismatch): fall back for good
+            # (device moved, donation mismatch): fall back for good,
+            # and do not hand it to the next engine either
             _count("call_fallback")
             self._programs[key] = None
+            _shared_drop(cache_dir(), self._persisted_key(key))
             return self._jitted(*args, **kwargs)
 
-    def _resolve(self, sig: str, args, kwargs, sp):
-        from ..telemetry import instruments as ti
-
-        key = make_key(
+    def _persisted_key(self, key) -> str:
+        """The key string of the file, and of the memory tier's entry,
+        for one `_programs` key."""
+        sig = signature_string(key[0]) + "|" + repr(key[1])
+        return make_key(
             self._name, sig, schedule=self._schedule, plan=self._plan
         )
+
+    def _resolve(self, key: str, args, kwargs, sp):
+        from ..telemetry import instruments as ti
+
+        base = cache_dir()
+        compiled = _shared_get(base, key)
+        if compiled is not None:
+            ti.AOT_CACHE.inc(outcome="shared")
+            sp.set(how="shared")
+            return compiled
         try:
             compiled = load(key)
         except Exception:  # belt and braces: load already never raises
@@ -391,6 +489,7 @@ class AotProgram:
         if compiled is not None:
             ti.AOT_CACHE.inc(outcome="hit")
             sp.set(how="adopted")
+            _shared_put(base, key, compiled)
             return compiled
         ti.AOT_CACHE.inc(outcome="miss")
         sp.set(how="built")
@@ -406,4 +505,7 @@ class AotProgram:
             sp.set(how="fallback")
             return None
         store(key, compiled)
+        # whether or not it could be serialised: the next engine need
+        # not read back what this one just wrote
+        _shared_put(base, key, compiled)
         return compiled

@@ -206,8 +206,9 @@ AUTOTUNE_CACHE = REGISTRY.counter(
 AOT_CACHE = REGISTRY.counter(
     "cyclonus_tpu_aot_cache_total",
     "Persistent AOT executable-cache events by outcome: hit (serialized "
-    "executable adopted — zero trace, zero compile), miss (no entry -> "
-    "fresh lower+compile), store (executable persisted), corrupt/stale "
+    "executable adopted from disk — zero trace, zero compile), shared "
+    "(the process had loaded it already: no file read), miss (no entry "
+    "-> fresh lower+compile), store (executable persisted), corrupt/stale "
     "(entry rejected -> fresh compile), unserializable (store refused "
     "by the runtime), fallback (wrapper pinned to plain jit).",
     labelnames=("outcome",),

@@ -18,6 +18,9 @@ Covered cache families (the acceptance list in ISSUE 13):
     adopted with zero fresh compiles, and a mutated dtype-plan
     component (CYCLONUS_PACK) misses every entry while the verdicts
     stay bit-identical;
+  * the memory tier in front of those files (aot_cache._SHARED, the
+    executables this process has loaded) — each of its six components
+    sends a new wrapper past the tier, the revert shares again;
   * the persisted autotune winner cache (engine/autotune.py) — every
     shape-bucket field, the mesh signature, and the dtype plan;
   * the in-process sharded-program cache (engine/sharded.py
@@ -190,6 +193,74 @@ def scenario_aot_key_fields(ctx: Ctx) -> Dict:
     except ImportError:  # pragma: no cover - jaxlib always rides jax here
         pass
     return {"mutations": muts}
+
+
+#: what `aot_cache` registers for its memory tier (the census leg
+#: compares); the scenario below mutates exactly these
+SHARED_TIER_COMPONENTS = (
+    "cache_dir", "name", "signature", "platform", "schedule", "plan"
+)
+
+
+def scenario_shared_tier_key(ctx: Ctx) -> Dict:
+    """The memory tier in front of the AOT files (aot_cache._SHARED): a
+    new wrapper of a key the process has loaded shares the executable
+    (outcome `shared`); with any one component mutated it does not, and
+    with the component back it shares again."""
+    import jax
+    import jax.numpy as jnp
+
+    from cyclonus_tpu.engine import aot_cache
+    from cyclonus_tpu.telemetry import instruments as ti
+
+    x8 = jnp.arange(8, dtype=jnp.int32)
+    x16 = jnp.arange(16, dtype=jnp.int32)
+
+    def shares(name="kh.shared", plan="p0", schedule="single", x=x8) -> bool:
+        """Whether a new wrapper got its executable from the tier."""
+        before = ti.AOT_CACHE.value(outcome="shared")
+        aot_cache.AotProgram(
+            name, jax.jit(lambda v: v + 1), plan=plan, schedule=schedule
+        ).resolve(x)
+        return ti.AOT_CACHE.value(outcome="shared") == before + 1
+
+    def other_dir() -> bool:
+        with _env(CYCLONUS_AOT_CACHE=os.path.join(ctx.tmp, "aot-shared-2")):
+            return shares()
+
+    def other_platform() -> bool:
+        orig = jax.__version__
+        try:
+            jax.__version__ = orig + ".mut"
+            return shares()
+        finally:
+            jax.__version__ = orig
+
+    mutations = {
+        "cache_dir": other_dir,
+        "name": lambda: shares(name="kh.shared2"),
+        "signature": lambda: shares(x=x16),
+        "platform": other_platform,
+        "schedule": lambda: shares(schedule="ring"),
+        "plan": lambda: shares(plan="p1"),
+    }
+    _check(
+        tuple(mutations) == SHARED_TIER_COMPONENTS,
+        "aot.shared", "components", "a component has no mutation",
+    )
+    with _env(CYCLONUS_AOT_CACHE=os.path.join(ctx.tmp, "aot-shared")):
+        aot_cache.forget()
+        _check(not shares(), "aot.shared", "cold", "an empty tier shared")
+        _check(shares(), "aot.shared", "steady", "same key did not share")
+        for component, mutated_shares in mutations.items():
+            _check(
+                not mutated_shares(),
+                "aot.shared", component, "mutation did not miss",
+            )
+            _check(shares(), "aot.shared", component, "revert did not hit")
+        aot_cache.forget()
+        _check(not shares(), "aot.shared", "forget", "shared after forget()")
+    return {"mutations": len(mutations)}
 
 
 def scenario_autotune_key_fields(ctx: Ctx) -> Dict:
@@ -532,8 +603,13 @@ print(json.dumps({{
         any(n.startswith("aot:") for n in names),
         "registry", "aot", f"no AOT families registered: {names}",
     )
-    for family in ("autotune", "sharded.programs"):
+    for family in ("autotune", "sharded.programs", "aot.shared"):
         _check(family in names, "registry", family, f"not registered: {names}")
+    _check(
+        tuple(out["components"]["aot.shared"]) == SHARED_TIER_COMPONENTS,
+        "registry", "aot.shared",
+        f"components differ: {out['components']['aot.shared']}",
+    )
     _check(
         "aot:pairs" in names, "registry", "aot:pairs",
         f"serve pair program not registered: {names}",
@@ -547,6 +623,7 @@ print(json.dumps({{
 #: (name, fn, in_quick_slice)
 SCENARIOS: List[Tuple[str, Callable[[Ctx], Dict], bool]] = [
     ("aot_key_fields", scenario_aot_key_fields, True),
+    ("shared_tier_key", scenario_shared_tier_key, True),
     ("autotune_key_fields", scenario_autotune_key_fields, True),
     ("invalidate_derived_contract", scenario_invalidate_derived_contract, True),
     ("pairs_program_key", scenario_pairs_program_key, True),

@@ -231,7 +231,8 @@ class TestCacheModule:
 
     def test_aot_program_round_trip_in_process(self, tmp_path, monkeypatch):
         """AotProgram stores on first call and a FRESH wrapper adopts
-        from disk (load path exercised without a subprocess)."""
+        from disk (load path exercised without a subprocess: forget()
+        stands for the new process, whose memory tier is empty)."""
         import jax
         import jax.numpy as jnp
 
@@ -242,10 +243,13 @@ class TestCacheModule:
         x = jnp.arange(8, dtype=jnp.int32)
         p1 = aot_cache.AotProgram("t.roundtrip", jitted, plan="unit")
         out1 = p1(x)
+        aot_cache.forget()
         hits0 = ti.AOT_CACHE.value(outcome="hit")
+        shared0 = ti.AOT_CACHE.value(outcome="shared")
         p2 = aot_cache.AotProgram("t.roundtrip", jitted, plan="unit")
         out2 = p2(x)
         assert ti.AOT_CACHE.value(outcome="hit") == hits0 + 1
+        assert ti.AOT_CACHE.value(outcome="shared") == shared0
         assert (out1 == out2).all()
 
     def test_aot_program_falls_back_on_unlowerable(self, tmp_path, monkeypatch):
@@ -262,9 +266,11 @@ class TestCacheModule:
 
     def test_counters_schema(self):
         c = aot_cache.counters()
-        for k in ("hits", "misses", "adopted", "stores", "compiles", "dir"):
+        for k in (
+            "hits", "shared", "misses", "adopted", "stores", "compiles", "dir"
+        ):
             assert k in c
-        assert c["adopted"] == c["hits"]
+        assert c["adopted"] == c["hits"]  # `hit` still means: from disk
 
 
 class TestGridResultFormat:
@@ -336,10 +342,277 @@ class TestGridResultFormat:
         assert aot_cache.load(old_key) is not None  # it WOULD load
         path.unlink()
         # a new process' engine: finds only the old entry, builds its own
+        aot_cache.forget()
         grid = engine().evaluate_grid(cases)
         assert grid.combined_dev.dtype == np.uint32
         assert np.array_equal(grid.combined, want)
         assert set(entries()) == {key, old_key}
+
+
+def _outcomes():
+    from cyclonus_tpu.telemetry import instruments as ti
+
+    return {
+        "shared": ti.AOT_CACHE.value(outcome="shared"),
+        "hit": ti.AOT_CACHE.value(outcome="hit"),
+        "miss": ti.AOT_CACHE.value(outcome="miss"),
+        "compiles": ti.AOT_COMPILES.value(),
+    }
+
+
+def _moved(before):
+    """The outcomes that changed since `before`, and by how much."""
+    return {
+        k: v - before[k] for k, v in _outcomes().items() if v != before[k]
+    }
+
+
+class TestMemoryTier:
+    """The process-wide tier in front of the files: a wrapper of a key
+    the process has already loaded shares the loaded executable.  Where
+    a wrapper would get its executable from the DISK it is resolved and
+    not called: on the suite's eight virtual devices a deserialized
+    one-device executable is loaded for all eight and rejected at call
+    time (ROADMAP D12).  That each key component keeps wrappers apart
+    is tests/keyharness.py's `shared_tier_key`."""
+
+    @pytest.fixture(autouse=True)
+    def _fresh_tier(self, tmp_path, monkeypatch):
+        monkeypatch.setenv("CYCLONUS_AOT_CACHE", str(tmp_path / "aot"))
+        aot_cache.forget()
+        yield
+        aot_cache.forget()
+
+    @staticmethod
+    def _wrapper(name="t.shared", plan="unit"):
+        import jax
+
+        return aot_cache.AotProgram(name, jax.jit(lambda x: x * 3 + 1), plan=plan)
+
+    @staticmethod
+    def _x(n=8):
+        import jax.numpy as jnp
+
+        return jnp.arange(n, dtype=jnp.int32)
+
+    def test_second_wrapper_shares_even_without_the_file(self, tmp_path):
+        x = self._x()
+        want = self._wrapper()(x)
+        (entry,) = (tmp_path / "aot").glob("*.aotx")
+        entry.unlink()
+        before = _outcomes()
+        assert (self._wrapper()(x) == want).all()
+        assert _moved(before) == {"shared": 1}
+        assert not list((tmp_path / "aot").glob("*.aotx"))  # nothing rewritten
+
+    def test_forget_sends_the_next_wrapper_back_to_the_disk(self):
+        x = self._x()
+        want = self._wrapper()(x)  # built here: callable on any host
+        aot_cache.forget()
+        before = _outcomes()
+        self._wrapper().resolve(x)
+        assert _moved(before) == {"hit": 1}
+        # and what the disk gave is in the tier again
+        before = _outcomes()
+        self._wrapper().resolve(x)
+        assert _moved(before) == {"shared": 1}
+        # forget() leaves a wrapper what it has resolved
+        first = self._wrapper()
+        first(x)
+        aot_cache.forget()
+        before = _outcomes()
+        assert (first(x) == want).all()
+        assert _moved(before) == {}
+
+    def test_another_cache_directory_does_not_share(self, tmp_path, monkeypatch):
+        x = self._x()
+        self._wrapper()(x)
+        monkeypatch.setenv("CYCLONUS_AOT_CACHE", str(tmp_path / "other"))
+        before = _outcomes()
+        self._wrapper()(x)
+        assert _moved(before) == {"miss": 1, "compiles": 1}
+        monkeypatch.setenv("CYCLONUS_AOT_CACHE", str(tmp_path / "aot"))
+        before = _outcomes()
+        self._wrapper()(x)
+        assert _moved(before) == {"shared": 1}
+
+    def test_lru_never_holds_more_than_its_bound(self, monkeypatch):
+        monkeypatch.setattr(aot_cache, "SHARED_MAX", 3)
+        x = self._x()
+        for i in range(5):
+            self._wrapper(plan=f"p{i}")(x)
+            assert len(aot_cache._SHARED) <= 3
+        assert len(aot_cache._SHARED) == 3
+        # p2 is now the oldest: resolving it makes it the newest, and
+        # the next new key pushes p3 out, not p2
+        before = _outcomes()
+        self._wrapper(plan="p2")(x)
+        self._wrapper(plan="p5")(x)
+        self._wrapper(plan="p2")(x)
+        assert _moved(before) == {"shared": 2, "miss": 1, "compiles": 1}
+        before = _outcomes()
+        self._wrapper(plan="p3").resolve(x)  # evicted: back to its file
+        assert _moved(before) == {"hit": 1}
+        assert len(aot_cache._SHARED) == 3
+
+    def test_an_evicted_executable_stays_with_the_wrapper_that_holds_it(
+        self, monkeypatch
+    ):
+        monkeypatch.setattr(aot_cache, "SHARED_MAX", 1)
+        x = self._x()
+        first = self._wrapper(plan="p0")
+        want = first(x)
+        self._wrapper(plan="p1")(x)
+        before = _outcomes()
+        assert (first(x) == want).all()
+        assert _moved(before) == {}
+
+    def test_a_call_time_rejection_evicts(self):
+        x = self._x()
+        want = self._wrapper()(x)
+        (slot,) = aot_cache._SHARED
+
+        def rejected(*args, **kwargs):
+            raise RuntimeError("the runtime rejects this executable")
+
+        aot_cache._SHARED[slot] = rejected
+        before = _outcomes()
+        second = self._wrapper()
+        assert (second(x) == want).all()  # through the plain jit
+        assert (second(x) == want).all()  # pinned, still answers
+        assert _moved(before) == {"shared": 1}
+        assert slot not in aot_cache._SHARED
+        before = _outcomes()
+        self._wrapper().resolve(x)
+        assert _moved(before) == {"hit": 1}  # the next one asks the disk
+        assert aot_cache._SHARED[slot] is not rejected
+
+    def test_a_built_program_is_shared_where_store_fails(self, monkeypatch):
+        monkeypatch.setattr(aot_cache, "store", lambda key, compiled: False)
+        x = self._x()
+        want = self._wrapper()(x)
+        before = _outcomes()
+        assert (self._wrapper()(x) == want).all()
+        assert _moved(before) == {"shared": 1}
+
+    def test_threads_keep_the_bound_and_never_cross_keys(self, monkeypatch):
+        """More threads than cores put, get, drop and forget for one
+        second: the tier never passes its bound, and a key
+        never yields another key's executable."""
+        import sys
+        import threading
+        import time
+
+        monkeypatch.setattr(aot_cache, "SHARED_MAX", 8)
+        n_threads = 4 * (os.cpu_count() or 4)
+        deadline = time.monotonic() + 1.0
+        faults = []
+
+        def work(seed):
+            i = seed
+            while time.monotonic() < deadline and not faults:
+                i = (i * 1103515245 + 12345) % (1 << 31)
+                key = f"k{i % 24}"
+                step = i % 7
+                if step < 3:
+                    aot_cache._shared_put("d", key, ("exe", key))
+                elif step < 5:
+                    got = aot_cache._shared_get("d", key)
+                    if got is not None and got != ("exe", key):
+                        faults.append((key, got))
+                elif step == 5:
+                    aot_cache._shared_drop("d", key)
+                elif i % 97 == 0:
+                    aot_cache.forget()
+                if len(aot_cache._SHARED) > 8:
+                    faults.append(("bound", len(aot_cache._SHARED)))
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            threads = [
+                threading.Thread(target=work, args=(t + 1,), daemon=True)
+                for t in range(n_threads)
+            ]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=30)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        assert not faults, faults[:5]
+        assert len(aot_cache._SHARED) <= 8
+
+    def test_disabled_cache_never_enters_the_tier(self, monkeypatch):
+        monkeypatch.setenv("CYCLONUS_AOT_CACHE", "0")
+        before = _outcomes()
+        p = self._wrapper()
+        p.resolve(self._x())
+        p(self._x())
+        assert _moved(before) == {}
+        assert not aot_cache._SHARED
+
+
+class TestEnginesShare:
+    def test_a_new_engine_of_loaded_shapes_shares_every_program(
+        self, tmp_path, monkeypatch
+    ):
+        """Two engines of the same shapes and different policy sets in
+        one process (the what-if user): the second obtains every
+        program from the memory tier and answers as the scalar oracle
+        does; an engine of another shape bucket misses."""
+        import random
+
+        import numpy as np
+
+        from bench import build_synthetic
+        from cyclonus_tpu.engine import PortCase, TpuPolicyEngine
+        from cyclonus_tpu.matcher import build_network_policies
+        from cyclonus_tpu.telemetry import events
+        from tests.test_engine_parity import oracle_grid
+
+        monkeypatch.setenv("CYCLONUS_AOT_CACHE", str(tmp_path))
+        monkeypatch.setattr(events, "ACTIVE", True)
+        aot_cache.forget()
+        cases = [PortCase(80, "serve-80-tcp", "TCP")]
+
+        def what_if(n_pods, policy_seed):
+            """(how each engine.program span obtained its program, the
+            policy, the cluster, the evaluated grid)"""
+            pods, namespaces, _ = build_synthetic(n_pods, 10, random.Random(3))
+            _, _, policies = build_synthetic(
+                n_pods, 10, random.Random(policy_seed)
+            )
+            policy = build_network_policies(True, policies)
+            mark = events.mark()
+            grid = TpuPolicyEngine(policy, pods, namespaces).evaluate_grid(cases)
+            grid.block_until_ready()
+            how = [
+                e["args"]["how"] for e in events.since(mark)
+                if e["ph"] == "E" and e["name"] == "engine.program"
+            ]
+            return how, policy, pods, namespaces, grid
+
+        how1, *_rest, grid1 = what_if(40, 3)
+        assert how1 and set(how1) == {"built"}
+        before = _outcomes()
+        how2, policy, pods, namespaces, grid2 = what_if(40, 5)
+        # seeds 3 and 5 draw policy sets that encode to the same shape
+        # bucket; if the generator changes, pick another pair
+        assert how2 == ["shared"] * len(how1), how2
+        assert _moved(before) == {"shared": len(how1)}
+        assert not np.array_equal(grid1.combined, grid2.combined)
+        want = oracle_grid(policy, pods, namespaces, cases)
+        wrong = [
+            cell for cell, verdicts in want.items()
+            if grid2.job_verdict(*cell) != verdicts
+        ]
+        assert len(want) == 40 * 40 and not wrong, wrong[:5]
+        before = _outcomes()
+        how3, *_rest = what_if(300, 3)
+        assert set(how3) == {"built"}
+        assert _moved(before) == {"miss": len(how3), "compiles": len(how3)}
 
 
 @pytest.mark.slow

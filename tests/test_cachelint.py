@@ -827,14 +827,15 @@ class TestCachekeysRegistry:
 class TestKeyharnessTier1:
     def test_quick_slice(self, tmp_path):
         """The bounded tier-1 slice of the key-mutation harness: AOT +
-        autotune key fields, the invalidate contract, and the pair
-        program (the full sweep incl. subprocess restart legs is `make
-        keyharness` / -m slow below)."""
+        autotune key fields, the AOT memory tier, the invalidate
+        contract, and the pair program (the full sweep incl. subprocess
+        restart legs is `make keyharness` / -m slow below)."""
         from tests import keyharness
 
         results = keyharness.run(str(tmp_path), quick=True)
         assert set(results) == {
             "aot_key_fields",
+            "shared_tier_key",
             "autotune_key_fields",
             "invalidate_derived_contract",
             "pairs_program_key",
