@@ -45,7 +45,7 @@ test:
 # one-minute wall-clock budget so the gate stays cheap enough to run.
 lint: shapelint cachelint planlint statelint wirelint
 	@if python -m ruff --version >/dev/null 2>&1; then \
-	  python -m ruff check cyclonus_tpu tools bench.py; \
+	  python -m ruff check cyclonus_tpu tools; \
 	else echo "ruff not installed; skipping"; fi
 	python tools/jaxlint.py cyclonus_tpu/engine cyclonus_tpu/telemetry \
 	  cyclonus_tpu/worker cyclonus_tpu/analysis cyclonus_tpu/probe \
@@ -161,8 +161,7 @@ serve-smoke:
 # multichip smoke (docs/DESIGN.md "Multi-chip scale-out"): one
 # 8-virtual-device OVERLAPPED ring run — ring grid bit-identical to the
 # all-gather schedule and the single-device kernel, every collective
-# counts path verified, and the per-chip row emitted in the bench's
-# detail.mesh schema
+# counts path verified, and the per-chip row emitted as one JSON line
 multichip-smoke:
 	JAX_PLATFORMS=cpu python -c \
 	  "from __graft_entry__ import dryrun_multichip; dryrun_multichip(8)"
@@ -240,15 +239,12 @@ race:
 	CYCLONUS_GUARD_CHECK=1 JAX_PLATFORMS=cpu python -m tests.raceharness \
 	  --schedules 200 --threads 16 --seed 99 --verbose
 
-bench:
-	python bench.py
-
 fmt:
-	python -m black cyclonus_tpu tests bench.py 2>/dev/null || \
+	python -m black cyclonus_tpu tests 2>/dev/null || \
 	  echo "black not installed; skipping"
 
 vet:
-	python -m compileall -q cyclonus_tpu tests bench.py __graft_entry__.py
+	python -m compileall -q cyclonus_tpu tests __graft_entry__.py
 
 cyclonus:
 	pip install -e .
@@ -256,4 +252,4 @@ cyclonus:
 docker:
 	docker build -t cyclonus-tpu:latest .
 
-.PHONY: test check conformance fuzz fuzz-full race bench chaos slo audit fmt vet lint lint-changed shapelint cachelint planlint statelint wirelint keyharness planharness stateharness skewharness parity-compressed parity-cidr serve-smoke multichip-smoke cyclonus docker
+.PHONY: test check conformance fuzz fuzz-full race chaos slo audit fmt vet lint lint-changed shapelint cachelint planlint statelint wirelint keyharness planharness stateharness skewharness parity-compressed parity-cidr serve-smoke multichip-smoke cyclonus docker

@@ -63,7 +63,7 @@ OUT = os.environ.get("CHIP_SMOKE_OUT") or os.path.join(
 
 # Fixed sizes: BASELINE config 5 cut to one chip (counts), config 3 (tables).
 REAL = {
-    "pods": 100_000, "policies": 10_000,  # bench.build_synthetic's default
+    "pods": 100_000, "policies": 10_000,
     "table_pods": 10_000, "table_policies": 1_000,
     "serve_ns": 400,
     "tier_pods": 1_024, "tier_policies": 32,
@@ -228,12 +228,12 @@ def batch_counts_phase(state: dict) -> None:
     """Counts at the headline shape on the default route, the dense packed
     route (cold fused call, split call, steady call with the tile autotune)
     and the XLA tile loop: all equal."""
-    import bench
     from cyclonus_tpu.engine import TpuPolicyEngine
     from cyclonus_tpu.matcher import build_network_policies
+    from cyclonus_tpu.synthetic import build_synthetic
 
     n, p = SIZES["pods"], SIZES["policies"]
-    pods, namespaces, policies = bench.build_synthetic(
+    pods, namespaces, policies = build_synthetic(
         n, p, random.Random(SEED)
     )
     policy = build_network_policies(True, policies)
@@ -321,10 +321,10 @@ def packed_tiles_phase(state: dict) -> None:
 def oracle_phase(state: dict) -> None:
     """Seeded pairs through evaluate_pairs against the scalar oracle (the
     per-pair device->host syncs stay outside anything timed)."""
-    import bench
+    from cyclonus_tpu.analysis.oracle import spot_check_pairs
 
     for name in ("default", "dense"):
-        bench.spot_check_pairs(
+        spot_check_pairs(
             state[name], state["policy"], state["pods"], state["namespaces"],
             state["cases"], ORACLE_PAIRS, random.Random(SEED + 1),
         )
@@ -334,12 +334,13 @@ def oracle_phase(state: dict) -> None:
 def tables_phase(state: dict) -> None:
     """The full [Q, N, N] grid: sampled cells against the scalar oracle,
     and its sums against the counts kernels on the same engines."""
-    import bench
+    from cyclonus_tpu.analysis.oracle import spot_check
     from cyclonus_tpu.engine import TpuPolicyEngine
     from cyclonus_tpu.matcher import build_network_policies
+    from cyclonus_tpu.synthetic import build_synthetic
 
     n, p = SIZES["table_pods"], SIZES["table_policies"]
-    pods, namespaces, policies = bench.build_synthetic(
+    pods, namespaces, policies = build_synthetic(
         n, p, random.Random(SEED + 2)
     )
     policy = build_network_policies(True, policies)
@@ -349,7 +350,7 @@ def tables_phase(state: dict) -> None:
     for name, cc in (("default", "1" if REHEARSE else None), ("dense", "0")):
         engine = TpuPolicyEngine(policy, pods, namespaces, class_compress=cc)
         grid, r = routes_of(lambda: engine.evaluate_grid(cases))
-        bench.spot_check(
+        spot_check(
             policy, pods, namespaces, cases, grid, ORACLE_CELLS,
             random.Random(SEED + 3),
         )
@@ -373,18 +374,18 @@ def tier_phase() -> None:
     scalar oracle."""
     import numpy as np
 
-    import bench
     from cyclonus_tpu.analysis.oracle import traffic_for_cell
     from cyclonus_tpu.engine import TpuPolicyEngine
     from cyclonus_tpu.matcher import build_network_policies
     from cyclonus_tpu.matcher.tiered import TieredPolicy
+    from cyclonus_tpu.synthetic import build_synthetic, tiers_lattice
 
     n, p = SIZES["tier_pods"], SIZES["tier_policies"]
-    pods, namespaces, policies = bench.build_synthetic(
+    pods, namespaces, policies = build_synthetic(
         n, p, random.Random(777)
     )
     policy = build_network_policies(True, policies)
-    tiers = bench.tiers_lattice()
+    tiers = tiers_lattice()
     cases = port_cases()
     oracle = TieredPolicy(policy, tiers)
     rng = random.Random(SEED + 4)
@@ -414,15 +415,15 @@ def tier_phase() -> None:
 def dense_plan_phase() -> None:
     """The CYCLONUS_PACK=0 kernels: the multi-chunk general kernel in int8
     and bf16, and the slab kernel."""
-    import bench
     from cyclonus_tpu.engine import TpuPolicyEngine
     from cyclonus_tpu.matcher import build_network_policies
+    from cyclonus_tpu.synthetic import build_synthetic
     from cyclonus_tpu.telemetry import instruments as ti
 
     cases = port_cases()
 
     def run(label, n, p, n_ns, **engine_kw):
-        pods, namespaces, policies = bench.build_synthetic(
+        pods, namespaces, policies = build_synthetic(
             n, p, random.Random(SEED + 5), n_ns=n_ns
         )
         policy = build_network_policies(True, policies)
@@ -438,10 +439,14 @@ def dense_plan_phase() -> None:
         return engine
 
     n, p, n_ns = (SIZES[k] for k in ("dense_pods", "dense_policies", "dense_ns"))
+    # which kernel a program holds shows only while it is traced, and an
+    # executable adopted from a warm AOT cache is never traced: these
+    # engines build theirs (JAX's own compile cache still serves them)
+    traced = {"CYCLONUS_AOT_CACHE": "0"}
     for dtype, extra in (("int8", 0), ("bf16", 8)):  # +8: a fresh pod bucket
         chunked0 = ti.KERNEL_TRACES.value(kernel="counts_chunked")
         with env(CYCLONUS_PACK="0", CYCLONUS_PALLAS_DTYPE=dtype,
-                 CYCLONUS_PALLAS_SLAB="0"):
+                 CYCLONUS_PALLAS_SLAB="0", **traced):
             engine = run(f"dense {dtype}", n + extra, p, n_ns, compact=False)
         t = engine._tensors
         depth = [int(t[d]["target_ns"].shape[0]) for d in ("egress", "ingress")]
@@ -451,7 +456,7 @@ def dense_plan_phase() -> None:
             "multi-chunk kernel",
         )
     with env(CYCLONUS_PACK="0", CYCLONUS_PALLAS_DTYPE="int8",
-             CYCLONUS_PALLAS_SLAB="1"):
+             CYCLONUS_PALLAS_SLAB="1", **traced):
         slab0 = ti.KERNEL_TRACES.value(kernel="counts_slab")
         engine = run(
             "slab int8", SIZES["slab_pods"], SIZES["slab_policies"], None
@@ -467,11 +472,12 @@ def dense_plan_phase() -> None:
 def cidr_phase() -> None:
     """The TSS/LPM CIDR stage on the device over an ipBlock-heavy set:
     counts equal to the dense per-spec path, pairs equal to the oracle."""
-    import bench
+    from cyclonus_tpu.analysis.oracle import spot_check_pairs
     from cyclonus_tpu.engine import TpuPolicyEngine
     from cyclonus_tpu.matcher import build_network_policies
+    from cyclonus_tpu.synthetic import cidr_cluster
 
-    pods, namespaces, netpols, rng = bench.cidr_cluster(
+    pods, namespaces, netpols, rng = cidr_cluster(
         SIZES["cidr_pods"], SIZES["cidr_distinct"], 64
     )
     policy = build_network_policies(True, netpols)
@@ -491,7 +497,7 @@ def cidr_phase() -> None:
     want = dense.evaluate_grid_counts(cases, backend="xla")
     say(f"   tss {r}: {got}")
     check(got == want, f"TSS counts {got} != dense {want}")
-    bench.spot_check_pairs(engine, policy, pods, namespaces, cases, 16, rng)
+    spot_check_pairs(engine, policy, pods, namespaces, cases, 16, rng)
 
 
 def cli_phase(engine_flag: str) -> None:
@@ -717,10 +723,10 @@ def serve_phase(policies) -> dict:
     """Cold start, the delta and query script with every verdict checked
     against the scalar oracle on a mirrored state, then a warm start."""
     from cyclonus_tpu.analysis.oracle import oracle_verdicts, traffic_for_cell
-    from cyclonus_tpu.cli.serve_cmd import synthetic_cluster
     from cyclonus_tpu.engine.api import PortCase
     from cyclonus_tpu.kube.yaml_io import parse_policy_dict, policies_to_yaml
     from cyclonus_tpu.matcher.builder import build_network_policies
+    from cyclonus_tpu.synthetic import synthetic_cluster
     from cyclonus_tpu.worker.model import Batch, Delta, FlowQuery
 
     pol_dir = os.path.join(OUT, "policies")
@@ -847,8 +853,10 @@ def serve_phase(policies) -> dict:
     finally:
         rc = warm.close()
     check(rc == 0, f"warm serve child exited {rc}")
+    # on a machine that came with this repository's caches the first
+    # start is a warm one too: then neither compiles anything
     check(
-        warm_facts["compiles"] < cold_facts["compiles"],
+        warm_facts["compiles"] < max(cold_facts["compiles"], 1),
         f"the warm start compiled as much as the cold one: cold {cold_facts} "
         f"warm {warm_facts}",
     )
@@ -863,9 +871,9 @@ def parent_main() -> int:
     device = run_batch_child()
     check(device["platform"] == PLATFORM, f"batch child ran on {device}")
 
-    import bench
+    from cyclonus_tpu.synthetic import build_synthetic
 
-    _, _, policies = bench.build_synthetic(
+    _, _, policies = build_synthetic(
         SIZES["pods"], SIZES["policies"], random.Random(SEED)
     )
     starts = serve_phase(policies)

@@ -11,9 +11,8 @@ subset of (class, peer, case) cells, following the package convention:
 a violation is an internal-consistency failure (an engine bug), never a
 report row — callers raise on it.
 
-bench.py's 1M-pod synthetic case runs this audit as the scale-time spot
-check; tests/test_engine_classes.py runs it exhaustively on small
-clusters (and proves it FIRES on a deliberately corrupted class map).
+tests/test_engine_classes.py runs it exhaustively on small clusters
+(and proves it FIRES on a deliberately corrupted class map).
 """
 
 from __future__ import annotations

@@ -126,3 +126,51 @@ def sample_cells(
         )
         for _ in range(k)
     ]
+
+
+def _hold_to_oracle(policy, pods, namespaces, case, si, di, got) -> None:
+    expected = oracle_verdicts(
+        policy, traffic_for_cell(pods, namespaces, case, si, di)
+    )
+    if tuple(bool(x) for x in got) != expected:
+        raise AssertionError(
+            f"PARITY FAILURE at q={case} s={si} d={di}: "
+            f"oracle={expected} engine={tuple(got)}"
+        )
+
+
+def spot_check(
+    policy: Policy,
+    pods: Sequence[PodTuple],
+    namespaces: Dict[str, Dict[str, str]],
+    cases: Sequence[PortCase],
+    grid,
+    n_samples: int,
+    rng: random.Random,
+) -> None:
+    """Hold `n_samples` random cells of a GridVerdict to the scalar
+    oracle (one device gather, one tiny transfer); raises AssertionError
+    naming the first cell that differs."""
+    cells = sample_cells(len(pods), len(cases), n_samples, rng)
+    for (qi, si, di), got in zip(cells, grid.gather(cells)):
+        _hold_to_oracle(policy, pods, namespaces, cases[qi], si, di, got)
+
+
+def spot_check_pairs(
+    engine,
+    policy: Policy,
+    pods: Sequence[PodTuple],
+    namespaces: Dict[str, Dict[str, str]],
+    cases: Sequence[PortCase],
+    n_samples: int,
+    rng: random.Random,
+) -> None:
+    """Scale-path parity: `n_samples` random (src, dst) pairs through the
+    engine's pairs kernel (no N x N grid), every case of each held to the
+    scalar oracle."""
+    n = len(pods)
+    pairs = [(rng.randrange(n), rng.randrange(n)) for _ in range(n_samples)]
+    got = engine.evaluate_pairs(cases, pairs)  # [K, Q, 3]
+    for k, (si, di) in enumerate(pairs):
+        for qi, case in enumerate(cases):
+            _hold_to_oracle(policy, pods, namespaces, case, si, di, got[k, qi])

@@ -21,6 +21,7 @@ import time
 from typing import Dict, List, Optional
 
 from . import ChaosError, disarm, injected, reset
+from ..synthetic import synthetic_cluster
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -153,7 +154,6 @@ def scenario_serve_kill_restart(
     oracle-exact."""
     import tempfile
 
-    from ..cli.serve_cmd import synthetic_cluster
     from ..worker.model import Batch, Delta, FlowQuery
 
     bound = ttfv_bound_s if ttfv_bound_s is not None else _ttfv_bound_s()
@@ -280,7 +280,6 @@ def scenario_poisoned_caches(
     os.environ["CYCLONUS_AOT_CACHE"] = aot_dir
     os.environ["CYCLONUS_AUTOTUNE_CACHE"] = tune_path
     try:
-        from ..cli.serve_cmd import synthetic_cluster
         from ..engine import PortCase, TpuPolicyEngine
         from ..engine import aot_cache
         from ..matcher.builder import build_network_policies
@@ -441,7 +440,6 @@ def scenario_delta_drop(seed: int = 0, n_pods: int = 16) -> Dict:
     mutated): the service must roll the batch back wholesale, stay
     incremental==rebuild==oracle consistent, and accept the next batch
     cleanly."""
-    from ..cli.serve_cmd import synthetic_cluster
     from ..serve import VerdictService
     from ..worker.model import Delta
 
@@ -608,8 +606,6 @@ def scenario_audit_divergence(
                     return sent
                 time.sleep(0.05)
         return None
-
-    from ..cli.serve_cmd import synthetic_cluster
 
     pods, _namespaces = synthetic_cluster(n_pods, n_ns, seed)
     keys = [f"{p[0]}/{p[1]}" for p in pods]

@@ -86,7 +86,7 @@ def _run_fuzz(args) -> int:
     # the mesh leg is only a real multi-device differential when the
     # CPU backend exposes a virtual mesh; force the device count BEFORE
     # the first backend-touching jax call (XLA reads XLA_FLAGS at
-    # backend init — same pattern as bench.main / dryrun_multichip), so
+    # backend init — same pattern as dryrun_multichip), so
     # `cyclonus-tpu fuzz` exercises the ring exchange on 8 devices even
     # when invoked outside the test harness (e.g. `make fuzz`)
     import os

@@ -111,7 +111,7 @@ def setup_generate(sub) -> None:
     )
     cmd.add_argument(
         "--jax-profile",
-        "--trace-dir",  # the flag pair probe/bench also spell
+        "--trace-dir",  # the flag pair probe also spells
         dest="jax_profile",
         default="",
         metavar="DIR",

@@ -51,7 +51,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         default="text",
         choices=["text", "json", "prometheus"],
         help="text = human tree + metric lines; json = the full snapshot "
-        "(the BENCH `telemetry` block shape); prometheus = text "
+        "(what /telemetry.json serves); prometheus = text "
         "exposition, exactly what --metrics-port serves",
     )
     telemetry_cmd.add_argument(

@@ -96,34 +96,11 @@ def setup_serve(sub) -> None:
     cmd.set_defaults(func=run_serve)
 
 
-def synthetic_cluster(n_pods: int, n_ns: int, seed: int):
-    """A seeded synthetic pod set with bench-shaped label diversity
-    (app/tier cycling) — the serve bench and smoke tests start here."""
-    import random
-
-    rng = random.Random(seed)
-    n_ns = max(1, n_ns)
-    namespaces = {
-        f"ns{i}": {"ns": f"ns{i}", "team": f"team{i % 7}"}
-        for i in range(n_ns)
-    }
-    pods = []
-    for i in range(n_pods):
-        ns = f"ns{rng.randrange(n_ns)}"
-        labels = {
-            "pod": f"p{i % 100}",
-            "app": f"app{i % 20}",
-            "tier": f"tier{i % 5}",
-        }
-        ip = f"10.{(i >> 16) & 255}.{(i >> 8) & 255}.{i & 255}"
-        pods.append((ns, f"pod-{i}", labels, ip))
-    return pods, namespaces
-
-
 def run_serve(args) -> int:
     from ..kube.yaml_io import load_policies_from_path
     from ..serve import VerdictService, run_stdio
     from ..serve.service import register_http
+    from ..synthetic import synthetic_cluster
     from ..telemetry.server import MetricsPortBusy, start_metrics_server
 
     policies = (

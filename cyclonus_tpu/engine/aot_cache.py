@@ -3,8 +3,7 @@
 The JAX persistent compilation cache (engine/__init__.py) already skips
 the XLA *compile* on a warm restart, but a fresh process still pays the
 full Python *trace* of every program plus the cache's own lookup
-machinery — at the bench shape that trace+lookup residue is seconds of
-the 7.2s warmup, and it recurs for every compiled program family.  This
+machinery, and it recurs for every compiled program family.  This
 module goes the rest of the way: compiled executables are SERIALIZED
 (jax.experimental.serialize_executable — the loaded binary, not the
 StableHLO) keyed by
@@ -301,8 +300,8 @@ def _count(outcome: str) -> None:
 
 
 def counters() -> Dict[str, Any]:
-    """The per-process AOT cache forensics bench.py records as
-    detail.aot_cache: hits (executables adopted from disk —
+    """The per-process AOT cache forensics (serve's prewarm report and
+    the restart tests read them): hits (executables adopted from disk —
     `adopted` aliases it for the acceptance schema), shared (resolved
     in the memory tier: loaded earlier by this process), misses,
     stores, and fresh compiles actually paid (the restart gate's flat

@@ -52,10 +52,10 @@ class GridVerdict:
     and `gather` fetches individual cells with one device-side take.
 
     A table arrives in one of two forms, told apart by its dtype: bool
-    [Q, N, N] (the sharded routes, the native evaluator, the empty case),
-    or uint32 `kernel.cell_words` [Q, >= N, >= ceil(N/4)] (the
-    single-device grid programs): cell c of a row is byte c % 4 of word
-    c // 4, little-endian, each byte 0 or 1.  A one-byte element leaves
+    [Q, N, N] (the native evaluator, the empty case), or uint32
+    `kernel.cell_words` [Q, >= N, >= ceil(N/4)] (every grid program of
+    the device, single-device and sharded): cell c of a row is byte
+    c % 4 of word c // 4, little-endian, each byte 0 or 1.  A one-byte element leaves
     the device four times slower than a 32-bit one (the runtime un-tiles
     either on the host), and on the host the words' bytes ARE the
     boolean table, so `ingress` / `egress` / `combined` are bool
@@ -1013,7 +1013,7 @@ class TpuPolicyEngine:
                 )
                 ti.CLASS_AUX_BYTES.set(st["aux_bytes"])
         # wall-clock of the last tiered grid evaluation's dispatch
-        # (detail.tiers.resolve_s; None until a tiered eval ran)
+        # (tier_stats()["resolve_s"]; None until a tiered eval ran)
         self._tier_resolve_s = None
         # The trailing `# derived-from:` declarations below are the
         # cache-coherence contract tools/cachelint.py CC002 enforces:
@@ -1060,7 +1060,7 @@ class TpuPolicyEngine:
         # _slab_lock with _slab_choice so the pair can never be read
         # half-updated against the autotune's abandoned thread.
         self._kernel_choice = None  # derived-from: buffer (re-tuned)
-        # autotune forensics for bench detail.pack: {"source":
+        # autotune forensics for pack_stats(): {"source":
         # search|cache|single, "search_s", "candidates": [...],
         # "noise_floor"} once the first steady-state call resolves it
         self._autotune_stats = None
@@ -1175,8 +1175,8 @@ class TpuPolicyEngine:
         return aot_cache.digest(sorted(unpack.metas_by_path.items()))
 
     def aot_stats(self) -> Dict:
-        """The per-process AOT executable-cache forensics (bench.py
-        records them under detail.aot_cache)."""
+        """The per-process AOT executable-cache forensics (serve's
+        prewarm report carries them)."""
         return aot_cache.counters()
 
     def _build_tensors(self) -> Dict:
@@ -1277,7 +1277,7 @@ class TpuPolicyEngine:
     def pod_classes(self):
         """The PodClasses of the active compression state, or None when
         compression is off / bypassed for this engine (analysis's
-        audit_class_reduction and bench.py consume this)."""
+        audit_class_reduction consumes this)."""
         st = self._class_state
         return st["classes"] if st is not None else None
 
@@ -1290,10 +1290,9 @@ class TpuPolicyEngine:
         return int(st["aux_bytes"]) if st is not None else 0
 
     def class_compression_stats(self) -> Dict:
-        """The grid-compression summary bench.py records as
-        detail.class_compression: pods, classes, ratio, the last
-        broadcast-back epilogue seconds, and the rule-axis partition
-        stats."""
+        """The grid-compression summary (serve's /state carries it):
+        pods, classes, ratio, the last broadcast-back epilogue seconds,
+        and the rule-axis partition stats."""
         n = self.encoding.cluster.n_pods
         st = self._class_state
         if st is None:
@@ -1318,8 +1317,8 @@ class TpuPolicyEngine:
         }
 
     def cidr_stats(self) -> Dict:
-        """The TSS/LPM CIDR pre-classification summary (bench.py records
-        it under detail.cidr): whether the stage is active, the distinct
+        """The TSS/LPM CIDR pre-classification summary (chip_smoke.py
+        prints it): whether the stage is active, the distinct
         spec/atom/partition counts, the last LPM stage wall-clock and
         whether it ran on device, and the partition-tensor bytes charged
         to the HBM budget."""
@@ -1348,8 +1347,8 @@ class TpuPolicyEngine:
         }
 
     def tier_stats(self) -> Dict:
-        """The precedence-tier summary bench.py records as detail.tiers
-        on every line: whether the lattice is active, the ANP object /
+        """The precedence-tier summary (serve's /state carries it):
+        whether the lattice is active, the ANP object /
         flat rule-row counts, and the wall-clock of the last tiered grid
         evaluation (resolve_s; None until one ran)."""
         if self.tiers is None:
@@ -1820,8 +1819,8 @@ class TpuPolicyEngine:
         steady-state call TIME both programs and keep the winner
         (_autotune_slab) — the depth-cut win only exists on hardware and
         interpret-mode timing is meaningless, so auto never engages off
-        TPU; "1" forces the slab kernel (how CPU tests and the bench
-        parity case exercise it); "0" disables.  Also requires the
+        TPU; "1" forces the slab kernel (how CPU tests and chip_smoke.py
+        exercise it); "0" disables.  Also requires the
         cluster to span at least two src tiles (below that the
         single-chunk kernel is already minimal) and the materialized
         slabs to fit the byte budget.  The numpy tmatch twin here is the
@@ -2016,8 +2015,8 @@ class TpuPolicyEngine:
         round issues CYCLONUS_AUTOTUNE_REPS async dispatches with ONE
         value readback as the barrier; the candidate keeps the MIN over
         CYCLONUS_AUTOTUNE_ROUNDS rounds — the same min-of-N discipline
-        the bench and the overhead tests use, because a single-shot
-        comparison under host timing jitter can pick the loser."""
+        the overhead tests use, because a single-shot comparison under
+        host timing jitter can pick the loser."""
         import os
         import time as _time
 
@@ -2147,7 +2146,7 @@ class TpuPolicyEngine:
                 self._slab_choice = False
                 self._kernel_choice = {"kernel": "default"}
                 self._slab_ops_cache = None
-            # the rejection is telemetry too: BENCH detail must show WHY
+            # the rejection is telemetry too: _slab_autotune must show WHY
             # there are no timed legs, and whether the abandoned thread's
             # in-flight work later raced a real dispatch
             self._slab_autotune = {
@@ -2389,8 +2388,8 @@ class TpuPolicyEngine:
         return winner[3]
 
     def pack_stats(self) -> Dict:
-        """The bit-packed-plan summary bench.py records as detail.pack
-        on every line: whether the packed dtype plan is active, the
+        """The bit-packed-plan summary (chip_smoke.py prints it):
+        whether the packed dtype plan is active, the
         packed word depths (kt twin), the tuned winner, and the
         autotune forensics (search time, candidates tried, cache
         source)."""
@@ -2977,7 +2976,7 @@ class TpuPolicyEngine:
         """Point verdicts for (src_idx, dst_idx) pod pairs: [K, Q, 3] bool
         (ingress, egress, combined) — no N x N grid anywhere, so it scales
         to arbitrary cluster sizes (powers the large-scale parity spot
-        checks in bench.py)."""
+        checks, analysis/oracle.py spot_check_pairs)."""
         from .tiled import evaluate_pairs_kernel
 
         self._check_ips()

@@ -145,7 +145,7 @@ class CidrSpace:
     #: bridge from partition signatures back to per-spec membership
     #: (spec_membership_words); python-side, row order = spec discovery
     spec_atoms: List[Tuple[int, Tuple[int, ...]]] = field(default_factory=list)
-    #: forensics of the last signature computation (bench detail.cidr)
+    #: forensics of the last signature computation (engine.cidr_stats())
     last_lpm_s: Optional[float] = None
     last_device: Optional[bool] = None
 

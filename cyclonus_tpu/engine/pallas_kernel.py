@@ -331,8 +331,7 @@ def _resolve_operand_dtype(operand_dtype: str | None) -> str:
     programs (api._build_counts_jits, tiled's shard_map bodies) wrap
     these calls in their own outer jits and therefore still bake the
     dtype in at THEIR trace time — an engine keeps the operand dtype it
-    was built with, and bench's compiled-parity cases keep their
-    distinct-pod-bucket spacing for exactly that reason."""
+    was built with."""
     if operand_dtype is None:
         operand_dtype = os.environ.get("CYCLONUS_PALLAS_DTYPE", "int8")
     if operand_dtype not in ("int8", "bf16"):

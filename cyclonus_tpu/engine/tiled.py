@@ -16,7 +16,7 @@ fixed-size SOURCE-ROW BLOCKS instead, in three modes:
   * pairs   — point evaluation of arbitrary (src, dst) index pairs
               (evaluate_pairs_kernel); no N x N grid anywhere, so it
               scales to any cluster size — powers the large-scale parity
-              spot checks (bench.py spot_check_pairs).
+              spot checks (analysis/oracle.py spot_check_pairs).
 
 Decision procedure identical to kernel.py (reference policy.go:138-174);
 parity is enforced by tests/test_engine_tiled.py against both the

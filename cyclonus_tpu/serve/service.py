@@ -331,7 +331,7 @@ class VerdictService:
 
     @property
     def engine(self) -> TpuPolicyEngine:
-        """The live engine (test/bench convenience; take the service's
+        """The live engine (test convenience; take the service's
         word for when it changes)."""
         with self._lock:
             return self._inc.engine

@@ -64,8 +64,8 @@ def render_prometheus() -> str:
 
 def snapshot() -> Dict[str, Any]:
     """One JSON-able view of everything: metrics, span aggregates (flat
-    + tree), and the flight-recorder window.  The BENCH `telemetry`
-    block and the /telemetry.json endpoint are this."""
+    + tree), and the flight-recorder window.  The /telemetry.json
+    endpoint and `telemetry --format json` are this."""
     return {
         "metrics": METRICS.snapshot(),
         "phases": {
@@ -105,8 +105,8 @@ def render_text() -> str:
 
 def reset() -> None:
     """Zero spans, metric series, the flight ring, and the trace-event
-    window (registrations and the active-trace state survive).  Bench
-    and tests isolate runs with this."""
+    window (registrations and the active-trace state survive).  Tests
+    isolate runs with this."""
     SPANS.reset()
     METRICS.reset()
     recorder.reset()

@@ -78,7 +78,7 @@ MESH_RING_STEP_SECONDS = REGISTRY.gauge(
     "cyclonus_tpu_mesh_ring_step_seconds",
     "Per-hop seconds of the last pipelined ring-counts eval "
     "(pipelined eval seconds / device count): the overlapped ICI-hop "
-    "budget the bench records as detail.mesh ring_step_s.",
+    "budget.",
 )
 
 DEVICE_BYTES = REGISTRY.gauge(
@@ -300,7 +300,7 @@ SERVE_DEGRADED = REGISTRY.counter(
 SERVE_QUERY_LATENCY = REGISTRY.histogram(
     "cyclonus_tpu_serve_query_latency_seconds",
     "Verdict service: per-flow query latency, batch-amortized (the "
-    "p50/p99 surfaced by /state and the bench serve detail).",
+    "p50/p99 surfaced by /state).",
 )
 SERVE_APPLY_SECONDS = REGISTRY.histogram(
     "cyclonus_tpu_serve_apply_seconds",

@@ -351,7 +351,7 @@ class MetricRegistry:
         return {name: metric.snapshot() for name, metric in families}
 
     def reset(self) -> None:
-        """Zero every series (keeps registrations; tests and bench).
+        """Zero every series (keeps registrations; tests).
         Lock order: registry before metric — the only nested
         acquisition in the package; Metric methods never take the
         registry lock, so the LK002 graph stays acyclic."""

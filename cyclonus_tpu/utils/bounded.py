@@ -1,8 +1,8 @@
 """Bounded containers and bounded execution.
 
 run_bounded puts a wall-clock bound on OPTIONAL work that compiles or
-executes a fresh program — an autotune candidate, a bench detail leg —
-so a wedged compile costs that candidate or leg, not the run.  It is
+executes a fresh program — an autotune candidate, a chaos scenario —
+so a wedged compile costs that candidate or scenario, not the run.  It is
 never put around backend initialisation: a backend that cannot
 initialise raises with JAX's own message.
 """

@@ -15,10 +15,9 @@ Strip contract (the utils/guards.py / utils/contracts.py discipline):
 production and the normal test suite — `register()` returns before
 touching any state, the registry stays empty, and the
 `cyclonus_tpu_cachekey_*` instruments are NEVER created, so their
-absence from a BENCH telemetry block is the proof the strip is real
-(tests/test_bench_guard.py asserts it, exactly like the
-contract-checks counter).  tests/test_cachelint.py pins the off-path
-cost with a paired-median differential (< 2%).
+absence from the metric registry is the proof the strip is real
+(tests/test_cachelint.py asserts it, and pins the off-path cost with a
+paired-median differential, < 2%).
 """
 
 from __future__ import annotations
@@ -90,8 +89,7 @@ def registered() -> Dict[str, RegisteredCache]:
 
 
 def registered_count() -> int:  # never-raises
-    """How many cache families have registered (0 when inactive) — the
-    number bench.py records as detail.key_audit."""
+    """How many cache families have registered (0 when inactive)."""
     try:
         with _LOCK:
             return len(_REG)
@@ -108,7 +106,7 @@ def clear() -> None:
 def _instruments(n: int) -> None:
     """Create/update the cyclonus_tpu_cachekey_* instruments — ONLY
     reachable under the harness env, so with it unset they never enter
-    the metric registry (the strip proof test_bench_guard asserts)."""
+    the metric registry (the strip proof test_cachelint asserts)."""
     global _GAUGE, _REGISTRATIONS
     if _GAUGE is None:
         from ..telemetry.metrics import REGISTRY

@@ -201,8 +201,8 @@ _COUNTER = None
 def _count(n: int) -> None:
     """Contract-check telemetry.  The counter is created ON FIRST CHECK,
     so with CYCLONUS_SHAPE_CHECK unset it never enters the metric
-    registry — tests/test_bench_guard.py asserts its absence from the
-    BENCH telemetry block as the proof the strip is real."""
+    registry — tests/test_shapelint.py asserts its absence after an
+    encode and an evaluation as the proof the strip is real."""
     global _COUNTER
     if _COUNTER is None:
         from ..telemetry.metrics import REGISTRY

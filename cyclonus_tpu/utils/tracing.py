@@ -6,8 +6,8 @@ closest analog is logrus trace-level logging of each simulated verdict
 (jobrunner.go:80 — mirrored by CYCLONUS_TRACE_VERDICTS in
 probe/runner.py).  Tracing here is first-class: `phase` is a structured
 span (hierarchical, thread-safe, attribute-carrying), and this module
-keeps the historical flat API so existing consumers (bench.py, the
-generate --phase-stats flag, tests) are unchanged:
+keeps the historical flat API so existing consumers (the generate
+--phase-stats flag, tests) are unchanged:
 
     with phase("encode"):
         ...

@@ -451,8 +451,7 @@ def guard_violations(name: str, payload: dict) -> List[str]:
 # --------------------------------------------------------------------------
 # The skew sweep: both peer directions for every registered message,
 # synthesized from the registry.  tests/skewharness.py drives this
-# (armed) plus the real serve wire loop; bench.py's detail.wire block
-# stamps its counters on every BENCH line.
+# (armed) plus the real serve wire loop.
 # --------------------------------------------------------------------------
 
 def _generic_codec(name: str):
@@ -476,7 +475,7 @@ def skew_sweep(
     -> older-reader) unknown-key injection, and every per-optional-key
     absence view — each driven through the real codec (worker/model.py
     CODECS) or the registry-generic one.  Returns the counters the
-    census and detail.wire stamp, with any divergence in
+    census stamps, with any divergence in
     ``problems``."""
     codecs = codecs or {}
     pairs = 0

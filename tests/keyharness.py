@@ -105,9 +105,9 @@ class Ctx:
 
     def engine(self):
         if self._engine is None:
-            from bench import build_synthetic
             from cyclonus_tpu.engine import PortCase, TpuPolicyEngine
             from cyclonus_tpu.matcher import build_network_policies
+            from cyclonus_tpu.synthetic import build_synthetic
 
             pods, namespaces, policies = build_synthetic(
                 24, 6, random.Random(7)
@@ -483,7 +483,7 @@ sys.path.insert(0, {repo!r})
 import numpy as np
 import jax
 jax.config.update("jax_platforms", "cpu")
-from bench import build_synthetic
+from cyclonus_tpu.synthetic import build_synthetic
 from cyclonus_tpu.engine import PortCase, TpuPolicyEngine, aot_cache
 from cyclonus_tpu.matcher import build_network_policies
 
@@ -566,7 +566,7 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
     " --xla_force_host_platform_device_count=8").strip()
 import jax
 jax.config.update("jax_platforms", "cpu")
-from bench import build_synthetic
+from cyclonus_tpu.synthetic import build_synthetic
 from cyclonus_tpu.engine import PortCase, TpuPolicyEngine, autotune
 from cyclonus_tpu.matcher import build_network_policies
 from cyclonus_tpu.utils import cachekeys

@@ -94,8 +94,8 @@ class Ctx:
         self.covered: set = set()
 
     def _fixture(self):
-        from bench import build_synthetic
         from cyclonus_tpu.matcher import build_network_policies
+        from cyclonus_tpu.synthetic import build_synthetic
 
         pods, namespaces, policies = build_synthetic(24, 6, random.Random(7))
         return build_network_policies(True, policies), pods, namespaces

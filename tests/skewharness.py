@@ -89,7 +89,7 @@ class Ctx:
 
     def cluster(self):
         if self._pods is None:
-            from cyclonus_tpu.cli.serve_cmd import synthetic_cluster
+            from cyclonus_tpu.synthetic import synthetic_cluster
 
             self._pods, self._namespaces = synthetic_cluster(
                 8, 2, self.seed

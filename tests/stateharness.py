@@ -172,7 +172,7 @@ class Ctx:
     def service(self):
         if self._svc is None:
             from cyclonus_tpu.audit import AuditController
-            from cyclonus_tpu.cli.serve_cmd import synthetic_cluster
+            from cyclonus_tpu.synthetic import synthetic_cluster
             from cyclonus_tpu.serve import VerdictService
 
             pods, namespaces = synthetic_cluster(8, 2, self.seed)
@@ -485,7 +485,7 @@ def scenario_scaled_parity(ctx: Ctx) -> Dict:
     registered kind committed in sequence, incremental-vs-rebuild
     parity verified after each batch — the registry-driven commit path
     under realistic churn."""
-    from cyclonus_tpu.cli.serve_cmd import synthetic_cluster
+    from cyclonus_tpu.synthetic import synthetic_cluster
     from cyclonus_tpu.serve import VerdictService, stateregistry
 
     pods, namespaces = synthetic_cluster(48, 4, ctx.seed + 1)

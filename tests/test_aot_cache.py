@@ -23,7 +23,7 @@ import numpy as np
 sys.path.insert(0, {repo!r})
 import jax
 jax.config.update("jax_platforms", "cpu")
-from bench import build_synthetic
+from cyclonus_tpu.synthetic import build_synthetic
 from cyclonus_tpu import telemetry
 from cyclonus_tpu.engine import PortCase, TpuPolicyEngine, aot_cache
 from cyclonus_tpu.matcher import build_network_policies
@@ -290,7 +290,7 @@ class TestGridResultFormat:
         import jax
         import numpy as np
 
-        from bench import build_synthetic
+        from cyclonus_tpu.synthetic import build_synthetic
         from cyclonus_tpu.engine import PortCase, TpuPolicyEngine
         from cyclonus_tpu.engine.kernel import evaluate_grid_kernel
         from cyclonus_tpu.matcher import build_network_policies
@@ -566,7 +566,7 @@ class TestEnginesShare:
 
         import numpy as np
 
-        from bench import build_synthetic
+        from cyclonus_tpu.synthetic import build_synthetic
         from cyclonus_tpu.engine import PortCase, TpuPolicyEngine
         from cyclonus_tpu.matcher import build_network_policies
         from cyclonus_tpu.telemetry import events
@@ -627,7 +627,7 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
     " --xla_force_host_platform_device_count=8").strip()
 import jax
 jax.config.update("jax_platforms", "cpu")
-from bench import build_synthetic
+from cyclonus_tpu.synthetic import build_synthetic
 from cyclonus_tpu.engine import PortCase, TpuPolicyEngine, aot_cache
 from cyclonus_tpu.matcher import build_network_policies
 

@@ -109,7 +109,7 @@ class TestScenarios:
 
 class TestServeWarmup:
     def _cluster(self, n=16):
-        from cyclonus_tpu.cli.serve_cmd import synthetic_cluster
+        from cyclonus_tpu.synthetic import synthetic_cluster
 
         return synthetic_cluster(n, 2, 5)
 

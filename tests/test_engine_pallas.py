@@ -224,12 +224,12 @@ class TestPallasCounts:
         count exactly (every other test cluster is far below one tile)."""
         import random
 
-        import bench as bench_mod
         from cyclonus_tpu.engine.pallas_kernel import _tiles_for
         from cyclonus_tpu.matcher import build_network_policies
+        from cyclonus_tpu.synthetic import build_synthetic
 
         rng = random.Random(31)
-        pods, namespaces, policies = bench_mod.build_synthetic(600, 60, rng)
+        pods, namespaces, policies = build_synthetic(600, 60, rng)
         policy = build_network_policies(True, policies)
         engine = TpuPolicyEngine(policy, pods, namespaces)
         for d in ("ingress", "egress"):
@@ -430,11 +430,11 @@ class TestPallasCounts:
         differing per tile, plus the bs != bd asymmetric layout."""
         import random
 
-        import bench as bench_mod
         from cyclonus_tpu.matcher import build_network_policies
+        from cyclonus_tpu.synthetic import build_synthetic
 
         rng = random.Random(77)
-        pods, namespaces, policies = bench_mod.build_synthetic(2000, 100, rng)
+        pods, namespaces, policies = build_synthetic(2000, 100, rng)
         pods = sorted(pods, key=lambda p: p[0])  # ns-sort, like the packed path
         policy = build_network_policies(True, policies)
         self._slab_case(policy, pods, namespaces, bs=256, bd=128, w=64)

@@ -42,7 +42,7 @@ def grids_equal(a, b):
 
 
 def synthetic_engine(n_pods, n_pols=6, seed=3, **kw):
-    from bench import build_synthetic
+    from cyclonus_tpu.synthetic import build_synthetic
 
     pods, namespaces, policies = build_synthetic(
         n_pods, n_pols, random.Random(seed)
@@ -355,7 +355,7 @@ class TestElasticResize:
         trace to the shared grid kernel or the cached sharded (ring)
         program — the bucketing makes the shapes identical, so the jit
         caches hit."""
-        from bench import build_synthetic
+        from cyclonus_tpu.synthetic import build_synthetic
         from cyclonus_tpu.engine.kernel import evaluate_grid_kernel
 
         n_a, n_b = 900, 990  # +10%: both bucket to 1024

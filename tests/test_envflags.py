@@ -127,7 +127,7 @@ class TestSlabBudgetDriftRegression:
     def test_malformed_budget_does_not_raise_on_cidr_gate(self):
         import random
 
-        from bench import build_synthetic
+        from cyclonus_tpu.synthetic import build_synthetic
         from cyclonus_tpu.engine import TpuPolicyEngine, cidrspace
         from cyclonus_tpu.matcher import build_network_policies
 
@@ -143,7 +143,7 @@ class TestSlabBudgetDriftRegression:
     def test_malformed_budget_does_not_raise_on_class_counts_gate(self):
         import random
 
-        from bench import build_synthetic
+        from cyclonus_tpu.synthetic import build_synthetic
         from cyclonus_tpu.engine import TpuPolicyEngine
         from cyclonus_tpu.matcher import build_network_policies
 

@@ -12,13 +12,13 @@ import random
 import numpy as np
 import pytest
 
-from bench import build_synthetic, tiers_lattice
 from cyclonus_tpu.analysis.oracle import traffic_for_cell
 from cyclonus_tpu.engine import PortCase, TpuPolicyEngine
 from cyclonus_tpu.engine.api import GridVerdict, _bucket_pods
 from cyclonus_tpu.engine.kernel import WORD_CELLS, WORD_TILE
 from cyclonus_tpu.matcher import build_network_policies
 from cyclonus_tpu.matcher.tiered import tiered_oracle_verdicts
+from cyclonus_tpu.synthetic import build_synthetic, tiers_lattice
 
 from test_engine_sharded import cpu_mesh
 

@@ -25,10 +25,7 @@ pytestmark = pytest.mark.skipif(
 
 
 def synthetic(n_pods, n_policies, seed):
-    import sys, os
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(__file__)))
-    from bench import build_synthetic
+    from cyclonus_tpu.synthetic import build_synthetic
 
     return build_synthetic(n_pods, n_policies, random.Random(seed))
 

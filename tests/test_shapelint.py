@@ -13,6 +13,8 @@ import sys
 import textwrap
 import time
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tools"))
 
@@ -689,6 +691,36 @@ class TestRuntimeContracts:
         ):
             assert not hasattr(fn, "__wrapped__"), fn
             assert hasattr(fn, "__tensor_contracts__")  # lint metadata rides
+
+    @pytest.mark.parametrize("shape_check, exists", [("", False), ("1", True)])
+    def test_check_counter_exists_only_when_checking(self, shape_check, exists):
+        """The strip proof: the contract-check counter is created on the
+        first check, so a process without CYCLONUS_SHAPE_CHECK that
+        encodes and evaluates never has it in its metric registry.  The
+        armed arm is the control: the same script registers it."""
+        code = textwrap.dedent(
+            """
+            import random
+            from cyclonus_tpu.engine import PortCase, TpuPolicyEngine
+            from cyclonus_tpu.matcher import build_network_policies
+            from cyclonus_tpu.synthetic import build_synthetic
+            from cyclonus_tpu.telemetry.metrics import REGISTRY
+
+            pods, namespaces, policies = build_synthetic(16, 8, random.Random(7))
+            engine = TpuPolicyEngine(
+                build_network_policies(True, policies), pods, namespaces
+            )
+            engine.evaluate_grid_counts([PortCase(80, "serve-80-tcp", "TCP")])
+            print("COUNTER", "cyclonus_tpu_contract_checks_total" in REGISTRY.snapshot())
+            """
+        )
+        env = dict(os.environ, CYCLONUS_SHAPE_CHECK=shape_check, JAX_PLATFORMS="cpu")
+        proc = subprocess.run(
+            [sys.executable, "-c", code],
+            cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
+        )
+        assert proc.returncode == 0, proc.stdout + proc.stderr
+        assert f"COUNTER {exists}" in proc.stdout, proc.stdout
 
     def test_zero_overhead_when_off(self):
         """<2% on dataclass construction: the contracts-annotated class

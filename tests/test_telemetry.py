@@ -430,11 +430,9 @@ def steady_engine():
     import os
     import random
 
-    sys.path.insert(0, REPO)
-    from bench import build_synthetic
-
     from cyclonus_tpu.engine import PortCase, TpuPolicyEngine
     from cyclonus_tpu.matcher import build_network_policies
+    from cyclonus_tpu.synthetic import build_synthetic
 
     pods, namespaces, policies = build_synthetic(512, 48, random.Random(7))
     policy = build_network_policies(True, policies)
@@ -601,11 +599,9 @@ class TestEngineInstrumentation:
     def test_counts_path_feeds_cache_counters_and_flight(self):
         import random
 
-        sys.path.insert(0, REPO)
-        from bench import build_synthetic
-
         from cyclonus_tpu.engine import PortCase, TpuPolicyEngine
         from cyclonus_tpu.matcher import build_network_policies
+        from cyclonus_tpu.synthetic import build_synthetic
 
         telemetry.reset()
         pods, namespaces, policies = build_synthetic(

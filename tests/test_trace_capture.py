@@ -79,7 +79,7 @@ def by_name(found, name):
 
 @pytest.fixture(scope="module")
 def cluster():
-    from bench import build_synthetic
+    from cyclonus_tpu.synthetic import build_synthetic
 
     return build_synthetic(256, 24, random.Random(11))
 

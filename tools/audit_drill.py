@@ -157,7 +157,7 @@ def wait_audit(srv: Serve, pred, timeout: float = 20.0):
 def main() -> int:
     import tempfile
 
-    from cyclonus_tpu.cli.serve_cmd import synthetic_cluster
+    from cyclonus_tpu.synthetic import synthetic_cluster
 
     workdir = tempfile.mkdtemp(prefix="cyclonus-audit-drill-")
     pods, _ns = synthetic_cluster(N_PODS, N_NS, SEED)

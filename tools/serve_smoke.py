@@ -29,7 +29,7 @@ from cyclonus_tpu.analysis.oracle import (  # noqa: E402
     oracle_verdicts,
     traffic_for_cell,
 )
-from cyclonus_tpu.cli.serve_cmd import synthetic_cluster  # noqa: E402
+from cyclonus_tpu.synthetic import synthetic_cluster  # noqa: E402
 from cyclonus_tpu.kube.yaml_io import parse_policy_dict  # noqa: E402
 from cyclonus_tpu.matcher.builder import build_network_policies  # noqa: E402
 from cyclonus_tpu.worker.model import Batch, Delta, FlowQuery  # noqa: E402

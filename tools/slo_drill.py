@@ -40,7 +40,7 @@ os.environ["CYCLONUS_SLO_HOLD_S"] = "1"
 os.environ["CYCLONUS_SLO_ENFORCE"] = "1"
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-from cyclonus_tpu.cli.serve_cmd import synthetic_cluster  # noqa: E402
+from cyclonus_tpu.synthetic import synthetic_cluster  # noqa: E402
 from cyclonus_tpu.slo.engine import SloController  # noqa: E402
 from cyclonus_tpu.serve.service import VerdictService  # noqa: E402
 from cyclonus_tpu.telemetry import instruments as ti  # noqa: E402
