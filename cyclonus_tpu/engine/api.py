@@ -1036,6 +1036,10 @@ class TpuPolicyEngine:
         self._class_unpack = None  # derived-from: patched
         self._class_device_tensors = None  # derived-from: buffer
         self._class_of_dev = None  # derived-from: classes
+        # the counts route's dst-side class weights (tiled.class_weights):
+        # the serve layer mutates class_size in place, so a kept copy is
+        # a wrong count unless every reset of _class_of_dev resets it too
+        self._class_w_dev = None  # derived-from: classes
         self._class_grid_jit = None  # derived-from: shapes
         self._pod_perm_dev = None  # derived-from: pod-rows (ns-order perm)
         self._pod_perm_host = None  # derived-from: pod-rows
@@ -1129,6 +1133,7 @@ class TpuPolicyEngine:
         self._device_tensors = None
         self._class_device_tensors = None
         self._class_of_dev = None
+        self._class_w_dev = None
         self._pre_cache = None
         self._pre_cache_misses = 0
         self._pre_cache_declined = None
@@ -1369,43 +1374,76 @@ class TpuPolicyEngine:
             "resolve_s": self._tier_resolve_s,
         }
 
+    def _class_resident_tensors(self) -> Dict:
+        """The class-representative tensor set on the device, WITHOUT
+        port cases: sent once through its own single-buffer transfer and
+        kept until a patch or a class rebuild drops it.  Callers must
+        not write into the dict."""
+        import jax
+
+        if self._class_device_tensors is None:
+            buf = self._packed_transfer(
+                "_class_packed_buf", "_class_unpack",
+                self._class_state["ctensors"], name="unpack_classes",
+            )
+            if self._class_unpack_jit is None:
+                self._class_unpack_jit = aot_cache.AotProgram(
+                    "unpack.classes",
+                    jax.jit(self._class_unpack),
+                    plan=self._aot_plan(
+                        self._metas_digest(self._class_unpack)
+                    ),
+                )
+                self._class_unpack_jit.resolve(buf)
+            with detail("engine.unpack"):
+                self._class_device_tensors = self._class_unpack_jit(buf)
+        return self._class_device_tensors
+
     def _ctensors_with_cases(
         self, cases: Sequence[PortCase], device: bool = False
     ) -> Dict:
         """Compressed-tensor twin of _tensors_with_cases: the class-
         representative tensor set + port-case arrays, optionally through
-        its own single-buffer device transfer."""
+        its own single-buffer device transfer.  The grid and mesh routes'
+        operand form (their AOT plans are keyed on the three case
+        leaves); the counts route has its own (_class_counts_operands)."""
         st = self._class_state
         with detail("engine.case_tensors", device=device):
             q_port, q_name, q_proto = self._port_case_arrays(cases)
             if device:
-                import jax
-
-                if self._class_device_tensors is None:
-                    buf = self._packed_transfer(
-                        "_class_packed_buf", "_class_unpack", st["ctensors"],
-                        name="unpack_classes",
-                    )
-                    if self._class_unpack_jit is None:
-                        self._class_unpack_jit = aot_cache.AotProgram(
-                            "unpack.classes",
-                            jax.jit(self._class_unpack),
-                            plan=self._aot_plan(
-                                self._metas_digest(self._class_unpack)
-                            ),
-                        )
-                        self._class_unpack_jit.resolve(buf)
-                    with detail("engine.unpack"):
-                        self._class_device_tensors = self._class_unpack_jit(
-                            buf
-                        )
-                tensors = dict(self._class_device_tensors)
+                tensors = dict(self._class_resident_tensors())
             else:
                 tensors = dict(st["ctensors"])
             tensors["q_port"] = q_port
             tensors["q_name"] = q_name
             tensors["q_proto"] = q_proto
         return tensors
+
+    def _class_counts_operands(self, cases: Sequence[PortCase]):
+        """(tensors, w, cases) for tiled.evaluate_grid_counts_classes:
+        what the engine owns — the resident class tensors as they are,
+        with no per-call copy, and the class weights, sent once — and
+        what the call brings, its port cases as ONE int32 [3, Q] host
+        array.  No table by case set: a user who sweeps distinct ports
+        would never hit it."""
+        import jax
+
+        from .tiled import class_weights
+
+        st = self._class_state
+        with detail("engine.case_tensors", device=True):
+            tensors = self._class_resident_tensors()
+            if self._class_w_dev is None:
+                pc = st["classes"]
+                w = class_weights(
+                    int(st["ctensors"]["pod_ns_id"].shape[0]),
+                    pc.n_classes,
+                    pc.class_size,
+                )
+                with phase("engine.device_put", bytes=w.nbytes):
+                    self._class_w_dev = jax.device_put(w)
+            q_cases = np.stack(self._port_case_arrays(cases))
+        return tensors, self._class_w_dev, q_cases
 
     def _class_counts_eligible(self, q: int) -> bool:
         """The compressed counts route must itself fit the HBM budget it
@@ -1474,7 +1512,7 @@ class TpuPolicyEngine:
             ) as fl:
                 counts, gather_s = evaluate_grid_counts_classes(
                     fl,
-                    self._ctensors_with_cases(cases, device=True),
+                    *self._class_counts_operands(cases),
                     pc.n_classes,
                     pc.class_size,
                     n,
@@ -1575,17 +1613,16 @@ class TpuPolicyEngine:
         st = self._class_state
         pc = st["classes"]
         n = self.encoding.cluster.n_pods
-        tensors = self._ctensors_with_cases(cases, device=True)
+        tensors = self._class_resident_tensors()
+        q_cases = np.stack(self._port_case_arrays(cases))
         w, block, n_tiles = class_rowsums_plan(
             tensors, pc.n_classes, pc.class_size
         )
-        out = _class_rowsums_kernel(tensors, w, block, n_tiles, self._pack)
+        args = (tensors, w, q_cases, block, n_tiles, self._pack)
+        out = _class_rowsums_kernel(*args)
         np.asarray(out)  # warm barrier
         t0 = _time.perf_counter()
-        outs = [
-            _class_rowsums_kernel(tensors, w, block, n_tiles, self._pack)
-            for _ in range(reps)
-        ]
+        outs = [_class_rowsums_kernel(*args) for _ in range(reps)]
         rs = np.asarray(outs[-1])  # in-order stream: one barrier
         dt = (_time.perf_counter() - t0) / reps
         counts = class_counts_finish(

@@ -497,15 +497,29 @@ def _class_tile_rowsums(
     return jnp.stack([rs(ingress_rows), rs(egress), rs(combined)], axis=-1)
 
 
+def _with_case_rows(tensors: Dict, cases: jnp.ndarray) -> Dict:
+    """`tensors` (the engine's case-free class tensor set) with the
+    port-case leaves set from the rows of `cases`, int32 [3, Q] =
+    (q_port, q_name, q_proto): inside a jit, three slices of ONE
+    operand, so a request sends one array for its cases and not three."""
+    return dict(tensors, q_port=cases[0], q_name=cases[1], q_proto=cases[2])
+
+
 @partial(jax.jit, static_argnames=("block", "n_tiles", "pack"))
 def _class_rowsums_kernel(
-    tensors: Dict, w: jnp.ndarray, block: int, n_tiles: int, pack: bool = False
+    tensors: Dict,
+    w: jnp.ndarray,
+    cases: jnp.ndarray,
+    block: int,
+    n_tiles: int,
+    pack: bool = False,
 ) -> jnp.ndarray:
     """[n_tiles * block, Q, 3] f32 weighted row sums over the class grid,
-    one device execution (fori_loop over class tiles)."""
-    pre = _precompute(tensors, pack)
+    one device execution (fori_loop over class tiles).  `tensors` carries
+    no port cases: they come as `cases` (_with_case_rows)."""
+    pre = _precompute(_with_case_rows(tensors, cases), pack)
     src, dst = _split_pre(pre)
-    q = tensors["q_port"].shape[0]
+    q = cases.shape[1]
 
     def body(i, out):
         rs = _class_tile_rowsums(src, dst, w, i * block, block)
@@ -515,21 +529,35 @@ def _class_rowsums_kernel(
     return jax.lax.fori_loop(0, n_tiles, body, out)
 
 
+def _class_tiling(cb: int, block: int) -> Tuple[int, int]:
+    """(block, n_tiles) of the XLA tile loop over a class axis of `cb`.
+    Bucketed axes (api._bucket_pods) are powers of two or multiples of
+    1024, so min(block, 1024, cb) always divides cb; the fallback to the
+    whole axis covers hand-built tensor dicts only."""
+    block = max(1, min(block, 1024, cb))
+    if cb % block:
+        block = cb
+    return block, cb // block
+
+
+def class_weights(cb: int, n_classes: int, class_size: np.ndarray) -> np.ndarray:
+    """The dst-side weights of the row sums: the class sizes as float32
+    on the padded class axis [cb], pad classes 0.  A function of the
+    class state and of nothing a call brings: the engine keeps it on the
+    device (api._class_counts_operands)."""
+    w = np.zeros((cb,), dtype=np.float32)
+    w[:n_classes] = np.asarray(class_size, dtype=np.float32)
+    return w
+
+
 def class_rowsums_plan(
     tensors: Dict, n_classes: int, class_size: np.ndarray, block: int = 1024
 ):
     """(w, block, n_tiles) for the class row-sum kernel over `tensors`
-    whose pod axis is the (bucketing-padded) class axis.  Bucketed axes
-    (api._bucket_pods) are powers of two or multiples of 1024, so
-    min(block, 1024, cb) always divides cb; the fallback to the whole
-    axis covers hand-built tensor dicts only."""
+    whose pod axis is the (bucketing-padded) class axis."""
     cb = int(tensors["pod_ns_id"].shape[0])
-    block = max(1, min(block, 1024, cb))
-    if cb % block:
-        block = cb
-    w = np.zeros((cb,), dtype=np.float32)
-    w[:n_classes] = np.asarray(class_size, dtype=np.float32)
-    return w, block, cb // block
+    block, n_tiles = _class_tiling(cb, block)
+    return class_weights(cb, n_classes, class_size), block, n_tiles
 
 
 def class_counts_finish(
@@ -556,7 +584,7 @@ def class_counts_finish(
 
 @partial(jax.jit, static_argnames=("interpret",))
 def _class_rowsums_fused_kernel(
-    tensors: Dict, w: jnp.ndarray, interpret: bool = False
+    tensors: Dict, w: jnp.ndarray, cases: jnp.ndarray, interpret: bool = False
 ) -> jnp.ndarray:
     """Fused-epilogue twin of _class_rowsums_kernel: packed precompute +
     the packed Pallas kernel whose EPILOGUE computes the dst-weighted
@@ -567,7 +595,7 @@ def _class_rowsums_fused_kernel(
     test."""
     from .pallas_kernel import verdict_counts_pallas_packed
 
-    pre = _precompute(tensors, True)
+    pre = _precompute(_with_case_rows(tensors, cases), True)
     tier = {
         d: pre[d]["tier"] for d in ("ingress", "egress")
     } if "tier" in pre["egress"] else None
@@ -622,6 +650,8 @@ def _class_counts_kernel_choice(tensors: Dict, pack: bool, kernel: str) -> str:
 def evaluate_grid_counts_classes(
     fl,
     tensors: Dict,
+    w: jnp.ndarray,
+    cases: np.ndarray,
     n_classes: int,
     class_size: np.ndarray,
     n_pods: int,
@@ -631,32 +661,52 @@ def evaluate_grid_counts_classes(
 ) -> Tuple[Dict[str, int], float]:
     """Allow counts over the FULL N x N x Q grid, evaluated on the
     compressed C x C class grid and weighted back exactly, inside the
-    caller's eval_flight `fl` (the engine opens it before it builds
-    `tensors`, so that the flight's `engine.eval` span covers the whole
+    caller's eval_flight `fl` (the engine opens it before it builds the
+    operands, so that the flight's `engine.eval` span covers the whole
     request): plan, dispatch, the readback barrier, the exact host
-    finish.  Returns (counts, gather_s) where gather_s is that finish
-    (the host weighting) — the cheap gather the compression trades the
-    dense grid for.  `kernel`: see _class_counts_kernel_choice."""
+    finish.  The operands are split into what the engine owns and what
+    the call brings: `tensors` (the class tensor set, WITHOUT port
+    cases) and `w` (class_weights) are the engine's and stay on its
+    device; `cases`, int32 [3, Q] on the host (_with_case_rows), is the
+    call's and is the one array sent, by one explicit device_put.  The
+    `engine.dispatch` span says what really crossed: `host_operands`
+    and `host_bytes` count the host arrays among all three.  Returns
+    (counts, gather_s) where gather_s is the finish (the host weighting)
+    — the cheap gather the compression trades the dense grid for.
+    `kernel`: see _class_counts_kernel_choice."""
     import time as _time
 
     if pack is None:
         pack = pack_enabled()
-    kernel = _class_counts_kernel_choice(tensors, pack, kernel)
-    q = int(tensors["q_port"].shape[0])
     with detail("engine.plan"):
-        w, block, n_tiles = class_rowsums_plan(
-            tensors, n_classes, class_size, block
+        kernel = _class_counts_kernel_choice(tensors, pack, kernel)
+        block, n_tiles = _class_tiling(
+            int(tensors["pod_ns_id"].shape[0]), block
         )
+    q = int(cases.shape[1])
     fl.set(block=block)
-    with phase("engine.dispatch"):
+    with phase("engine.dispatch") as sp:
+        sent = jax.device_put(cases)
         if kernel == "pallas":
             from .pallas_kernel import _should_interpret
 
             out = _class_rowsums_fused_kernel(
-                tensors, w, interpret=_should_interpret()
+                tensors, w, sent, interpret=_should_interpret()
             )
         else:
-            out = _class_rowsums_kernel(tensors, w, block, n_tiles, pack)
+            out = _class_rowsums_kernel(
+                tensors, w, sent, block, n_tiles, pack
+            )
+        # counted once the program is enqueued: the device is running,
+        # so walking the operands costs the request nothing
+        host = [
+            a
+            for a in jax.tree_util.tree_leaves((tensors, w, cases))
+            if isinstance(a, np.ndarray)
+        ]
+        sp.set(
+            host_operands=len(host), host_bytes=sum(a.nbytes for a in host)
+        )
     # the readback is the execution barrier (dispatch is async)
     with phase("engine.execute"):
         rs = np.asarray(out)
@@ -693,10 +743,8 @@ def evaluate_grid_counts_classes_sharded(
     pack = pack_enabled()
     shard = n_padded // n_dev
     tiles_per_shard = shard // block
-    w = np.zeros((n_padded,), dtype=np.float32)
-    w[:n_classes] = np.asarray(class_size, dtype=np.float32)
     t = dict(tensors)
-    t["class_w"] = w
+    t["class_w"] = class_weights(n_padded, n_classes, class_size)
 
     def per_device(td):
         w_all = td["class_w"]

@@ -624,6 +624,7 @@ class IncrementalEngine:
         eng._class_unpack_jit = None
         eng._class_device_tensors = None
         eng._class_of_dev = None
+        eng._class_w_dev = None
         ti.CLASS_PODS.set(n)
         ti.CLASS_COUNT.set(pc.n_classes)
         ti.CLASS_RATIO.set(st["ratio"])

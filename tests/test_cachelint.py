@@ -676,6 +676,24 @@ class TestCleanRun:
         assert findings == [], "\n".join(f.render() for f in findings)
         assert stats["annotations"] >= 25, stats
 
+    def test_class_weights_declared_and_cleared(self):
+        """The engine's device-resident class weights (`_class_w_dev`,
+        PR 31) derive from the class state, which the serve layer
+        mutates in place: the field is declared so, and
+        invalidate_after_patch resets it."""
+        import ast
+
+        path = os.path.join(REPO, "cyclonus_tpu", "engine", "api.py")
+        src = open(path).read()
+        model = cachelint.ModuleModel(path, ast.parse(src), src.splitlines())
+        decls, invalidate, reset = cachelint.derived_model(
+            model, model.classes["TpuPolicyEngine"]
+        )
+        assert invalidate is not None
+        tokens, _line = decls["_class_w_dev"]
+        assert tokens == decls["_class_of_dev"][0] == ["classes"]
+        assert "_class_w_dev" in reset
+
     def test_cli_exit_status(self):
         proc = subprocess.run(
             [sys.executable, os.path.join(REPO, "tools", "cachelint.py")],

@@ -267,7 +267,10 @@ class TestFusedEpilogues:
             assert fused == split, f"seed {fc.seed}"
 
     def test_fused_class_rowsums_equal_split(self, monkeypatch):
-        from cyclonus_tpu.engine.tiled import evaluate_grid_counts_classes
+        from cyclonus_tpu.engine.tiled import (
+            class_rowsums_plan,
+            evaluate_grid_counts_classes,
+        )
 
         monkeypatch.setenv("CYCLONUS_PACK", "1")
         monkeypatch.setenv("CYCLONUS_CLASS_COMPRESS", "1")
@@ -275,15 +278,19 @@ class TestFusedEpilogues:
         engine = TpuPolicyEngine(policy, pods, namespaces)
         assert engine._class_state is not None
         pc = engine._class_state["classes"]
-        tensors = engine._ctensors_with_cases(CASES)
+        tensors = engine._class_state["ctensors"]
+        w, _, _ = class_rowsums_plan(tensors, pc.n_classes, pc.class_size)
+        q_cases = np.stack(engine._port_case_arrays(CASES))
         n = len(pods)
         with eval_flight("counts.classes", n, len(CASES)) as fl:
             split, _ = evaluate_grid_counts_classes(
-                fl, tensors, pc.n_classes, pc.class_size, n, kernel="xla"
+                fl, tensors, w, q_cases, pc.n_classes, pc.class_size, n,
+                kernel="xla",
             )
         with eval_flight("counts.classes", n, len(CASES)) as fl:
             fused, _ = evaluate_grid_counts_classes(
-                fl, tensors, pc.n_classes, pc.class_size, n, kernel="pallas"
+                fl, tensors, w, q_cases, pc.n_classes, pc.class_size, n,
+                kernel="pallas",
             )
         assert fused == split
         # and both equal the dense truth
@@ -296,7 +303,10 @@ class TestFusedEpilogues:
         an oversized tier rule axis must refuse the fused kernel."""
         import cyclonus_tpu.engine.pallas_kernel as pk
 
-        from cyclonus_tpu.engine.tiled import evaluate_grid_counts_classes
+        from cyclonus_tpu.engine.tiled import (
+            class_rowsums_plan,
+            evaluate_grid_counts_classes,
+        )
         from cyclonus_tpu.tiers.fuzz import build_fuzz_case
 
         fc = None
@@ -313,19 +323,21 @@ class TestFusedEpilogues:
         if engine._class_state is None:
             pytest.skip("fuzz case compressed to nothing")
         pc = engine._class_state["classes"]
-        tensors = engine._ctensors_with_cases(fc.cases)
+        tensors = engine._class_state["ctensors"]
+        w, _, _ = class_rowsums_plan(tensors, pc.n_classes, pc.class_size)
+        q_cases = np.stack(engine._port_case_arrays(fc.cases))
         monkeypatch.setattr(pk, "PACKED_TIER_MAX_ROWS", 1)
         n, q = len(fc.pods), len(fc.cases)
         with pytest.raises(ValueError, match="static-unroll ceiling"):
             with eval_flight("counts.classes", n, q) as fl:
                 evaluate_grid_counts_classes(
-                    fl, tensors, pc.n_classes, pc.class_size, n,
+                    fl, tensors, w, q_cases, pc.n_classes, pc.class_size, n,
                     kernel="pallas",
                 )
         # auto routes to the XLA body and stays correct
         with eval_flight("counts.classes", n, q) as fl:
             counts, _ = evaluate_grid_counts_classes(
-                fl, tensors, pc.n_classes, pc.class_size, n
+                fl, tensors, w, q_cases, pc.n_classes, pc.class_size, n
             )
         want = engine.evaluate_grid_counts(fc.cases, block=8, backend="xla")
         assert counts["combined"] == want["combined"]

@@ -512,20 +512,33 @@ class TestOverhead:
         device sync costs ~ms, far above this bound) — deliberately
         loose because end-to-end timing on a shared box drifts +-5%."""
         engine, cases = steady_engine
-        samples = {True: [], False: []}
-        try:
-            for i in range(60):
-                enabled = i % 2 == 0
-                telemetry.set_enabled(enabled)
-                t0 = time.perf_counter()
-                engine.evaluate_grid_counts(cases, backend="pallas")
-                samples[enabled].append(time.perf_counter() - t0)
-        finally:
-            telemetry.set_enabled(True)
-        t_on, t_off = min(samples[True]), min(samples[False])
-        assert t_on <= 1.25 * t_off, (
-            f"enabled path {100 * (t_on / t_off - 1):.1f}% slower — "
-            f"instrumentation is doing real work on the hot path"
+
+        def on_over_off():
+            samples = {True: [], False: []}
+            try:
+                for i in range(60):
+                    enabled = i % 2 == 0
+                    telemetry.set_enabled(enabled)
+                    t0 = time.perf_counter()
+                    engine.evaluate_grid_counts(cases, backend="pallas")
+                    samples[enabled].append(time.perf_counter() - t0)
+            finally:
+                telemetry.set_enabled(True)
+            return min(samples[True]) / min(samples[False])
+
+        # work smuggled into a span slows EVERY round; a neighbour on
+        # the box (the suite runs under six workers) slows one, and has
+        # failed this test on trees that touched nothing it times.  So
+        # up to three rounds, and only all three over the ratio fail.
+        ratios = []
+        for _ in range(3):
+            ratios.append(on_over_off())
+            if ratios[-1] <= 1.25:
+                break
+        assert min(ratios) <= 1.25, (
+            f"enabled path {100 * (min(ratios) - 1):.1f}% slower in the "
+            f"best of {len(ratios)} rounds ({ratios}) — instrumentation "
+            f"is doing real work on the hot path"
         )
 
 
