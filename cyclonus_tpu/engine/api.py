@@ -970,6 +970,7 @@ class TpuPolicyEngine:
                 if class_compress is None
                 else str(class_compress).lower()
             )
+            class_route = "off"
             if mode != "0":
                 with phase("engine.partition"):
                     pstats = {}
@@ -979,7 +980,9 @@ class TpuPolicyEngine:
                         )
                         self._tensors[direction] = nd
                     self._partition_stats = pstats
-                self._maybe_build_class_state(mode)
+                class_route = self._maybe_build_class_state(mode)
+            # which side of the class-route hand-off this engine is on
+            ti.CLASS_ROUTE.inc(outcome=class_route)
             with phase("engine.class_tensors"):
                 self._tensors = _bucket_tensors(
                     _sort_targets_by_ns(self._tensors),
@@ -1226,22 +1229,25 @@ class TpuPolicyEngine:
 
     # --- equivalence-class grid compression ------------------------------
 
-    def _maybe_build_class_state(self, mode: str) -> None:
+    def _maybe_build_class_state(self, mode: str) -> str:
         """Bucket pods into label-equivalence classes and keep the
         compressed tensor set when compression is forced (mode "1") or
         worth it (auto: above the pod floor with a real reduction).
         Reuses the SAME host selector pass dead-target compaction paid
         for; when compaction's work budget skipped that pass, auto mode
-        skips classes too (forcing recomputes it)."""
+        skips classes too (forcing recomputes it).  Returns the decision
+        as cyclonus_tpu_class_route_total{outcome} names it: "kept", or
+        why no class state was kept ("off", "below_floor",
+        "no_selector_pass", "no_reduction")."""
         n = self.encoding.cluster.n_pods
-        if n == 0 or n >= _CLASS_MAX_PODS_EXACT:
-            return
-        if mode != "1" and n < _class_auto_min_pods():
-            return
+        if n >= _CLASS_MAX_PODS_EXACT:
+            return "off"
+        if n == 0 or (mode != "1" and n < _class_auto_min_pods()):
+            return "below_floor"
         selpod = self._selpod_prebucket
         if selpod is None:
             if mode != "1":
-                return
+                return "no_selector_pass"
             selpod = self._selpod_prebucket = _selector_pod_matches_host(
                 self._tensors
             )
@@ -1254,15 +1260,29 @@ class TpuPolicyEngine:
         # to the pre-TSS signature.
         from . import cidrspace
 
-        with phase("engine.cidrspace"):
+        with phase("engine.cidrspace") as sp:
             space = cidrspace.resolve(
                 self._tensors, mode=self._opt_cidr_tss, n_pods=n
             )
+            # what cidr_stats() knows, here too: a refused class state
+            # takes the space with it
+            if space is None:
+                sp.set(active=False)
+            else:
+                sp.set(
+                    active=True,
+                    specs=space.n_specs,
+                    atoms=space.n_atoms,
+                    partitions=space.n_partitions,
+                    device=space.lpm_on_device(n),
+                )
         with phase("engine.classify") as sp:
             pc = compute_pod_classes(self._tensors, selpod, cidr=space)
-            sp.set(classes=pc.n_classes)
-        if mode != "1" and pc.n_classes > int(0.9 * n):
-            return  # no real reduction: the second tensor set isn't worth it
+            kept = mode == "1" or pc.n_classes <= int(0.9 * n)
+            sp.set(classes=pc.n_classes, pods=n, kept=kept)
+        if not kept:
+            # no real reduction: the second tensor set isn't worth it
+            return "no_reduction"
         # engine.class_tensors: the second tensor set (one row a class),
         # then in __init__ the padding of both sets to their shape buckets
         with phase("engine.class_tensors"):
@@ -1278,6 +1298,7 @@ class TpuPolicyEngine:
         ti.CLASS_PODS.set(n)
         ti.CLASS_COUNT.set(pc.n_classes)
         ti.CLASS_RATIO.set(self._class_state["ratio"])
+        return "kept"
 
     def pod_classes(self):
         """The PodClasses of the active compression state, or None when

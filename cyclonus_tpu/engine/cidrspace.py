@@ -177,6 +177,11 @@ class CidrSpace:
         rather than patch over a stale map."""
         return tuple(int(m) for m in self.pmask)
 
+    def lpm_on_device(self, n_pods: int) -> bool:
+        """The leg `signature` takes by default for `n_pods` pods: the
+        accelerator above the pods x atoms work floor (_device_enabled)."""
+        return bool(n_pods) and _device_enabled(n_pods * max(self.n_atoms, 1))
+
     def signature(
         self,
         pod_ip: np.ndarray,
@@ -191,7 +196,7 @@ class CidrSpace:
 
         n = int(pod_ip.shape[0])
         if device is None:
-            device = _device_enabled(n * max(self.n_atoms, 1))
+            device = self.lpm_on_device(n)
         t0 = time.perf_counter()
         if device and n:
             import jax

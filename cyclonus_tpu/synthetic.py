@@ -23,11 +23,19 @@ tests/test_synthetic.py holds the two equal where they are meant to be.
 cidr_cluster and tiers_lattice are the two special-purpose shapes over
 the same label scheme: an ipBlock-heavy cluster for the TSS/LPM CIDR
 stage, and a fixed ANP/BANP lattice for the precedence tiers.
+
+cidr_allowlists is the cluster whose IP structure cuts across its labels
+(node /24s, labels drawn a pod, allowlists kept by CIDR with except
+lists): the one that auto class compression REFUSES, so the dense routes
+run.  `benchmarks/generators_cidr.py` is the benchmark's own copy (its
+configuration `cidr-10k-5k` holds the shape parameters repeated in
+CIDR_ALLOWLISTS below), held equal to it in tests/test_synthetic.py.
 """
 
 from __future__ import annotations
 
 import random
+from ipaddress import IPv4Address
 from typing import Optional
 
 from .kube.netpol import (
@@ -220,6 +228,146 @@ def cidr_cluster(n_pods: int, distinct: int, pool: int):
             )
         )
     return pods, namespaces, netpols, rng
+
+
+#: the shapes of cidr_allowlists; benchmarks/configs/cidr-10k-5k.json's
+#: `generator` block holds the same values (tests/test_synthetic.py)
+CIDR_ALLOWLISTS = {
+    "structure_seed": 20261002,
+    "pods_per_node": 110,  # kubelet --max-pods; a node owns a /24
+    "vocab": {"app": 20, "tier": 5},
+    "peers_per_rule": [1, 2, 2, 4, 4, 8],
+    "tier_peer_share": 0.3,
+    "ingress_only_share": 0.4,
+    "egress_only_share": 0.3,
+    "named_udp_share": 0.3,
+    # [prefix length, weight]: ClassBench ACL-like, as recalled
+    "prefix_lengths": [
+        [32, 35], [24, 25], [16, 8], [28, 7], [27, 5], [26, 5], [20, 5],
+        [22, 4], [30, 2], [12, 2], [8, 2],
+    ],
+    "inside_share": 0.5,
+    "outside_first_octet": [11, 223],
+    "except_max_prefix": 28,
+    "except_counts": [0, 0, 1, 1, 2, 4],
+    "except_extra_bits": [4, 8],
+}
+
+
+def _node_pod_addr(i: int, per_node: int) -> int:
+    """Pod i's address: node i // per_node owns
+    10.(64 + (node >> 8)).(node & 255).0/24, host byte 1 + i % per_node."""
+    node = i // per_node
+    return (
+        (10 << 24) | ((64 + (node >> 8)) << 16) | ((node & 255) << 8)
+        | (1 + i % per_node)
+    )
+
+
+def _allowlist_block(n_pods: int, gen: dict, rng: random.Random) -> IPBlock:
+    lengths, weights = zip(*gen["prefix_lengths"])
+    (length,) = rng.choices(lengths, weights)
+    if rng.random() < gen["inside_share"]:
+        addr = _node_pod_addr(rng.randrange(n_pods), gen["pods_per_node"])
+    else:
+        lo, hi = gen["outside_first_octet"]
+        addr = (rng.randrange(lo, hi + 1) << 24) | rng.getrandbits(24)
+    base = addr & ~((1 << (32 - length)) - 1)
+    excepts: list = []
+    if length <= gen["except_max_prefix"]:
+        for _ in range(rng.choice(gen["except_counts"])):
+            longer = length + rng.choice(gen["except_extra_bits"])
+            if longer > 32:
+                longer = length + min(gen["except_extra_bits"])
+            inside = base | (rng.getrandbits(longer - length) << (32 - longer))
+            entry = f"{IPv4Address(inside)}/{longer}"
+            if entry not in excepts:
+                excepts.append(entry)
+    return IPBlock.make(f"{IPv4Address(base)}/{length}", excepts)
+
+
+def _allowlist_peers(n_pods: int, gen: dict, rng: random.Random) -> list:
+    peers = [
+        NetworkPolicyPeer(ip_block=_allowlist_block(n_pods, gen, rng))
+        for _ in range(rng.choice(gen["peers_per_rule"]))
+    ]
+    if rng.random() < gen["tier_peer_share"]:
+        tier = f"tier{rng.randrange(gen['vocab']['tier'])}"
+        peers.append(
+            NetworkPolicyPeer(
+                pod_selector=LabelSelector.make(match_labels={"tier": tier})
+            )
+        )
+    return peers
+
+
+def cidr_allowlists(
+    n_pods: int, n_policies: int, n_ns: int, gen: Optional[dict] = None
+):
+    """(pods, namespaces, policies): pod i in namespace i % n_ns on node
+    i // 110 (a /24 a node), `app` and `tier` drawn a pod; `n_policies`
+    NetworkPolicies that target one app of one namespace with one rule a
+    direction of 1 - 8 ipBlock peers (half inside the pod range, half
+    external; prefix lengths by CIDR_ALLOWLISTS; except lists on /28 and
+    shorter) and sometimes a tier podSelector beside them; 40 % ingress
+    only, 30 % egress only, 30 % both.  Every draw comes from
+    gen["structure_seed"], pods and policies from separate streams."""
+    gen = gen or CIDR_ALLOWLISTS
+    vocab, per_node = gen["vocab"], gen["pods_per_node"]
+    rng = random.Random(f"{gen['structure_seed']}/pods")
+    pods = []
+    for i in range(n_pods):
+        labels = {
+            "app": f"app{rng.randrange(vocab['app'])}",
+            "tier": f"tier{rng.randrange(vocab['tier'])}",
+        }
+        pods.append(
+            (f"ns{i % n_ns}", f"pod-{i}", labels,
+             str(IPv4Address(_node_pod_addr(i, per_node))))
+        )
+    namespaces = {f"ns{i}": {"ns": f"ns{i}"} for i in range(n_ns)}
+
+    rng = random.Random(f"{gen['structure_seed']}/policy-set/0")
+    policies = []
+    for i in range(n_policies):
+        ns = f"ns{rng.randrange(n_ns)}"
+        target = LabelSelector.make(
+            match_labels={"app": f"app{rng.randrange(vocab['app'])}"}
+        )
+        ports = [NetworkPolicyPort(protocol="TCP", port=IntOrString(80))]
+        if rng.random() < gen["named_udp_share"]:
+            ports.append(
+                NetworkPolicyPort(protocol="UDP", port=IntOrString("serve-81-udp"))
+            )
+        roll = rng.random()
+        if roll < gen["ingress_only_share"]:
+            types = ["Ingress"]
+        elif roll < gen["ingress_only_share"] + gen["egress_only_share"]:
+            types = ["Egress"]
+        else:
+            types = ["Ingress", "Egress"]
+        ingress, egress = [], []
+        if "Ingress" in types:
+            ingress = [NetworkPolicyIngressRule(
+                ports=ports, from_=_allowlist_peers(n_pods, gen, rng)
+            )]
+        if "Egress" in types:
+            egress = [NetworkPolicyEgressRule(
+                ports=ports, to=_allowlist_peers(n_pods, gen, rng)
+            )]
+        policies.append(
+            NetworkPolicy(
+                name=f"cidr-{i}",
+                namespace=ns,
+                spec=NetworkPolicySpec(
+                    pod_selector=target,
+                    policy_types=types,
+                    ingress=ingress,
+                    egress=egress,
+                ),
+            )
+        )
+    return pods, namespaces, policies
 
 
 def tiers_lattice() -> TierSet:

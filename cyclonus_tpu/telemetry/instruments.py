@@ -139,6 +139,17 @@ CLASS_AUX_BYTES = REGISTRY.gauge(
     "map, weights, compressed tensor buffer) counted against the "
     "CYCLONUS_SLAB_MAX_BYTES budget.",
 )
+CLASS_ROUTE = REGISTRY.counter(
+    "cyclonus_tpu_class_route_total",
+    "Grid compression: every engine's decision at construction, by "
+    "outcome: kept (a class state was built and kept), no_reduction "
+    "(auto: classes > 0.9 x pods, the classes and their CIDR space are "
+    "dropped), below_floor (auto under CYCLONUS_CLASS_MIN_PODS, or no "
+    "pods), no_selector_pass (auto: compaction's budget skipped the "
+    "host selector pass), off (CYCLONUS_CLASS_COMPRESS=0, or past the "
+    "2^24 pods exact counts allow).",
+    labelnames=("outcome",),
+)
 CLASS_EVALS = REGISTRY.counter(
     "cyclonus_tpu_class_evals_total",
     "Evaluations served by the compressed class path, by path "
@@ -442,7 +453,8 @@ class Flight:
 @contextlib.contextmanager
 def eval_flight(path: str, n_pods: int, q: int, **attrs: Any) -> Iterator[Flight]:
     """Wrap one engine evaluation: its number, its `engine.eval` span
-    (attr `route` = the PathSpec name), histogram + dispatch counter +
+    (attr `route` = the PathSpec name; `mode` where the route's dispatch
+    says which of its programs ran), histogram + dispatch counter +
     flight record, outcome 'ok' or the exception repr."""
     if not state.ENABLED:
         yield _NULL_FLIGHT  # type: ignore[misc]
@@ -455,8 +467,14 @@ def eval_flight(path: str, n_pods: int, q: int, **attrs: Any) -> Iterator[Flight
     outcome = "ok"
     t0 = time.perf_counter()
     try:
-        with spans.evaluation(eval_id), spans.span("engine.eval", route=path):
+        with spans.evaluation(eval_id), spans.span(
+            "engine.eval", route=path
+        ) as sp:
             yield flight
+            # which program of the route ran (fused / split / steady)
+            # shows on the span as it does in the flight entry
+            if "mode" in flight.data:
+                sp.set(mode=flight.data["mode"])
     except BaseException as e:
         outcome = f"{type(e).__name__}: {e}"[:300]
         raise

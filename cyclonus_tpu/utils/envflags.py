@@ -59,7 +59,7 @@ _FLAGS = [
     Flag("CYCLONUS_CLASS_COMPRESS", "enum", "auto", "engine",
          "Pod-class compression: 'auto' (size floor), '1' (force), "
          "'0' (off).", choices=("auto", "0", "1")),
-    Flag("CYCLONUS_CLASS_MIN_PODS", "int", 4096, "engine",
+    Flag("CYCLONUS_CLASS_MIN_PODS", "int", 2048, "engine",
          "Pod-count floor below which auto class compression stays "
          "off."),
     Flag("CYCLONUS_FULL_LOCATIONS", "bool", False, "engine",

@@ -15,6 +15,7 @@
 
 import hashlib
 import importlib.util
+import ipaddress
 import json
 import os
 import random
@@ -27,7 +28,9 @@ from cyclonus_tpu.engine import PortCase, TpuPolicyEngine
 from cyclonus_tpu.kube.yaml_io import parse_policy_dict, policy_to_dict
 from cyclonus_tpu.matcher import build_network_policies
 from cyclonus_tpu.synthetic import (
+    CIDR_ALLOWLISTS,
     build_synthetic,
+    cidr_allowlists,
     cidr_cluster,
     synthetic_cluster,
     tiers_lattice,
@@ -175,6 +178,117 @@ class TestTheBenchmarksCopy:
         assert policy_dicts(parse_policy_dict(d) for d in theirs) == policy_dicts(
             policies
         )
+
+
+@pytest.fixture(scope="module")
+def generators_cidr():
+    """benchmarks/generators_cidr.py by path, as `generators` above."""
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_generators_cidr",
+        os.path.join(REPO, "benchmarks", "generators_cidr.py"),
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def cidr_config():
+    with open(os.path.join(REPO, "benchmarks", "configs", "cidr-10k-5k.json")) as f:
+        return json.load(f)
+
+
+class TestCidrAllowlists:
+    """PR 32's generator: pinned, and the benchmark's copy
+    (`benchmarks/generators_cidr.py`, configuration `cidr-10k-5k`) held to
+    the program's."""
+
+    SIZES = {"pods": 660, "policies": 330, "namespaces": 4}
+
+    @pytest.mark.parametrize(
+        "n_pods, n_policies, n_ns, want",
+        [
+            (660, 330, 4,
+             "90fb9eff6cb4a635792de4a9954da638d40deccbfd376005c36275f53bc6f634"),
+            (64, 48, 3,
+             "86b5378f89878333aeb6d1ee4b731d8497ef2f67c9f26448d628986c8efd038c"),
+        ],
+    )
+    def test_pinned(self, n_pods, n_policies, n_ns, want):
+        pods, namespaces, policies = cidr_allowlists(n_pods, n_policies, n_ns)
+        assert digest(pods, namespaces, policy_dicts(policies)) == want
+
+    def test_the_configuration_holds_the_programs_shapes(self, cidr_config):
+        gen = dict(cidr_config["generator"])
+        assert gen.pop("module") == "generators_cidr"
+        assert gen == CIDR_ALLOWLISTS
+
+    def test_the_benchmarks_copy_draws_the_same_cluster(
+        self, generators_cidr, cidr_config
+    ):
+        gen = cidr_config["generator"]
+        pods, namespaces, policies = cidr_allowlists(660, 330, 4)
+        assert generators_cidr.cluster(660, 4, gen) == (pods, namespaces)
+        theirs = generators_cidr.allowlist_policies(
+            330, 660, 4, gen, random.Random(f"{gen['structure_seed']}/policy-set/0")
+        )
+        assert policy_dicts(parse_policy_dict(d) for d in theirs) == policy_dicts(
+            policies
+        )
+
+    def test_every_shape_the_issue_names_is_drawn(self, generators_cidr, cidr_config):
+        """Several peers a rule, egress-only policies, /32 and /8, an
+        except as long as /32, a pod's address inside its node's /24."""
+        _, _, policies = generators_cidr.build(
+            self.SIZES, cidr_config["generator"], 1
+        )
+        types = {tuple(p["spec"]["policyTypes"]) for p in policies}
+        assert types == {("Ingress",), ("Egress",), ("Ingress", "Egress")}
+        blocks = [
+            peer["ipBlock"]
+            for p in policies
+            for rules, key in ((p["spec"].get("ingress", []), "from"),
+                               (p["spec"].get("egress", []), "to"))
+            for rule in rules for peer in rule[key] if "ipBlock" in peer
+        ]
+        lengths = {int(b["cidr"].split("/")[1]) for b in blocks}
+        assert lengths == {32, 30, 28, 27, 26, 24, 22, 20, 16, 12, 8}
+        excepts = [e for b in blocks for e in b.get("except", [])]
+        assert any(e.endswith("/32") for e in excepts)
+        assert max(len(b.get("except", [])) for b in blocks) == 4
+        assert max(
+            len(rule[key])
+            for p in policies
+            for rules, key in ((p["spec"].get("ingress", []), "from"),
+                               (p["spec"].get("egress", []), "to"))
+            for rule in rules
+        ) == 9  # eight blocks and the tier selector beside them
+        # every except lies inside its block, and is longer
+        for b in blocks:
+            net = ipaddress.ip_network(b["cidr"])  # strict: no host bits set
+            for e in b.get("except", []):
+                inner = ipaddress.ip_network(e)
+                assert inner.subnet_of(net) and inner.prefixlen > net.prefixlen
+
+    def test_the_seed_reorders_and_changes_no_count(
+        self, generators_cidr, cidr_config
+    ):
+        gen = cidr_config["generator"]
+        a = generators_cidr.build(self.SIZES, gen, 1)
+        b = generators_cidr.build(self.SIZES, gen, 3000000019)
+        assert a[0] != b[0] and a[2] != b[2] and a[1] == b[1]
+        assert sorted(a[0]) == sorted(b[0])
+        by_name = lambda ps: sorted(ps, key=lambda p: p["metadata"]["name"])
+        assert by_name(a[2]) == by_name(b[2])
+        counts = []
+        for pods, namespaces, policies in (a, b):
+            policy = build_network_policies(
+                True, [parse_policy_dict(d) for d in policies]
+            )
+            got = TpuPolicyEngine(policy, pods, namespaces).evaluate_grid_counts(CASES)
+            counts.append({k: int(got[k]) for k in ("ingress", "egress", "combined")})
+        assert counts[0] == counts[1]
+        assert 0 < counts[0]["combined"] < len(CASES) * 660 * 660
 
 
 class Flipped:
