@@ -253,9 +253,11 @@ def batch_counts_phase(state: dict) -> None:
 
     dense = TpuPolicyEngine(policy, pods, namespaces, class_compress="0")
     calls = []
-    # the engine reaches its steady dispatch in four calls: one fused
-    # program, the split pair that pins the precompute, the first steady
-    # call (which runs the tile autotune instead), then the tuned kernel
+    # the engine reaches its steady dispatch in four calls: the cold one
+    # (the fused program where the static half of the precompute passes
+    # the pins' byte ceiling, else the resident pair), the split pair
+    # that pins the precompute, the first steady call (which runs the
+    # tile autotune instead), then the tuned kernel
     for label in ("cold fused", "split", "autotune", "steady"):
         got, r = routes_of(
             lambda: dense.evaluate_grid_counts(cases, backend=backend)

@@ -697,6 +697,14 @@ def _bucket_tensors(tensors: Dict, headroom: int = 0) -> Dict:
 _PRE_CACHE_MAX_BYTES = 2 << 30
 
 
+def _tree_nbytes(tree) -> int:
+    """Bytes of a pytree's array leaves (.nbytes is a host-side
+    attribute: no device sync)."""
+    import jax
+
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(tree))
+
+
 def _pre_cache_enabled() -> bool:
     """Repeat evaluations of one case set keep the precompute on device
     (CYCLONUS_PRE_CACHE=0 opts out)."""
@@ -880,7 +888,9 @@ class TpuPolicyEngine:
     only by the issuing thread, and the one place the orphan reads it
     (_slab_ops_for's operand build) snapshots it once and treats a
     concurrent eviction as a contained candidate failure.  The rest of
-    the per-engine caches stay single-threaded by contract.
+    the per-engine caches stay single-threaded by contract, _static_pre
+    (the case-independent half of the dense precompute) among them: no
+    autotune candidate reads it.
     """
 
     # the guarded-by contract (tools/locklint.py LK001 statically; under
@@ -1091,6 +1101,15 @@ class TpuPolicyEngine:
         self._counts_from_pre_jit = None  # derived-from: shapes
         self._counts_from_pre_packed_jit = None  # derived-from: shapes
         self._pre_cache = None  # derived-from: buffer (cases key + pre pytree)
+        # the half of the precompute the port cases do not touch
+        # (tiled._precompute_static), built at the first counts.pallas
+        # call and kept: a request whose case set is not pinned runs
+        # `counts.cases`, the program that starts where its cases enter.
+        # Written by the issuing thread alone, like _pre_cache, and
+        # never read by the autotune's orphan
+        self._static_jit = None  # derived-from: shapes
+        self._counts_cases_jit = None  # derived-from: shapes
+        self._static_pre = None  # derived-from: buffer (static pytree)
         # gathered slab operands, cached next to the pre: building them
         # per dispatch cost more than the slab's depth cut saved
         self._slab_ops_jit = None  # derived-from: shapes
@@ -1142,6 +1161,8 @@ class TpuPolicyEngine:
         self._pre_cache_declined = None
         self._last_counts_key = None
         ti.PRE_CACHE_BYTES.set(0)
+        self._static_pre = None
+        ti.STATIC_PRE_BYTES.set(0)
         with self._slab_lock:
             self._slab_choice = None
             self._slab_ops_cache = None
@@ -2470,10 +2491,13 @@ class TpuPolicyEngine:
         }
 
     def _build_counts_jits(self) -> None:
-        """Build the three counts programs once per engine: the fused
-        cold-path jit (unpack + sort + precompute + pallas in one
-        program), and the split pair (_pre_jit / _counts_from_pre_jit)
-        the repeat path uses to keep the precompute device-resident."""
+        """Build the counts programs once per engine: the fused jit
+        (unpack + sort + precompute + pallas in one program), the
+        resident pair (_static_jit / _counts_cases_jit: the precompute
+        cut where the port cases enter, its first half kept on the
+        device), and the split pair (_pre_jit / _counts_from_pre_jit)
+        the repeat path uses to keep the WHOLE precompute of one case
+        set device-resident."""
         import jax
 
         from .pallas_kernel import (
@@ -2485,13 +2509,13 @@ class TpuPolicyEngine:
             verdict_counts_pallas_slab_from_ops,
         )
         from .sharded import _POD_KEYS
-        from .tiled import _precompute
+        from .tiled import _precompute, _precompute_cases, _precompute_static
 
         unpack = self._unpack
         interpret = _should_interpret()
         pack = self._pack
 
-        def prepared_tensors(buf, perm, q_port, q_name, q_proto):
+        def sorted_tensors(buf, perm):
             import jax.numpy as jnp
 
             tensors = dict(unpack(buf))
@@ -2504,6 +2528,10 @@ class TpuPolicyEngine:
                         d["host_ip_match"], perm, axis=1
                     )
                     tensors[direction] = d
+            return tensors
+
+        def prepared_tensors(buf, perm, q_port, q_name, q_proto):
+            tensors = sorted_tensors(buf, perm)
             tensors["q_port"] = q_port
             tensors["q_name"] = q_name
             tensors["q_proto"] = q_proto
@@ -2583,6 +2611,28 @@ class TpuPolicyEngine:
         self._counts_from_pre_jit = aot_cache.AotProgram(
             "counts.from_pre", jax.jit(counts_from_pre), plan=self._aot_plan()
         )
+        # the resident pair: `counts.static` is the precompute up to
+        # where the port cases enter, run once per engine state and
+        # kept (_static_pre_resident); `counts.cases` is the request's
+        # program from there on, its cases ONE int32 [3, Q] operand
+        # (the class route's form, tiled._with_case_rows)
+        self._static_jit = aot_cache.AotProgram(
+            "counts.static",
+            jax.jit(
+                lambda buf, perm: _precompute_static(
+                    sorted_tensors(buf, perm), pack
+                )
+            ),
+            plan=unpack_plan,
+        )
+
+        def counts_cases(static, cases, n_pods, t0_e=None, t0_i=None):
+            pre = _precompute_cases(static, cases[0], cases[1], cases[2], pack)
+            return counts_from_pre(pre, n_pods, t0_e, t0_i)
+
+        self._counts_cases_jit = aot_cache.AotProgram(
+            "counts.cases", jax.jit(counts_cases), plan=self._aot_plan()
+        )
         self._counts_from_pre_packed_jit = aot_cache.AotProgram(
             "counts.from_pre_packed",
             jax.jit(counts_from_pre_packed, static_argnames=("bs", "bd")),
@@ -2618,13 +2668,18 @@ class TpuPolicyEngine:
     def _counts_pallas_dispatch(
         self, cases: Sequence[PortCase], n: int, fl
     ) -> Dict[str, int]:
-        """The fused pallas counts path over the SINGLE-BUFFER tensor
-        transfer: unpack + pod-axis ns-sort + precompute + pallas counts
-        all trace into one jit, so a cold process pays one host->device
-        transfer (shared with the grid/pairs paths), one trace, one
-        (persistently cached) compile, and one execution.  Records as
-        planspec path "counts.pallas"; the steady-state kernel choice
-        within it records its own counts.steady.* leaf.
+        """The pallas counts path over the SINGLE-BUFFER tensor
+        transfer (shared with the grid/pairs paths).  Which program a
+        call runs is its `mode`: `steady` (the case set's precompute is
+        pinned: the counts kernel alone), `split` (its second call in a
+        row: pin it), else `resident` (the half of the precompute the
+        cases do not touch is built once per engine state and kept, the
+        request runs `counts.cases` from there) or, where that half
+        does not fit the pins' ceiling or CYCLONUS_PRE_CACHE=0, `fused`
+        (unpack + pod-axis ns-sort + precompute + pallas counts in one
+        jit, everything computed again).  Records as planspec path
+        "counts.pallas"; the steady-state kernel choice within it
+        records its own counts.steady.* leaf.
 
         Why the sort: a target applies to pods of exactly one namespace,
         so with pods ns-sorted (on device, via the permutation gather
@@ -2700,8 +2755,8 @@ class TpuPolicyEngine:
         ):
             # second consecutive evaluation of the same case set: switch
             # to the split path and keep the precompute device-resident.
-            # The split programs compile once (persistently cached); the
-            # cold first call keeps the single fused compile.
+            # The split programs compile once (persistently cached), and
+            # only for a caller that repeats a case set.
             ti.PRE_CACHE_MISSES.inc()
             ti.PRE_CACHE_BUDGET_BYTES.set(_PRE_CACHE_MAX_BYTES)
             fl.set(mode="split")
@@ -2709,9 +2764,7 @@ class TpuPolicyEngine:
                 pre = self._pre_jit(
                     buf, self._pod_perm_dev, q_port, q_name, q_proto
                 )
-                nbytes = sum(
-                    x.nbytes for x in jax.tree_util.tree_leaves(pre)
-                )
+                nbytes = _tree_nbytes(pre)
                 if nbytes <= _PRE_CACHE_MAX_BYTES:
                     self._pre_cache = (key, pre)  # evicts any other set
                     with self._slab_lock:
@@ -2732,7 +2785,8 @@ class TpuPolicyEngine:
         else:
             self._last_counts_key = key
             ti.PRE_CACHE_MISSES.inc()
-            fl.set(mode="fused")
+            resident = self._static_pre_admitted(len(cases))
+            fl.set(mode="resident" if resident else "fused")
             if self._pre_cache is not None:
                 # release the cached set's HBM only after two consecutive
                 # other-set evaluations: a single interleaved call (the
@@ -2744,16 +2798,79 @@ class TpuPolicyEngine:
                         self._slab_ops_cache = None  # HBM goes with the pre
                     ti.PRE_CACHE_BYTES.set(0)
             with phase("engine.dispatch"):
-                partials = self._counts_packed_jit(
-                    buf, self._pod_perm_dev, q_port, q_name, q_proto,
-                    np.int32(n), *slab_args,
-                )
+                if resident:
+                    # the first call enqueues both programs back to
+                    # back: nothing here waits for the static
+                    partials = self._counts_cases_jit(
+                        self._static_pre_resident(buf),
+                        jax.device_put(np.stack((q_port, q_name, q_proto))),
+                        np.int32(n), *slab_args,
+                    )
+                else:
+                    partials = self._counts_packed_jit(
+                        buf, self._pod_perm_dev, q_port, q_name, q_proto,
+                        np.int32(n), *slab_args,
+                    )
         # the [Q, n_tiles, 3] readback is the execution barrier: device
         # run time lands here, not in the async dispatch above (nor in
         # engine.autotune, whose candidates run synchronously, timed)
         with phase("engine.execute"):
             partials = np.asarray(partials)
         return sum_partials(partials, len(cases), n)
+
+    def _static_pre_bytes(self) -> int:
+        """The bytes tiled._precompute_static's result holds, from the
+        shapes alone, before any program exists: per direction the
+        [P, N] peer matches, the [T, N] target matches (and their packed
+        words), has_target, and the encoding leaves that ride along; a
+        tiered set also keeps both selector matches."""
+        t = self._tensors
+        n = int(t["pod_ns_id"].shape[0])
+        total = 0
+        for d in ("ingress", "egress"):
+            enc = t[d]
+            n_t = int(enc["target_ns"].shape[0])
+            total += n * (int(enc["peer_target"].shape[0]) + n_t + 1)
+            if self._pack:
+                total += 4 * n * packed_words(n_t)
+            total += enc["peer_target"].nbytes + enc["target_ns"].nbytes
+            total += sum(x.nbytes for x in enc["port_spec"].values())
+        if "tiers" in t:
+            s = int(t["sel_req_kv"].shape[0])
+            total += s * (n + int(t["ns_kv"].shape[0])) + 4 * n
+            total += _tree_nbytes(t["tiers"])
+        return total
+
+    def _static_pre_admitted(self, q: int) -> bool:
+        """Whether a request of `q` cases that no pin serves runs from
+        the resident static (mode `resident`) or the fused program: the
+        static and the precompute a repeat would pin beside it have to
+        fit the pins' ceiling together, and CYCLONUS_PRE_CACHE=0 keeps
+        nothing on the device.  Decided from shapes, so nothing
+        compiles for a static that would not be kept."""
+        if not (
+            _pre_cache_enabled()
+            and self._static_pre_bytes() + self._pre_bytes_estimate(q)
+            <= _PRE_CACHE_MAX_BYTES
+        ):
+            ti.STATIC_PRE.inc(outcome="declined")
+            return False
+        if self._static_pre is not None:
+            ti.STATIC_PRE.inc(outcome="hit")
+        return True
+
+    def _static_pre_resident(self, buf):
+        """The static half of the precompute on the device, built from
+        the packed buffer where this engine state has none yet (span
+        engine.static_pre; the build is enqueued, not waited for)."""
+        if self._static_pre is None:
+            with phase("engine.static_pre") as sp:
+                self._static_pre = self._static_jit(buf, self._pod_perm_dev)
+                nbytes = _tree_nbytes(self._static_pre)
+                sp.set(bytes=nbytes)
+            ti.STATIC_PRE.inc(outcome="built")
+            ti.STATIC_PRE_BYTES.set(nbytes)
+        return self._static_pre
 
     def _steady_state_args(self, cases: Sequence[PortCase]):
         """(key, slab_ok, slab_args, (q_port, q_name, q_proto), choice)
@@ -2826,12 +2943,7 @@ class TpuPolicyEngine:
             w=slab.get("w"),
         )
         # the ACTUAL pinned bytes supersede the plan-time q=2 estimate
-        # (.nbytes is a host-side attribute: no device sync)
-        import jax as _jax
-
-        ti.SLAB_HBM_BYTES.set(
-            sum(x.nbytes for x in _jax.tree_util.tree_leaves(ops))
-        )
+        ti.SLAB_HBM_BYTES.set(_tree_nbytes(ops))
         # check-and-fill under the SAME lock as the autotune's rejection
         # writes: without it an abandoned candidate thread can pass the
         # choice check, lose the CPU to the main thread's rejection +

@@ -31,7 +31,8 @@ to zero, so the `> 0` threshold stays exact.
 Threading note (lock discipline, docs/DESIGN.md): everything here is
 pure functions of explicit operands — no module-level mutable state, no
 locks — by design.  All caching of these programs' operands (the pinned
-precompute, the gathered slab operands) lives in api.TpuPolicyEngine,
+precompute, its resident case-independent half, the gathered slab
+operands) lives in api.TpuPolicyEngine,
 where it is guarded by _slab_lock and checked by tools/locklint.py;
 keep it that way rather than adding module-level caches here (a second
 cache layer would need its own lock AND a consistent order against
@@ -75,24 +76,22 @@ def _apply_host_ip(enc: Dict, pre: Dict) -> Dict:
     return pre
 
 
-def _precompute(
-    tensors: Dict, pack: bool = False
-) -> Dict[str, Dict[str, jnp.ndarray]]:
-    """Per-direction, port-resolved precompute shared by every tile:
+def _precompute_static(tensors: Dict, pack: bool = False) -> Dict:
+    """The half of _precompute that the port cases do not touch: a
+    function of the cluster and the policy set alone, so a holder
+    (api.TpuPolicyEngine's dense counts route) computes it once per
+    engine state and keeps it on the device.  Per direction:
 
-      tallow_bf [T, N, Q] bf16 — target t allows traffic with pod n on the
-                                 PEER side for port case q (m_tp @ peer_allow)
-      tmatch    [T, N] bool    — target t applies to pod n (target side)
-      has_target[N] bool
+      peer_match [P, N] bool — peer row p matches pod n (host-IP rows
+                               applied)
+      tmatch     [T, N] bool, has_target [N] bool, and with pack=True
+      tmatch_pk  [W, N] int32
 
-    With pack=True (static; docs/DESIGN.md "Bit-packed kernel") the
-    target-axis operands ship 32-per-word instead: tallow_pk [W, N, Q]
-    int32 and tmatch_pk [W, N] int32 REPLACE tallow_bf (W =
-    encoding.packed_words(T)) — 16x fewer peer-bundle bytes on the ring
-    and a 32x shallower contraction in every tile body.  The bool
-    tmatch/has_target stay (they are small and the count masks and slab
-    plan read them).
-    """
+    and the leaves _precompute_cases still reads from the encoding:
+    peer_target [P], target_ns [T] (for its length) and port_spec.  A
+    tiered tensor set carries its tier encodings and the two selector
+    matches under "tiers": tier_direction_arrays takes the cases, so
+    it stays whole in the second half."""
     selpod = selector_match(
         tensors["sel_req_kv"],
         tensors["sel_exp_op"],
@@ -110,7 +109,6 @@ def _precompute(
         tensors["ns_key"],
     )
     out = {}
-    q = tensors["q_port"].shape[0]
     for direction in ("ingress", "egress"):
         enc = tensors[direction]
         pre = direction_precompute(
@@ -122,52 +120,112 @@ def _precompute(
             tensors["pod_ip_valid"],
         )
         pre = _apply_host_ip(enc, pre)
-        pport = port_spec_allows(
-            enc["port_spec"],
-            tensors["q_port"],
-            tensors["q_name"],
-            tensors["q_proto"],
-        )
-        n_p, n = pre["peer_match"].shape
+        out[direction] = {
+            "peer_match": pre["peer_match"],
+            "tmatch": pre["tmatch"],
+            "has_target": pre["has_target"],
+            "peer_target": enc["peer_target"],
+            "target_ns": enc["target_ns"],
+            "port_spec": enc["port_spec"],
+        }
+        if pack:
+            out[direction]["tmatch_pk"] = pack_bool_words_jnp(
+                pre["tmatch"]
+            )  # shape: (W, N) int32
+    if "tiers" in tensors:
+        out["tiers"] = {
+            "enc": tensors["tiers"],
+            "selpod": selpod,
+            "selns": selns,
+            "pod_ns_id": tensors["pod_ns_id"],
+        }
+    return out
+
+
+def _precompute_cases(
+    static: Dict,
+    q_port: jnp.ndarray,
+    q_name: jnp.ndarray,
+    q_proto: jnp.ndarray,
+    pack: bool = False,
+) -> Dict[str, Dict[str, jnp.ndarray]]:
+    """The half of _precompute that starts where the port cases enter:
+    from _precompute_static's result and the three [Q] case arrays, the
+    `pre` dict of _precompute, leaf for leaf."""
+    out = {}
+    q = q_port.shape[0]
+    for direction in ("ingress", "egress"):
+        st = static[direction]
+        pport = port_spec_allows(st["port_spec"], q_port, q_name, q_proto)
+        n_p, n = st["peer_match"].shape
         peer_allow = (
-            pre["peer_match"][:, :, None] & pport[:, None, :]
+            st["peer_match"][:, :, None] & pport[:, None, :]
         ).reshape(n_p, n * q)  # shape: (P, NQ)
         tallow = jnp.matmul(
-            m_tp_onehot(enc).astype(jnp.bfloat16),
+            m_tp_onehot(st).astype(jnp.bfloat16),
             peer_allow.astype(jnp.bfloat16),
             preferred_element_type=jnp.bfloat16,
         )
         t = tallow.shape[0]
         out[direction] = {
-            "tmatch": pre["tmatch"],
-            "has_target": pre["has_target"],
+            "tmatch": st["tmatch"],
+            "has_target": st["has_target"],
         }
         if pack:
             tallow_b = (tallow > 0).reshape(t, n, q)
             out[direction]["tallow_pk"] = pack_bool_words_jnp(
                 tallow_b
             )  # shape: (W, N, Q) int32
-            out[direction]["tmatch_pk"] = pack_bool_words_jnp(
-                pre["tmatch"]
-            )  # shape: (W, N) int32
+            out[direction]["tmatch_pk"] = st["tmatch_pk"]
         else:
             out[direction]["tallow_bf"] = (
                 (tallow > 0).astype(jnp.bfloat16).reshape(t, n, q)
             )
-        if "tiers" in tensors:
+        if "tiers" in static:
             # precedence-tier precompute (docs/DESIGN.md "Precedence
             # tiers"): subj/peerq/keys ride next to tallow so every tile
             # body can run the first-match resolution epilogue
+            tiers = static["tiers"]
             out[direction]["tier"] = tier_direction_arrays(
-                tensors["tiers"][direction],
-                selpod,
-                selns,
-                tensors["pod_ns_id"],
-                tensors["q_port"],
-                tensors["q_name"],
-                tensors["q_proto"],
+                tiers["enc"][direction],
+                tiers["selpod"],
+                tiers["selns"],
+                tiers["pod_ns_id"],
+                q_port,
+                q_name,
+                q_proto,
             )
     return out
+
+
+def _precompute(
+    tensors: Dict, pack: bool = False
+) -> Dict[str, Dict[str, jnp.ndarray]]:
+    """Per-direction, port-resolved precompute shared by every tile:
+
+      tallow_bf [T, N, Q] bf16 — target t allows traffic with pod n on the
+                                 PEER side for port case q (m_tp @ peer_allow)
+      tmatch    [T, N] bool    — target t applies to pod n (target side)
+      has_target[N] bool
+
+    With pack=True (static; docs/DESIGN.md "Bit-packed kernel") the
+    target-axis operands ship 32-per-word instead: tallow_pk [W, N, Q]
+    int32 and tmatch_pk [W, N] int32 REPLACE tallow_bf (W =
+    encoding.packed_words(T)) — 16x fewer peer-bundle bytes on the ring
+    and a 32x shallower contraction in every tile body.  The bool
+    tmatch/has_target stay (they are small and the count masks and slab
+    plan read them).
+
+    The composition of its two halves, cut where q_port first appears:
+    every caller but the dense counts route's resident request traces
+    both in one program, as before the cut."""
+    return _precompute_cases(
+        _precompute_static(tensors, pack),
+        tensors["q_port"],
+        tensors["q_name"],
+        tensors["q_proto"],
+        pack,
+    )
 
 
 #: the dst-side bundle keys — the arrays the ring paths rotate with

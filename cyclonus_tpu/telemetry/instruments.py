@@ -65,6 +65,11 @@ PRE_CACHE_BUDGET_BYTES = REGISTRY.gauge(
     "cyclonus_tpu_pre_cache_budget_bytes",
     "Precompute pin ceiling (engine/api.py _PRE_CACHE_MAX_BYTES).",
 )
+STATIC_PRE_BYTES = REGISTRY.gauge(
+    "cyclonus_tpu_static_pre_bytes",
+    "Bytes of the case-independent half of the dense counts precompute "
+    "kept on the device (tiled._precompute_static; 0 = none resident).",
+)
 MESH_PEER_BYTES = REGISTRY.gauge(
     "cyclonus_tpu_mesh_peer_buffer_bytes",
     "Per-device peer-side working-set bytes of the last sharded grid "
@@ -168,6 +173,15 @@ PRE_CACHE_MISSES = REGISTRY.counter(
     "cyclonus_tpu_pre_cache_misses_total",
     "Counts evaluations that could not use a pinned precompute (cold "
     "call, case-set change, or cache declined/evicted).",
+)
+STATIC_PRE = REGISTRY.counter(
+    "cyclonus_tpu_static_pre_total",
+    "Dense counts requests that no pinned precompute serves, by what "
+    "the resident static half did for them: built (computed and kept), "
+    "hit (the request ran only what its port cases decide), declined "
+    "(over the pins' byte ceiling or CYCLONUS_PRE_CACHE=0: the fused "
+    "program ran).",
+    labelnames=("outcome",),
 )
 SLAB_OPS_CACHE_HITS = REGISTRY.counter(
     "cyclonus_tpu_slab_ops_cache_hits_total",
@@ -471,7 +485,7 @@ def eval_flight(path: str, n_pods: int, q: int, **attrs: Any) -> Iterator[Flight
             "engine.eval", route=path
         ) as sp:
             yield flight
-            # which program of the route ran (fused / split / steady)
+            # which program of the route ran (resident / fused / split / steady)
             # shows on the span as it does in the flight entry
             if "mode" in flight.data:
                 sp.set(mode=flight.data["mode"])

@@ -55,7 +55,10 @@ _FLAGS = [
          "Rule-compaction opt-out: '0' disables, '1' forces past the "
          "host-work budget, '' (default) auto.", choices=("", "0", "1")),
     Flag("CYCLONUS_PRE_CACHE", "bool", True, "engine",
-         "Pre-classification cache of selector->pod matches."),
+         "Device-resident precompute of the dense counts route: the "
+         "pin of a repeated case set and the case-independent half "
+         "kept for every other request; off keeps nothing on the "
+         "device (the fused program runs)."),
     Flag("CYCLONUS_CLASS_COMPRESS", "enum", "auto", "engine",
          "Pod-class compression: 'auto' (size floor), '1' (force), "
          "'0' (off).", choices=("auto", "0", "1")),

@@ -267,7 +267,7 @@ def scenario_counts_routes(ctx: Ctx) -> Dict:
 
 def scenario_counts_steady_routes(ctx: Ctx) -> Dict:
     """The pallas counts path and its steady-state sub-dispatch: the
-    cold fused call and the split call record only counts.pallas; the
+    cold call (resident) and the split call record only counts.pallas; the
     third (pinned-precompute) call adds the counts.steady.* leaf —
     default, tuned-packed (via a planted kernel choice), and the slab
     kernel on a CYCLONUS_PACK=0 engine (the pack x slab matrix cell
@@ -279,7 +279,7 @@ def scenario_counts_steady_routes(ctx: Ctx) -> Dict:
         eng = ctx.engine(env=(("CYCLONUS_AUTOTUNE", "0"),))
         ctx.drain()
         cp = planspec.predict("counts", {"backend": "pallas", "pack": True})
-        for _ in range(2):  # cold fused, then split
+        for _ in range(2):  # cold (resident), then split
             eng.evaluate_grid_counts(cases, backend="pallas")
         _expect("counts.pallas.warmup", ctx.drain(), [cp, cp])
         eng.evaluate_grid_counts(cases, backend="pallas")  # steady

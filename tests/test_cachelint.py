@@ -676,10 +676,21 @@ class TestCleanRun:
         assert findings == [], "\n".join(f.render() for f in findings)
         assert stats["annotations"] >= 25, stats
 
-    def test_class_weights_declared_and_cleared(self):
-        """The engine's device-resident class weights (`_class_w_dev`,
-        PR 31) derive from the class state, which the serve layer
-        mutates in place: the field is declared so, and
+    @pytest.mark.parametrize(
+        "attr,twin,derived_from",
+        [
+            ("_class_w_dev", "_class_of_dev", ["classes"]),
+            ("_static_pre", "_pre_cache", ["buffer"]),
+        ],
+    )
+    def test_resident_operands_declared_and_cleared(
+        self, attr, twin, derived_from
+    ):
+        """What the engine keeps on the device from call to call derives
+        from state the serve layer mutates in place: the class weights
+        (`_class_w_dev`, PR 31) from the class state, the static half of
+        the dense precompute (`_static_pre`, PR 33) from the packed
+        buffer.  Each is declared as its older twin is, and
         invalidate_after_patch resets it."""
         import ast
 
@@ -690,9 +701,9 @@ class TestCleanRun:
             model, model.classes["TpuPolicyEngine"]
         )
         assert invalidate is not None
-        tokens, _line = decls["_class_w_dev"]
-        assert tokens == decls["_class_of_dev"][0] == ["classes"]
-        assert "_class_w_dev" in reset
+        tokens, _line = decls[attr]
+        assert tokens == decls[twin][0] == derived_from
+        assert attr in reset and twin in reset
 
     def test_cli_exit_status(self):
         proc = subprocess.run(

@@ -11,7 +11,8 @@ its labels, and what the engine does with it.
     counts it, `engine.classify` says kept=False and `engine.cidrspace`
     what was dropped; the other outcomes count too;
   * the dense counts evaluation's `engine.eval` span names the program
-    that ran (`mode`), as its flight entry does;
+    that ran (`mode`: resident / fused / split / steady), as its flight
+    entry does;
   * the benchmark's plain reference (`benchmarks/reference.py`, read-only
     here) reads what this generator emits as the scalar oracle does.
 """
@@ -169,8 +170,10 @@ class TestTheRefusal:
 
 
 def test_the_dense_counts_eval_span_names_its_program(small, low_floor):
-    """fused, then split on the repeat, then steady: the progression the
-    flight recorder has always shown, on the `engine.eval` span too."""
+    """resident (since PR 33 the first call runs from the static half of
+    the precompute it builds; `fused` before), then split on the repeat,
+    then steady: the progression the flight recorder has always shown,
+    on the `engine.eval` span too."""
     policy, pods, namespaces, _ = small
     engine = TpuPolicyEngine(policy, pods, namespaces)
     seen = []
@@ -181,7 +184,7 @@ def test_the_dense_counts_eval_span_names_its_program(small, low_floor):
         assert attrs["route"] == "counts.pallas"
         assert attrs["mode"] == recorder.entries()[-1]["mode"]
         seen.append(attrs["mode"])
-    assert seen == ["fused", "split", "steady"]
+    assert seen == ["resident", "split", "steady"]
 
 
 @pytest.mark.parametrize("broken", ["", "drop_except", "drop_named_ports"])

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from cyclonus_tpu.engine import PortCase, TpuPolicyEngine
+from cyclonus_tpu.telemetry import recorder
 
 from test_engine_tiled import CASES, fuzz_problem, full_grids
 
@@ -53,8 +54,12 @@ class TestPallasCounts:
         B = [PortCase(81, "", "UDP")]
         C = [PortCase(9999, "", "TCP")]
         want_a = engine.evaluate_grid_counts(A, backend="xla")
-        # 1st A: fused path, no cache; 2nd A: split path populates it
+        # 1st A: the resident path (the static half built and kept, the
+        # request's program from where its cases enter; `fused` before
+        # PR 33), no pin; 2nd A: split path populates it
         assert engine.evaluate_grid_counts(A, backend="pallas") == want_a
+        assert recorder.entries()[-1]["mode"] == "resident"
+        assert engine._static_pre is not None
         assert engine._pre_cache is None
         assert engine.evaluate_grid_counts(A, backend="pallas") == want_a
         assert engine._pre_cache is not None
@@ -98,14 +103,16 @@ class TestPallasCounts:
         monkeypatch.setattr(api, "_PRE_CACHE_MAX_BYTES", 0)
         for _ in range(3):
             assert engine.evaluate_grid_counts(CASES, backend="pallas") == want
-        assert engine._pre_cache is None
+            assert recorder.entries()[-1]["mode"] == "fused"
+        assert engine._pre_cache is None and engine._static_pre is None
 
         monkeypatch.undo()
         monkeypatch.setenv("CYCLONUS_PRE_CACHE", "0")
         engine2 = TpuPolicyEngine(policy, pods, namespaces)
         for _ in range(3):
             assert engine2.evaluate_grid_counts(CASES, backend="pallas") == want
-        assert engine2._pre_cache is None
+            assert recorder.entries()[-1]["mode"] == "fused"
+        assert engine2._pre_cache is None and engine2._static_pre is None
 
     def test_bf16_operand_mode(self, monkeypatch):
         """The CYCLONUS_PALLAS_DTYPE=bf16 fallback (f32 accumulators)

@@ -400,6 +400,7 @@ class TestMetricsEndpoint:
                 "cyclonus_tpu_slab_hbm_budget_bytes",
                 "cyclonus_tpu_pre_cache_hits_total",
                 "cyclonus_tpu_pre_cache_misses_total",
+                "cyclonus_tpu_static_pre_bytes",
                 "cyclonus_tpu_slab_ops_cache_hits_total",
                 "cyclonus_tpu_slab_ops_cache_misses_total",
             ):
@@ -625,15 +626,30 @@ class TestEngineInstrumentation:
         cases = [PortCase(80, "serve-80-tcp", "TCP")]
         for _ in range(3):
             counts = engine.evaluate_grid_counts(cases, backend="pallas")
-        # eval 1 = fused (miss), eval 2 = split build (miss), eval 3 =
-        # pinned steady state (hit)
+        # eval 1 = resident (miss: no pin serves it; it builds the
+        # static half of the precompute and runs from there), eval 2 =
+        # split build (miss), eval 3 = pinned steady state (hit)
         assert ti.PRE_CACHE_MISSES.value() == 2
         assert ti.PRE_CACHE_HITS.value() == 1
         assert ti.PRE_CACHE_BYTES.value() > 0
         assert ti.EVAL_CELLS_PER_SEC.value() > 0
         ents = telemetry.recorder.entries()
         modes = [e.get("mode") for e in ents if e["path"] == "counts.pallas"]
-        assert modes == ["fused", "split", "steady"]
+        assert modes == ["resident", "split", "steady"]
+        # the static's own instruments: built once (a labelled counter
+        # has no sample before its first inc), its bytes on the gauge,
+        # its build a span inside the first evaluation's dispatch
+        assert ti.STATIC_PRE.value(outcome="built") == 1
+        assert ti.STATIC_PRE.value(outcome="hit") == 0
+        assert ti.STATIC_PRE.value(outcome="declined") == 0
+        assert ti.STATIC_PRE_BYTES.value() > 0
+        built = telemetry.SPANS.tree()[
+            "engine.eval/engine.dispatch/engine.static_pre"
+        ]
+        assert built["count"] == 1
+        assert built["attrs"]["bytes"] == ti.STATIC_PRE_BYTES.value()
+        body = telemetry.METRICS.render_prometheus()
+        assert 'cyclonus_tpu_static_pre_total{outcome="built"} 1' in body
         assert all(e["outcome"] == "ok" for e in ents)
         assert ents[-1]["cells"] == counts["cells"]
         # the dispatch/execute split: one span each an evaluation, both
