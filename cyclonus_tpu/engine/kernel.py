@@ -619,9 +619,21 @@ def cell_words(lanes) -> jnp.ndarray:
     (GridVerdict views them, no copy); the runtime un-tiles every
     fetched buffer on the host, and a 32-bit element four times faster
     than a one-byte one (PERF.md section 6, PR 27)."""
+    return pad_words(lane_words(lanes))
+
+
+def lane_words(lanes) -> jnp.ndarray:
+    """cell_words before its pad: uint32 [..., W], exactly as wide as
+    the lanes.  A mesh program packs a device's piece of a row with
+    this, lays the pieces side by side and pads the whole row once."""
     words = lanes[0].astype(jnp.uint32)
     for k in range(1, WORD_CELLS):
         words = words | (lanes[k].astype(jnp.uint32) << (8 * k))
+    return words
+
+
+def pad_words(words: jnp.ndarray) -> jnp.ndarray:
+    """Zero words up to a multiple of WORD_TILE's 128 on the last axis."""
     pad = [(0, 0)] * (words.ndim - 1) + [(0, -words.shape[-1] % WORD_TILE[1])]
     return jnp.pad(words, pad)
 

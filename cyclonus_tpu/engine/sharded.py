@@ -58,7 +58,9 @@ from .kernel import (
     cell_words,
     direction_precompute,
     gather_class_words,
+    lane_words,
     m_tp_onehot,
+    pad_words,
     port_spec_allows,
     resolve_tier_lattice,
     selector_match,
@@ -418,18 +420,32 @@ def _block_words(block: jnp.ndarray) -> jnp.ndarray:
 def _dense_words(ingress_rows, egress, combined):
     """The dense routes' epilogue, inside the sharded program: each
     device's [Sb, N, Q] blocks as words of its own rows.  Ingress is
-    indexed [dst, src]: its blocks change hands in ONE explicit
-    all_to_all (device j gets the columns of its destinations from
-    every source block), N * N * Q / n_dev bytes a device, and are
-    transposed block by block - never a swapaxes of a whole table."""
-    ingress = jnp.swapaxes(
-        jax.lax.all_to_all(
-            ingress_rows, "x", split_axis=1, concat_axis=0, tiled=True
-        ),
-        0,
-        1,
-    )  # [Db, N_src, Q]
-    return _block_words(ingress), _block_words(egress), _block_words(combined)
+    indexed [dst, src] and a word packs along src, so a device's source
+    block is whole WORDS of every destination's row: it packs them where
+    they are ([Q, N, Sb / 4] uint32, its columns of the table) and the
+    pieces change hands in ONE explicit all_to_all over a LEADING axis
+    of device-sized chunks (device j gets the words of its destinations
+    from every source block and lays them side by side), N * N * Q /
+    n_dev bytes a device as before.  The exchange used to carry the
+    booleans, split along their second axis: at 40,960 pods on four
+    v5e chips that one operation took the TPU compiler nine minutes and
+    327 MB of code (ISSUE 34; my compile for a described v5e:2x2)."""
+    shard, n_total, q = ingress_rows.shape
+    n_dev = n_total // shard
+    a = jnp.transpose(ingress_rows, (2, 1, 0))  # [Q, N_dst, Sb]
+    cols = lane_words([a[..., k::WORD_CELLS] for k in range(WORD_CELLS)])
+    width = cols.shape[-1]
+    got = jax.lax.all_to_all(
+        jnp.moveaxis(cols.reshape(q, n_dev, shard, width), 1, 0),
+        "x",
+        split_axis=0,
+        concat_axis=0,
+        tiled=True,
+    )  # [n_dev (source block), Q, Db, Sb / 4]
+    ingress = pad_words(
+        jnp.moveaxis(got, 0, 2).reshape(q, shard, n_dev * width)
+    )
+    return ingress, _block_words(egress), _block_words(combined)
 
 
 def _class_words(ingress_rows, egress, combined, rows, cols):
@@ -467,6 +483,12 @@ _SHARDED_PROGRAMS_MAX = 64
 
 #: the tables' sharding as they leave the program: [q, row, word], rows over x
 _WORDS_SPEC = P(None, "x", None)
+
+#: how the dense routes' epilogue hands ingress over (_dense_words): part
+#: of the persistent key, since the arg shapes and the result's form
+#: cannot see it and an executable that exchanges booleans computes the
+#: same tables
+DENSE_EXCHANGE = "xchg=words"
 
 
 def _sharded_program(
@@ -533,7 +555,8 @@ def _sharded_program(
             plan=(
                 f"shard={shard};pack={pack};classes={classes};"
                 f"mesh={','.join(mesh.axis_names)}x{n_dev};{spec_digest};"
-                f"{WORD_FORMAT}"
+                + ("" if classes else f"{DENSE_EXCHANGE};")
+                + WORD_FORMAT
             ),
         )
         if cachekeys.ACTIVE:
@@ -599,21 +622,49 @@ def evaluate_grid_sharded(
         rows = np.full(-(-n_pods // step) * step, -1, np.int32)
         rows[:n_pods] = class_of
         args = (tensors, rows, class_of)
-    ti.MESH_PEER_BYTES.set(
-        peer_buffer_bytes(tensors, n_dev, schedule, pack=pack),
-        schedule=schedule,
-    )
+    peer_bytes = peer_buffer_bytes(tensors, n_dev, schedule, pack=pack)
+    ti.MESH_PEER_BYTES.set(peer_bytes, schedule=schedule)
+    route = "classes" if classes else schedule
     with ti.eval_flight(
         "grid.sharded", n_pods, int(tensors["q_port"].shape[0]),
-        devices=n_dev, schedule=schedule, dispatch_only=True,
+        devices=n_dev, schedule=schedule, classes=classes,
+        dispatch_only=True,
     ) as fl:
         with mesh_device_context(mesh):
             fn.resolve(*args)
             with phase(
                 "engine.dispatch_sharded",
-                route="classes" if classes else schedule,
+                route=route,
                 devices=n_dev,
                 schedule=schedule,
-            ):
+                shard=shard,
+                peer_bytes=peer_bytes,
+            ) as sp:
                 out = fn(*args)
+                # counted once the program is enqueued: the chips are
+                # running, so walking the operands costs the request nothing
+                host_operands, host_bytes = _host_traffic(
+                    args, (in_specs, P("x"), P()), n_dev
+                )
+                sp.set(host_operands=host_operands, host_bytes=host_bytes)
+            ti.MESH_DISPATCH_BYTES.inc(host_bytes, route=route)
     return out, fl.eval_id
+
+
+def _host_traffic(args, specs, n_dev: int) -> Tuple[int, int]:
+    """(host arrays among a sharded program's operands, the bytes they
+    cost the call): an array sharded over 'x' goes out once, a piece to
+    each chip; a replicated one goes whole to every chip.  `specs` is
+    the operands' spec tree (or a longer one: the dense routes have no
+    row map)."""
+    host = [
+        (a, spec)
+        for a, spec in zip(
+            jax.tree_util.tree_leaves(args),
+            jax.tree_util.tree_leaves(specs[: len(args)]),
+        )
+        if isinstance(a, np.ndarray)
+    ]
+    return len(host), sum(
+        a.nbytes * (1 if "x" in spec else n_dev) for a, spec in host
+    )

@@ -79,6 +79,15 @@ MESH_PEER_BYTES = REGISTRY.gauge(
     "devices (engine/sharded.py peer_buffer_bytes).",
     labelnames=("schedule",),
 )
+MESH_DISPATCH_BYTES = REGISTRY.counter(
+    "cyclonus_tpu_mesh_dispatch_bytes_total",
+    "Host bytes the sharded grid program's launches have sent to the "
+    "mesh, by route (classes / ring / allgather): every host array "
+    "among a call's operands, an array sharded over the mesh once and a "
+    "replicated one times the chips (the `host_bytes` of the "
+    "`engine.dispatch_sharded` span, summed).",
+    labelnames=("route",),
+)
 MESH_RING_STEP_SECONDS = REGISTRY.gauge(
     "cyclonus_tpu_mesh_ring_step_seconds",
     "Per-hop seconds of the last pipelined ring-counts eval "
@@ -468,7 +477,8 @@ class Flight:
 def eval_flight(path: str, n_pods: int, q: int, **attrs: Any) -> Iterator[Flight]:
     """Wrap one engine evaluation: its number, its `engine.eval` span
     (attr `route` = the PathSpec name; `mode` where the route's dispatch
-    says which of its programs ran), histogram + dispatch counter +
+    says which of its programs ran; `schedule` and `classes` on the mesh
+    grid route), histogram + dispatch counter +
     flight record, outcome 'ok' or the exception repr."""
     if not state.ENABLED:
         yield _NULL_FLIGHT  # type: ignore[misc]
@@ -489,6 +499,13 @@ def eval_flight(path: str, n_pods: int, q: int, **attrs: Any) -> Iterator[Flight
             # shows on the span as it does in the flight entry
             if "mode" in flight.data:
                 sp.set(mode=flight.data["mode"])
+            # and which leaf of the mesh grid route: its exchange, and
+            # whether it ran over the class axis
+            if "schedule" in flight.data:
+                sp.set(
+                    schedule=flight.data["schedule"],
+                    classes=bool(flight.data.get("classes")),
+                )
     except BaseException as e:
         outcome = f"{type(e).__name__}: {e}"[:300]
         raise

@@ -389,7 +389,7 @@ class TestMeshWordPrograms:
     program holds, and what its persistent key names."""
 
     @staticmethod
-    def _lowered(monkeypatch, engine, mesh, schedule=None):
+    def _lowered(monkeypatch, engine, mesh, schedule=None, cases=CASES):
         """The StableHLO text of the sharded program one evaluation
         runs, read where evaluate_grid_sharded resolves it."""
         texts = []
@@ -408,7 +408,7 @@ class TestMeshWordPrograms:
             return Spy()
 
         monkeypatch.setattr(sharded_mod, "_sharded_program", spy)
-        grid = engine.evaluate_grid_sharded(CASES, mesh=mesh, schedule=schedule)
+        grid = engine.evaluate_grid_sharded(cases, mesh=mesh, schedule=schedule)
         (text,) = texts
         return text, grid
 
@@ -453,6 +453,9 @@ class TestMeshWordPrograms:
             monkeypatch, engine, cpu_mesh(4), schedule=schedule
         )
         assert len(re.findall(r"stablehlo\.all_to_all", text)) == 1
+        # and as WORDS (PR 34; tests/test_cidr_mesh.py holds the shape)
+        (exchange,) = re.findall(r"stablehlo\.all_to_all.*", text)
+        assert "ui32>" in exchange and "xi1>" not in exchange
         n_pad = grid.ingress_dev.shape[1]
         assert n_pad % (4 * 8) == 0
         # no boolean table over all rows, in either order of its axes

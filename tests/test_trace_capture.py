@@ -386,7 +386,9 @@ class TestMeshSpans:
         assert out.eval_id == root["eval_id"] is not None
         (dispatch,) = by_name(found, "engine.dispatch_sharded")
         assert dispatch["path"] == "engine.eval/engine.dispatch_sharded"
-        assert dispatch["attrs"] == {
+        assert {
+            k: dispatch["attrs"][k] for k in ("route", "devices", "schedule")
+        } == {
             "route": route, "devices": n_dev,
             "schedule": schedule or "ring",
         }
@@ -417,6 +419,76 @@ class TestMeshSpans:
         # one buffer of the final shape a table, viewed and not copied
         for table in tables:
             assert table.dtype == np.bool_ and table.base is not None
+
+    @pytest.mark.parametrize(
+        "route,class_compress,schedule",
+        [("classes", "1", None), ("ring", "0", "ring"),
+         ("allgather", "0", "allgather")],
+    )
+    @pytest.mark.parametrize("n_dev", [2, 4])
+    def test_the_dispatch_says_what_it_sent_and_the_eval_which_leaf_ran(
+        self, cluster, tmp_path, route, class_compress, schedule, n_dev
+    ):
+        """`engine.dispatch_sharded` counts the host arrays among the
+        program's operands and their bytes times the chips each goes to
+        (an array sharded over the pods once, a replicated one to every
+        chip), and says the rows a chip holds and the peer working set;
+        the counter sums the bytes by route; `engine.eval` says the
+        schedule and whether the class axis was evaluated."""
+        from jax.sharding import Mesh
+
+        from cyclonus_tpu.engine import PortCase, TpuPolicyEngine, sharded
+        from cyclonus_tpu.matcher import build_network_policies
+        from cyclonus_tpu.telemetry import instruments as ti
+
+        pods, namespaces, policies = cluster
+        eng = TpuPolicyEngine(
+            build_network_policies(True, policies), pods, namespaces,
+            class_compress=class_compress,
+        )
+        cases = [PortCase(80, "serve-80-tcp", "TCP"), PortCase(81, "", "UDP")]
+        mesh = Mesh(np.array(jax.devices("cpu")[:n_dev]), ("x",))
+        eng.evaluate_grid_sharded(cases, mesh=mesh, schedule=schedule)  # warm
+        before = ti.MESH_DISPATCH_BYTES.value(route=route)
+        with capture(tmp_path):
+            eng.evaluate_grid_sharded(cases, mesh=mesh, schedule=schedule)
+        found = events.capture_spans()
+        # what the call hands the program, worked out here from the
+        # engine's own tensors: per-pod arrays are cut over the chips
+        if route == "classes":
+            n_eval = eng.pod_classes().n_classes
+            tensors, padded = sharded._pad_pod_arrays(
+                eng._ctensors_with_cases(cases), n_eval, n_dev
+            )
+            step = n_dev * 8
+            rows = -(-len(pods) // step) * step  # the pod -> class map, cut
+            extra = [(4 * rows, 1), (4 * len(pods), n_dev)]  # and whole
+        else:
+            tensors, padded = sharded._pad_pod_arrays(
+                eng._tensors_with_cases(cases), len(pods), n_dev * 8
+            )
+            extra = []
+        sent = [
+            (np.asarray(leaf).nbytes, 1 if path[0].key in sharded._POD_KEYS else n_dev)
+            for path, leaf in jax.tree_util.tree_flatten_with_path(tensors)[0]
+        ] + extra
+        (dispatch,) = by_name(found, "engine.dispatch_sharded")
+        attrs = dispatch["attrs"]
+        assert attrs["host_operands"] == len(sent)
+        assert attrs["host_bytes"] == sum(b * chips for b, chips in sent)
+        assert attrs["shard"] == padded // n_dev
+        assert attrs["peer_bytes"] == ti.MESH_PEER_BYTES.value(
+            schedule=schedule or "ring"
+        ) > 0
+        assert (
+            ti.MESH_DISPATCH_BYTES.value(route=route) - before
+            == attrs["host_bytes"]
+        )
+        (root,) = by_name(found, "engine.eval")
+        assert root["attrs"] == {
+            "route": "grid.sharded", "schedule": schedule or "ring",
+            "classes": route == "classes",
+        }
 
     def test_outside_a_capture_a_shard_copy_is_no_span(self, cluster):
         """`grid.shard_copy` is a detail span: it exists only while
