@@ -1184,14 +1184,22 @@ class TpuPolicyEngine:
 
     def _aot_plan(self, extra: str = "") -> str:
         """The dtype-plan half of the persistent AOT executable key
-        (engine/aot_cache.py): packed32 vs the dense operand dtype plus
-        the tier flag.  Programs whose trace bakes per-engine constants
-        (the unpack closures' leaf layout) append a metas digest via
-        `extra` — two engines with equal buffer lengths but different
-        leaf layouts must never share an executable."""
+        (engine/aot_cache.py): packed32, with the form of the packed
+        contraction (kernel.PACKED_CONTRACTION: every packed program can
+        trace kernel.packed_any, whose code the key cannot see), vs the
+        dense operand dtype, plus the tier flag.  Programs whose trace
+        bakes per-engine constants (the unpack closures' leaf layout)
+        append a metas digest via `extra` — two engines with equal
+        buffer lengths but different leaf layouts must never share an
+        executable."""
+        from .kernel import PACKED_CONTRACTION
         from .pallas_kernel import _resolve_operand_dtype
 
-        dtype = "packed32" if self._pack else _resolve_operand_dtype(None)
+        dtype = (
+            f"packed32;{PACKED_CONTRACTION}"
+            if self._pack
+            else _resolve_operand_dtype(None)
+        )
         plan = f"{dtype};tiered={self.tiers is not None}"
         return plan + (";" + extra if extra else "")
 

@@ -258,22 +258,29 @@ def pack_bool_words_jnp(a: jnp.ndarray, axis: int = 0) -> jnp.ndarray:
     return jnp.moveaxis(words, 0, axis)
 
 
+#: the form of packed_any's contraction, in the AOT plan of every program
+#: that can trace it (TpuPolicyEngine._aot_plan with pack on, sharded.grid
+#: with pack=True): aot_cache.make_key sees nothing of a program's code,
+#: and an executable built from another form (the lax.scan of PR 34 and
+#: before) computes the same tables six times slower under the same key
+PACKED_CONTRACTION = "any=reduce"
+
+
 def packed_any(a_pk: jnp.ndarray, b_pk: jnp.ndarray) -> jnp.ndarray:
     """[A, B] bool: OR_w (a_pk[w, a] AND b_pk[w, b]) != 0 — the packed
     twin of `_bool_matmul(a.T, b) over a [T, A] x [T, B] contraction`,
     with the target axis pre-packed 32-per-word (a_pk [W, A], b_pk
-    [W, B] int32).  A lax.scan walks the W words sequentially with one
-    [A, B] int32 accumulator, so no [W, A, B] intermediate ever
-    materializes; W is ceil(T/32), which is what cuts the contraction
-    depth 32x vs the elementwise bool form."""
-
-    def body(acc, wab):
-        wa, wb = wab  # [A], [B]
-        return acc | (wa[:, None] & wb[None, :]), None
-
-    init = jnp.zeros((a_pk.shape[1], b_pk.shape[1]), dtype=jnp.int32)
-    acc, _ = jax.lax.scan(body, init, (a_pk, b_pk))
-    return acc != 0
+    [W, B] int32); W is ceil(T/32), which is what cuts the contraction
+    depth 32x vs the elementwise bool form.  ONE reduction over the word
+    axis of the broadcast AND: the compiler fuses the broadcast into the
+    reduce, so no [W, A, B] intermediate ever materializes and an output
+    tile's running OR stays in registers (a lax.scan's [A, B] int32
+    carry went through HBM once a word: 129.6 ms against 9.5 at
+    [100, 10240] x [100, 10240] on a v5e chip, PERF.md section 6).  Bit
+    31 rides the int32 sign and an AND never carries, so `!= 0` per word
+    is exact.  That nothing materializes rests on the compiler's fusion:
+    tests/test_tpu_compile.py holds the TPU compiler to it."""
+    return jnp.any((a_pk[:, :, None] & b_pk[:, None, :]) != 0, axis=0)
 
 
 @contracts.args(

@@ -51,6 +51,7 @@ from ..utils import cachekeys
 from ..utils.tracing import phase
 
 from .kernel import (
+    PACKED_CONTRACTION,
     WORD_CELLS,
     WORD_FORMAT,
     WORD_TILE,
@@ -541,8 +542,9 @@ def _sharded_program(
         # programs too (engine/aot_cache.py): a restarted process
         # adopts the ring/allgather executables for its mesh without a
         # retrace.  The partition-spec structure, the shard/pack
-        # statics, the epilogue and the result's form are program
-        # identity the arg shapes can't see, so they ride in the plan.
+        # statics, the form of the packed contraction, the epilogue
+        # and the result's form are program identity the arg shapes
+        # can't see, so they ride in the plan.
         from . import aot_cache
 
         spec_digest = aot_cache.digest(
@@ -553,7 +555,9 @@ def _sharded_program(
             fn,
             schedule=schedule,
             plan=(
-                f"shard={shard};pack={pack};classes={classes};"
+                f"shard={shard};pack={pack};"
+                + (f"{PACKED_CONTRACTION};" if pack else "")
+                + f"classes={classes};"
                 f"mesh={','.join(mesh.axis_names)}x{n_dev};{spec_digest};"
                 + ("" if classes else f"{DENSE_EXCHANGE};")
                 + WORD_FORMAT

@@ -583,6 +583,8 @@ reg = cachekeys.registered()
 print(json.dumps({{
     "names": sorted(reg),
     "components": {{k: list(v.components) for k, v in reg.items()}},
+    "fingerprints": {{k: v.fingerprint for k, v in reg.items()}},
+    "packed": engine._pack,
     "count": cachekeys.registered_count(),
 }}))
 """
@@ -614,6 +616,20 @@ print(json.dumps({{
         "aot:pairs" in names, "registry", "aot:pairs",
         f"serve pair program not registered: {names}",
     )
+    if out["packed"]:
+        # every program that can trace kernel.packed_any names the
+        # contraction's form in its persisted key (PR 35)
+        from cyclonus_tpu.engine.kernel import PACKED_CONTRACTION
+
+        for family in ("aot:sharded.grid", "aot:pairs") + tuple(
+            n for n in names if n in ("aot:grid", "aot:grid.classes")
+        ):
+            _check(
+                PACKED_CONTRACTION in (out["fingerprints"].get(family) or ""),
+                "registry", family,
+                f"key does not name the packed contraction: "
+                f"{out['fingerprints'].get(family)}",
+            )
     _check(out["count"] == len(names), "registry", "count", "census mismatch")
     for name, comps in out["components"].items():
         _check(bool(comps), "registry", name, "registered with no components")

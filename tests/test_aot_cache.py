@@ -273,6 +273,29 @@ class TestCacheModule:
         assert c["adopted"] == c["hits"]  # `hit` still means: from disk
 
 
+def _small_problem():
+    """(policy, pods, namespaces, cases): the driver's cluster."""
+    import random
+
+    from cyclonus_tpu.synthetic import build_synthetic
+    from cyclonus_tpu.engine import PortCase
+    from cyclonus_tpu.matcher import build_network_policies
+
+    pods, namespaces, policies = build_synthetic(40, 10, random.Random(3))
+    policy = build_network_policies(True, policies)
+    return policy, pods, namespaces, [PortCase(80, "serve-80-tcp", "TCP")]
+
+
+def _entries(cache_dir, name):
+    """key -> file of program `name`'s entries in the cache directory"""
+    found = {}
+    for f in cache_dir.glob("*.aotx"):
+        key = pickle.loads(f.read_bytes())["key"]
+        if json.loads(key)["name"] == name:
+            found[key] = f
+    return found
+
+
 class TestGridResultFormat:
     """make_key sees nothing of a program's code or result, so the grid
     programs name their result format in the plan: the executable of a
@@ -285,20 +308,14 @@ class TestGridResultFormat:
     def test_boolean_era_executable_is_not_adopted(
         self, tmp_path, monkeypatch, name, class_compress
     ):
-        import random
-
         import jax
         import numpy as np
 
-        from cyclonus_tpu.synthetic import build_synthetic
-        from cyclonus_tpu.engine import PortCase, TpuPolicyEngine
+        from cyclonus_tpu.engine import TpuPolicyEngine
         from cyclonus_tpu.engine.kernel import evaluate_grid_kernel
-        from cyclonus_tpu.matcher import build_network_policies
 
         monkeypatch.setenv("CYCLONUS_AOT_CACHE", str(tmp_path))
-        pods, namespaces, policies = build_synthetic(40, 10, random.Random(3))
-        policy = build_network_policies(True, policies)
-        cases = [PortCase(80, "serve-80-tcp", "TCP")]
+        policy, pods, namespaces, cases = _small_problem()
 
         def engine():
             return TpuPolicyEngine(
@@ -306,13 +323,7 @@ class TestGridResultFormat:
             )
 
         def entries():
-            """key -> file of this program's entries in the cache"""
-            found = {}
-            for f in tmp_path.glob("*.aotx"):
-                key = pickle.loads(f.read_bytes())["key"]
-                if json.loads(key)["name"] == name:
-                    found[key] = f
-            return found
+            return _entries(tmp_path, name)
 
         first = engine()
         want = first.evaluate_grid(cases).combined
@@ -347,6 +358,87 @@ class TestGridResultFormat:
         assert grid.combined_dev.dtype == np.uint32
         assert np.array_equal(grid.combined, want)
         assert set(entries()) == {key, old_key}
+
+
+class TestPackedContractionInKey:
+    """make_key sees nothing of a program's code, so every program that
+    can trace kernel.packed_any names the contraction's form in its plan
+    (kernel.PACKED_CONTRACTION): the executable of a build whose
+    packed_any was a lax.scan (PR 34 and before) lies under another key
+    and is never adopted by one whose packed_any is one reduction."""
+
+    @pytest.mark.parametrize("pack", ["1", "0"])
+    def test_engine_plan_names_the_form_only_when_packed(
+        self, monkeypatch, pack
+    ):
+        from cyclonus_tpu.engine import TpuPolicyEngine, kernel
+
+        monkeypatch.setenv("CYCLONUS_PACK", pack)
+        policy, pods, namespaces, _cases = _small_problem()
+        engine = TpuPolicyEngine(policy, pods, namespaces)
+        plan = engine._aot_plan()
+        if pack == "0":
+            assert "any=" not in plan and "packed32" not in plan
+            return
+        # the dtype word itself is the autotuner's and pack_stats()'s
+        assert plan.startswith(f"packed32;{kernel.PACKED_CONTRACTION};")
+        assert engine.pack_stats()["dtype"] == "packed32"
+        form = kernel.PACKED_CONTRACTION
+        monkeypatch.setattr(kernel, "PACKED_CONTRACTION", "any=scan")
+        other = engine._aot_plan()
+        assert other == plan.replace(form, "any=scan") != plan
+        keys = {aot_cache.make_key("grid", "sig", plan=p) for p in (plan, other)}
+        assert len(keys) == 2
+
+    @pytest.mark.parametrize(
+        "name,class_compress", [("grid", "0"), ("grid.classes", "1")]
+    )
+    def test_scan_era_executable_is_not_adopted(
+        self, tmp_path, monkeypatch, name, class_compress
+    ):
+        """A cache directory that holds the PARENT's executable of the
+        same program, shapes and platform: the engine misses, builds its
+        own and stores it beside the old one."""
+        import numpy as np
+
+        from cyclonus_tpu.engine import TpuPolicyEngine, kernel
+
+        monkeypatch.setenv("CYCLONUS_AOT_CACHE", str(tmp_path))
+        monkeypatch.setenv("CYCLONUS_PACK", "1")
+        policy, pods, namespaces, cases = _small_problem()
+
+        def engine():
+            return TpuPolicyEngine(
+                policy, pods, namespaces, class_compress=class_compress
+            )
+
+        def entries():
+            return _entries(tmp_path, name)
+
+        aot_cache.forget()
+        first = engine()
+        want = first.evaluate_grid(cases).combined
+        ((key, path),) = entries().items()
+        parts = json.loads(key)
+        assert f";{kernel.PACKED_CONTRACTION};" in parts["plan"]
+        # the parent's key: the same plan without the contraction's form
+        old_key = aot_cache.make_key(
+            name, parts["sig"], schedule=parts["schedule"],
+            plan=parts["plan"].replace(f";{kernel.PACKED_CONTRACTION}", ""),
+        )
+        assert old_key != key
+        # what lies under it would load (any executable of this
+        # signature stands for the scan's: adopting it is the fault)
+        assert aot_cache.store(old_key, aot_cache.load(key))
+        assert aot_cache.load(old_key) is not None
+        path.unlink()
+        aot_cache.forget()
+        before = _outcomes()
+        grid = engine().evaluate_grid(cases)
+        assert np.array_equal(grid.combined, want)
+        assert set(entries()) == {key, old_key}
+        moved = _moved(before)
+        assert moved.get("miss", 0) >= 1 and moved.get("compiles", 0) >= 1
 
 
 def _outcomes():

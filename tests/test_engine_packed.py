@@ -70,22 +70,97 @@ class TestPackPrimitives:
             back[i] = (uw[i // PACK_BITS] >> np.uint32(i % PACK_BITS)) & 1
         assert np.array_equal(back, a)
 
-    def test_packed_any_equals_bool_contraction(self):
+    @pytest.mark.parametrize(
+        "t,a,b",
+        [
+            (1, 3, 4),
+            (31, 5, 7),
+            (32, 5, 7),
+            (33, 5, 7),
+            (67, 12, 20),
+            (3199, 9, 11),  # the cidr-40k-20k-x4 ring's depth: 100 words
+            (70, 1, 20),
+            (70, 12, 1),
+            (64, 1, 1),
+        ],
+    )
+    def test_packed_any_equals_bool_contraction(self, t, a, b):
         import jax.numpy as jnp
 
         from cyclonus_tpu.engine.kernel import packed_any, pack_bool_words_jnp
 
-        rng = np.random.default_rng(3)
-        a = rng.random((67, 12)) > 0.8  # [T, A]
-        b = rng.random((67, 20)) > 0.6  # [T, B]
-        want = (a.astype(np.int64).T @ b.astype(np.int64)) > 0
+        rng = np.random.default_rng(3 + t)
+        # thin enough that some cells of the deep cases stay False
+        x = rng.random((t, a)) > 1 - 0.4 / np.sqrt(t)  # [T, A]
+        y = rng.random((t, b)) > 1 - 0.4 / np.sqrt(t)  # [T, B]
+        want = (x.astype(np.int64).T @ y.astype(np.int64)) > 0
+        assert want.size < 30 or (want.any() and not want.all())
         got = np.asarray(
             packed_any(
-                pack_bool_words_jnp(jnp.asarray(a)),
-                pack_bool_words_jnp(jnp.asarray(b)),
+                pack_bool_words_jnp(jnp.asarray(x)),
+                pack_bool_words_jnp(jnp.asarray(y)),
             )
         )
+        assert got.dtype == np.bool_ and got.shape == (a, b)
         assert np.array_equal(want, got)
+
+    @pytest.mark.parametrize("bit", [0, 30, 31])
+    def test_packed_any_sees_one_shared_bit_alone(self, bit):
+        """One bit of one word set on both sides and nothing else: bit
+        31 is the int32 sign, and the contraction must see it as any
+        other (`!= 0`, never `> 0`)."""
+        import jax.numpy as jnp
+
+        from cyclonus_tpu.engine.kernel import packed_any
+
+        word = np.int32(-(2**31)) if bit == 31 else np.int32(1 << bit)
+        a_pk = np.zeros((3, 4), np.int32)
+        b_pk = np.zeros((3, 5), np.int32)
+        a_pk[1, 2] = word
+        b_pk[1, 3] = word
+        b_pk[2, 0] = word  # another word: shares nothing with a_pk
+        got = np.asarray(packed_any(jnp.asarray(a_pk), jnp.asarray(b_pk)))
+        want = np.zeros((4, 5), bool)
+        want[2, 3] = True
+        assert np.array_equal(got, want)
+
+    @pytest.mark.parametrize("zero", ["a", "b", "both"])
+    def test_packed_any_of_all_zero_operands_is_false(self, zero):
+        import jax.numpy as jnp
+
+        from cyclonus_tpu.engine.kernel import packed_any
+
+        ones = np.full((2, 6), -1, np.int32)
+        a_pk = np.zeros_like(ones) if zero in ("a", "both") else ones
+        b_pk = np.zeros_like(ones) if zero in ("b", "both") else ones
+        got = np.asarray(packed_any(jnp.asarray(a_pk), jnp.asarray(b_pk)))
+        assert got.shape == (6, 6) and not got.any()
+
+    def test_packed_any_is_one_reduction_and_no_loop(self):
+        """The contraction is ONE reduce over the words of the broadcast
+        AND (kernel.PACKED_CONTRACTION): a scan or a while would carry
+        an [A, B] accumulator through HBM once a word again (PERF.md
+        section 6, PR 35), and must not come back unnoticed."""
+        import jax
+        import jax.numpy as jnp
+
+        from cyclonus_tpu.engine.kernel import PACKED_CONTRACTION, packed_any
+
+        assert PACKED_CONTRACTION == "any=reduce"
+        jaxpr = jax.make_jaxpr(packed_any)(
+            jax.ShapeDtypeStruct((100, 64), jnp.int32),
+            jax.ShapeDtypeStruct((100, 48), jnp.int32),
+        )
+
+        def primitives(j):
+            for eqn in j.eqns:
+                yield eqn.primitive.name
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from primitives(sub)
+
+        names = list(primitives(jaxpr.jaxpr))
+        assert not {"scan", "while", "cond"} & set(names), names
+        assert sum(n.startswith("reduce") for n in names) == 1, names
 
     def test_pack_enabled_resolution(self, monkeypatch):
         monkeypatch.delenv("CYCLONUS_PACK", raising=False)

@@ -501,3 +501,45 @@ class TestMeshWordPrograms:
         }
         assert len(keys) == 2
         sharded_mod._SHARDED_PROGRAMS.clear()
+
+    @pytest.mark.parametrize("class_compress", ["0", "1"])
+    @pytest.mark.parametrize("pack", ["1", "0"])
+    def test_aot_key_of_sharded_grid_names_the_packed_contraction(
+        self, monkeypatch, pack, class_compress
+    ):
+        """Nor does make_key see a program's code: with pack on, both
+        schedules can trace kernel.packed_any, so the plan names the
+        contraction's form (kernel.PACKED_CONTRACTION) and the scan's
+        executable (PR 34 and before) lies under another key; with
+        CYCLONUS_PACK=0 nothing packed is traced and nothing is named."""
+        from cyclonus_tpu.engine import aot_cache
+        from cyclonus_tpu.engine.kernel import PACKED_CONTRACTION
+
+        monkeypatch.setenv("CYCLONUS_PACK", pack)
+        engine, _policy, _pods = synthetic_engine(
+            40, class_compress=class_compress
+        )
+        mesh = cpu_mesh(2)
+
+        def program():
+            sharded_mod._SHARDED_PROGRAMS.clear()
+            engine.evaluate_grid_sharded(CASES, mesh=mesh)
+            (fn,) = sharded_mod._SHARDED_PROGRAMS.values()
+            sharded_mod._SHARDED_PROGRAMS.clear()
+            return fn
+
+        now = program()
+        if pack == "0":
+            assert "pack=False;classes=" in now._plan and "any=" not in now._plan
+            return
+        assert f"pack=True;{PACKED_CONTRACTION};classes=" in now._plan
+        monkeypatch.setattr(sharded_mod, "PACKED_CONTRACTION", "any=scan")
+        other = program()
+        assert other._plan == now._plan.replace(PACKED_CONTRACTION, "any=scan")
+        keys = {
+            aot_cache.make_key(
+                "sharded.grid", "sig", schedule=fn._schedule, plan=fn._plan
+            )
+            for fn in (now, other)
+        }
+        assert len(keys) == 2
