@@ -300,6 +300,9 @@ def _run_generate_cases(
 
     printer.print_summary()
     if args.phase_stats:
+        from ..telemetry import instruments
+
+        print(f"\nstart-up:\n{instruments.render_startup()}")
         print(f"\nphase timers:\n{render_stats()}")
 
     if args.cleanup_namespaces:

@@ -11,10 +11,62 @@ Pipeline:
   TpuPolicyEngine - the user-facing facade
 """
 
+import importlib as _importlib
 import logging as _logging
 import os as _os
+import sys as _sys
+
+from ..telemetry import instruments as _ti
+from ..telemetry.spans import span as _span
 
 _cache_configured = False
+_backend_started = False
+
+
+def first_import(module: str = "jax") -> None:
+    """Import `module` here and now if the process has not yet, in a
+    `startup.import` span (attr `module`): JAX with libtpu takes seconds
+    to import, `jax.experimental.pallas` a further one, and whoever
+    happens to be first would otherwise carry that in its own span, or
+    in none.  Every place of the engine that may be first calls this
+    ahead of its own import statement.  With JAX in, the compile
+    listener is registered (instruments.watch_jax_compiles)."""
+    if module not in _sys.modules:
+        with _span("startup.import", module=module):
+            _importlib.import_module(module)
+    if module == "jax":
+        _ti.watch_jax_compiles()
+
+
+def start_backend() -> None:
+    """Start the default backend if nothing has yet, in a
+    `startup.backend` span (attrs `platform`, `devices`): the process's
+    first `jax.devices()` is the TPU runtime's start, which otherwise
+    hides in whichever span first touches the device (or in none)."""
+    global _backend_started
+    if _backend_started:
+        return
+    _backend_started = True
+    first_import()
+    import jax
+
+    # JAX says whether something else started the backend already (a
+    # private name: where a JAX has moved it, the first call here is
+    # taken for the start, and a started backend reads as a short span)
+    bridge = getattr(getattr(jax, "_src", None), "xla_bridge", None)
+    started = getattr(bridge, "backends_are_initialized", None)
+    if started is None or not started():
+        with _span("startup.backend") as sp:
+            found = jax.devices()
+            sp.set(platform=found[0].platform, devices=len(found))
+
+
+def devices() -> list:
+    """`jax.devices()` of the default backend, started by start_backend."""
+    start_backend()
+    import jax
+
+    return jax.devices()
 
 
 def cache_root() -> str:  # never-raises
@@ -40,13 +92,11 @@ def device_identity() -> dict:
     """The devices of the default backend as JAX reports them —
     {"platform", "kind", "count"} — for every line, banner and result
     that has to say what answered.  Initialises the backend."""
-    import jax
-
-    devices = jax.devices()
+    found = devices()
     return {
-        "platform": devices[0].platform,
-        "kind": devices[0].device_kind,
-        "count": len(devices),
+        "platform": found[0].platform,
+        "kind": found[0].device_kind,
+        "count": len(found),
     }
 
 
@@ -66,6 +116,7 @@ def ensure_persistent_compile_cache() -> None:
     if _cache_configured:
         return
     _cache_configured = True
+    first_import()
     import jax
 
     # Full-traceback locations leak CALLER line numbers into the

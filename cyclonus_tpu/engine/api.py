@@ -911,229 +911,237 @@ class TpuPolicyEngine:
         tiers=None,
         slab_headroom: int = 0,
     ):
-        # compact/class_compress override the CYCLONUS_COMPACT /
-        # CYCLONUS_CLASS_COMPRESS env defaults per engine (None = env).
-        # The serve layer builds its engines with compact=False — dead-
-        # target compaction bakes "no pod matches this target" into the
-        # tensors, and a pod delta can make a dead target live, so a
-        # delta-oriented engine must keep every target resident.
-        # tiers: an optional tiers.model.TierSet — AdminNetworkPolicy/
-        # BANP precedence tiers layered over the NetworkPolicy verdict
-        # (docs/DESIGN.md "Precedence tiers").  With it absent or empty,
-        # the tensor set — and therefore every compiled program — is
-        # byte-identical to the networkingv1-only engine.
-        # every evaluation path below is jax-backed: first-touch setup of
-        # the persistent compile cache happens here, not at import time
-        from . import ensure_persistent_compile_cache
+        with phase(
+            "engine.new", pods=len(pods),
+            targets=len(policy.ingress) + len(policy.egress),
+        ):
+            # compact/class_compress override the CYCLONUS_COMPACT /
+            # CYCLONUS_CLASS_COMPRESS env defaults per engine (None = env).
+            # The serve layer builds its engines with compact=False — dead-
+            # target compaction bakes "no pod matches this target" into the
+            # tensors, and a pod delta can make a dead target live, so a
+            # delta-oriented engine must keep every target resident.
+            # tiers: an optional tiers.model.TierSet — AdminNetworkPolicy/
+            # BANP precedence tiers layered over the NetworkPolicy verdict
+            # (docs/DESIGN.md "Precedence tiers").  With it absent or empty,
+            # the tensor set — and therefore every compiled program — is
+            # byte-identical to the networkingv1-only engine.
+            # every evaluation path below is jax-backed: first-touch setup of
+            # the persistent compile cache happens here, not at import time
+            from . import ensure_persistent_compile_cache, start_backend
 
-        ensure_persistent_compile_cache()
-        self._opt_compact = compact
-        self._opt_class_compress = class_compress
-        # cidr_tss overrides CYCLONUS_CIDR_TSS for the TSS/LPM CIDR
-        # pre-classification stage (engine/cidrspace.py; docs/DESIGN.md
-        # "CIDR tuple-space pre-classification") — None = env
-        self._opt_cidr_tss = cidr_tss
-        # rule-slab headroom (extra _bucket_dim steps pre-reserved on
-        # the selector/target/peer/tier row buckets).  0 for batch
-        # engines; the serve path passes CYCLONUS_SERVE_HEADROOM so
-        # bucket-crossing policy churn patches into the reservation
-        # (serve/incremental.py patch_policy) instead of rebuilding.
-        self._slab_headroom = max(0, int(slab_headroom or 0))
-        self.tiers = tiers if tiers else None
-        if self.tiers is not None:
-            self.tiers.validate()
-        with phase("engine.encode"):
-            with phase("engine.encode_policy", pods=len(pods)):
-                self.encoding: PolicyEncoding = encode_policy(
-                    policy, pods, namespaces, tiers=self.tiers
+            ensure_persistent_compile_cache()
+            # the process's first engine starts the backend here, in a span
+            # of its own (startup.backend), and not inside whatever first
+            # touches the device: engine.cidrspace, a device_put, or no span
+            start_backend()
+            self._opt_compact = compact
+            self._opt_class_compress = class_compress
+            # cidr_tss overrides CYCLONUS_CIDR_TSS for the TSS/LPM CIDR
+            # pre-classification stage (engine/cidrspace.py; docs/DESIGN.md
+            # "CIDR tuple-space pre-classification") — None = env
+            self._opt_cidr_tss = cidr_tss
+            # rule-slab headroom (extra _bucket_dim steps pre-reserved on
+            # the selector/target/peer/tier row buckets).  0 for batch
+            # engines; the serve path passes CYCLONUS_SERVE_HEADROOM so
+            # bucket-crossing policy churn patches into the reservation
+            # (serve/incremental.py patch_policy) instead of rebuilding.
+            self._slab_headroom = max(0, int(slab_headroom or 0))
+            self.tiers = tiers if tiers else None
+            if self.tiers is not None:
+                self.tiers.validate()
+            with phase("engine.encode"):
+                with phase("engine.encode_policy", pods=len(pods)):
+                    self.encoding: PolicyEncoding = encode_policy(
+                        policy, pods, namespaces, tiers=self.tiers
+                    )
+                with phase("engine.build_tensors"):
+                    self._tensors = self._build_tensors()
+                # one O(S*N) host selector pass serves both consumers: dead-
+                # target compaction here and the slab-window plan later
+                # (selector and pod axes are unchanged by compaction, only
+                # padded by bucketing)
+                self._selpod_prebucket = None
+                compact_on = (
+                    _compaction_enabled(self._tensors)
+                    if compact is None
+                    else bool(compact)
                 )
-            with phase("engine.build_tensors"):
-                self._tensors = self._build_tensors()
-            # one O(S*N) host selector pass serves both consumers: dead-
-            # target compaction here and the slab-window plan later
-            # (selector and pod axes are unchanged by compaction, only
-            # padded by bucketing)
-            self._selpod_prebucket = None
-            compact_on = (
-                _compaction_enabled(self._tensors)
-                if compact is None
-                else bool(compact)
-            )
-            if compact_on:
-                with phase("engine.compact"):
-                    self._selpod_prebucket = _selector_pod_matches_host(
-                        self._tensors
-                    )
-                    self._tensors = _compact_dead_targets(
-                        self._tensors, selpod=self._selpod_prebucket
-                    )
-            # equivalence-class grid compression (docs/DESIGN.md "Grid
-            # compression"): tuple-space partition compression of the
-            # rule axes is exact and cheap, so it applies whenever
-            # compression isn't disabled outright; the pod-class state
-            # additionally needs the host selector pass and a real
-            # reduction (auto mode) before paying for a second tensor set
-            self._partition_stats = None
-            self._class_state = None
-            mode = (
-                _class_compress_mode()
-                if class_compress is None
-                else str(class_compress).lower()
-            )
-            class_route = "off"
-            if mode != "0":
-                with phase("engine.partition"):
-                    pstats = {}
-                    for direction in ("ingress", "egress"):
-                        nd, pstats[direction] = compress_rule_axes(
-                            self._tensors[direction]
+                if compact_on:
+                    with phase("engine.compact"):
+                        self._selpod_prebucket = _selector_pod_matches_host(
+                            self._tensors
                         )
-                        self._tensors[direction] = nd
-                    self._partition_stats = pstats
-                class_route = self._maybe_build_class_state(mode)
-            # which side of the class-route hand-off this engine is on
-            ti.CLASS_ROUTE.inc(outcome=class_route)
-            with phase("engine.class_tensors"):
-                self._tensors = _bucket_tensors(
-                    _sort_targets_by_ns(self._tensors),
-                    headroom=self._slab_headroom,
+                        self._tensors = _compact_dead_targets(
+                            self._tensors, selpod=self._selpod_prebucket
+                        )
+                # equivalence-class grid compression (docs/DESIGN.md "Grid
+                # compression"): tuple-space partition compression of the
+                # rule axes is exact and cheap, so it applies whenever
+                # compression isn't disabled outright; the pod-class state
+                # additionally needs the host selector pass and a real
+                # reduction (auto mode) before paying for a second tensor set
+                self._partition_stats = None
+                self._class_state = None
+                mode = (
+                    _class_compress_mode()
+                    if class_compress is None
+                    else str(class_compress).lower()
                 )
-                if self._class_state is not None:
-                    self._class_state["ctensors"] = _bucket_tensors(
-                        _sort_targets_by_ns(
-                            self._class_state.pop("ctensors_raw")
-                        ),
+                class_route = "off"
+                if mode != "0":
+                    with phase("engine.partition"):
+                        pstats = {}
+                        for direction in ("ingress", "egress"):
+                            nd, pstats[direction] = compress_rule_axes(
+                                self._tensors[direction]
+                            )
+                            self._tensors[direction] = nd
+                        self._partition_stats = pstats
+                    class_route = self._maybe_build_class_state(mode)
+                # which side of the class-route hand-off this engine is on
+                ti.CLASS_ROUTE.inc(outcome=class_route)
+                with phase("engine.class_tensors"):
+                    self._tensors = _bucket_tensors(
+                        _sort_targets_by_ns(self._tensors),
                         headroom=self._slab_headroom,
                     )
-            if self._class_state is not None:
-                st = self._class_state
-                # the gather/index tensors the compressed path pins on
-                # device: class map + weights + the compressed tensor
-                # buffer — counted against CYCLONUS_SLAB_MAX_BYTES by
-                # the slab plan and the compressed-counts eligibility
-                cb = int(st["ctensors"]["pod_ns_id"].shape[0])
-                # the TSS partition tensors (trie map) charge the same
-                # budget: the LPM stage must never over-commit the HBM
-                # the compression exists to save
-                cidr_bytes = (
-                    st["cidr"].nbytes() if st.get("cidr") is not None else 0
-                )
-                st["aux_bytes"] = int(
-                    self.encoding.cluster.n_pods * 4
-                    + cb * 4
-                    + sum(a.nbytes for a in _np_leaves(st["ctensors"]))
-                    + cidr_bytes
-                )
-                ti.CLASS_AUX_BYTES.set(st["aux_bytes"])
-        # wall-clock of the last tiered grid evaluation's dispatch
-        # (tier_stats()["resolve_s"]; None until a tiered eval ran)
-        self._tier_resolve_s = None
-        # The trailing `# derived-from:` declarations below are the
-        # cache-coherence contract tools/cachelint.py CC002 enforces:
-        # a VALUE token means invalidate_after_patch must reset the
-        # attribute after an in-place buffer patch; `shapes` marks a
-        # compiled-program cache (shape-keyed, survives value patches);
-        # `patched` marks state the serve patch path maintains itself.
-        self._device_tensors = None  # derived-from: buffer (unpacked views)
-        self._packed_buf = None  # derived-from: patched (scatter writes back)
-        self._unpack = None  # derived-from: patched (layout fixed at build)
-        # jit wrappers over the unpack closures, cached so the serve
-        # layer's patch/invalidate cycle re-unpacks through the SAME
-        # compiled program instead of retracing per patch
-        self._unpack_jit = None  # derived-from: shapes
-        self._class_unpack_jit = None  # derived-from: shapes
-        # compressed-path device state (all lazy; None when no class
-        # state): packed class-representative buffer + unpacked pytree,
-        # the pod->class gather map, and the fused grid+gather program
-        self._class_packed_buf = None  # derived-from: patched
-        self._class_unpack = None  # derived-from: patched
-        self._class_device_tensors = None  # derived-from: buffer
-        self._class_of_dev = None  # derived-from: classes
-        # the counts route's dst-side class weights (tiled.class_weights):
-        # the serve layer mutates class_size in place, so a kept copy is
-        # a wrong count unless every reset of _class_of_dev resets it too
-        self._class_w_dev = None  # derived-from: classes
-        self._class_grid_jit = None  # derived-from: shapes
-        self._pod_perm_dev = None  # derived-from: pod-rows (ns-order perm)
-        self._pod_perm_host = None  # derived-from: pod-rows
-        self._slab_plan_state = "unset"  # derived-from: buffer (window proof)
-        # None = not yet tuned (auto mode times both at the first
-        # steady-state call); True/False = slab kernel chosen/rejected
-        self._slab_choice = None  # derived-from: buffer (re-timed)
-        self._slab_autotune = None  # {"default_s", "slab_s"} once timed
-        # the bit-packed dtype plan (docs/DESIGN.md "Bit-packed
-        # kernel"): resolved ONCE per engine from CYCLONUS_PACK — the
-        # compiled program set is a function of it, like the operand
-        # dtype — and passed static everywhere
-        self._pack = pack_enabled()
-        # persistent AOT executable adapters (engine/aot_cache.py):
-        # built lazily per program family; with CYCLONUS_AOT_CACHE off
-        # they pass straight through to the plain jits
-        self._grid_aot = None  # derived-from: shapes
-        self._pairs_aot = None  # derived-from: shapes
-        # the tuned counts configuration: None until the autotune (or a
-        # persisted-cache adoption) picks one; then {"kernel":
-        # "default"|"slab"|"packed", optional "bs"/"bd"}.  Shares
-        # _slab_lock with _slab_choice so the pair can never be read
-        # half-updated against the autotune's abandoned thread.
-        self._kernel_choice = None  # derived-from: buffer (re-tuned)
-        # autotune forensics for pack_stats(): {"source":
-        # search|cache|single, "search_s", "candidates": [...],
-        # "noise_floor"} once the first steady-state call resolves it
-        self._autotune_stats = None
-        # slab HBM cost scales with the port-case count, but the plan and
-        # choice persist for the engine's life; dispatch re-checks the
-        # budget against the ACTUAL q (plan time budgets q=2)
-        self._slab_bytes_per_case = None
-        self._slab_budget = None
-        # set after an autotune TIMEOUT: {"event": Event, "waited": bool}
-        # — the abandoned candidate thread's completion marker; dispatches
-        # gate on it (_drain_autotune_orphan)
-        self._autotune_orphan = None
-        # guards the (_slab_choice, _slab_ops_cache) pair: the autotune's
-        # rejection writes and the ops-cache fill can race an abandoned
-        # candidate thread still inside _slab_ops_for
-        self._slab_lock = guards.lock()
-        self._counts_packed_jit = None  # derived-from: shapes
-        # steady-state counts: cache the device-resident precompute per
-        # port-case set so repeat evaluations run only the pallas kernel
-        self._pre_jit = None  # derived-from: shapes
-        self._counts_from_pre_jit = None  # derived-from: shapes
-        self._counts_from_pre_packed_jit = None  # derived-from: shapes
-        self._pre_cache = None  # derived-from: buffer (cases key + pre pytree)
-        # the half of the precompute the port cases do not touch
-        # (tiled._precompute_static), built at the first counts.pallas
-        # call and kept: a request whose case set is not pinned runs
-        # `counts.cases`, the program that starts where its cases enter.
-        # Written by the issuing thread alone, like _pre_cache, and
-        # never read by the autotune's orphan
-        self._static_jit = None  # derived-from: shapes
-        self._counts_cases_jit = None  # derived-from: shapes
-        self._static_pre = None  # derived-from: buffer (static pytree)
-        # gathered slab operands, cached next to the pre: building them
-        # per dispatch cost more than the slab's depth cut saved
-        self._slab_ops_jit = None  # derived-from: shapes
-        self._counts_from_slab_ops_jit = None  # derived-from: shapes
-        self._slab_ops_cache = None  # derived-from: buffer (gathered ops)
-        self._pre_cache_misses = 0  # derived-from: buffer
-        self._pre_cache_declined = None  # derived-from: buffer (declined key)
-        self._last_counts_key = None  # derived-from: buffer
-        self._has_ip_peers = (
-            bool(np.any(self.encoding.ingress.peer_kind == PEER_IP))
-            or bool(np.any(self.encoding.egress.peer_kind == PEER_IP))
-        )
-        # pod_ip_valid=True already proves parseability (the encoder's
-        # IPv4 fast path), so only the residue — IPv6 pods and garbage —
-        # pays ipaddress.ip_address; at 100k all-IPv4 pods this pass was
-        # ~0.5 s of redundant parsing
-        self._unparseable_ips = [
-            ip
-            for ip, v4 in zip(
-                self.encoding.cluster.pod_ips,
-                self.encoding.cluster.pod_ip_valid,
+                    if self._class_state is not None:
+                        self._class_state["ctensors"] = _bucket_tensors(
+                            _sort_targets_by_ns(
+                                self._class_state.pop("ctensors_raw")
+                            ),
+                            headroom=self._slab_headroom,
+                        )
+                if self._class_state is not None:
+                    st = self._class_state
+                    # the gather/index tensors the compressed path pins on
+                    # device: class map + weights + the compressed tensor
+                    # buffer — counted against CYCLONUS_SLAB_MAX_BYTES by
+                    # the slab plan and the compressed-counts eligibility
+                    cb = int(st["ctensors"]["pod_ns_id"].shape[0])
+                    # the TSS partition tensors (trie map) charge the same
+                    # budget: the LPM stage must never over-commit the HBM
+                    # the compression exists to save
+                    cidr_bytes = (
+                        st["cidr"].nbytes() if st.get("cidr") is not None else 0
+                    )
+                    st["aux_bytes"] = int(
+                        self.encoding.cluster.n_pods * 4
+                        + cb * 4
+                        + sum(a.nbytes for a in _np_leaves(st["ctensors"]))
+                        + cidr_bytes
+                    )
+                    ti.CLASS_AUX_BYTES.set(st["aux_bytes"])
+            # wall-clock of the last tiered grid evaluation's dispatch
+            # (tier_stats()["resolve_s"]; None until a tiered eval ran)
+            self._tier_resolve_s = None
+            # The trailing `# derived-from:` declarations below are the
+            # cache-coherence contract tools/cachelint.py CC002 enforces:
+            # a VALUE token means invalidate_after_patch must reset the
+            # attribute after an in-place buffer patch; `shapes` marks a
+            # compiled-program cache (shape-keyed, survives value patches);
+            # `patched` marks state the serve patch path maintains itself.
+            self._device_tensors = None  # derived-from: buffer (unpacked views)
+            self._packed_buf = None  # derived-from: patched (scatter writes back)
+            self._unpack = None  # derived-from: patched (layout fixed at build)
+            # jit wrappers over the unpack closures, cached so the serve
+            # layer's patch/invalidate cycle re-unpacks through the SAME
+            # compiled program instead of retracing per patch
+            self._unpack_jit = None  # derived-from: shapes
+            self._class_unpack_jit = None  # derived-from: shapes
+            # compressed-path device state (all lazy; None when no class
+            # state): packed class-representative buffer + unpacked pytree,
+            # the pod->class gather map, and the fused grid+gather program
+            self._class_packed_buf = None  # derived-from: patched
+            self._class_unpack = None  # derived-from: patched
+            self._class_device_tensors = None  # derived-from: buffer
+            self._class_of_dev = None  # derived-from: classes
+            # the counts route's dst-side class weights (tiled.class_weights):
+            # the serve layer mutates class_size in place, so a kept copy is
+            # a wrong count unless every reset of _class_of_dev resets it too
+            self._class_w_dev = None  # derived-from: classes
+            self._class_grid_jit = None  # derived-from: shapes
+            self._pod_perm_dev = None  # derived-from: pod-rows (ns-order perm)
+            self._pod_perm_host = None  # derived-from: pod-rows
+            self._slab_plan_state = "unset"  # derived-from: buffer (window proof)
+            # None = not yet tuned (auto mode times both at the first
+            # steady-state call); True/False = slab kernel chosen/rejected
+            self._slab_choice = None  # derived-from: buffer (re-timed)
+            self._slab_autotune = None  # {"default_s", "slab_s"} once timed
+            # the bit-packed dtype plan (docs/DESIGN.md "Bit-packed
+            # kernel"): resolved ONCE per engine from CYCLONUS_PACK — the
+            # compiled program set is a function of it, like the operand
+            # dtype — and passed static everywhere
+            self._pack = pack_enabled()
+            # persistent AOT executable adapters (engine/aot_cache.py):
+            # built lazily per program family; with CYCLONUS_AOT_CACHE off
+            # they pass straight through to the plain jits
+            self._grid_aot = None  # derived-from: shapes
+            self._pairs_aot = None  # derived-from: shapes
+            # the tuned counts configuration: None until the autotune (or a
+            # persisted-cache adoption) picks one; then {"kernel":
+            # "default"|"slab"|"packed", optional "bs"/"bd"}.  Shares
+            # _slab_lock with _slab_choice so the pair can never be read
+            # half-updated against the autotune's abandoned thread.
+            self._kernel_choice = None  # derived-from: buffer (re-tuned)
+            # autotune forensics for pack_stats(): {"source":
+            # search|cache|single, "search_s", "candidates": [...],
+            # "noise_floor"} once the first steady-state call resolves it
+            self._autotune_stats = None
+            # slab HBM cost scales with the port-case count, but the plan and
+            # choice persist for the engine's life; dispatch re-checks the
+            # budget against the ACTUAL q (plan time budgets q=2)
+            self._slab_bytes_per_case = None
+            self._slab_budget = None
+            # set after an autotune TIMEOUT: {"event": Event, "waited": bool}
+            # — the abandoned candidate thread's completion marker; dispatches
+            # gate on it (_drain_autotune_orphan)
+            self._autotune_orphan = None
+            # guards the (_slab_choice, _slab_ops_cache) pair: the autotune's
+            # rejection writes and the ops-cache fill can race an abandoned
+            # candidate thread still inside _slab_ops_for
+            self._slab_lock = guards.lock()
+            self._counts_packed_jit = None  # derived-from: shapes
+            # steady-state counts: cache the device-resident precompute per
+            # port-case set so repeat evaluations run only the pallas kernel
+            self._pre_jit = None  # derived-from: shapes
+            self._counts_from_pre_jit = None  # derived-from: shapes
+            self._counts_from_pre_packed_jit = None  # derived-from: shapes
+            self._pre_cache = None  # derived-from: buffer (cases key + pre pytree)
+            # the half of the precompute the port cases do not touch
+            # (tiled._precompute_static), built at the first counts.pallas
+            # call and kept: a request whose case set is not pinned runs
+            # `counts.cases`, the program that starts where its cases enter.
+            # Written by the issuing thread alone, like _pre_cache, and
+            # never read by the autotune's orphan
+            self._static_jit = None  # derived-from: shapes
+            self._counts_cases_jit = None  # derived-from: shapes
+            self._static_pre = None  # derived-from: buffer (static pytree)
+            # gathered slab operands, cached next to the pre: building them
+            # per dispatch cost more than the slab's depth cut saved
+            self._slab_ops_jit = None  # derived-from: shapes
+            self._counts_from_slab_ops_jit = None  # derived-from: shapes
+            self._slab_ops_cache = None  # derived-from: buffer (gathered ops)
+            self._pre_cache_misses = 0  # derived-from: buffer
+            self._pre_cache_declined = None  # derived-from: buffer (declined key)
+            self._last_counts_key = None  # derived-from: buffer
+            self._has_ip_peers = (
+                bool(np.any(self.encoding.ingress.peer_kind == PEER_IP))
+                or bool(np.any(self.encoding.egress.peer_kind == PEER_IP))
             )
-            if not v4 and not _parseable_ip(ip)
-        ]
+            # pod_ip_valid=True already proves parseability (the encoder's
+            # IPv4 fast path), so only the residue — IPv6 pods and garbage —
+            # pays ipaddress.ip_address; at 100k all-IPv4 pods this pass was
+            # ~0.5 s of redundant parsing
+            self._unparseable_ips = [
+                ip
+                for ip, v4 in zip(
+                    self.encoding.cluster.pod_ips,
+                    self.encoding.cluster.pod_ip_valid,
+                )
+                if not v4 and not _parseable_ip(ip)
+            ]
 
     @property
     def pod_keys(self) -> List[str]:
@@ -2662,7 +2670,6 @@ class TpuPolicyEngine:
                 ops, interpret=interpret
             )
         )
-        ti.ENGINE_PROGRAMS_BUILT.inc()
 
     def _counts_pallas_packed(self, cases: Sequence[PortCase], n: int) -> Dict[str, int]:
         """Telemetry shell around the pallas counts path: one flight-

@@ -26,6 +26,9 @@ from __future__ import annotations
 from functools import partial
 from typing import Dict, Optional, Tuple
 
+from . import first_import
+
+first_import()  # ahead of `import jax`: this may be the process's first
 import jax
 import jax.numpy as jnp
 import numpy as np

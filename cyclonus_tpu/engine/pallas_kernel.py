@@ -43,6 +43,10 @@ import os
 from functools import partial
 from typing import Dict, Tuple
 
+from . import first_import
+
+first_import()  # ahead of the imports below: these may be the process's first
+first_import("jax.experimental.pallas")
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl

@@ -41,6 +41,9 @@ import math
 import os
 from typing import Dict, Optional, Tuple
 
+from . import first_import
+
+first_import()  # ahead of `import jax`: this may be the process's first
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -131,7 +134,9 @@ def default_mesh() -> Mesh:
     TPU gets a one-device mesh, never a virtual CPU mesh that would
     compute `--engine tpu-sharded` on the host.  Callers that want a
     virtual CPU mesh (the tests, dryrun_multichip) build and pass it."""
-    return Mesh(np.array(jax.devices()), ("x",))
+    from . import devices
+
+    return Mesh(np.array(devices()), ("x",))
 
 
 def _pad_pod_arrays(tensors: Dict, n_pods: int, n_dev: int) -> Tuple[Dict, int]:

@@ -45,6 +45,9 @@ import math
 from functools import partial
 from typing import Dict, Iterator, Tuple
 
+from . import first_import
+
+first_import()  # ahead of `import jax`: this may be the process's first
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -41,7 +41,7 @@ from ..kube.netpol import NAMESPACE_DEFAULT, NetworkPolicy
 from ..kube.yaml_io import parse_policy_dict
 from ..matcher.builder import build_network_policies
 from ..slo.engine import SloController
-from ..telemetry import instruments as ti
+from ..telemetry import events, instruments as ti
 # graduated to telemetry.metrics (now interpolates inside the winning
 # bucket); re-exported here for compatibility
 from ..telemetry.metrics import histogram_quantile  # noqa: F401
@@ -746,6 +746,10 @@ class VerdictService:
 
     def mark_ready(self) -> None:
         self._ready.set()
+        # /readyz turns ready: start-up is over, and its record closes
+        # (telemetry/events.py; the cyclonus_tpu_startup_seconds gauges
+        # are then set for good)
+        events.close_startup()
 
     def readiness(self) -> Tuple[bool, str]:
         """The (ready, detail) pair telemetry/server.py's /readyz route

@@ -17,7 +17,12 @@ first-class layer.  This package is that layer:
                   JSON on unhandled crash and on demand
   events.py       trace-event recorder: span enter/exit as timestamped
                   events in a bounded ring, with (trace_id, parent path)
-                  context propagated driver->worker over the wire
+                  context propagated driver->worker over the wire; and
+                  the START-UP RECORD: from this package's import until
+                  the first profiler capture, `events.close_startup()`
+                  or a fixed count of events, every span is kept in that
+                  ring, so `events.startup_spans()` is what the process
+                  did before its first request (JAX's compiles included)
   trace_export.py Chrome trace-event JSON export of the merged timeline
                   (Perfetto / chrome://tracing; `--trace-out`, the
                   `cyclonus-tpu trace` CLI mode)

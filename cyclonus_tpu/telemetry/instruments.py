@@ -18,10 +18,11 @@ from __future__ import annotations
 
 import contextlib
 import sys
+import threading
 import time
-from typing import Any, Dict, Iterator, Optional
+from typing import Any, Dict, Iterable, Iterator, Optional
 
-from . import recorder, spans, state
+from . import events, recorder, spans, state
 from .metrics import REGISTRY
 
 # --- evaluation throughput / latency ------------------------------------
@@ -208,10 +209,6 @@ KERNEL_TRACES = REGISTRY.counter(
     "hits); the persistent XLA cache may still serve the binary.",
     labelnames=("kernel",),
 )
-ENGINE_PROGRAMS_BUILT = REGISTRY.counter(
-    "cyclonus_tpu_engine_programs_built_total",
-    "Per-engine counts-program families built (api._build_counts_jits).",
-)
 
 # --- autotune ------------------------------------------------------------
 
@@ -252,6 +249,39 @@ AOT_COMPILES = REGISTRY.counter(
     "Fresh lower+compile passes paid by AOT-wrapped programs.  A "
     "restarted process adopting a warm cache keeps this flat — the "
     "zero-recompile restart contract tests/test_aot_cache.py asserts.",
+)
+
+# --- start-up: what a process did before its first verdict ---------------
+
+JAX_COMPILE_SECONDS = REGISTRY.counter(
+    "cyclonus_tpu_jax_compile_seconds_total",
+    "Seconds JAX spent making programs, by stage as jax.monitoring "
+    "reports it: trace (jaxpr), lower (to MLIR), backend_compile (XLA, "
+    "or the read of its persistent cache).  Every jit of the process, "
+    "AOT-wrapped or not.",
+    labelnames=("stage",),
+)
+JAX_COMPILES = REGISTRY.counter(
+    "cyclonus_tpu_jax_compiles_total",
+    "Backend compiles by what JAX's persistent compilation cache did: "
+    "hit (the executable was read from it), miss (compiled and written), "
+    "uncached (compiled; too quick to keep, or no cache configured).",
+    labelnames=("cache",),
+)
+STARTUP_SECONDS = REGISTRY.gauge(
+    "cyclonus_tpu_startup_seconds",
+    "The main thread's time between process start and the close of the "
+    "start-up record (telemetry/events.py), by phase: import (JAX and "
+    "Pallas), backend (the runtime's start), matcher (matcher.build), "
+    "engine (the engine's constructor), program (compiles, loads, the "
+    "autotune), first_eval (the first evaluation).  An instant counts "
+    "once, for the innermost of these that was open.",
+    labelnames=("phase",),
+)
+TIME_TO_FIRST_VERDICT = REGISTRY.gauge(
+    "cyclonus_tpu_time_to_first_verdict_seconds",
+    "Process start to the end of the first engine evaluation that "
+    "handed its caller a result (0: none yet).",
 )
 
 WORKER_RETRIES = REGISTRY.counter(
@@ -445,6 +475,214 @@ VERDICTS = REGISTRY.counter(
 )
 
 
+# start-up phase -> the spans it is made of (docs/DESIGN.md "Start-up
+# record"); of engine.eval only the process's first counts.  The
+# benchmark's eight `setup.*` phases (benchmarks/startup_spans.py PHASES)
+# cut the same record finer, by the same exclusive_seconds: `engine` here
+# is its setup.engine_s + setup.classes_s (the class spans lie inside
+# engine.new), `first_eval` the first evaluation of its setup.warmup_s
+# (every warm-up request, with its fetches and waits), and what is
+# outside every span is no phase here.
+STARTUP_PHASES = {
+    "import": ("startup.import",),
+    "backend": ("startup.backend",),
+    "matcher": ("matcher.build",),
+    "engine": ("engine.new",),
+    "program": (
+        "engine.program", "engine.static_pre", "engine.autotune",
+        "jax.compile",
+    ),
+    "first_eval": ("engine.eval",),
+}
+
+
+def exclusive_seconds(
+    spans_of_thread: Iterable[Dict[str, Any]],
+    phase_of: Dict[str, str],
+    lo: float = float("-inf"),
+    hi: float = float("inf"),
+) -> Dict[str, float]:
+    """One thread's time line between `lo` and `hi` shared out among
+    phases: an instant goes to the innermost open span that `phase_of`
+    (span name -> phase) names, to no phase where none is open, and is
+    counted once however many spans cover it.  A phase whose spans all
+    lie outside [lo, hi], or have no length, is there with 0.0.  The
+    one attribution of the start-up record: the gauges below and the
+    benchmark's `setup.*` readers (benchmarks/startup_spans.py, with a
+    phase map of its own) both go through it."""
+    marks = sorted(
+        (
+            (
+                max(sp["start_s"], lo),
+                min(sp["start_s"] + sp["dur_s"], hi),
+                phase_of[sp["name"]],
+            )
+            for sp in spans_of_thread if sp["name"] in phase_of
+        ),
+        # of two that start together the longer is the outer one
+        key=lambda m: (m[0], -m[1]),
+    )
+    out: Dict[str, float] = {}
+    open_: list = []  # (end, phase), innermost last
+    cursor = lo  # the time line is shared out up to here
+
+    def run_to(t: float) -> None:
+        nonlocal cursor
+        while open_ and open_[-1][0] <= t:
+            end, phase = open_.pop()
+            if end > cursor:
+                out[phase] = out.get(phase, 0.0) + end - cursor
+                cursor = end
+        if open_ and t > cursor:
+            phase = open_[-1][1]
+            out[phase] = out.get(phase, 0.0) + t - cursor
+        cursor = max(cursor, t)
+
+    for start, end, phase in marks:
+        if end <= start:
+            out.setdefault(phase, 0.0)
+            continue
+        run_to(start)
+        open_.append((end, phase))
+    while open_:
+        run_to(open_[-1][0])
+    return out
+
+
+def startup_phases() -> Optional[Dict[str, float]]:
+    """STARTUP_PHASES' seconds as the start-up record has them now, every
+    phase present; None where the ring no longer holds the whole record."""
+    found = events.startup_spans()
+    if found["wrapped"]:
+        return None
+    main = threading.main_thread().ident
+    mine, seen_eval = [], False
+    for sp in found["spans"]:
+        if sp["thread"] != main:
+            continue
+        if sp["name"] == "engine.eval":
+            if seen_eval:
+                continue
+            seen_eval = True
+        mine.append(sp)
+    phase_of = {n: ph for ph, names in STARTUP_PHASES.items() for n in names}
+    got = exclusive_seconds(mine, phase_of)
+    return {phase: got.get(phase, 0.0) for phase in STARTUP_PHASES}
+
+
+def render_startup() -> str:
+    """The six phases and the time to the first verdict, a line each
+    (`generate --phase-stats` prints them above its table)."""
+    phases = startup_phases() or {}
+    rows = [f"startup.{ph:<16}{s:>10.4f}s" for ph, s in phases.items()]
+    rows.append(
+        f"{'time_to_first_verdict':<24}{TIME_TO_FIRST_VERDICT.value():>10.4f}s"
+    )
+    return "\n".join(rows)
+
+
+class _Startup:
+    """Keeps STARTUP_SECONDS true to the record: refreshed at every
+    scrape while the record is open, set for good when it closes
+    (events.close_startup calls refresh_startup; the ring may drop the
+    record later)."""
+
+    def __init__(self) -> None:
+        self.final = False
+
+    def refresh(self) -> None:
+        if self.final:
+            return
+        self.final = not events.STARTUP
+        for phase, seconds in (startup_phases() or {}).items():
+            STARTUP_SECONDS.set(seconds, phase=phase)
+
+
+_STARTUP = _Startup()
+REGISTRY.register_collector(_STARTUP.refresh)
+refresh_startup = _STARTUP.refresh
+
+_JAX_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+_JAX_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+
+
+class _JaxCompiles:
+    """The one listener on jax.monitoring: each stage of each compile
+    feeds JAX_COMPILE_SECONDS and becomes a completed `jax.compile` span
+    (attrs `stage`, `fun`; `cache` on a backend compile), so that a
+    compile which goes through a plain `jit`, and so through no
+    `engine.program` span, still lies on the timeline.
+
+    JAX says when a stage starts (a scalar) and when it has ended (a
+    duration).  Stages nest: tracing one jit traces every jit it calls,
+    hundreds of them a program.  A trace or a lowering INSIDE another
+    stage is in that stage's seconds already and is neither counted nor
+    recorded again; a backend compile always is.  JAX reports the
+    persistent cache's hit or miss as an event of its own inside the
+    backend compile it belongs to: kept per thread until that ends."""
+
+    def __init__(self) -> None:
+        self.watching = False
+        self._tls = threading.local()
+
+    def on_start(self, event: str, value: float, **kw: Any) -> None:
+        if event in _JAX_STAGES:
+            self._tls.depth = getattr(self._tls, "depth", 0) + 1
+
+    def on_event(self, event: str, **kw: Any) -> None:
+        outcome = _JAX_CACHE_EVENTS.get(event)
+        if outcome is not None:
+            self._tls.cache = outcome
+
+    def on_duration(self, event: str, duration: float, **kw: Any) -> None:
+        stage = _JAX_STAGES.get(event)
+        if stage is None:
+            return
+        depth = getattr(self._tls, "depth", 1)
+        self._tls.depth = max(depth - 1, 0)
+        attrs = {"stage": stage, "fun": str(kw.get("fun_name", ""))[:64]}
+        if stage == "backend_compile":
+            attrs["cache"] = getattr(self._tls, "cache", None) or "uncached"
+            self._tls.cache = None
+            JAX_COMPILES.inc(cache=attrs["cache"])
+        elif depth > 1:
+            return
+        JAX_COMPILE_SECONDS.inc(max(duration, 0.0), stage=stage)
+        spans.completed("jax.compile", duration, **attrs)
+
+
+_JAX_COMPILES = _JaxCompiles()
+
+
+def watch_jax_compiles() -> None:
+    """Register the compile listener, once a process (engine.first_import
+    calls this wherever the program may be first to import JAX)."""
+    if _JAX_COMPILES.watching:
+        return
+    _JAX_COMPILES.watching = True
+    from jax import monitoring
+
+    monitoring.register_scalar_listener(_JAX_COMPILES.on_start)
+    monitoring.register_event_listener(_JAX_COMPILES.on_event)
+    monitoring.register_event_duration_secs_listener(_JAX_COMPILES.on_duration)
+
+
+_first_verdict_at: Optional[float] = None
+
+
+def _first_verdict() -> None:
+    global _first_verdict_at
+    _first_verdict_at = time.time()
+    TIME_TO_FIRST_VERDICT.set(_first_verdict_at - events.T0_EPOCH)
+
+
 class _NullFlight:
     __slots__ = ()
     eval_id = None
@@ -516,6 +754,8 @@ def eval_flight(path: str, n_pods: int, q: int, **attrs: Any) -> Iterator[Flight
         cells = flight.data.get("cells")
         if outcome == "ok" and cells and dt > 0:
             EVAL_CELLS_PER_SEC.set(cells / dt)
+        if outcome == "ok" and _first_verdict_at is None:
+            _first_verdict()
         flight.data["seconds"] = round(dt, 6)
         flight.data["outcome"] = outcome
         recorder.record(**flight.data)

@@ -32,8 +32,14 @@ span.  For that time, and only then, its B/E events are recorded too
 `TraceAnnotation.is_enabled()` call.  JAX is never imported from here:
 no capture can run in a process that has not imported it.
 
+From the import of telemetry until the start-up record closes (its
+first capture, `events.close_startup()`, or its cap) a span's B/E events
+are recorded as well, capture or none: `events.startup_spans()` is what
+the process did before its first request.  Closed, the record costs one
+more module-attribute read.
+
 `detail(name)` is a span that exists ONLY while a capture or a trace
-records: the steps of a request that take less than a span costs to
+records (the start-up record does not count): the steps of a request that take less than a span costs to
 aggregate are visible on a timeline and free otherwise.
 """
 
@@ -70,6 +76,18 @@ _ANNOTATION = None
 # what a trace annotation carries of a span's attributes: the viewer
 # shows them as the event's stats, and a long repr would bloat every event
 _SMALL = (bool, int, float, str)
+
+
+def _capture_number() -> int:
+    """The number of the profiler capture that records now, 0 where none
+    does: the first span (or completed span) to see a capture counts it,
+    the first to see it gone ends it."""
+    annotation = _ANNOTATION or _trace_annotation()
+    if annotation is not None and annotation.is_enabled():
+        return events.CAPTURE or events.begin_capture()
+    if events.CAPTURE:
+        events.end_capture()
+    return 0
 
 
 def _small(attrs: Dict[str, Any]) -> Dict[str, Any]:
@@ -109,14 +127,9 @@ class Span:
         if parent:
             self.path = f"{parent}/{name}"
         _tls.path = self.path
-        capture, annotation = 0, _ANNOTATION or _trace_annotation()
-        if annotation is not None and annotation.is_enabled():
-            capture = events.CAPTURE or events.begin_capture()
-        elif events.CAPTURE:
-            events.end_capture()
-        self._capture = capture
+        capture = self._capture = _capture_number()
         self._annotation = self._eval_id = None
-        if capture or events.ACTIVE:
+        if capture or events.ACTIVE or events.STARTUP:
             eval_id = self._eval_id = getattr(_tls, "eval_id", None)
             events.record(
                 "B", name, self.path, self.attrs,
@@ -127,7 +140,8 @@ class Span:
                 if eval_id is not None:
                     shown["eval_id"] = eval_id
                 self._shown = tuple(shown)
-                self._annotation = annotation("cyclonus." + name, **shown)
+                # a capture records, so _ANNOTATION is the class by now
+                self._annotation = _ANNOTATION("cyclonus." + name, **shown)
                 self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
@@ -143,7 +157,7 @@ class Span:
             self._annotation.__exit__(*exc)
         _tls.path = self._parent
         REGISTRY.record(self.path, self.name, dt, self.attrs)
-        if self._capture or events.ACTIVE:
+        if self._capture or events.ACTIVE or events.STARTUP:
             # exit carries the FINAL attrs (s.set() calls inside the block)
             events.record(
                 "E", self.name, self.path, self.attrs,
@@ -299,6 +313,31 @@ def detail(name: str, **attrs: Any):
         if annotation is not None and annotation.is_enabled():
             return Span(name, attrs)
     return _NULL_SPAN
+
+
+def completed(name: str, dur_s: float, **attrs: Any) -> None:
+    """Record a span that is over already and ended now, as a child of
+    the current thread's active span: what a callback hears of only when
+    it is done (a JAX compile stage, instruments.watch_jax_compiles).
+    The registry gets it as it gets any span; a timeline that is being
+    kept (the start-up record, a capture, an ACTIVE trace) gets its B/E
+    pair, the B dated back by the span's length."""
+    if not state.ENABLED:
+        return
+    parent = getattr(_tls, "path", "")
+    path = f"{parent}/{name}" if parent else name
+    REGISTRY.record(path, name, dur_s, attrs)
+    capture = _capture_number()
+    if capture or events.ACTIVE or events.STARTUP:
+        eval_id = getattr(_tls, "eval_id", None)
+        events.record(
+            "B", name, path, attrs, capture=capture, eval_id=eval_id,
+            ts=time.time() - dur_s,
+        )
+        events.record(
+            "E", name, path, attrs, capture=capture, eval_id=eval_id,
+            dur_s=dur_s,
+        )
 
 
 class evaluation:

@@ -36,10 +36,12 @@ class BoundedRing:
         self._items: deque = deque(maxlen=maxlen)  # guarded-by: self._lock
         self._appended = 0  # guarded-by: self._lock (lifetime total)
 
-    def append(self, item: Any) -> None:
+    def append(self, item: Any) -> int:
+        """Returns the lifetime append count with this item in it."""
         with self._lock:
             self._items.append(item)
             self._appended += 1
+            return self._appended
 
     def snapshot(self) -> List[Any]:
         """Oldest-to-newest copy of the current window."""

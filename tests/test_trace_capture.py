@@ -8,6 +8,9 @@
   * the span tree of the three routes the benchmark's cells run;
   * with no capture nothing is recorded and no annotation is built, and
     a `detail` span does not exist;
+  * the START-UP RECORD: spans are kept from the import on, capture or
+    none, until the first capture, `close_startup()` or the cap; the
+    ring's other readers never see them; JAX's compiles lie in it;
   * importing telemetry, and scraping it, leave JAX alone.
 """
 
@@ -19,6 +22,7 @@ import os
 import random
 import subprocess
 import sys
+import time
 
 import jax
 import numpy as np
@@ -35,10 +39,14 @@ from cyclonus_tpu.utils.bounded import BoundedRing  # noqa: E402
 
 @pytest.fixture(autouse=True)
 def _clean_events():
+    # every test starts as a process long past its start-up: the record
+    # closed (TestStartupRecord opens one of its own)
     events.disable()
+    events.close_startup()
     events.reset()
     yield
     events.disable()
+    events.close_startup()
     events.reset()
 
 
@@ -229,6 +237,329 @@ class TestCaptureSpans:
         assert all(sp["dur_s"] > 0 for sp in found["spans"])
 
 
+@pytest.fixture
+def record():
+    """An open start-up record on an empty ring, as a fresh process has."""
+    events._open_startup()
+    return events.startup_spans
+
+
+def _event(ph, name, path, ts, tid=1, **more):
+    return {"ph": ph, "name": name, "path": path, "ts": ts, "pid": 7,
+            "tid": tid, **more}
+
+
+class TestStartupRecord:
+    def test_it_holds_a_span_run_before_any_capture(self, record):
+        with span("boot.outer", pods=3) as s:
+            with span("boot.inner"):
+                pass
+            s.set(targets=2)
+        found = record()
+        assert found["closed_by"] is None and found["wrapped"] is False
+        assert found["events"] == 4
+        assert [sp["path"] for sp in found["spans"]] == [
+            "boot.outer", "boot.outer/boot.inner",
+        ]
+        outer, inner = found["spans"]
+        assert outer["attrs"] == {"pods": 3, "targets": 2}
+        assert outer["thread"] == inner["thread"] != None  # noqa: E711
+        assert outer["start_s"] <= inner["start_s"]
+        assert inner["dur_s"] <= outer["dur_s"]
+        # the process's start, on the spans' clock, is behind us
+        assert 0 < found["t0_epoch"] <= outer["start_s"]
+        # nothing else that reads the ring sees any of it
+        assert events.entries() == [] and events.since(0) == []
+        assert events.capture_spans()["spans"] == []
+
+    @pytest.mark.parametrize("by", ["capture", "call", "cap"])
+    def test_it_closes_for_good_and_records_nothing_after(
+        self, record, tmp_path, monkeypatch, by
+    ):
+        monkeypatch.setattr(events, "STARTUP_CAP", 6)
+        with span("boot.kept"):
+            pass
+        assert events.STARTUP is True
+        if by == "capture":
+            with capture(tmp_path):
+                with span("cap.first"):
+                    pass
+            # the record is what preceded the capture's first span,
+            # and the capture holds that span
+            assert [
+                sp["name"] for sp in events.capture_spans()["spans"]
+            ] == ["cap.first"]
+        elif by == "call":
+            events.close_startup()
+        else:
+            for _ in range(2):
+                with span("boot.kept"):
+                    pass
+            assert record()["events"] == 6
+        assert events.STARTUP is False
+        with span("boot.late"):
+            pass
+        found = record()
+        assert found["closed_by"] == by
+        assert {sp["name"] for sp in found["spans"]} == {"boot.kept"}
+        assert len(found["spans"]) == (3 if by == "cap" else 1)
+        events.close_startup("call")  # a closed record stays as it closed
+        assert record()["closed_by"] == by
+
+    def test_both_doors_pair_one_event_list_identically(self, monkeypatch):
+        """startup_spans() and capture_spans() are one pairing function:
+        the same hand-made events, tagged for the one and for the other,
+        come back as the same spans."""
+        plain = [
+            _event("B", "a", "a", 10.0, args={"x": 1}),
+            _event("B", "b", "a/b", 10.5, eval_id=4),
+            _event("B", "t", "t", 10.6, tid=2),            # another thread
+            _event("E", "b", "a/b", 11.0, eval_id=4, dur_s=0.5),
+            _event("E", "c", "a/c", 12.0, dur_s=0.25),     # its B was dropped
+            _event("E", "a", "a", 13.0, args={"x": 1, "y": 2}, dur_s=3.0),
+            _event("B", "open", "open", 14.0),             # never closed
+            _event("E", "t", "t", 15.0, tid=2, dur_s=4.4),
+        ]
+        want = [
+            ("a", 10.0, 3.0, {"x": 1, "y": 2}, None, 1),
+            ("a/b", 10.5, 0.5, {}, 4, 1),
+            ("t", 10.6, 4.4, {}, None, 2),
+            ("a/c", 11.75, 0.25, {}, None, 1),
+        ]
+
+        def through(tag):
+            monkeypatch.setattr(events, "RING", BoundedRing(64))
+            for e in plain:
+                events.RING.append({**e, **tag})
+
+        def shape(spans):
+            return [
+                (sp["path"], sp["start_s"], sp["dur_s"], sp["attrs"],
+                 sp["eval_id"], sp["thread"])
+                for sp in spans
+            ]
+
+        through({"startup": "only"})
+        events._open_startup()
+        events._STARTUP["first"] = 0
+        from_record = events.startup_spans()["spans"]
+        through({"capture": 1})
+        monkeypatch.setattr(events, "_capture_seen", 1)
+        monkeypatch.setattr(events, "_CAPTURE_STARTS", {1: 0})
+        from_capture = events.capture_spans()["spans"]
+        assert shape(from_record) == shape(from_capture) == want
+        assert from_record == from_capture == events.pair_spans(plain)
+
+    def test_wrapped_when_the_ring_has_dropped_the_first_event(
+        self, monkeypatch
+    ):
+        monkeypatch.setattr(events, "RING", BoundedRing(8))
+        events._open_startup()
+        for _ in range(4):
+            with span("boot.fits"):
+                pass
+        assert events.startup_spans()["wrapped"] is False
+        with span("boot.one_more"):
+            pass
+        found = events.startup_spans()
+        assert found["wrapped"] is True and found["events"] == 8
+        assert all(sp["dur_s"] > 0 for sp in found["spans"])
+
+    def test_a_trace_shares_the_events_and_a_worker_ships_none_of_ours(
+        self, record
+    ):
+        with span("boot.untraced"):
+            pass
+        marker = events.mark()
+        events.enable("t")
+        with span("boot.traced"):
+            pass
+        events.disable()
+        # one event a B or E, wanted twice: the record has both spans,
+        # the trace's readers the traced one alone
+        assert events.RING.appended == 4
+        assert [sp["name"] for sp in record()["spans"]] == [
+            "boot.untraced", "boot.traced",
+        ]
+        assert [e["name"] for e in events.entries()] == ["boot.traced"] * 2
+        shipped = events.since(marker)
+        assert [e["name"] for e in shipped] == ["boot.traced"] * 2
+        # a worker's events come in without its record's tag
+        foreign = [{**e, "pid": e["pid"] + 1} for e in shipped]
+        assert events.ingest(foreign) == 2
+        assert len(record()["spans"]) == 2
+        assert len(events.entries()) == 4
+
+    def test_with_telemetry_off_nothing_is_recorded(self):
+        code = (
+            "from cyclonus_tpu.telemetry import events\n"
+            "from cyclonus_tpu.telemetry.spans import span, completed\n"
+            "assert events.STARTUP is False\n"
+            "with span('boot.x'):\n"
+            "    pass\n"
+            "completed('jax.compile', 0.5, stage='trace')\n"
+            "found = events.startup_spans()\n"
+            "assert found['spans'] == [] and found['events'] == 0, found\n"
+            "assert events.RING.appended == 0\n"
+        )
+        subprocess.run(
+            [sys.executable, "-c", code], check=True, cwd=REPO, timeout=120,
+            env={**os.environ, "CYCLONUS_TELEMETRY": "0"},
+        )
+
+    def test_one_compile_for_one_new_jit_and_none_for_its_second_call(
+        self, record
+    ):
+        from cyclonus_tpu.engine import first_import
+        from cyclonus_tpu.telemetry import instruments as ti
+
+        first_import()  # JAX is in: this registers the listener, once
+        first_import()
+
+        def compiles():
+            return sum(
+                ti.JAX_COMPILES.value(cache=c)
+                for c in ("hit", "miss", "uncached")
+            )
+
+        def stages():
+            return [
+                sp["attrs"]["stage"] for sp in record()["spans"]
+                if sp["name"] == "jax.compile"
+            ]
+
+        @jax.jit
+        def fresh(x):  # nested jits: their traces are the outer one's
+            return jax.numpy.sum(jax.numpy.sort(x * 3 + 1))
+
+        before, seconds = compiles(), ti.JAX_COMPILE_SECONDS.value(
+            stage="backend_compile"
+        )
+        with span("boot.program"):
+            fresh(np.arange(7.0)).block_until_ready()
+        assert compiles() == before + 1
+        assert ti.JAX_COMPILE_SECONDS.value(stage="backend_compile") > seconds
+        assert stages() == ["trace", "lower", "backend_compile"]
+        compiled = [
+            sp for sp in record()["spans"] if sp["name"] == "jax.compile"
+        ]
+        (outer,) = by_name(record(), "boot.program")
+        for sp in compiled:
+            assert sp["path"] == "boot.program/jax.compile"
+            assert outer["start_s"] <= sp["start_s"]
+            assert sp["dur_s"] > 0 and "fresh" in sp["attrs"]["fun"]
+        assert compiled[-1]["attrs"]["cache"] in ("hit", "miss", "uncached")
+        fresh(np.arange(7.0)).block_until_ready()
+        assert compiles() == before + 1 and len(stages()) == 3
+
+    def test_the_gauges_are_set_when_the_record_closes(self, record):
+        from cyclonus_tpu.telemetry import instruments as ti
+
+        ti._STARTUP.final = False
+        with span("engine.new", pods=1):
+            with span("startup.import", module="jax"):
+                time.sleep(0.02)
+            with span("startup.backend"):
+                time.sleep(0.01)
+            time.sleep(0.01)
+        with span("engine.eval"):
+            with span("engine.program"):
+                time.sleep(0.01)
+        with span("engine.eval"):   # only the first evaluation counts
+            time.sleep(0.05)
+        open_ = ti.startup_phases()
+        assert open_["import"] >= 0.02 and open_["backend"] >= 0.01
+        assert 0.01 <= open_["engine"] < 0.02 + 0.01 + 0.01
+        assert open_["program"] >= 0.01 and open_["first_eval"] < 0.01
+        assert open_["matcher"] == 0.0
+        # a scrape refreshes them while the record is open ...
+        assert "cyclonus_tpu_startup_seconds" in telemetry.render_prometheus()
+        assert ti.STARTUP_SECONDS.value(phase="import") == open_["import"]
+        with span("startup.import", module="jax.experimental.pallas"):
+            time.sleep(0.01)
+        events.close_startup()
+        # ... and the close sets them for good
+        closed = ti.STARTUP_SECONDS.value(phase="import")
+        assert closed >= open_["import"] + 0.01
+        events.reset()
+        telemetry.render_prometheus()
+        assert ti.STARTUP_SECONDS.value(phase="import") == closed
+        lines = ti.render_startup().splitlines()
+        assert lines[-1].startswith("time_to_first_verdict")
+
+    def test_instants_are_shared_out_once_to_the_innermost_named_span(self):
+        from cyclonus_tpu.telemetry.instruments import exclusive_seconds
+
+        def sp(name, start, dur):
+            return {"name": name, "start_s": start, "dur_s": dur}
+
+        got = exclusive_seconds(
+            [sp("a", 0, 10), sp("b", 1, 2), sp("c", 1.5, 1), sp("b", 5, 1),
+             sp("unnamed", 3, 1), sp("a", 20, 1),
+             # a compile inside a compile: counted once
+             sp("b", 30, 4), sp("b", 31, 2),
+             # two that start together: the longer is the outer one
+             sp("c", 40, 1), sp("a", 40, 3)],
+            {"a": "A", "b": "B", "c": "C"},
+        )
+        assert got == pytest.approx({"A": 10.0, "B": 6.0, "C": 2.0})
+
+    def test_the_sharing_out_is_clipped_to_its_window(self):
+        from cyclonus_tpu.telemetry.instruments import exclusive_seconds
+
+        def sp(name, start, dur):
+            return {"name": name, "start_s": start, "dur_s": dur}
+
+        got = exclusive_seconds(
+            [sp("a", -2, 5),      # began before lo: counted from lo
+             sp("b", 4, 2), sp("b", 5, 0),
+             sp("a", 9, 4),       # runs past hi: counted up to hi
+             sp("c", 12, 1)],     # outside the window: there, with 0.0
+            {"a": "A", "b": "B", "c": "C"}, 0.0, 10.0,
+        )
+        assert got == pytest.approx({"A": 4.0, "B": 2.0, "C": 0.0})
+
+    def test_a_fresh_process_records_its_imports_backend_and_engine(self):
+        code = (
+            "import random, sys, threading\n"
+            "from cyclonus_tpu.engine import PortCase, TpuPolicyEngine\n"
+            "assert 'jax' not in sys.modules\n"
+            "from cyclonus_tpu.matcher import build_network_policies\n"
+            "from cyclonus_tpu.synthetic import build_synthetic\n"
+            "from cyclonus_tpu.telemetry import events, instruments as ti\n"
+            "pods, namespaces, policies = build_synthetic(\n"
+            "    64, 8, random.Random(3))\n"
+            "eng = TpuPolicyEngine(\n"
+            "    build_network_policies(True, policies), pods, namespaces)\n"
+            "TpuPolicyEngine(\n"
+            "    build_network_policies(True, policies), pods, namespaces)\n"
+            "assert ti.TIME_TO_FIRST_VERDICT.value() == 0\n"
+            "eng.evaluate_grid([PortCase(80, '', 'TCP')]).combined\n"
+            "found = events.startup_spans()\n"
+            "paths = [sp['path'] for sp in found['spans']]\n"
+            "assert paths.count('engine.new/startup.import') >= 1, paths\n"
+            "assert paths.count('engine.new/startup.backend') == 1, paths\n"
+            "assert paths.count('engine.new') == 2, paths\n"
+            "assert 'engine.new/engine.encode' in paths\n"
+            "modules = [sp['attrs']['module'] for sp in found['spans']\n"
+            "           if sp['name'] == 'startup.import']\n"
+            "assert modules[0] == 'jax' and len(set(modules)) == len(modules)\n"
+            "(backend,) = [sp for sp in found['spans']\n"
+            "              if sp['name'] == 'startup.backend']\n"
+            "assert backend['attrs'] == {'platform': 'cpu', 'devices': 1}\n"
+            "assert any(sp['name'] == 'jax.compile' for sp in found['spans'])\n"
+            "phases = ti.startup_phases()\n"
+            "assert phases['import'] > 0 and phases['engine'] > 0, phases\n"
+            "age = ti.TIME_TO_FIRST_VERDICT.value()\n"
+            "assert sum(phases.values()) < age < 600, (phases, age)\n"
+        )
+        subprocess.run(
+            [sys.executable, "-c", code], check=True, cwd=REPO, timeout=300,
+            env={**os.environ, "JAX_PLATFORMS": "cpu", "XLA_FLAGS": "",
+                 "CYCLONUS_AOT_CACHE": "0", "CYCLONUS_JAX_CACHE": "0"},
+        )
+
+
 class TestEvaluationSpans:
     def test_one_eval_id_an_evaluation_with_its_fetches(self, engine, tmp_path):
         eng, cases = engine
@@ -326,7 +657,7 @@ class TestEvaluationSpans:
         assert build["attrs"]["targets"] > 0
         inside_encode = [
             sp["name"] for sp in found["spans"]
-            if sp["path"].startswith("engine.encode/")
+            if sp["path"].startswith("engine.new/engine.encode/")
         ]
         want = ["engine.encode_policy", "engine.build_tensors", "engine.compact"]
         if class_compress == "1":
@@ -335,6 +666,10 @@ class TestEvaluationSpans:
                 "engine.class_tensors",
             ]
         assert inside_encode == want + ["engine.class_tensors"]
+        # the constructor is one span, the parent of all of that
+        (new,) = by_name(found, "engine.new")
+        assert new["path"] == "engine.new"
+        assert new["attrs"]["pods"] == len(pods) and new["attrs"]["targets"] > 0
         (root,) = by_name(found, "engine.eval")
         route = "grid.classes" if class_compress == "1" else "grid"
         assert root["attrs"]["route"] == route
