@@ -11,6 +11,7 @@ from __future__ import annotations
 import ipaddress
 import threading
 import time
+import weakref
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -59,7 +60,10 @@ class GridVerdict:
     the device four times slower than a 32-bit one (the runtime un-tiles
     either on the host), and on the host the words' bytes ARE the
     boolean table, so `ingress` / `egress` / `combined` are bool
-    [Q, N, N] in either form, with no host copy."""
+    [Q, N, N] in either form, with no host copy.  A row-sharded table's
+    host memory is lent by `_host_buffers` and goes back for the next
+    table when its last view is gone, the GridVerdict's or a caller's
+    (PR 37): a table a caller holds is never written again."""
 
     def __init__(
         self, pod_keys, port_cases, ingress_dev, egress_dev, combined_dev,
@@ -100,10 +104,13 @@ class GridVerdict:
                         dev.block_until_ready()
                 with phase("grid.copy") as sp:
                     shards = _shards_of(dev)
-                    out = _copy_shards(dev, shards) if shards else np.asarray(dev)
+                    if shards:
+                        out, recycled = _copy_shards(dev, shards)
+                    else:
+                        out, recycled = np.asarray(dev), False
                     sp.set(
                         bytes=out.nbytes, dtype=str(out.dtype),
-                        shards=len(shards) or 1,
+                        shards=len(shards) or 1, recycled=int(recycled),
                     )
                 if words:
                     from .kernel import host_cells
@@ -195,9 +202,11 @@ def _shards_of(dev) -> Sequence:
 
 
 #: a shard is laid into its table's host buffer by up to this many
-#: threads, a piece of about _LAY_BYTES each: the buffer's pages are fresh,
-#: and first touching 9.7 GB of them from one thread took 10 s of a 13 s
-#: request on the four-chip host, four threads 4.3 (my chip run, PR 28)
+#: threads, a piece of about _LAY_BYTES each.  History (my chip run, PR 28):
+#: the buffer's pages were fresh then, and first touching 9.7 GB of them
+#: from one thread took 10 s of a 13 s request on the four-chip host, four
+#: threads 4.3; since PR 37 only a process's first tables meet fresh pages
+#: (_HostBuffers), and the threads share out a plain copy
 _LAY_THREADS = 16
 _LAY_BYTES = 32 << 20
 _lay_pool = None
@@ -205,7 +214,8 @@ _lay_pool = None
 
 def _lay(out: np.ndarray, index, piece: np.ndarray) -> None:
     """out[index] = piece; a large piece in row blocks on the lay threads
-    (numpy copies with the GIL released)."""
+    (numpy copies with the GIL released).  `out` is a buffer of
+    _host_buffers: its pages are mapped already when it is a recycled one."""
     global _lay_pool
     dst = out[index]
     if piece.nbytes <= _LAY_BYTES:
@@ -229,25 +239,106 @@ def _lay(out: np.ndarray, index, piece: np.ndarray) -> None:
         job.result()
 
 
-def _copy_shards(dev, shards) -> np.ndarray:
+#: free host buffers _HostBuffers keeps for the next sharded table: one
+#: verdict's worth (ingress, egress, combined), all of the shape asked for
+#: last.  THE COST: a process that has fetched sharded tables keeps up to
+#: 3 x a table's bytes mapped after its last verdict is dropped (4.85 GB
+#: at 40,000 pods and one port case), until it exits or fetches a table of
+#: another shape
+_FREE_BUFFERS = 3
+
+
+class _TableMemory:
+    """The owner of one sharded table's host memory while anything views
+    it.  The table `_HostBuffers.take` hands out is `np.asarray` of this
+    object, so the `base` chain of every view of the table (the boolean
+    view `kernel.host_cells` makes, a caller's slice, a memoryview) ends
+    here, and this object dies with the last of them: only then does its
+    `weakref.finalize` hand the memory back."""
+
+    __slots__ = ("__array_interface__", "__weakref__")
+
+    def __init__(self, raw: np.ndarray):
+        self.__array_interface__ = raw.__array_interface__
+
+
+class _HostBuffers:
+    """The host buffers sharded tables are laid into (`_copy_shards`),
+    recycled: `np.empty` of a 1.62 GB table is a fresh mmap whose every
+    page the lay threads fault in and the drop unmaps again, the larger
+    half of a four-chip request's copy (PERF.md section 6, PR 37).  A
+    buffer comes back when NOTHING can read it any more (_TableMemory), is
+    handed out again with the last table's bytes in it (the shards cover
+    every byte), and at most _FREE_BUFFERS free ones are kept, of the
+    shape asked for last alone.  One lock, re-entrant because a finalizer
+    may run wherever the collector does, inside `take` too."""
+
+    def __init__(self):
+        self._lock = threading.RLock()
+        self._key = None
+        self._free: List[np.ndarray] = []
+
+    def take(self, shape, dtype) -> Tuple[np.ndarray, bool]:
+        """(a writable buffer of that shape, whether it is a recycled
+        one); its bytes are whatever the memory held."""
+        key = (tuple(shape), np.dtype(dtype))
+        with self._lock:
+            if key != self._key:
+                # the key first: a buffer of the old shape that comes back
+                # from here on is dropped, not kept under the new one
+                self._key = key
+                self._free.clear()
+            raw = self._free.pop() if self._free else None
+        recycled = raw is not None
+        if not recycled:
+            raw = np.empty(shape, dtype)
+        ti.GRID_HOST_BUFFER.inc(outcome="recycled" if recycled else "fresh")
+        owner = _TableMemory(raw)
+        # the finalizer's argument is what keeps the memory alive
+        weakref.finalize(owner, self._give_back, key, raw).atexit = False
+        return np.asarray(owner), recycled
+
+    def _give_back(self, key, raw: np.ndarray) -> None:
+        with self._lock:
+            if key == self._key and len(self._free) < _FREE_BUFFERS:
+                self._free.append(raw)
+
+    def free_bytes(self) -> int:
+        """Bytes of the free buffers held now."""
+        with self._lock:
+            return sum(raw.nbytes for raw in self._free)
+
+
+_host_buffers = _HostBuffers()
+
+
+def _copy_shards(dev, shards) -> Tuple[np.ndarray, bool]:
     """A sharded table on the host: ONE buffer of the final shape, each
     shard laid into its place (span `grid.shard_copy`, one a shard: the
-    wait for the shard's own transfer and un-tiling, then _lay).  The
-    shards' transfers are all started first, so the runtime brings the
-    later ones over while the earlier ones are laid in place.  JAX hands
-    a shard over in a buffer of the runtime's, so every byte is written
-    twice on the host; there is no call that names a destination (and
-    JAX keeps each shard's host copy with the shard, so the host holds
-    the table twice until the GridVerdict is dropped)."""
+    wait for the shard's own transfer and un-tiling, then _lay, whose
+    part of the span is `lay_ms`); returns the table and whether its
+    buffer was a recycled one.  The shards' transfers are all started
+    first, so the runtime brings the later ones over while the earlier
+    ones are laid in place.  JAX hands a shard over in a buffer of the
+    runtime's, so every byte is written twice on the host; there is no
+    call that names a destination (and JAX keeps each shard's host copy
+    with the shard, so the host holds the table twice until the
+    GridVerdict is dropped).  The buffer is _host_buffers': the memory of
+    a table nobody holds any more where there is one, fresh pages
+    otherwise."""
     for sh in shards:
         sh.data.copy_to_host_async()
-    out = np.empty(dev.shape, dev.dtype)
+    out, recycled = _host_buffers.take(dev.shape, dev.dtype)
     for sh in shards:
         with detail("grid.shard_copy", device=sh.device.id) as sp:
             piece = np.asarray(sh.data)
+            t0 = time.perf_counter()
             _lay(out, sh.index, piece)
-            sp.set(bytes=piece.nbytes, dtype=str(piece.dtype))
-    return out
+            sp.set(
+                bytes=piece.nbytes, dtype=str(piece.dtype),
+                lay_ms=(time.perf_counter() - t0) * 1e3,
+            )
+    return out, recycled
 
 
 def _direction_tensors(enc: _DirectionEncoding) -> Dict:

@@ -89,6 +89,14 @@ MESH_DISPATCH_BYTES = REGISTRY.counter(
     "`engine.dispatch_sharded` span, summed).",
     labelnames=("route",),
 )
+GRID_HOST_BUFFER = REGISTRY.counter(
+    "cyclonus_tpu_grid_host_buffer_total",
+    "Host buffers handed to sharded tables' readbacks "
+    "(engine/api.py _HostBuffers), one a table: recycled (the memory of "
+    "a table nothing views any more, its pages mapped already) or fresh "
+    "(np.empty: every page is faulted in while the shards are laid).",
+    labelnames=("outcome",),
+)
 MESH_RING_STEP_SECONDS = REGISTRY.gauge(
     "cyclonus_tpu_mesh_ring_step_seconds",
     "Per-hop seconds of the last pipelined ring-counts eval "

@@ -7,7 +7,9 @@ handing it boolean tables.  Either way the caller sees bool [Q, N, N], held
 here to the scalar oracle."""
 
 import functools
+import gc
 import random
+import threading
 
 import numpy as np
 import pytest
@@ -19,6 +21,7 @@ from cyclonus_tpu.engine.kernel import WORD_CELLS, WORD_TILE
 from cyclonus_tpu.matcher import build_network_policies
 from cyclonus_tpu.matcher.tiered import tiered_oracle_verdicts
 from cyclonus_tpu.synthetic import build_synthetic, tiers_lattice
+from cyclonus_tpu.telemetry import instruments as ti
 
 from test_engine_sharded import cpu_mesh
 
@@ -205,6 +208,224 @@ def test_a_large_shard_is_laid_into_the_table_in_row_blocks(monkeypatch):
     # 3 tables x 4 shards x 3 port cases, a shard's 40 rows of 128 words in
     # blocks of 8 rows (4 KiB)
     assert blocks == [(8, 128)] * (3 * 4 * 3 * 5)
+
+
+@pytest.fixture
+def holder(monkeypatch):
+    """A holder of host buffers of the test's own (the module's one is
+    shared by every sharded fetch of the process)."""
+    from cyclonus_tpu.engine import api
+
+    fresh = api._HostBuffers()
+    monkeypatch.setattr(api, "_host_buffers", fresh)
+    return fresh
+
+
+def handed_out() -> dict:
+    return {
+        o: ti.GRID_HOST_BUFFER.value(outcome=o) for o in ("recycled", "fresh")
+    }
+
+
+def address(table: np.ndarray) -> int:
+    return table.__array_interface__["data"][0]
+
+
+def sharded_engine(n: int, class_compress: str = "1"):
+    policy, pods, namespaces, single = cluster(n)
+    engine = TpuPolicyEngine(
+        policy, pods, namespaces, class_compress=class_compress
+    )
+    return engine, single
+
+
+def test_a_dropped_verdicts_memory_serves_the_next_one(holder):
+    """Once a verdict AND its tables are gone, the next sharded tables of
+    that shape are laid into the same memory: no fresh pages."""
+    engine, single = sharded_engine(130)
+    mesh = cpu_mesh(4)
+    grid = engine.evaluate_grid_sharded(CASES, mesh=mesh)
+    tables = [getattr(grid, name) for name in TABLES]
+    first = sorted(address(t) for t in tables)
+    nbytes = tables[0].base.nbytes
+    assert len(set(first)) == 3 and holder.free_bytes() == 0
+    before = handed_out()
+    del grid
+    assert holder.free_bytes() == 0  # the tables outlive the verdict
+    del tables
+    gc.collect()
+    assert holder.free_bytes() == 3 * nbytes
+    grid = engine.evaluate_grid_sharded(CASES, mesh=mesh)
+    again = sorted(address(getattr(grid, name)) for name in TABLES)
+    assert again == first
+    assert handed_out() == {
+        "recycled": before["recycled"] + 3, "fresh": before["fresh"],
+    }
+    assert holder.free_bytes() == 0
+    for name in TABLES:
+        assert np.array_equal(getattr(grid, name), single[name]), name
+
+
+#: what a caller may keep of a table after the GridVerdict is gone
+VIEWS = {
+    "table": lambda t: t,
+    "slice": lambda t: t[0, 3:7],
+    "memoryview": memoryview,
+}
+
+
+@pytest.mark.parametrize("kept", list(VIEWS))
+def test_a_table_somebody_still_views_is_never_written_again(holder, kept):
+    """While any view of a table lives, a later evaluation of the same
+    shape takes other memory: the view's bytes stay the oracle's."""
+    n = 13
+    policy, pods, namespaces, _, want = problem(n, False)
+    engine = TpuPolicyEngine(policy, pods, namespaces, class_compress="1")
+    mesh = cpu_mesh(4)
+    grid = engine.evaluate_grid_sharded(CASES[:1], mesh=mesh)
+    held = [VIEWS[kept](getattr(grid, name)) for name in TABLES]
+    expected = [VIEWS[kept](want[name][:1]) for name in TABLES]
+    taken = {address(getattr(grid, name)) for name in TABLES}
+    del grid
+    gc.collect()
+    assert holder.free_bytes() == 0
+    before = handed_out()
+    # another case of the same shape: its tables differ from the first's
+    later = engine.evaluate_grid_sharded(CASES[1:2], mesh=mesh)
+    for name in TABLES:
+        table = getattr(later, name)
+        assert address(table) not in taken
+        assert np.array_equal(table, want[name][1:2]), name
+    assert handed_out() == {
+        "recycled": before["recycled"], "fresh": before["fresh"] + 3,
+    }
+    for view, oracle in zip(held, expected):
+        assert np.array_equal(np.asarray(view), np.asarray(oracle))
+    assert any(
+        not np.array_equal(want[name][:1], want[name][1:2]) for name in TABLES
+    )
+
+
+@pytest.mark.parametrize("route", list(MESH_ROUTES))
+def test_a_recycled_buffer_holds_the_new_request_alone(holder, route):
+    """A recycled buffer comes with the last table's bytes in it and the
+    shards cover every one: the words on the host, pad words included, are
+    the device's, and the table is evaluate_grid's, case set after case
+    set."""
+    class_compress, schedule = MESH_ROUTES[route]
+    engine, single = sharded_engine(130, class_compress)
+    mesh = cpu_mesh(4)
+    for turn, k in enumerate([0, 1, 2, 0]):
+        before = handed_out()
+        grid = engine.evaluate_grid_sharded(
+            CASES[k : k + 1], mesh=mesh, schedule=schedule
+        )
+        for name in TABLES:
+            table = getattr(grid, name)
+            assert np.array_equal(table, single[name][k : k + 1]), (name, k)
+            words = table.base
+            assert words.dtype == np.uint32
+            # JAX's own assembly of the shards, into memory of its own
+            assert np.array_equal(
+                words, np.asarray(getattr(grid, name + "_dev"))
+            ), (name, k)
+        outcome = "recycled" if turn else "fresh"
+        assert handed_out()[outcome] == before[outcome] + 3
+        del grid, table, words
+        gc.collect()
+    assert any(
+        not np.array_equal(single[name][0], single[name][1]) for name in TABLES
+    )
+
+
+def test_the_holder_keeps_three_free_buffers_of_the_last_shape():
+    from cyclonus_tpu.engine import api
+
+    assert api._FREE_BUFFERS == 3
+    holder = api._HostBuffers()
+    shape, other = (1, 32, 128), (2, 32, 128)
+    one = int(np.prod(shape)) * 4
+    taken = [holder.take(shape, np.uint32) for _ in range(4)]
+    assert [recycled for _, recycled in taken] == [False] * 4
+    assert all(
+        buf.shape == shape and buf.dtype == np.uint32 and buf.flags.writeable
+        for buf, _ in taken
+    )
+    assert holder.free_bytes() == 0
+    for k in range(4):
+        taken.pop()
+        # the fourth free buffer is released at once
+        assert holder.free_bytes() == min(k + 1, 3) * one
+    buf, recycled = holder.take(shape, np.uint32)
+    assert recycled and holder.free_bytes() == 2 * one
+    # another shape (or dtype) evicts every free buffer ...
+    wide, recycled = holder.take(other, np.uint32)
+    assert not recycled and wide.shape == other and holder.free_bytes() == 0
+    # ... and a buffer of the old shape that comes back now is not kept
+    del buf
+    assert holder.free_bytes() == 0
+    del wide
+    assert holder.free_bytes() == 2 * one
+    same_bytes, recycled = holder.take(other, np.int32)
+    assert not recycled and same_bytes.dtype == np.int32
+    assert holder.free_bytes() == 0
+
+
+def test_two_threads_fetching_two_verdicts_get_disjoint_buffers(holder):
+    engine, single = sharded_engine(130)
+    mesh = cpu_mesh(4)
+    # three free buffers to contend for
+    warm = engine.evaluate_grid_sharded(CASES, mesh=mesh)
+    nbytes = warm.combined.base.nbytes
+    _ = warm.ingress, warm.egress
+    del warm, _
+    gc.collect()
+    assert holder.free_bytes() == 3 * nbytes
+    grids = [engine.evaluate_grid_sharded(CASES, mesh=mesh) for _ in range(2)]
+    before = handed_out()
+    start = threading.Barrier(2)
+    failed = []
+
+    def fetch(grid):
+        try:
+            start.wait()
+            for name in TABLES:
+                getattr(grid, name)
+        except Exception as e:  # surfaced below
+            failed.append(e)
+
+    threads = [threading.Thread(target=fetch, args=(g,)) for g in grids]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert not failed
+    tables = [getattr(g, name) for g in grids for name in TABLES]
+    assert len({address(t) for t in tables}) == 6
+    for a in range(6):
+        for b in range(a + 1, 6):
+            assert not np.shares_memory(tables[a], tables[b])
+    assert handed_out() == {
+        "recycled": before["recycled"] + 3, "fresh": before["fresh"] + 3,
+    }
+    for g in grids:
+        for name in TABLES:
+            assert np.array_equal(getattr(g, name), single[name]), name
+
+
+@pytest.mark.parametrize("entry", ["evaluate_grid", "one_device_mesh"])
+def test_a_one_device_table_takes_no_buffer_from_the_holder(holder, entry):
+    engine, single = sharded_engine(130)
+    before = handed_out()
+    if entry == "evaluate_grid":
+        grid = engine.evaluate_grid(CASES)
+    else:
+        grid = engine.evaluate_grid_sharded(CASES, mesh=cpu_mesh(1))
+    for name in TABLES:
+        assert np.array_equal(getattr(grid, name), single[name]), name
+    del grid
+    gc.collect()
+    assert handed_out() == before and holder.free_bytes() == 0
 
 
 def boolean_grid(form: str):

@@ -604,8 +604,10 @@ class TestEvaluationSpans:
         assert fetch["attrs"] == {"table": "combined", "form": "words"}
         assert copy["attrs"] == {
             "bytes": words.nbytes, "dtype": "uint32", "shards": 1,
+            "recycled": 0,
         }
-        # a table on one device is copied whole: no shard copies
+        # a table on one device is copied whole: no shard copies, and its
+        # destination is the runtime's, not a buffer of the holder's
         assert by_name(found, "grid.shard_copy") == []
         assert table.dtype == np.bool_ and np.shares_memory(table, words)
         assert wait["dur_s"] + copy["dur_s"] <= fetch["dur_s"]
@@ -731,9 +733,13 @@ class TestMeshSpans:
         assert len(copies) == 3
         words = np.asarray(out.combined_dev)
         for copy in copies:
+            # recycled: 1 where an earlier test's tables of this shape
+            # are gone (the holder is the process's)
             assert copy["attrs"] == {
                 "bytes": words.nbytes, "dtype": "uint32", "shards": n_dev,
+                "recycled": copy["attrs"]["recycled"],
             }
+            assert copy["attrs"]["recycled"] in (0, 1)
         shard_copies = by_name(found, "grid.shard_copy")
         assert len(shard_copies) == 3 * n_dev
         assert {sp["path"] for sp in shard_copies} == {
@@ -746,6 +752,8 @@ class TestMeshSpans:
             assert sum(sp["attrs"]["bytes"] for sp in of_table) == words.nbytes
             assert {sp["attrs"]["dtype"] for sp in of_table} == {"uint32"}
             assert sum(sp["dur_s"] for sp in of_table) <= copies[k]["dur_s"]
+            for sp in of_table:
+                assert 0 <= sp["attrs"]["lay_ms"] <= sp["dur_s"] * 1e3
         # the evaluation and its fetches carry the evaluation's number
         # (the case tensors are made before the sharded evaluation opens)
         for sp in found["spans"]:
@@ -754,6 +762,53 @@ class TestMeshSpans:
         # one buffer of the final shape a table, viewed and not copied
         for table in tables:
             assert table.dtype == np.bool_ and table.base is not None
+
+    def test_the_copy_says_recycled_and_a_shard_copy_its_lay_ms(
+        self, cluster, tmp_path, monkeypatch
+    ):
+        """`grid.copy` carries `recycled` (1 where the table's buffer was
+        the memory of a table nothing views any more, the counter beside
+        it) and every `grid.shard_copy` `lay_ms`, the part of the span
+        that writes the shard into the buffer."""
+        from jax.sharding import Mesh
+
+        from cyclonus_tpu.engine import PortCase, TpuPolicyEngine, api
+        from cyclonus_tpu.matcher import build_network_policies
+        from cyclonus_tpu.telemetry import instruments as ti
+
+        monkeypatch.setattr(api, "_host_buffers", api._HostBuffers())
+        pods, namespaces, policies = cluster
+        eng = TpuPolicyEngine(
+            build_network_policies(True, policies), pods, namespaces,
+            class_compress="1",
+        )
+        cases = [PortCase(80, "serve-80-tcp", "TCP")]
+        mesh = Mesh(np.array(jax.devices("cpu")[:4]), ("x",))
+        before = {
+            o: ti.GRID_HOST_BUFFER.value(outcome=o) for o in ("fresh", "recycled")
+        }
+        for turn, outcome in enumerate(["fresh", "recycled"]):
+            with capture(tmp_path / outcome):
+                out = eng.evaluate_grid_sharded(cases, mesh=mesh)
+                tables = out.ingress, out.egress, out.combined
+            # two captures with no span between them read as one
+            found = {"spans": [
+                sp for sp in events.capture_spans()["spans"]
+                if sp["eval_id"] == out.eval_id
+            ]}
+            copies = by_name(found, "grid.copy")
+            assert [sp["attrs"]["recycled"] for sp in copies] == [turn] * 3
+            shard_copies = by_name(found, "grid.shard_copy")
+            assert len(shard_copies) == 3 * 4
+            for sp in shard_copies:
+                assert 0 <= sp["attrs"]["lay_ms"] <= sp["dur_s"] * 1e3
+            assert (
+                ti.GRID_HOST_BUFFER.value(outcome=outcome) == before[outcome] + 3
+            )
+            del out, tables
+        body = telemetry.render_prometheus()
+        assert 'cyclonus_tpu_grid_host_buffer_total{outcome="recycled"}' in body
+        assert 'cyclonus_tpu_grid_host_buffer_total{outcome="fresh"}' in body
 
     @pytest.mark.parametrize(
         "route,class_compress,schedule",
