@@ -787,6 +787,14 @@ def _bucket_tensors(tensors: Dict, headroom: int = 0) -> Dict:
 # multi-million-pod scale, where recomputing beats pinning HBM)
 _PRE_CACHE_MAX_BYTES = 2 << 30
 
+#: the mesh counts entry's route decision (_mesh_counts_route): the dense
+#: precompute may be REPLICATED on every chip (the source-row route) while
+#: one chip's copy of it, from the shapes, stays under this many bytes and
+#: under half of what the device reports as its memory; past it both pod
+#: axes stay sharded (the ring).  Half of a v5e chip's 16 GB: the program's
+#: temporaries (peer_allow as bf16 beside the boolean) take the other half
+_MESH_REPLICATED_MAX_BYTES = 8 << 30
+
 
 def _tree_nbytes(tree) -> int:
     """Bytes of a pytree's array leaves (.nbytes is a host-side
@@ -1209,6 +1217,12 @@ class TpuPolicyEngine:
             self._static_jit = None  # derived-from: shapes
             self._counts_cases_jit = None  # derived-from: shapes
             self._static_pre = None  # derived-from: buffer (static pytree)
+            # the mesh counts entry's held pair (tiled.mesh_counts_programs),
+            # one a (mesh, route, kernel, block), and the static half of the
+            # precompute its `static` program left on the chips: (key of the
+            # pair, static pytree, the pod count as a device scalar)
+            self._mesh_counts_jits = {}  # derived-from: shapes
+            self._mesh_static = None  # derived-from: buffer (key + static pytree)
             # gathered slab operands, cached next to the pre: building them
             # per dispatch cost more than the slab's depth cut saved
             self._slab_ops_jit = None  # derived-from: shapes
@@ -1261,6 +1275,7 @@ class TpuPolicyEngine:
         self._last_counts_key = None
         ti.PRE_CACHE_BYTES.set(0)
         self._static_pre = None
+        self._mesh_static = None
         ti.STATIC_PRE_BYTES.set(0)
         with self._slab_lock:
             self._slab_choice = None
@@ -3140,11 +3155,20 @@ class TpuPolicyEngine:
         mesh=None,
         kernel: str = None,
     ) -> Dict[str, int]:
-        """Mesh-parallel tiled counts: source rows split over the mesh,
-        per-device work, one all-gather of partials (engine/tiled.py).
-        The multi-chip path for grids past one device's wall-clock.
-        kernel="pallas" (the TPU default) runs the fused rectangular
-        verdict+count kernel per device; kernel="xla" the tile loop."""
+        """THE mesh counts entry: allow counts of the full grid over all
+        the devices of `mesh` (default: sharded.default_mesh), exact
+        int64 sums of int32 partials, one gather of partials a request.
+
+        It picks its route from what it can see.  A class state takes
+        the compressed route (counts.sharded.classes).  The dense route
+        REPLICATES the precompute and splits the source rows
+        (counts.sharded.pallas, the TPU default, or .xla under `kernel`)
+        where one chip holds the replicated precompute, else keeps both
+        pod axes sharded and rotates the dst-side bundle round the ring
+        (counts.ring): _mesh_counts_route, from the shapes, before
+        anything compiles.  Either way the dense program is HELD
+        (_counts_mesh): built once per engine state, the static half of
+        the precompute placed once, a request sends its port cases."""
         self._check_ips()
         n = self.encoding.cluster.n_pods
         if not cases or n == 0:
@@ -3155,8 +3179,6 @@ class TpuPolicyEngine:
             return self._counts_classes(
                 cases, n, sharded=True, block=block, mesh=mesh
             )
-        from .tiled import evaluate_grid_counts_sharded
-
         # tiers x per-device pallas: same matrix cell discipline as
         # evaluate_grid_counts — auto routes to the XLA tile body (it
         # carries the tier resolution epilogue), an explicit pallas
@@ -3164,10 +3186,135 @@ class TpuPolicyEngine:
         kernel = planspec.resolve_sharded_counts_kernel(
             kernel=kernel, tiers=self.tiers is not None
         )
-        return evaluate_grid_counts_sharded(
-            self._tensors_with_cases(cases), n, block=block, mesh=mesh,
-            kernel=kernel,
+        return self._counts_mesh(cases, n, block, mesh, kernel)
+
+    def _mesh_replicated_bytes(self, q: int, n_padded: int) -> int:
+        """One chip's bytes of the REPLICATED dense precompute at `q`
+        cases, from the shapes alone: the static half
+        (_static_pre_bytes), what a request keeps of the other
+        (_pre_bytes_estimate), and the largest temporary of
+        tiled._precompute_cases, a direction's `peer_allow` [P, N * Q]
+        as booleans and as bf16 for the one-hot matmul beside its
+        product [T, N * Q] bf16."""
+        t = self._tensors
+        temp = max(
+            3 * int(t[d]["peer_target"].shape[0])
+            + 2 * int(t[d]["target_ns"].shape[0])
+            for d in ("ingress", "egress")
         )
+        return (
+            self._static_pre_bytes()
+            + self._pre_bytes_estimate(q)
+            + temp * n_padded * q
+        )
+
+    def _mesh_counts_route(self, q: int, n_padded: int, mesh) -> Tuple[str, int, int]:
+        """(route, bytes, ceiling): "rows" (replicated precompute,
+        source rows split) where one chip's replicated bytes pass under
+        the ceiling - _MESH_REPLICATED_MAX_BYTES, and half the device's
+        memory where the backend reports it - else "ring"."""
+        need = self._mesh_replicated_bytes(q, n_padded)
+        ceiling = _MESH_REPLICATED_MAX_BYTES
+        stats = mesh.devices.flat[0].memory_stats() or {}
+        if stats.get("bytes_limit"):
+            ceiling = min(ceiling, int(stats["bytes_limit"]) // 2)
+        return ("rows" if need <= ceiling else "ring"), need, ceiling
+
+    def _counts_mesh(
+        self, cases: Sequence[PortCase], n: int, block: int, mesh, kernel
+    ) -> Dict[str, int]:
+        """One dense mesh counts request on the held pair: route from
+        the shapes, the pair and the static from the engine's state
+        (built where it has none: spans engine.program,
+        engine.static_pre), the port cases sent (engine.dispatch_sharded,
+        host_operands 1, host_bytes 12 x Q: the one host array, which
+        the runtime lays on every chip), the readback barrier
+        (engine.execute), the int64 host sum."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from .sharded import _pad_pod_arrays, default_mesh, mesh_device_context
+        from .tiled import (
+            _int32_safe_block,
+            mesh_counts_kernel,
+            mesh_counts_programs,
+        )
+
+        mesh = mesh or default_mesh()
+        n_dev = int(mesh.devices.size)
+        q = len(cases)
+        block = _int32_safe_block(min(block, max(n // n_dev, 1)), n, q)
+        kernel = mesh_counts_kernel(kernel)
+        step = n_dev * block
+        n_padded = -(-int(self._tensors["pod_ns_id"].shape[0]) // step) * step
+        route, need, ceiling = self._mesh_counts_route(q, n_padded, mesh)
+        if route == "ring":
+            path = "counts.ring"
+            planspec.record("counts.ring")
+        elif kernel == "pallas":
+            path = "counts.sharded.pallas"
+            planspec.record("counts.sharded.pallas")
+        else:
+            path = "counts.sharded.xla"
+            planspec.record("counts.sharded.xla")
+        key = (
+            tuple(mesh.devices.flat), tuple(mesh.axis_names), route,
+            kernel if route == "rows" else None, block,
+        )
+        replicated = NamedSharding(mesh, PartitionSpec())
+        with ti.eval_flight(
+            path, n, q, devices=n_dev, mode="held",
+            replicated_bytes=need, ceiling_bytes=ceiling,
+        ) as fl, mesh_device_context(mesh):
+            held = self._mesh_static
+            if key not in self._mesh_counts_jits or held is None or held[0] != key:
+                # the case-free tensors, padded to whole tiles a device:
+                # what the pair is traced over and what `static` is sent
+                tensors, _ = _pad_pod_arrays(self._tensors, n, step)
+            if key not in self._mesh_counts_jits:
+                self._mesh_counts_jits[key] = mesh_counts_programs(
+                    mesh, tensors, block, route, kernel, self._pack,
+                    self._aot_plan(),
+                )
+            static_fn, cases_fn = self._mesh_counts_jits[key]
+            if held is None or held[0] != key:
+                with phase("engine.static_pre", devices=n_dev) as sp:
+                    static = static_fn(tensors)
+                    # a chip's share: the ring's static is sharded
+                    nbytes = _tree_nbytes(static) // (
+                        n_dev if route == "ring" else 1
+                    )
+                    sp.set(bytes=nbytes)
+                self._mesh_static = (
+                    key, static, jax.device_put(np.int32(n), replicated)
+                )
+                ti.STATIC_PRE.inc(outcome="built")
+                ti.STATIC_PRE_BYTES.set(nbytes)
+            else:
+                ti.STATIC_PRE.inc(outcome="hit")
+            _, static, n32 = self._mesh_static
+            q_port, q_name, q_proto = self._port_case_arrays(cases)
+            sent = np.stack((q_port, q_name, q_proto))
+            shard = n_padded // n_dev
+            cases_fn.resolve(static, sent, n32)
+            with phase(
+                "engine.dispatch_sharded", route=route, devices=n_dev,
+                shard=shard,
+            ) as sp:
+                out = cases_fn(static, jax.device_put(sent, replicated), n32)
+                sp.set(host_operands=1, host_bytes=sent.nbytes)
+            ti.MESH_DISPATCH_BYTES.inc(sent.nbytes, route=route)
+            # the [tiles, 3] readback is the execution barrier: the
+            # chips' whole run lands here, not in the dispatch above
+            with phase("engine.execute"):
+                counts = np.asarray(out, dtype=np.int64).sum(axis=0)
+            fl.set(cells=q * n * n)
+        return {
+            "ingress": int(counts[0]),
+            "egress": int(counts[1]),
+            "combined": int(counts[2]),
+            "cells": q * n * n,
+        }
 
     def evaluate_grid_counts_ring(
         self, cases: Sequence[PortCase], block: int = 1024, mesh=None

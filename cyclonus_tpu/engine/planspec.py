@@ -523,6 +523,11 @@ def predict(entry: str, features: Mapping[str, object]) -> str:
         f["backend"] = backend
     elif entry == "counts_sharded":
         if not f.get("classes", False):
+            # the mesh counts entry's own decision (api._mesh_counts_route):
+            # a dense precompute that no chip holds replicated keeps both
+            # pod axes sharded, whatever the kernel
+            if f.get("replicated_fits") is False:
+                return "counts.ring"
             kernel = resolve_sharded_counts_kernel(
                 kernel=f.get("kernel"), tiers=bool(f.get("tiers", False))
             )

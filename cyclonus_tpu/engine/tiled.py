@@ -903,6 +903,19 @@ def _mesh_counts_setup(tensors: Dict, n_pods: int, block: int, mesh):
     return mesh, n_dev, q, block, tensors, n_padded
 
 
+def mesh_counts_kernel(kernel: str = None) -> str:
+    """The per-device kernel of the replicated source-row route: the
+    rectangular Pallas kernel on a TPU, the XLA tile loop elsewhere
+    (where Pallas would run in slow interpret mode), unless named."""
+    if kernel is None:
+        kernel = "pallas" if jax.default_backend() == "tpu" else "xla"
+    if kernel not in ("pallas", "xla"):
+        raise ValueError(
+            f"unknown sharded counts kernel {kernel!r} (want 'pallas' or 'xla')"
+        )
+    return kernel
+
+
 def _run_mesh_counts(
     per_device, mesh, in_specs, tensors: Dict, q: int, n_pods: int,
     path: str = "counts.mesh",
@@ -934,6 +947,222 @@ def _run_mesh_counts(
     }
 
 
+def _ring_counts(pre: Dict, n_pods, n_dev: int, shard: int, block: int):
+    """The pod-sharded ring's per-device body from the shard's `pre` on:
+    the src view stays local, the dst view (and its validity mask)
+    rotates, one _tile_counts_split a tile a step, then the ONE gather
+    of the [n_dev * tiles, 3] int32 partials.  With packing on the
+    rotating bundle carries the packed words (~16x fewer bytes a hop).
+    `n_pods` may be traced.  Shared by the per-call ring
+    (evaluate_grid_counts_ring) and the held program
+    (mesh_counts_programs)."""
+    tiles_per_shard = shard // block
+    row0 = jax.lax.axis_index("x") * shard
+    valid_local = (jnp.arange(shard) + row0) < n_pods  # [shard]
+    src, dst0 = _split_pre(pre)
+    ring = dict(dst0, valid=valid_local)
+
+    def body(step, ring, counts):
+        dst = {k: ring[k] for k in _dst_bundle_keys(ring)}
+
+        def tile(i, counts):
+            row = _tile_counts_split(
+                src, dst, valid_local, ring["valid"], i * block, block
+            )
+            return counts.at[step * tiles_per_shard + i].set(row)
+
+        return jax.lax.fori_loop(0, tiles_per_shard, tile, counts)
+
+    counts = jnp.zeros((n_dev * tiles_per_shard, 3), dtype=jnp.int32)
+    counts, _ = _ring_sweep(n_dev, ring, counts, body)
+    return jax.lax.all_gather(counts, "x", axis=0, tiled=True)
+
+
+def _row_counts(
+    pre: Dict, n_pods, n_dev: int, n_padded: int, block: int, kernel: str,
+    pack: bool,
+):
+    """The replicated source-row route's per-device body from the WHOLE
+    (replicated) `pre` on: this device's rows against every pod, by the
+    rectangular Pallas kernel (kernel="pallas") or the XLA tile loop,
+    then the ONE gather of the int32 partials.  `n_pods` may be traced.
+    Shared by the per-call program (evaluate_grid_counts_sharded) and
+    the held one (mesh_counts_programs)."""
+    shard = n_padded // n_dev
+    tiles_per_dev = shard // block
+    row0 = jax.lax.axis_index("x") * shard
+    valid = jnp.arange(n_padded) < n_pods
+
+    if kernel == "pallas":
+        from .pallas_kernel import (
+            _should_interpret,
+            verdict_counts_pallas_packed,
+            verdict_counts_pallas_rect,
+        )
+
+        e, ig = pre["egress"], pre["ingress"]
+        sl = partial(jax.lax.dynamic_slice_in_dim, start_index=row0)
+        # rect form: src = this device's row shard, dst = the full axis;
+        # the packed words slice on the pod axis like the dense operands
+        fn = verdict_counts_pallas_packed if pack else verdict_counts_pallas_rect
+        allow, match = (
+            ("tallow_pk", "tmatch_pk") if pack else ("tallow_bf", "tmatch")
+        )
+        partials = fn(
+            sl(e[match], slice_size=shard, axis=1),
+            sl(e["has_target"], slice_size=shard, axis=0),
+            e[allow],
+            ig[match],
+            ig["has_target"],
+            sl(ig[allow], slice_size=shard, axis=1),
+            valid_src=sl(valid, slice_size=shard, axis=0),
+            valid_dst=valid,
+            interpret=_should_interpret(),
+        )  # [Q, n_src_tiles_local, 3]
+        return jax.lax.all_gather(
+            partials.reshape(-1, 3), "x", axis=0, tiled=True
+        )
+
+    def body(i, counts):
+        return counts.at[i].set(
+            _tile_counts(pre, valid, row0 + i * block, block)
+        )
+
+    counts = jax.lax.fori_loop(
+        0,
+        tiles_per_dev,
+        body,
+        jnp.zeros((tiles_per_dev, 3), dtype=jnp.int32),
+    )
+    # one collective: gather every device's per-tile partials so the
+    # host can sum them in int64 (device int32 would overflow first)
+    return jax.lax.all_gather(counts, "x", axis=0, tiled=True)
+
+
+# --- the HELD mesh counts program ------------------------------------------
+#
+# What the one-chip dense counts route does since PR 29 / 31 / 33, on the
+# mesh: the program pair is built once per engine state (AotProgram, the
+# plan in its key), `static` runs once and its result - the half of the
+# precompute the port cases do not touch, per shard on the ring route,
+# whole on every device on the rows route - stays on the chips, and a
+# request runs `cases` from there: its port cases (int32 [3, Q]) are the
+# one array it sends.  api.TpuPolicyEngine holds the pair
+# (_mesh_counts_jits) and the static (_mesh_static): the Threading note
+# above, no cache lives here.
+
+#: names the held pair in the persistent key of both programs (the arg
+#: shapes cannot see that a mesh counts program starts from a resident
+#: static), and is what a caller of the mesh counts entry can ask a
+#: program for before it builds a cluster whose per-call precompute no
+#: chip holds (benchmarks/kinds/sweep_mesh_counts_generated.py)
+MESH_COUNTS_HELD = "mesh-counts=held"
+
+
+def _mesh_static_specs(tensors: Dict, pack: bool, ring: bool) -> Dict:
+    """shard_map specs of _precompute_static's result: on the ring route
+    every leaf with a pod axis is sharded over it, on the rows route all
+    of it is replicated."""
+
+    def pod(*lead):
+        return P(*lead, "x") if ring else P()
+
+    out = {}
+    for direction in ("ingress", "egress"):
+        spec = {
+            "peer_match": pod(None),
+            "tmatch": pod(None),
+            "has_target": pod(),
+            "peer_target": P(),
+            "target_ns": P(),
+            "port_spec": {k: P() for k in tensors[direction]["port_spec"]},
+        }
+        if pack:
+            spec["tmatch_pk"] = pod(None)
+        out[direction] = spec
+    if "tiers" in tensors:
+        out["tiers"] = {
+            "enc": jax.tree_util.tree_map(lambda _: P(), tensors["tiers"]),
+            "selpod": pod(None),
+            "selns": P(),
+            "pod_ns_id": pod(),
+        }
+    return out
+
+
+def mesh_counts_programs(
+    mesh, tensors: Dict, block: int, route: str, kernel: str, pack: bool,
+    plan: str,
+):
+    """(static, cases): the held pair over `mesh` for the case-free
+    `tensors`, padded to whole tiles a device.  `route` "ring"
+    keeps both pod axes sharded (evaluate_grid_counts_ring's program),
+    "rows" replicates the precompute and splits the source rows
+    (evaluate_grid_counts_sharded's, under `kernel`).
+
+      static(tensors)               -> _precompute_static a device
+      cases(static, cases, n_pods)  -> [*, 3] int32 partials, gathered
+
+    Both are AotPrograms; `plan` (the engine's dtype plan) is completed
+    here with everything else the arg shapes cannot see."""
+    from . import aot_cache
+    from .sharded import pod_sharded_in_specs, shard_map_no_check
+
+    ring = route == "ring"
+    n_dev = int(mesh.devices.size)
+    n_padded = int(tensors["pod_ns_id"].shape[0])
+    shard = n_padded // n_dev
+    in_specs = (
+        pod_sharded_in_specs(tensors)
+        if ring
+        else jax.tree_util.tree_map(lambda _: P(), tensors)
+    )
+    static_specs = _mesh_static_specs(tensors, pack, ring)
+
+    def static_device(t):
+        return _precompute_static(t, pack)
+
+    def cases_device(static, cases, n_pods):
+        pre = _precompute_cases(static, cases[0], cases[1], cases[2], pack)
+        if ring:
+            return _ring_counts(pre, n_pods, n_dev, shard, block)
+        return _row_counts(pre, n_pods, n_dev, n_padded, block, kernel, pack)
+
+    leaves, treedef = jax.tree_util.tree_flatten(in_specs)
+    plan = (
+        f"{plan};{MESH_COUNTS_HELD};route={route};"
+        + ("" if ring else f"kernel={kernel};")
+        + f"shard={shard};block={block};pack={pack};"
+        f"mesh={','.join(mesh.axis_names)}x{n_dev};"
+        + aot_cache.digest((str(treedef), [str(x) for x in leaves]))
+    )
+    # static_specs is a function of in_specs' tree, pack and the route,
+    # all three in the plan
+    static_fn = aot_cache.AotProgram(
+        "counts.mesh.static",
+        jax.jit(
+            shard_map_no_check(
+                static_device, mesh=mesh, in_specs=(in_specs,),
+                out_specs=static_specs,
+            )
+        ),
+        schedule=route,
+        plan=plan,  # cache-key: static_specs
+    )
+    cases_fn = aot_cache.AotProgram(
+        "counts.mesh.cases",
+        jax.jit(
+            shard_map_no_check(
+                cases_device, mesh=mesh, in_specs=(static_specs, P(), P()),
+                out_specs=P(),
+            )
+        ),
+        schedule=route,
+        plan=plan,  # cache-key: static_specs
+    )
+    return static_fn, cases_fn
+
+
 def evaluate_grid_counts_ring(
     tensors: Dict, n_pods: int, block: int = 1024, mesh=None
 ) -> Dict[str, int]:
@@ -962,37 +1191,11 @@ def evaluate_grid_counts_ring(
     )
     pack = pack_enabled()
     shard = n_padded // n_dev
-    tiles_per_shard = shard // block
 
     def per_device(t):
         # local precompute over THIS device's pod shard only (t's pod
-        # arrays arrive shard-sharded via in_specs); with packing on the
-        # rotating dst bundle carries the packed words — the ppermute
-        # hop moves ~16x fewer bytes per step
-        pre = _precompute(t, pack)
-        dev = jax.lax.axis_index("x")
-        row0 = dev * shard
-        valid_local = (jnp.arange(shard) + row0) < n_pods  # [shard]
-
-        # src view stays local; the dst view (+ its validity mask) is the
-        # rotating ring bundle, seeded with our own shard's dst-side view
-        src, dst0 = _split_pre(pre)
-        ring = dict(dst0, valid=valid_local)
-
-        def body(step, ring, counts):
-            dst = {k: ring[k] for k in _dst_bundle_keys(ring)}
-
-            def tile(i, counts):
-                row = _tile_counts_split(
-                    src, dst, valid_local, ring["valid"], i * block, block
-                )
-                return counts.at[step * tiles_per_shard + i].set(row)
-
-            return jax.lax.fori_loop(0, tiles_per_shard, tile, counts)
-
-        counts = jnp.zeros((n_dev * tiles_per_shard, 3), dtype=jnp.int32)
-        counts, _ = _ring_sweep(n_dev, ring, counts, body)
-        return jax.lax.all_gather(counts, "x", axis=0, tiled=True)
+        # arrays arrive shard-sharded via in_specs)
+        return _ring_counts(_precompute(t, pack), n_pods, n_dev, shard, block)
 
     return _run_mesh_counts(
         per_device, mesh, pod_sharded_in_specs(tensors), tensors, q, n_pods,
@@ -1345,12 +1548,7 @@ def evaluate_grid_counts_sharded(
 
     The per-pod precompute (selector matches, tallow) is evaluated
     replicated — it is O(N), negligible next to the O(N^2) grid."""
-    if kernel is None:
-        kernel = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if kernel not in ("pallas", "xla"):
-        raise ValueError(
-            f"unknown sharded counts kernel {kernel!r} (want 'pallas' or 'xla')"
-        )
+    kernel = mesh_counts_kernel(kernel)
     from . import planspec
 
     if kernel == "pallas":
@@ -1361,72 +1559,11 @@ def evaluate_grid_counts_sharded(
         tensors, n_pods, block, mesh
     )
     pack = pack_enabled()
-    tiles_per_dev = n_padded // (n_dev * block)
-    shard = n_padded // n_dev
 
     def per_device(t):
-        pre = _precompute(t, pack)
-        # this device's source-row range
-        dev = jax.lax.axis_index("x")
-        row0 = dev * tiles_per_dev * block
-        valid = jnp.arange(n_padded) < n_pods
-
-        if kernel == "pallas":
-            from .pallas_kernel import (
-                _should_interpret,
-                verdict_counts_pallas_packed,
-                verdict_counts_pallas_rect,
-            )
-
-            e, ig = pre["egress"], pre["ingress"]
-            sl = partial(jax.lax.dynamic_slice_in_dim, start_index=row0)
-            if pack:
-                # packed rect form: src = this device's row shard, dst =
-                # the full axis; the packed words slice on the pod axis
-                # exactly like the dense operands
-                partials = verdict_counts_pallas_packed(
-                    sl(e["tmatch_pk"], slice_size=shard, axis=1),
-                    sl(e["has_target"], slice_size=shard, axis=0),
-                    e["tallow_pk"],
-                    ig["tmatch_pk"],
-                    ig["has_target"],
-                    sl(ig["tallow_pk"], slice_size=shard, axis=1),
-                    valid_src=sl(valid, slice_size=shard, axis=0),
-                    valid_dst=valid,
-                    interpret=_should_interpret(),
-                )
-            else:
-                partials = verdict_counts_pallas_rect(
-                    sl(e["tmatch"], slice_size=shard, axis=1),
-                    sl(e["has_target"], slice_size=shard, axis=0),
-                    e["tallow_bf"],
-                    ig["tmatch"],
-                    ig["has_target"],
-                    sl(ig["tallow_bf"], slice_size=shard, axis=1),
-                    valid_src=sl(valid, slice_size=shard, axis=0),
-                    valid_dst=valid,
-                    interpret=_should_interpret(),
-                )  # [Q, n_src_tiles_local, 3]
-            return jax.lax.all_gather(
-                partials.reshape(-1, 3), "x", axis=0, tiled=True
-            )
-
-        def body(i, counts):
-            return counts.at[i].set(
-                _tile_counts(pre, valid, row0 + i * block, block)
-            )
-
-        counts = jax.lax.fori_loop(
-            0,
-            tiles_per_dev,
-            body,
-            jnp.zeros((tiles_per_dev, 3), dtype=jnp.int32),
+        return _row_counts(
+            _precompute(t, pack), n_pods, n_dev, n_padded, block, kernel, pack
         )
-        # one collective: gather every device's per-tile partials so the
-        # host can sum them in int64 (device int32 would overflow first)
-        return jax.lax.all_gather(counts, "x", axis=0, tiled=True)
-
-    from jax.sharding import PartitionSpec as P
 
     in_specs = jax.tree_util.tree_map(lambda _: P(), tensors)
     return _run_mesh_counts(

@@ -752,6 +752,14 @@ def eval_flight(path: str, n_pods: int, q: int, **attrs: Any) -> Iterator[Flight
                     schedule=flight.data["schedule"],
                     classes=bool(flight.data.get("classes")),
                 )
+            # and what the mesh counts entry decided its route from: one
+            # chip's bytes of the replicated precompute and their ceiling
+            if "replicated_bytes" in flight.data:
+                sp.set(
+                    devices=flight.data["devices"],
+                    replicated_bytes=flight.data["replicated_bytes"],
+                    ceiling_bytes=flight.data["ceiling_bytes"],
+                )
     except BaseException as e:
         outcome = f"{type(e).__name__}: {e}"[:300]
         raise

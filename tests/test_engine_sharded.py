@@ -290,16 +290,22 @@ class TestMeshCounts:
         pipelined ring counts, peer bundle double-buffered and donated,
         per-eval transfer/precompute amortized away — must sustain at
         least the all-gather-style path's throughput (the replicated
-        sharded counts, which re-transfers and replicates the full
-        peer-side precompute per eval) on the virtual 8-device mesh.
+        sharded counts as a PER-CALL program, which re-transfers and
+        replicates the full peer-side precompute per eval:
+        tiled.evaluate_grid_counts_sharded; the engine's entry holds
+        its program and static since PR 38 and pays neither) on the
+        virtual 8-device mesh.
         min-of-5 per leg absorbs scheduler noise; the measured gap is
         several-fold, so the bound has real margin."""
-        engine, _policy, _pods = synthetic_engine(512, n_pols=48, seed=11)
+        from cyclonus_tpu.engine import tiled
+
+        engine, _policy, pods = synthetic_engine(512, n_pols=48, seed=11)
         mesh = cpu_mesh(8)
 
         def run_allgather():
-            return engine.evaluate_grid_counts_sharded(
-                CASES, block=256, mesh=mesh, kernel="xla"
+            return tiled.evaluate_grid_counts_sharded(
+                engine._tensors_with_cases(CASES), len(pods), block=256,
+                mesh=mesh, kernel="xla",
             )
 
         want = run_allgather()  # compile outside the timing
