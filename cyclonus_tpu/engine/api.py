@@ -3236,6 +3236,7 @@ class TpuPolicyEngine:
         from .sharded import _pad_pod_arrays, default_mesh, mesh_device_context
         from .tiled import (
             _int32_safe_block,
+            counts_tile_form,
             mesh_counts_kernel,
             mesh_counts_programs,
         )
@@ -3262,9 +3263,15 @@ class TpuPolicyEngine:
             kernel if route == "rows" else None, block,
         )
         replicated = NamedSharding(mesh, PartitionSpec())
+        # the XLA counts tile body's form, where the program runs it
+        tile = (
+            {"tile": counts_tile_form(self._tensors)}
+            if route == "ring" or kernel == "xla"
+            else {}
+        )
         with ti.eval_flight(
             path, n, q, devices=n_dev, mode="held",
-            replicated_bytes=need, ceiling_bytes=ceiling,
+            replicated_bytes=need, ceiling_bytes=ceiling, **tile,
         ) as fl, mesh_device_context(mesh):
             held = self._mesh_static
             if key not in self._mesh_counts_jits or held is None or held[0] != key:

@@ -145,44 +145,70 @@ def _precompute_static(tensors: Dict, pack: bool = False) -> Dict:
     return out
 
 
+#: the forms of a tile's verdicts: [B, Nd, Q], what every tables path
+#: reads (and the tier epilogue is written for), or the counts tile
+#: body's [B, Q, Nd], a [B, Nd] slab a port case with the pods on the
+#: minor (lane) axis
+TILE_BNQ = "bnq"
+TILE_BQN = "bqn"
+
+
+def counts_tile_form(tensors: Dict) -> str:
+    """The layout the XLA counts tile body runs in over `tensors` (a
+    tensor set or _precompute_static's result): TILE_BQN, except where
+    tier arrays ride, whose epilogue keeps the parent's TILE_BNQ body.
+    The one rule _precompute_cases and _tile_counts_split follow."""
+    return TILE_BNQ if "tiers" in tensors else TILE_BQN
+
+
 def _precompute_cases(
     static: Dict,
     q_port: jnp.ndarray,
     q_name: jnp.ndarray,
     q_proto: jnp.ndarray,
     pack: bool = False,
+    tile: str = TILE_BNQ,
 ) -> Dict[str, Dict[str, jnp.ndarray]]:
     """The half of _precompute that starts where the port cases enter:
     from _precompute_static's result and the three [Q] case arrays, the
-    `pre` dict of _precompute, leaf for leaf."""
+    `pre` dict of _precompute, leaf for leaf.  `tile` TILE_BQN lays the
+    EGRESS tallow [T, Q, N] (W for T packed) for the counts tile body
+    (_tile_counts_split), the same bytes in another order; a tiered
+    static keeps [T, N, Q] (counts_tile_form)."""
     out = {}
     q = q_port.shape[0]
+    qn = tile == TILE_BQN and counts_tile_form(static) == TILE_BQN
     for direction in ("ingress", "egress"):
         st = static[direction]
         pport = port_spec_allows(st["port_spec"], q_port, q_name, q_proto)
         n_p, n = st["peer_match"].shape
-        peer_allow = (
-            st["peer_match"][:, :, None] & pport[:, None, :]
-        ).reshape(n_p, n * q)  # shape: (P, NQ)
+        if qn and direction == "egress":
+            peer_allow = (
+                st["peer_match"][:, None, :] & pport[:, :, None]
+            ).reshape(n_p, q * n)  # shape: (P, QN)
+        else:
+            peer_allow = (
+                st["peer_match"][:, :, None] & pport[:, None, :]
+            ).reshape(n_p, n * q)  # shape: (P, NQ)
         tallow = jnp.matmul(
             m_tp_onehot(st).astype(jnp.bfloat16),
             peer_allow.astype(jnp.bfloat16),
             preferred_element_type=jnp.bfloat16,
         )
         t = tallow.shape[0]
+        lay = (t, q, n) if qn and direction == "egress" else (t, n, q)
         out[direction] = {
             "tmatch": st["tmatch"],
             "has_target": st["has_target"],
         }
         if pack:
-            tallow_b = (tallow > 0).reshape(t, n, q)
             out[direction]["tallow_pk"] = pack_bool_words_jnp(
-                tallow_b
-            )  # shape: (W, N, Q) int32
+                (tallow > 0).reshape(lay)
+            )  # shape: (W, N, Q) | (W, Q, N) int32
             out[direction]["tmatch_pk"] = st["tmatch_pk"]
         else:
             out[direction]["tallow_bf"] = (
-                (tallow > 0).astype(jnp.bfloat16).reshape(t, n, q)
+                (tallow > 0).astype(jnp.bfloat16).reshape(lay)
             )
         if "tiers" in static:
             # precedence-tier precompute (docs/DESIGN.md "Precedence
@@ -202,7 +228,7 @@ def _precompute_cases(
 
 
 def _precompute(
-    tensors: Dict, pack: bool = False
+    tensors: Dict, pack: bool = False, tile: str = TILE_BNQ
 ) -> Dict[str, Dict[str, jnp.ndarray]]:
     """Per-direction, port-resolved precompute shared by every tile:
 
@@ -221,13 +247,14 @@ def _precompute(
 
     The composition of its two halves, cut where q_port first appears:
     every caller but the dense counts route's resident request traces
-    both in one program, as before the cut."""
+    both in one program, as before the cut.  `tile`: _precompute_cases."""
     return _precompute_cases(
         _precompute_static(tensors, pack),
         tensors["q_port"],
         tensors["q_name"],
         tensors["q_proto"],
         pack,
+        tile,
     )
 
 
@@ -321,15 +348,30 @@ def _split_pre(pre: Dict) -> Tuple[Dict, Dict]:
     return src, dst
 
 
+def _any_bf16(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """[A, B] bool: OR_t (a[t, a] AND b[t, b]) over 0/1 [T, A] and
+    [T, B] — packed_any's unpacked twin, on the MXU: a bf16 sum of 0/1
+    products is positive at its first hit and never rounds to zero."""
+    return (
+        jnp.matmul(
+            a.T.astype(jnp.bfloat16),
+            b.astype(jnp.bfloat16),
+            preferred_element_type=jnp.bfloat16,
+        )
+        > 0
+    )
+
+
 def _tile_verdicts_split(
     src: Dict, dst: Dict, start: jnp.ndarray, block: int
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Verdict blocks for source rows [start, start+block) of the src
     view against ALL dst-view pods: (ingress_rows, egress, combined),
     each [B, Nd, Q] bool; ingress_rows[b, d, q] = ingress verdict for
-    dst d <- src (start+b).  THE per-tile verdict body — every tiled
-    path (single-device, mesh-parallel, ring) goes through here so the
-    semantics cannot diverge.  The contraction is picked by the view
+    dst d <- src (start+b).  THE per-tile verdict body of every path
+    that hands verdicts on (tables, class row sums, blocks), and of a
+    tiered counts tile; the counts tile has its own layout
+    (_tile_verdicts_bqn).  The contraction is picked by the view
     REPRESENTATION (_split_pre): int32 views are 32-per-word packed
     bitmaps contracted with packed_any; bool/bf16 views keep the bf16
     matmul.  Both forms are exact on 0/1 values, pinned bit-identical
@@ -426,6 +468,42 @@ def _pad_pod_axis(tensors: Dict, n_pods: int, block: int) -> Tuple[Dict, int]:
     return tensors, padded // block
 
 
+def _tile_verdicts_bqn(src: Dict, dst: Dict, start, block: int) -> list:
+    """The counts tile's verdicts, case by case: for each port case q,
+    (ingress, egress, combined) [B, Nd] bool of the tile's source rows
+    against the dst pods.  Both contractions put the tile's rows on the
+    major axis and the dst pods on the minor (lane) one, so nothing is
+    transposed, and Q never sits between them (a [B, Q, Nd] array made
+    by a reshape is a relayout on the TPU: PERF.md section 6, PR 39).
+    Ingress contracts the tile's tallow_i[:, :, q] against the dst
+    tmatch_i, egress the tile's tmatch_e against the dst tallow_e[:, q],
+    which _precompute_cases lays [W, Q, Nd] for it (tile=TILE_BQN).  A
+    count never leaves the chip as verdicts, so their layout is free;
+    the tables paths read _tile_verdicts_split."""
+    w_i, _, q = src["tallow_i"].shape
+    w_e = src["tmatch_e"].shape[0]
+    nd = dst["tmatch_i"].shape[1]
+    if dst["tallow_e"].shape != (w_e, q, nd):
+        raise ValueError(
+            f"the counts tile body wants the egress tallow as (W, Q, N) = "
+            f"{(w_e, q, nd)}, got {dst['tallow_e'].shape}: build the "
+            f"precompute with tile={TILE_BQN!r}"
+        )
+    any_ = packed_any if src["tmatch_e"].dtype == jnp.int32 else _any_bf16
+    tme = jax.lax.dynamic_slice(src["tmatch_e"], (0, start), (w_e, block))
+    no_e = ~jax.lax.dynamic_slice(src["has_e"], (start,), (block,))[:, None]
+    no_i = ~dst["has_i"][None, :]
+    tli = jax.lax.dynamic_slice(
+        src["tallow_i"], (0, start, 0), (w_i, block, q)
+    )  # [W, B, Q]
+    out = []
+    for k in range(q):
+        egress = no_e | any_(tme, dst["tallow_e"][:, k])  # [B, Nd]
+        ingress = no_i | any_(tli[:, :, k], dst["tmatch_i"])  # [B, Nd]
+        out.append((ingress, egress, egress & ingress))
+    return out
+
+
 def _tile_counts_split(
     src: Dict,
     dst: Dict,
@@ -437,18 +515,27 @@ def _tile_counts_split(
     """[3] int32 validity-masked allow counts for src-view rows
     [start, start+block) against all dst-view pods — THE per-tile count
     body, shared by the single-device, mesh-parallel, and ring paths so
-    the masking/count semantics cannot diverge.  Safe in int32 for any
-    block*Nd*Q that fits in HBM."""
-    ingress_rows, egress, combined = _tile_verdicts_split(src, dst, start, block)
-    sv = jax.lax.dynamic_slice(src_valid, (start,), (block,))
-    mask = sv[:, None, None] & dst_valid[None, :, None]
-    return jnp.stack(
-        [
-            jnp.sum(ingress_rows & mask, dtype=jnp.int32),
-            jnp.sum(egress & mask, dtype=jnp.int32),
-            jnp.sum(combined & mask, dtype=jnp.int32),
-        ]
-    )
+    the masking/count semantics cannot diverge.  It runs on the counts
+    tile's [B, Nd] slabs a case (_tile_verdicts_bqn, a precompute built
+    with tile=TILE_BQN), and where tier arrays ride on the parent's
+    [B, Nd, Q] verdicts with the tier epilogue (_tile_verdicts_split):
+    counts_tile_form.  Safe in int32 for any block*Nd*Q that fits in
+    HBM."""
+    if "tier_subj_e" in src:
+        verdicts = [_tile_verdicts_split(src, dst, start, block)]
+        sv = jax.lax.dynamic_slice(src_valid, (start,), (block,))
+        mask = sv[:, None, None] & dst_valid[None, :, None]
+    else:
+        verdicts = _tile_verdicts_bqn(src, dst, start, block)
+        sv = jax.lax.dynamic_slice(src_valid, (start,), (block,))
+        mask = sv[:, None] & dst_valid[None, :]
+    counts = [
+        jnp.stack([jnp.sum(v & mask, dtype=jnp.int32) for v in case])
+        for case in verdicts
+    ]
+    for more in counts[1:]:
+        counts[0] = counts[0] + more
+    return counts[0]
 
 
 def _tile_counts(pre: Dict, valid: jnp.ndarray, start, block: int) -> jnp.ndarray:
@@ -471,7 +558,7 @@ def _counts_kernel(
     """[n_tiles, 3] int32 allow counts (ingress, egress, combined) over the
     full grid, computed with one device execution; the host sums tiles in
     int64."""
-    pre = _precompute(tensors, pack)
+    pre = _precompute(tensors, pack, TILE_BQN)
     n_padded = tensors["pod_ns_id"].shape[0]
     valid = jnp.arange(n_padded) < n_pods  # [N] pod-validity mask
 
@@ -496,7 +583,9 @@ def evaluate_grid_counts(
     # equivalent global-accumulator overflow bit the pallas backend at
     # 100k pods before partials were introduced)
     block = _int32_safe_block(min(block, max(n_pods, 1)), n_pods, q)
-    with ti.eval_flight("counts.xla", n_pods, q, block=block) as fl:
+    with ti.eval_flight(
+        "counts.xla", n_pods, q, block=block, tile=counts_tile_form(tensors)
+    ) as fl:
         tensors, n_tiles = _pad_pod_axis(tensors, n_pods, block)
         with phase("engine.dispatch"):
             out = _counts_kernel(tensors, block, n_tiles, n_pods, pack)
@@ -918,12 +1007,14 @@ def mesh_counts_kernel(kernel: str = None) -> str:
 
 def _run_mesh_counts(
     per_device, mesh, in_specs, tensors: Dict, q: int, n_pods: int,
-    path: str = "counts.mesh",
+    path: str = "counts.mesh", xla_tile: bool = True,
 ) -> Dict[str, int]:
     """Shared tail of every mesh count path: one shard_map execution,
     then the int64 host sum of the [*, 3] int32 partials (device-side
     int64 silently truncates without jax_enable_x64).  `path` labels the
-    telemetry flight entry with the calling mesh strategy."""
+    telemetry flight entry with the calling mesh strategy; `xla_tile`
+    (the program runs the XLA counts tile body) puts the tile's form
+    there."""
     from jax.sharding import PartitionSpec as P
 
     from .sharded import mesh_device_context, shard_map_no_check
@@ -933,8 +1024,9 @@ def _run_mesh_counts(
             per_device, mesh=mesh, in_specs=(in_specs,), out_specs=P()
         )
     )
+    form = {"tile": counts_tile_form(tensors)} if xla_tile else {}
     with ti.eval_flight(
-        path, n_pods, q, devices=int(mesh.devices.size)
+        path, n_pods, q, devices=int(mesh.devices.size), **form
     ) as fl:
         with mesh_device_context(mesh):
             counts = np.asarray(fn(tensors), dtype=np.int64).sum(axis=0)
@@ -951,8 +1043,9 @@ def _ring_counts(pre: Dict, n_pods, n_dev: int, shard: int, block: int):
     """The pod-sharded ring's per-device body from the shard's `pre` on:
     the src view stays local, the dst view (and its validity mask)
     rotates, one _tile_counts_split a tile a step, then the ONE gather
-    of the [n_dev * tiles, 3] int32 partials.  With packing on the
-    rotating bundle carries the packed words (~16x fewer bytes a hop).
+    of the [n_dev * tiles, 3] int32 partials.  `pre` is built with
+    tile=TILE_BQN.  With packing on the rotating bundle carries the
+    packed words (~16x fewer bytes a hop).
     `n_pods` may be traced.  Shared by the per-call ring
     (evaluate_grid_counts_ring) and the held program
     (mesh_counts_programs)."""
@@ -986,8 +1079,10 @@ def _row_counts(
     (replicated) `pre` on: this device's rows against every pod, by the
     rectangular Pallas kernel (kernel="pallas") or the XLA tile loop,
     then the ONE gather of the int32 partials.  `n_pods` may be traced.
-    Shared by the per-call program (evaluate_grid_counts_sharded) and
-    the held one (mesh_counts_programs)."""
+    `pre` is built with tile=TILE_BQN for the tile loop and at the
+    default for the Pallas kernel.  Shared by the per-call program
+    (evaluate_grid_counts_sharded) and the held one
+    (mesh_counts_programs)."""
     shard = n_padded // n_dev
     tiles_per_dev = shard // block
     row0 = jax.lax.axis_index("x") * shard
@@ -1109,6 +1204,7 @@ def mesh_counts_programs(
     from .sharded import pod_sharded_in_specs, shard_map_no_check
 
     ring = route == "ring"
+    xla_tile = ring or kernel == "xla"
     n_dev = int(mesh.devices.size)
     n_padded = int(tensors["pod_ns_id"].shape[0])
     shard = n_padded // n_dev
@@ -1123,15 +1219,21 @@ def mesh_counts_programs(
         return _precompute_static(t, pack)
 
     def cases_device(static, cases, n_pods):
-        pre = _precompute_cases(static, cases[0], cases[1], cases[2], pack)
+        pre = _precompute_cases(
+            static, cases[0], cases[1], cases[2], pack,
+            TILE_BQN if xla_tile else TILE_BNQ,
+        )
         if ring:
             return _ring_counts(pre, n_pods, n_dev, shard, block)
         return _row_counts(pre, n_pods, n_dev, n_padded, block, kernel, pack)
 
     leaves, treedef = jax.tree_util.tree_flatten(in_specs)
+    # the tile body's form: the key cannot see code, and the parent's
+    # programs of the same shapes hold the [B, Nd, Q] body
     plan = (
         f"{plan};{MESH_COUNTS_HELD};route={route};"
         + ("" if ring else f"kernel={kernel};")
+        + (f"tile={counts_tile_form(tensors)};" if xla_tile else "")
         + f"shard={shard};block={block};pack={pack};"
         f"mesh={','.join(mesh.axis_names)}x{n_dev};"
         + aot_cache.digest((str(treedef), [str(x) for x in leaves]))
@@ -1195,7 +1297,9 @@ def evaluate_grid_counts_ring(
     def per_device(t):
         # local precompute over THIS device's pod shard only (t's pod
         # arrays arrive shard-sharded via in_specs)
-        return _ring_counts(_precompute(t, pack), n_pods, n_dev, shard, block)
+        return _ring_counts(
+            _precompute(t, pack, TILE_BQN), n_pods, n_dev, shard, block
+        )
 
     return _run_mesh_counts(
         per_device, mesh, pod_sharded_in_specs(tensors), tensors, q, n_pods,
@@ -1240,7 +1344,9 @@ _SRC_SPECS = {
 #: shard_map specs of the rotating peer-side ring bundle (the arrays a
 #: ppermute hop moves; donated by the step program)
 _RING_SPECS = {
-    "tallow_e": P(None, "x", None),  # (T_e, N, Q) bf16 | (W_e, N, Q) int32
+    # (T_e, N, Q) bf16 | (W_e, N, Q) int32 on a tiered set; the
+    # counts tile's (W_e, Q, N) otherwise (ring_counts_pipeline)
+    "tallow_e": P(None, "x", None),
     "tmatch_i": P(None, "x"),  # shape: (T_i, N) bool | (W_i, N) int32
     "has_i": P("x"),  # shape: (N,) bool
     "tier_peerq_e": P(None, "x", None),  # shape: (G_e, N, Q) bool
@@ -1284,7 +1390,7 @@ def ring_counts_pipeline(tensors: Dict, n_pods: int, block: int, mesh):
         return cached
 
     def seed_device(t):
-        pre = _precompute(t, pack)
+        pre = _precompute(t, pack, TILE_BQN)
         src, dst0 = _split_pre(pre)
         dev = jax.lax.axis_index("x")
         valid = (jnp.arange(shard) + dev * shard) < n_pods
@@ -1323,6 +1429,8 @@ def ring_counts_pipeline(tensors: Dict, n_pods: int, block: int, mesh):
         for k, v in _RING_SPECS.items()
         if tiered or not k.startswith("tier")
     }
+    if counts_tile_form(tensors) == TILE_BQN:
+        ring_specs["tallow_e"] = P(None, None, "x")  # (W_e, Q, N)
     seed_fn = jax.jit(
         shard_map_no_check(
             seed_device,
@@ -1383,7 +1491,8 @@ def evaluate_grid_counts_ring_pipelined(
         tensors, n_pods, block, mesh
     )
     with ti.eval_flight(
-        "counts.ring.pipelined", n_pods, q, devices=int(n_dev), reps=reps
+        "counts.ring.pipelined", n_pods, q, devices=int(n_dev), reps=reps,
+        tile=counts_tile_form(tensors),
     ) as fl:
         with mesh_device_context(mesh):
             src, ring = seed_fn(tensors)
@@ -1457,7 +1566,7 @@ def evaluate_grid_counts_ring2d(
     tiles_per_shard = shard // block
 
     def per_device(t):
-        pre = _precompute(t, pack)
+        pre = _precompute(t, pack, TILE_BQN)
         dev = jax.lax.axis_index("dcn") * n_ici + jax.lax.axis_index("ici")
         row0 = dev * shard
         valid_local = (jnp.arange(shard) + row0) < n_pods
@@ -1560,15 +1669,18 @@ def evaluate_grid_counts_sharded(
     )
     pack = pack_enabled()
 
+    tile = TILE_BQN if kernel == "xla" else TILE_BNQ
+
     def per_device(t):
         return _row_counts(
-            _precompute(t, pack), n_pods, n_dev, n_padded, block, kernel, pack
+            _precompute(t, pack, tile), n_pods, n_dev, n_padded, block,
+            kernel, pack,
         )
 
     in_specs = jax.tree_util.tree_map(lambda _: P(), tensors)
     return _run_mesh_counts(
         per_device, mesh, in_specs, tensors, q, n_pods,
-        path="counts.sharded",
+        path="counts.sharded", xla_tile=kernel == "xla",
     )
 
 

@@ -89,6 +89,15 @@ MESH_DISPATCH_BYTES = REGISTRY.counter(
     "`engine.dispatch_sharded` span, summed).",
     labelnames=("route",),
 )
+COUNTS_TILE = REGISTRY.counter(
+    "cyclonus_tpu_counts_tile_total",
+    "Counts evaluations that ran the XLA counts tile body, by its form "
+    "(engine/tiled.py counts_tile_form): bqn (both directions in one "
+    "[block, Q, pods] layout) or bnq (a tiered set: the tables body "
+    "with its tier epilogue).  The `tile` of the evaluation's "
+    "`engine.eval` span, counted.",
+    labelnames=("form",),
+)
 GRID_HOST_BUFFER = REGISTRY.counter(
     "cyclonus_tpu_grid_host_buffer_total",
     "Host buffers handed to sharded tables' readbacks "
@@ -752,6 +761,11 @@ def eval_flight(path: str, n_pods: int, q: int, **attrs: Any) -> Iterator[Flight
                     schedule=flight.data["schedule"],
                     classes=bool(flight.data.get("classes")),
                 )
+            # and the layout of the XLA counts tile body it ran
+            # (tiled.counts_tile_form), counted a form
+            if "tile" in flight.data:
+                sp.set(tile=flight.data["tile"])
+                COUNTS_TILE.inc(form=flight.data["tile"])
             # and what the mesh counts entry decided its route from: one
             # chip's bytes of the replicated precompute and their ceiling
             if "replicated_bytes" in flight.data:

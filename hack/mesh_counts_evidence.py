@@ -146,8 +146,9 @@ def compile_only(rehearse: bool):
          lambda x: tiled._row_counts(tiled._precompute(x, pack), n, n_dev,
                                      n_padded, block, "pallas", pack)),
         ("ring (counts.ring, per call)", pod_sharded_in_specs(with_cases),
-         lambda x: tiled._ring_counts(tiled._precompute(x, pack), n, n_dev,
-                                      shard, block)),
+         lambda x: tiled._ring_counts(
+             tiled._precompute(x, pack, tiled.TILE_BQN), n, n_dev, shard,
+             block)),
     ):
         fn = jax.jit(shard_map_no_check(body, mesh=mesh, in_specs=(specs,),
                                         out_specs=P()))

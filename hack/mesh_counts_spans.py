@@ -6,7 +6,10 @@ sent (`engine.dispatch_sharded`) and how long its readback barrier waited
 (`engine.execute`); then every `jax.compile`, `engine.program` and
 `engine.static_pre` span that lies INSIDE the window (there should be none:
 the pair is built and the static placed in set-up), the start-up record's
-`engine.static_pre` and the counter `cyclonus_tpu_static_pre_total`.
+`engine.static_pre` and the counter `cyclonus_tpu_static_pre_total`; and
+(PR 39) the counter `cyclonus_tpu_counts_tile_total` by form beside the
+process's counts evaluations (`cyclonus_tpu_eval_dispatches_total` of the
+counts routes): one a counts evaluation that ran the XLA tile body.
 
     python3 hack/mesh_counts_spans.py --workload <cell> --seed 1 --seconds 30 --trace 1
 
@@ -52,6 +55,13 @@ def main() -> int:
     print("  cyclonus_tpu_static_pre_total: " + ", ".join(
         f"{o} {ti.STATIC_PRE.value(outcome=o):.0f}"
         for o in ("built", "hit", "declined")), file=sys.stderr)
+    evals = sum(
+        v for labels, v in ti.EVAL_DISPATCHES.samples()
+        if dict(labels).get("path", "").startswith("counts.")
+    )
+    print("  cyclonus_tpu_counts_tile_total: " + ", ".join(
+        f"{f} {ti.COUNTS_TILE.value(form=f):.0f}" for f in ("bqn", "bnq"))
+        + f"; counts evaluations {evals:.0f}", file=sys.stderr)
     return rc
 
 

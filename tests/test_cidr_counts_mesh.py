@@ -10,6 +10,10 @@ namespaces), on the sweep's three port pairs.
     monkeypatching it, and no environment variable);
   * ROUTE: the decision, its bytes and its ceiling are on `engine.eval`,
     the recorded PathSpec is the route's and nothing else;
+  * TILE: both routes run the XLA counts tile body here, in its
+    [block, Q, pods] form: `tile=bqn` on `engine.eval`, one
+    `cyclonus_tpu_counts_tile_total{form="bqn"}` an evaluation, and the
+    form in both programs' persistent key;
   * HELD: the pair is built once per engine state; the second and third
     request trace, lower and compile nothing and send their port cases
     alone (`engine.dispatch_sharded`: `host_bytes` = 12 x Q); no
@@ -166,6 +170,7 @@ def test_the_route_its_bytes_and_its_ceiling_are_on_the_evaluation(
     monkeypatch.setattr(planspec, "ACTIVE", True)  # arm the recorder
     planspec.drain()
     spans.REGISTRY.reset()
+    tiles = ti.COUNTS_TILE.value(form=tiled.TILE_BQN)
     engine.evaluate_grid_counts_sharded(PAIRS[0], mesh=cpu_mesh(4))
     assert planspec.drain() == [ROUTES[route]]
     (root,) = spans_named("engine.eval")
@@ -175,7 +180,9 @@ def test_the_route_its_bytes_and_its_ceiling_are_on_the_evaluation(
         "route": ROUTES[route], "mode": "held", "devices": 4,
         "replicated_bytes": need,
         "ceiling_bytes": api._MESH_REPLICATED_MAX_BYTES,
+        "tile": tiled.TILE_BQN,
     }
+    assert ti.COUNTS_TILE.value(form=tiled.TILE_BQN) == tiles + 1
     # from the shapes: the static half and a direction's peer_allow
     assert need > engine._static_pre_bytes() > 0
     assert (need <= root["ceiling_bytes"]) == (route == "rows")
@@ -232,6 +239,9 @@ def test_the_pair_names_itself_in_its_persistent_key(engine, route):
     (plan,) = plans
     assert f";{tiled.MESH_COUNTS_HELD};route={route};" in plan
     assert plan.startswith(engine._aot_plan() + ";")
+    # the tile body's form: the key cannot see code, and a parent's
+    # program of the same shapes holds the [B, Nd, Q] body
+    assert f";tile={tiled.TILE_BQN};" in plan
 
 
 @both_routes
