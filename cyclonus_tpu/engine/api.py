@@ -806,14 +806,21 @@ def _tier_pre_bytes(tensors: Dict, n: int, q: int) -> int:
     return tier_rows(tensors) * n * (q + 1)
 
 
-def _tier_scan_bytes(tensors: Dict, rows: int, cols: int, q: int) -> int:
-    """Device bytes of the tier epilogue's XLA scan over a [rows, cols]
-    tile, both directions: two int32 [rows, cols, Q] min keys carried
-    and one chunk's bool [c, rows, cols, Q] match a direction
-    (kernel.tier_first_match_keys, c = 8); 0 on an untiered set."""
+def _tier_first_match_bytes(tensors: Dict, rows: int, cols: int, q: int) -> int:
+    """Device bytes of the tier epilogue's first-match over a [rows, cols]
+    tile, both directions (kernel.tier_first_match_keys): a direction's
+    two int32 [rows, cols, Q] keys, its packed peer words [W, *, Q] and a
+    packed subject [W, *] a tier, W the direction's rule words (egress
+    subjects are the tile's rows, ingress subjects its columns); the match
+    itself never leaves the reduction.  0 on an untiered set."""
     if "tiers" not in tensors:
         return 0
-    return 2 * rows * cols * q * (2 * 4 + 8)
+    w_e, w_i = (
+        packed_words(int(tensors["tiers"][d]["action"].shape[0]))
+        for d in ("egress", "ingress")
+    )
+    keys = 2 * 2 * 4 * rows * cols * q
+    return keys + 4 * w_e * (2 * rows + cols * q) + 4 * w_i * (2 * cols + rows * q)
 
 
 def _tree_nbytes(tree) -> int:
@@ -1321,12 +1328,14 @@ class TpuPolicyEngine:
         (engine/aot_cache.py): packed32, with the form of the packed
         contraction (kernel.PACKED_CONTRACTION: every packed program can
         trace kernel.packed_any, whose code the key cannot see), vs the
-        dense operand dtype, plus the tier flag.  Programs whose trace
+        dense operand dtype, plus the tier flag and, on a tiered engine
+        alone, the form of the tier first-match (kernel.TIER_FIRST_MATCH:
+        an untiered plan stays what it was).  Programs whose trace
         bakes per-engine constants (the unpack closures' leaf layout)
         append a metas digest via `extra` — two engines with equal
         buffer lengths but different leaf layouts must never share an
         executable."""
-        from .kernel import PACKED_CONTRACTION
+        from .kernel import PACKED_CONTRACTION, TIER_FIRST_MATCH
         from .pallas_kernel import _resolve_operand_dtype
 
         dtype = (
@@ -1335,6 +1344,8 @@ class TpuPolicyEngine:
             else _resolve_operand_dtype(None)
         )
         plan = f"{dtype};tiered={self.tiers is not None}"
+        if self.tiers is not None:
+            plan += f";{TIER_FIRST_MATCH}"
         return plan + (";" + extra if extra else "")
 
     @staticmethod
@@ -1667,9 +1678,9 @@ class TpuPolicyEngine:
             # tallow bf16 [T, Cb, Q] per direction + tmatch + f32 row sums
             est = st["aux_bytes"] + t * cb * (2 * q + 1) + cb * q * 12
         # a tiered set: its precompute and, on the XLA tile loop past the
-        # fused kernel's row ceiling, a class tile's scan state
+        # fused kernel's row ceiling, a class tile's first-match
         block, _ = _class_tiling(cb, 1024)
-        return est + _tier_pre_bytes(ct, cb, q) + _tier_scan_bytes(
+        return est + _tier_pre_bytes(ct, cb, q) + _tier_first_match_bytes(
             ct, block, cb, q
         )
 

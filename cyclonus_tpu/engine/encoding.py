@@ -759,12 +759,21 @@ class TierDirectionEncoding:
     One row per (rule, peer scope) pair, flattened over BOTH admin tiers
     (`tier` 0=ANP, 1=BANP) in resolution order: `rank` is the rule's
     position in TierSet.ordered_rules for its tier, shared by all of the
-    rule's peer rows — the first-match reduction is a min over matching
-    rows of the int32 key rank * 4 + action, so equal-rank rows
-    implement the within-rule peer OR exactly.  `action` is the int8
-    verdict slab (TIER_ACT_*; 0 = pad, matches nothing — the inert fill
-    shape bucketing uses).  Selector ids index the SAME deduped selector
-    table as the NetworkPolicy slabs: subject/peer namespace selectors
+    rule's peer rows.  A tier's first match is the min over matching rows
+    of the int32 key rank * 4 + action, so equal-rank rows implement the
+    within-rule peer OR exactly.  `action` is the int8 verdict slab
+    (TIER_ACT_*; 0 = pad, matches nothing — the inert fill shape
+    bucketing uses).
+
+    THE ROW ORDER IS PART OF THE ENCODING (kernel.tier_first_match_keys
+    finds a tier's first match as its LOWEST matching row, a packed word
+    at a time): within each tier `rank` is nondecreasing down the rows,
+    all ANP rows come before all BANP rows, and every pad row (action 0)
+    comes after both, in the slab as encoded and as bucketed
+    (tests/test_tier_first_match.py).
+
+    Selector ids index the SAME deduped selector table as the
+    NetworkPolicy slabs: subject/peer namespace selectors
     are evaluated against namespace labels (selns), pod selectors
     against pod labels (selpod), which is also what keeps the
     equivalence-class pod signature complete under tiers.

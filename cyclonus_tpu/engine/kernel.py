@@ -396,12 +396,23 @@ def tier_scope_match(
 
 
 def tier_rows(tensors: Dict) -> int:
-    """The bucketed tier rule rows of a tensor set, ingress + egress: the
-    depth of the first-match scan; 0 on an untiered set."""
+    """The bucketed tier rule rows of a tensor set, ingress + egress; 0 on
+    an untiered set."""
     if "tiers" not in tensors:
         return 0
     return sum(
         int(tensors["tiers"][d]["action"].shape[0]) for d in ("ingress", "egress")
+    )
+
+
+def tier_words(tensors: Dict) -> int:
+    """The packed rule words tier_first_match_keys reduces over, ingress +
+    egress; 0 on an untiered set."""
+    if "tiers" not in tensors:
+        return 0
+    return sum(
+        packed_words(int(tensors["tiers"][d]["action"].shape[0]))
+        for d in ("ingress", "egress")
     )
 
 
@@ -419,50 +430,65 @@ def tier_keys(tenc: Dict[str, jnp.ndarray]) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return anp, banp
 
 
+#: the form of tier_first_match_keys, in the AOT plan of every TIERED
+#: program (TpuPolicyEngine._aot_plan, sharded.grid); untiered plans stay
+#: as they were.  The key sees no code, and an executable of the 8-row
+#: scan this form replaced gives the same tables under the same key,
+#: carrying two keys through HBM once a chunk
+TIER_FIRST_MATCH = "first=words"
+
+
 def tier_first_match_keys(
     subj: jnp.ndarray,  # [G, A] bool — subject side (target pods)
     peerq: jnp.ndarray,  # [G, B, Q] bool — peer side x port cases
     anp_key: jnp.ndarray,  # [G] int32
     banp_key: jnp.ndarray,  # [G] int32
-    chunk: int = 8,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """([A, B, Q], [A, B, Q]) int32 min matching keys per tier.
+    """([A, B, Q], [A, B, Q]) int32: each tier's first matching rule a
+    cell, keyed w * 4 + action (w the packed rule word that holds the
+    rule's row), TIER_KEY_NONE where no row of the tier matches.  The two
+    things resolve_tier_lattice reads of a key, `< TIER_KEY_NONE` and
+    `% 4`, are those of the min of rank * 4 + action over the matching
+    rows; the value itself is ordered by word, not by rank.
 
-    Scans the rule axis in `chunk`-row slices so the [c, A, B, Q] match
-    intermediate — not [G, A, B, Q] — is the only rule-axis blowup; G is
-    shape-bucketed to a power of two (api._bucket_tensors), so the
-    clamped chunk always divides it."""
-    g = subj.shape[0]
-    a = subj.shape[1]
-    b, q = peerq.shape[1], peerq.shape[2]
-    c = min(chunk, g)
+    PRECONDITION (encoding.TierDirectionEncoding; tests/
+    test_tier_first_match.py): within a tier, row order is key order, and
+    pad rows (TIER_KEY_NONE in both keys) come last.  Rows of equal key
+    are one rule's peer rows and share its action.  The lowest matching
+    row of a tier then holds the tier's first match.  With the rule axis
+    packed 32 rows a word (LSB = lowest row, pack_bool_words_jnp), that
+    row is the lowest set bit `m & -m` of the first non-zero word of
+    m = subj_pk & peerq_pk over the tier's rows, and its action is read
+    from two per-word masks of the tier's key bits (action = key % 4 is
+    1 - 3, so a matching row sets at least one).  ONE min-reduction over
+    the word axis a tier: the compiler fuses the broadcast into the
+    reduce as it does packed_any's, so no [W, A, B, Q] match is
+    materialized and no key is carried through HBM (tests/
+    test_tpu_compile.py holds the TPU compiler to it).  Bit 31 rides the
+    int32 sign: `m & -m` of INT_MIN is INT_MIN, and every test of a word
+    is `!= 0`.  The word depth follows G; G is any size (padded to whole
+    words with rows of no tier)."""
     none = jnp.int32(TIER_KEY_NONE)
-    init = (
-        jnp.full((a, b, q), none, dtype=jnp.int32),
-        jnp.full((a, b, q), none, dtype=jnp.int32),
-    )
+    peer_pk = pack_bool_words_jnp(peerq)  # [W, B, Q]
+    base = 4 * jnp.arange(peer_pk.shape[0], dtype=jnp.int32)[:, None, None, None]
 
-    def body(carry, xs):
-        s, pq, ka, kb = xs  # [c, A], [c, B, Q], [c], [c]
-        m = s[:, :, None, None] & pq[:, None, :, :]  # [c, A, B, Q]
-        a_min = jnp.min(jnp.where(m, ka[:, None, None, None], none), axis=0)
-        b_min = jnp.min(jnp.where(m, kb[:, None, None, None], none), axis=0)
-        return (
-            jnp.minimum(carry[0], a_min),
-            jnp.minimum(carry[1], b_min),
-        ), None
+    def first(key):  # key [G] -> [A, B, Q]
+        mine = key < TIER_KEY_NONE  # the tier's real rows
+        s_pk = pack_bool_words_jnp(subj & mine[:, None])  # [W, A]
+        bit0 = pack_bool_words_jnp(mine & ((key & 1) != 0))[:, None, None, None]
+        bit1 = pack_bool_words_jnp(mine & ((key & 2) != 0))[:, None, None, None]
+        m = s_pk[:, :, None, None] & peer_pk[:, None, :, :]  # [W, A, B, Q]
+        low = m & -m
+        c0 = (low & bit0) != 0
+        c1 = (low & bit1) != 0
+        v = jnp.where(
+            c1,
+            jnp.where(c0, base + 3, base + 2),
+            jnp.where(c0, base + 1, none),
+        )
+        return jnp.min(v, axis=0)
 
-    (anp_min, banp_min), _ = jax.lax.scan(
-        body,
-        init,
-        (
-            subj.reshape(g // c, c, a),
-            peerq.reshape(g // c, c, b, q),
-            anp_key.reshape(g // c, c),
-            banp_key.reshape(g // c, c),
-        ),
-    )
-    return anp_min, banp_min
+    return first(anp_key), first(banp_key)
 
 
 def resolve_tier_lattice(

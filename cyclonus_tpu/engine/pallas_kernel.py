@@ -711,7 +711,12 @@ def _packed_compiler_params(blocks, bs: int, bd: int, tiered: bool):
 def _tier_min_keys(src_words, dst_row, anp_ref, banp_ref, rows: int, shape):
     """([BS, BD], [BS, BD]) int32 min ANP / BANP key over the `rows`
     tier rule rows matching each (src, dst) cell — the kernel-local
-    twin of kernel.tier_first_match_keys.  The rule axis arrives
+    twin of kernel.tier_first_match_keys, still a loop over single rule
+    rows with rank keys: that one finds the first match a packed word at
+    a time and keys it by word, and the lattice reads the same of both
+    (`< TIER_KEY_NONE`, `% 4`).  The word form has not been carried in
+    here because no measured cell runs this kernel (at most
+    PACKED_TIER_MAX_ROWS rows).  The rule axis arrives
     bit-packed 32-per-word on BOTH sides: `src_words` [BS, Wl] holds
     the src pods' scope bits with words on the lane axis; `dst_row(w)`
     loads word w of the dst pods' bits as a [1, BD] row.  One rolled

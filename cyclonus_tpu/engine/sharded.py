@@ -55,6 +55,7 @@ from ..utils.tracing import phase
 
 from .kernel import (
     PACKED_CONTRACTION,
+    TIER_FIRST_MATCH,
     WORD_CELLS,
     WORD_FORMAT,
     WORD_TILE,
@@ -547,9 +548,10 @@ def _sharded_program(
         # programs too (engine/aot_cache.py): a restarted process
         # adopts the ring/allgather executables for its mesh without a
         # retrace.  The partition-spec structure, the shard/pack
-        # statics, the form of the packed contraction, the epilogue
-        # and the result's form are program identity the arg shapes
-        # can't see, so they ride in the plan.
+        # statics, the form of the packed contraction, that of the tier
+        # first-match (a tiered set alone: an untiered plan stays as it
+        # was), the epilogue and the result's form are program identity
+        # the arg shapes can't see, so they ride in the plan.
         from . import aot_cache
 
         spec_digest = aot_cache.digest(
@@ -562,6 +564,7 @@ def _sharded_program(
             plan=(
                 f"shard={shard};pack={pack};"
                 + (f"{PACKED_CONTRACTION};" if pack else "")
+                + (f"{TIER_FIRST_MATCH};" if "tiers" in in_specs else "")
                 + f"classes={classes};"
                 f"mesh={','.join(mesh.axis_names)}x{n_dev};{spec_digest};"
                 + ("" if classes else f"{DENSE_EXCHANGE};")

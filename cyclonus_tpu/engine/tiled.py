@@ -68,6 +68,7 @@ from .kernel import (
     tier_direction_arrays,
     tier_first_match_keys,
     tier_rows,
+    tier_words,
 )
 
 
@@ -163,8 +164,8 @@ def counts_tile_form(tensors: Dict) -> str:
 
 
 #: the precedence-tier epilogue an evaluation runs: the min-key loop inside
-#: the fused packed Pallas kernel, or the XLA chunked scan
-#: (kernel.tier_first_match_keys) that every other route runs
+#: the fused packed Pallas kernel, or the XLA min-reduction over packed
+#: rule words (kernel.tier_first_match_keys) that every other route runs
 TIER_FUSED = "fused"
 TIER_XLA = "xla"
 
@@ -172,11 +173,15 @@ TIER_XLA = "xla"
 def tier_attrs(tensors: Dict, kernel: str = TIER_XLA) -> Dict:
     """What an evaluation over the tensor set `tensors` says of its tiers in
     its flight entry and on its `engine.eval` span: tiered=1, tier_rows
-    (the bucketed rule rows, ingress + egress) and tier_kernel; nothing
-    on an untiered set."""
+    (the bucketed rule rows, ingress + egress) and tier_kernel, and on
+    the XLA epilogue tier_words (the packed rule words its first-match
+    reduces over, ingress + egress); nothing on an untiered set."""
     if "tiers" not in tensors:
         return {}
-    return {"tiered": 1, "tier_rows": tier_rows(tensors), "tier_kernel": kernel}
+    out = {"tiered": 1, "tier_rows": tier_rows(tensors), "tier_kernel": kernel}
+    if kernel == TIER_XLA:
+        out["tier_words"] = tier_words(tensors)
+    return out
 
 
 def _precompute_cases(

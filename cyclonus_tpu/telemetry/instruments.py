@@ -94,7 +94,8 @@ TIER_EVALS = REGISTRY.counter(
     "Evaluations of a tiered engine (AdminNetworkPolicy / "
     "BaselineAdminNetworkPolicy over NetworkPolicy), by the epilogue that "
     "resolved the tiers (engine/tiled.py tier_attrs): fused (the packed "
-    "Pallas kernel's) or xla (the chunked scan every other route runs).  "
+    "Pallas kernel's) or xla (the min-reduction over packed rule words "
+    "every other route runs).  "
     "The `tier_kernel` of the evaluation's `engine.eval` span, counted.",
     labelnames=("kernel",),
 )
@@ -783,6 +784,8 @@ def eval_flight(path: str, n_pods: int, q: int, **attrs: Any) -> Iterator[Flight
                     tier_rows=flight.data["tier_rows"],
                     tier_kernel=flight.data["tier_kernel"],
                 )
+                if "tier_words" in flight.data:
+                    sp.set(tier_words=flight.data["tier_words"])
                 TIER_EVALS.inc(kernel=flight.data["tier_kernel"])
             # and what the mesh counts entry decided its route from: one
             # chip's bytes of the replicated precompute and their ceiling

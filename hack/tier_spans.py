@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """Run one benchmark cell traced and list what its window's TIERED requests say
 of themselves (PR 40): a line a request with the evaluation's route, `tiered`,
-`tier_rows` and `tier_kernel`, and its readback barrier (`engine.execute`);
+`tier_rows`, `tier_kernel` and `tier_words`, and its readback barrier
+(`engine.execute`);
 then set-up's `engine.tier_encode` (anps, rules, rows) and `engine.classify`
 (classes, pods) from the start-up record, and the counter
 `cyclonus_tpu_tier_evals_total` by kernel beside the process's evaluations.

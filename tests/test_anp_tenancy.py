@@ -33,7 +33,7 @@ from cyclonus_tpu.matcher import build_network_policies
 from cyclonus_tpu.matcher.tiered import TieredPolicy
 from cyclonus_tpu.synthetic import ANP_TENANCY, anp_tenancy_objects, build_synthetic
 from cyclonus_tpu.telemetry import instruments as ti
-from cyclonus_tpu.telemetry import spans
+from cyclonus_tpu.telemetry import recorder, spans
 from cyclonus_tpu.tiers.model import TierSet, parse_tier_object
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -185,6 +185,12 @@ def test_a_tiered_evaluation_says_so_and_is_counted(engines, route):
     assert (root["tiered"], root["tier_rows"], root["tier_kernel"]) == (
         1, tiled.tier_rows(eng._tensors), tiled.TIER_XLA
     )
+    # the packed rule words the first-match reduces over, ingress + egress
+    words = sum(-(-int(eng._tensors["tiers"][d]["action"].shape[0]) // 32)
+                for d in ("ingress", "egress"))
+    assert root["tier_words"] == words > 0
+    entry = recorder.entries()[-1]
+    assert (entry["tier_kernel"], entry["tier_words"]) == (tiled.TIER_XLA, words)
     assert ti.TIER_EVALS.value(kernel=tiled.TIER_XLA) == before + 1
     spans.REGISTRY.reset()
     eng.evaluate_grid(PAIRS[2])
@@ -200,7 +206,7 @@ def test_an_untiered_evaluation_says_nothing_of_tiers(draw):
     before = ti.TIER_EVALS.value(kernel=tiled.TIER_XLA)
     eng.evaluate_grid_counts(PAIRS[0])
     (root,) = spans_named("engine.eval")
-    assert not {"tiered", "tier_rows", "tier_kernel"} & set(root)
+    assert not {"tiered", "tier_rows", "tier_kernel", "tier_words"} & set(root)
     assert ti.TIER_EVALS.value(kernel=tiled.TIER_XLA) == before
     assert tiled.tier_attrs(eng._tensors) == {}
 
@@ -223,8 +229,12 @@ def test_the_class_routes_estimate_counts_the_tier_arrays(engines, reference, mo
     ct = eng._class_state["ctensors"]
     cb = int(ct["pod_ns_id"].shape[0])
     block, _ = tiled._class_tiling(cb, 1024)
-    tier = api._tier_pre_bytes(ct, cb, 2) + api._tier_scan_bytes(ct, block, cb, 2)
-    assert tier == tiled.tier_rows(ct) * cb * 3 + 2 * block * cb * 2 * 16
+    tier = api._tier_pre_bytes(ct, cb, 2) + api._tier_first_match_bytes(ct, block, cb, 2)
+    # the precompute, then a tile's two int32 keys a direction and its
+    # packed operands: peer words [W, *, Q] and a subject [W, *] a tier
+    w_e, w_i = (-(-int(ct["tiers"][d]["action"].shape[0]) // 32) for d in ("egress", "ingress"))
+    packed = 4 * w_e * (2 * block + cb * 2) + 4 * w_i * (2 * cb + block * 2)
+    assert tier == tiled.tier_rows(ct) * cb * 3 + 2 * 2 * 4 * block * cb * 2 + packed
     est = eng._class_counts_bytes(2)
     assert est > tier > 0
     monkeypatch.setenv("CYCLONUS_SLAB_MAX_BYTES", str(est))

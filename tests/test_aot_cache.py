@@ -441,6 +441,76 @@ class TestPackedContractionInKey:
         assert moved.get("miss", 0) >= 1 and moved.get("compiles", 0) >= 1
 
 
+class TestTierFirstMatchInKey:
+    """The form of the tier first-match (kernel.TIER_FIRST_MATCH) rides in
+    the plan of every TIERED program: the executable of a build whose
+    first-match was the 8-row scan lies under another key and is never
+    adopted.  An untiered plan is the string it was, so no untiered
+    executable is rebuilt for it."""
+
+    @pytest.mark.parametrize("tiered", [True, False], ids=["tiered", "untiered"])
+    def test_engine_plan_names_the_form_only_when_tiered(self, monkeypatch, tiered):
+        from cyclonus_tpu.engine import TpuPolicyEngine, kernel
+        from cyclonus_tpu.synthetic import tiers_lattice
+
+        monkeypatch.setenv("CYCLONUS_PACK", "1")
+        policy, pods, namespaces, _cases = _small_problem()
+        engine = TpuPolicyEngine(
+            policy, pods, namespaces, tiers=tiers_lattice() if tiered else None
+        )
+        plan = engine._aot_plan()
+        parent = f"packed32;{kernel.PACKED_CONTRACTION};tiered={tiered}"
+        if not tiered:
+            assert plan == parent and "first=" not in plan
+            assert engine._aot_plan("x") == parent + ";x"
+            return
+        assert plan == f"{parent};{kernel.TIER_FIRST_MATCH}"
+        assert engine._aot_plan("x") == plan + ";x"
+
+    def test_row_scan_era_executable_is_not_adopted(self, tmp_path, monkeypatch):
+        """A cache directory that holds the PARENT's executable of the same
+        tiered program, shapes and platform (its plan without the form): the
+        engine misses, builds its own and stores it beside the old one."""
+        import numpy as np
+
+        from cyclonus_tpu.engine import TpuPolicyEngine, kernel
+        from cyclonus_tpu.synthetic import tiers_lattice
+
+        monkeypatch.setenv("CYCLONUS_AOT_CACHE", str(tmp_path))
+        monkeypatch.setenv("CYCLONUS_PACK", "1")
+        policy, pods, namespaces, cases = _small_problem()
+
+        def engine():
+            return TpuPolicyEngine(
+                policy, pods, namespaces, class_compress="0", tiers=tiers_lattice()
+            )
+
+        def entries():
+            return _entries(tmp_path, "grid")
+
+        aot_cache.forget()
+        first = engine()
+        want = first.evaluate_grid(cases).combined
+        ((key, path),) = entries().items()
+        parts = json.loads(key)
+        assert f";{kernel.TIER_FIRST_MATCH};" in parts["plan"]
+        old_key = aot_cache.make_key(
+            "grid", parts["sig"], schedule=parts["schedule"],
+            plan=parts["plan"].replace(f";{kernel.TIER_FIRST_MATCH}", ""),
+        )
+        assert old_key != key
+        assert aot_cache.store(old_key, aot_cache.load(key))
+        assert aot_cache.load(old_key) is not None
+        path.unlink()
+        aot_cache.forget()
+        before = _outcomes()
+        grid = engine().evaluate_grid(cases)
+        assert np.array_equal(grid.combined, want)
+        assert set(entries()) == {key, old_key}
+        moved = _moved(before)
+        assert moved.get("miss", 0) >= 1 and moved.get("compiles", 0) >= 1
+
+
 def _outcomes():
     from cyclonus_tpu.telemetry import instruments as ti
 
