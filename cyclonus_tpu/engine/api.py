@@ -792,7 +792,8 @@ _PRE_CACHE_MAX_BYTES = 2 << 30
 #: one chip's copy of it, from the shapes, stays under this many bytes and
 #: under half of what the device reports as its memory; past it both pod
 #: axes stay sharded (the ring).  Half of a v5e chip's 16 GB: the program's
-#: temporaries (peer_allow as bf16 beside the boolean) take the other half
+#: temporaries (peer_allow as bf16 beside the boolean, as the estimate
+#: still reckons them: _mesh_replicated_bytes) take the other half
 _MESH_REPLICATED_MAX_BYTES = 8 << 30
 
 
@@ -1329,13 +1330,15 @@ class TpuPolicyEngine:
         contraction (kernel.PACKED_CONTRACTION: every packed program can
         trace kernel.packed_any, whose code the key cannot see), vs the
         dense operand dtype, plus the tier flag and, on a tiered engine
-        alone, the form of the tier first-match (kernel.TIER_FIRST_MATCH:
-        an untiered plan stays what it was).  Programs whose trace
+        alone, the form of the tier first-match (kernel.TIER_FIRST_MATCH),
+        then the form of the target-allows contraction
+        (kernel.TARGET_ALLOWS: nearly every program traces
+        tiled._precompute_cases, through _precompute).  Programs whose trace
         bakes per-engine constants (the unpack closures' leaf layout)
         append a metas digest via `extra` — two engines with equal
         buffer lengths but different leaf layouts must never share an
         executable."""
-        from .kernel import PACKED_CONTRACTION, TIER_FIRST_MATCH
+        from .kernel import PACKED_CONTRACTION, TARGET_ALLOWS, TIER_FIRST_MATCH
         from .pallas_kernel import _resolve_operand_dtype
 
         dtype = (
@@ -1346,6 +1349,7 @@ class TpuPolicyEngine:
         plan = f"{dtype};tiered={self.tiers is not None}"
         if self.tiers is not None:
             plan += f";{TIER_FIRST_MATCH}"
+        plan += f";{TARGET_ALLOWS}"
         return plan + (";" + extra if extra else "")
 
     @staticmethod
@@ -3254,7 +3258,10 @@ class TpuPolicyEngine:
         (_pre_bytes_estimate), and the largest temporary of
         tiled._precompute_cases, a direction's `peer_allow` [P, N * Q]
         as booleans and as bf16 for the one-hot matmul beside its
-        product [T, N * Q] bf16."""
+        product [T, N * Q] bf16.  The function no longer builds that
+        operand (the port cases ride the one-hot: kernel.target_allows_q),
+        so the sum overstates a request; the term stays so that no route
+        moves before the rows route has a chip number."""
         t = self._tensors
         temp = max(
             3 * int(t[d]["peer_target"].shape[0])

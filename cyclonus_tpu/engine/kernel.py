@@ -9,8 +9,12 @@ policy.go:138-174), restructured for the MXU:
     tmatch[T, N]      target t applies to pod n                (ns eq AND sel)
     peer_match[P, N]  peer p matches pod n (ports aside)       (kind switch)
     pport[P, Q]       peer p's port spec allows port case q    (int compares)
-    peer_allow[P,N,Q] = peer_match & pport
-    tallow[T, N, Q]   = one_hot(peer->target) @ peer_allow     <- MXU matmul
+    tallow[T, N, Q]   = one_hot(peer->target) @ (peer_match & pport)
+                        <- MXU matmul; the counts precompute takes
+                        (one_hot & pport[:, q]) @ peer_match, the
+                        cases stacked on the one-hot's rows
+                        (target_allows_q), the tables kernel the
+                        [P, N*Q] operand (direction_allowed)
     any_allow[n,m,Q]  = tmatch^T @ tallow                      <- MXU matmul
     allowed[n, m, q]  = NOT has_target[n] OR any_allow > 0
 
@@ -320,16 +324,51 @@ def lpm_partition_signature(
     ).astype(jnp.int32)
 
 
-def m_tp_onehot(enc: Dict) -> jnp.ndarray:
+def m_tp_onehot(enc: Dict, rows: Optional[int] = None) -> jnp.ndarray:
     """[T, P] bool peer->target one-hot, built ON DEVICE from the [P]
     peer_target index vector.  The dense matrix reaches ~70 MB at the
     10k-policy bench scale — shipping the index vector instead keeps it
     out of the host->device transfer (the saving is not measured on the
     current machine; the one-hot compare is free next to the verdict
-    matmuls)."""
-    t = enc["target_ns"].shape[0]
+    matmuls).  `rows` past T adds rows that match no peer (peer_target
+    is a target index or the -1 pad)."""
+    t = enc["target_ns"].shape[0] if rows is None else rows
     pt = enc["peer_target"]
     return pt[None, :] == jnp.arange(t, dtype=pt.dtype)[:, None]
+
+
+#: the form of the target-allows contraction (target_allows_q: the port
+#: cases on the one-hot's side), in the AOT plan of every program that
+#: can trace tiled._precompute_cases (TpuPolicyEngine._aot_plan, the
+#: sharded ring's plan): an executable of the earlier form, which built
+#: peer_allow [P, N, Q] and relaid it, lies under another key
+TARGET_ALLOWS = "tallow=qonehot"
+
+
+def target_allows_q(
+    enc: Dict,
+    peer_match: jnp.ndarray,
+    pport: jnp.ndarray,
+    rows: Optional[int] = None,
+) -> jnp.ndarray:
+    """[Q, T, N] bool: target t allows pod n on the PEER side for port
+    case q, OR_p (peer_target[p] == t) & pport[p, q] & peer_match[p, n].
+    The port cases sit on the SMALL side of the contraction: the one-hot
+    masked by each case's column of `pport`, [Q * T, P], against
+    peer_match [P, N] as it is, in one MXU matmul with the flops of
+    `m_tp @ (peer_match & pport)` and no [P, N, Q] operand or relayout
+    of one.  `rows` (m_tp_onehot's) pads T with false rows, so that a
+    packer of T finds nothing to pad.  bf16 accumulation of 0/1 terms:
+    `> 0` is exact (tiled.py's memory note)."""
+    m_tp = m_tp_onehot(enc, rows)  # [T, P]
+    q, t = pport.shape[1], m_tp.shape[0]
+    folded = (pport.T[:, None, :] & m_tp[None, :, :]).reshape(q * t, -1)
+    tallow = jnp.matmul(
+        folded.astype(jnp.bfloat16),
+        peer_match.astype(jnp.bfloat16),
+        preferred_element_type=jnp.bfloat16,
+    )  # [Q * T, N]
+    return (tallow > 0).reshape(q, t, peer_match.shape[1])
 
 
 def direction_allowed(

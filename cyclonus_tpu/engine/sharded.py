@@ -55,6 +55,7 @@ from ..utils.tracing import phase
 
 from .kernel import (
     PACKED_CONTRACTION,
+    TARGET_ALLOWS,
     TIER_FIRST_MATCH,
     WORD_CELLS,
     WORD_FORMAT,
@@ -550,8 +551,10 @@ def _sharded_program(
         # retrace.  The partition-spec structure, the shard/pack
         # statics, the form of the packed contraction, that of the tier
         # first-match (a tiered set alone: an untiered plan stays as it
-        # was), the epilogue and the result's form are program identity
-        # the arg shapes can't see, so they ride in the plan.
+        # was), that of the target-allows contraction (the ring alone
+        # traces tiled._precompute), the epilogue and the result's form
+        # are program identity the arg shapes can't see, so they ride in
+        # the plan.
         from . import aot_cache
 
         spec_digest = aot_cache.digest(
@@ -562,7 +565,8 @@ def _sharded_program(
             fn,
             schedule=schedule,
             plan=(
-                f"shard={shard};pack={pack};"
+                (f"{TARGET_ALLOWS};" if schedule == "ring" else "")
+                + f"shard={shard};pack={pack};"
                 + (f"{PACKED_CONTRACTION};" if pack else "")
                 + (f"{TIER_FIRST_MATCH};" if "tiers" in in_specs else "")
                 + f"classes={classes};"

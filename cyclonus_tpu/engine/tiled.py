@@ -56,7 +56,7 @@ from jax.sharding import PartitionSpec as P
 from ..telemetry import instruments as ti
 from ..utils import cachekeys
 from ..utils.tracing import detail, phase
-from .encoding import TIER_KEY_NONE, pack_enabled
+from .encoding import PACK_BITS, TIER_KEY_NONE, pack_enabled, packed_words
 from .kernel import (
     direction_precompute,
     m_tp_onehot,
@@ -65,6 +65,7 @@ from .kernel import (
     port_spec_allows,
     resolve_tier_lattice,
     selector_match,
+    target_allows_q,
     tier_direction_arrays,
     tier_first_match_keys,
     tier_rows,
@@ -197,42 +198,35 @@ def _precompute_cases(
     `pre` dict of _precompute, leaf for leaf.  `tile` TILE_BQN lays the
     EGRESS tallow [T, Q, N] (W for T packed) for the counts tile body
     (_tile_counts_split), the same bytes in another order; a tiered
-    static keeps [T, N, Q] (counts_tile_form)."""
+    static keeps [T, N, Q] (counts_tile_form).  The contraction is
+    kernel.target_allows_q, [Q, T, N], the port cases on the one-hot's
+    rows against the resident peer_match: no [P, N, Q] operand exists,
+    and T is packed where it lies (the one-hot padded to whole words)
+    before the small words take their order."""
     out = {}
-    q = q_port.shape[0]
     qn = tile == TILE_BQN and counts_tile_form(static) == TILE_BQN
     for direction in ("ingress", "egress"):
         st = static[direction]
         pport = port_spec_allows(st["port_spec"], q_port, q_name, q_proto)
-        n_p, n = st["peer_match"].shape
-        if qn and direction == "egress":
-            peer_allow = (
-                st["peer_match"][:, None, :] & pport[:, :, None]
-            ).reshape(n_p, q * n)  # shape: (P, QN)
-        else:
-            peer_allow = (
-                st["peer_match"][:, :, None] & pport[:, None, :]
-            ).reshape(n_p, n * q)  # shape: (P, NQ)
-        tallow = jnp.matmul(
-            m_tp_onehot(st).astype(jnp.bfloat16),
-            peer_allow.astype(jnp.bfloat16),
-            preferred_element_type=jnp.bfloat16,
-        )
-        t = tallow.shape[0]
-        lay = (t, q, n) if qn and direction == "egress" else (t, n, q)
+        t = st["target_ns"].shape[0]
+        tallow = target_allows_q(
+            st, st["peer_match"], pport, packed_words(t) * PACK_BITS if pack else t
+        )  # shape: (Q, T, N) | (Q, 32W, N)
+        q_axis = 1 if qn and direction == "egress" else 2
         out[direction] = {
             "tmatch": st["tmatch"],
             "has_target": st["has_target"],
         }
         if pack:
-            out[direction]["tallow_pk"] = pack_bool_words_jnp(
-                (tallow > 0).reshape(lay)
+            words = pack_bool_words_jnp(tallow, axis=1)  # shape: (Q, W, N)
+            out[direction]["tallow_pk"] = jnp.moveaxis(
+                words, 0, q_axis
             )  # shape: (W, N, Q) | (W, Q, N) int32
             out[direction]["tmatch_pk"] = st["tmatch_pk"]
         else:
-            out[direction]["tallow_bf"] = (
-                (tallow > 0).astype(jnp.bfloat16).reshape(lay)
-            )
+            out[direction]["tallow_bf"] = jnp.moveaxis(
+                tallow.astype(jnp.bfloat16), 0, q_axis
+            )  # shape: (T, N, Q) | (T, Q, N)
         if "tiers" in static:
             # precedence-tier precompute (docs/DESIGN.md "Precedence
             # tiers"): subj/peerq/keys ride next to tallow so every tile
@@ -256,7 +250,8 @@ def _precompute(
     """Per-direction, port-resolved precompute shared by every tile:
 
       tallow_bf [T, N, Q] bf16 — target t allows traffic with pod n on the
-                                 PEER side for port case q (m_tp @ peer_allow)
+                                 PEER side for port case q
+                                 (kernel.target_allows_q)
       tmatch    [T, N] bool    — target t applies to pod n (target side)
       has_target[N] bool
 

@@ -445,8 +445,7 @@ class TestTierFirstMatchInKey:
     """The form of the tier first-match (kernel.TIER_FIRST_MATCH) rides in
     the plan of every TIERED program: the executable of a build whose
     first-match was the 8-row scan lies under another key and is never
-    adopted.  An untiered plan is the string it was, so no untiered
-    executable is rebuilt for it."""
+    adopted.  An untiered plan names no first-match form."""
 
     @pytest.mark.parametrize("tiered", [True, False], ids=["tiered", "untiered"])
     def test_engine_plan_names_the_form_only_when_tiered(self, monkeypatch, tiered):
@@ -460,11 +459,12 @@ class TestTierFirstMatchInKey:
         )
         plan = engine._aot_plan()
         parent = f"packed32;{kernel.PACKED_CONTRACTION};tiered={tiered}"
+        tail = f";{kernel.TARGET_ALLOWS}"
         if not tiered:
-            assert plan == parent and "first=" not in plan
-            assert engine._aot_plan("x") == parent + ";x"
+            assert plan == parent + tail and "first=" not in plan
+            assert engine._aot_plan("x") == parent + tail + ";x"
             return
-        assert plan == f"{parent};{kernel.TIER_FIRST_MATCH}"
+        assert plan == f"{parent};{kernel.TIER_FIRST_MATCH}{tail}"
         assert engine._aot_plan("x") == plan + ";x"
 
     def test_row_scan_era_executable_is_not_adopted(self, tmp_path, monkeypatch):
@@ -509,6 +509,62 @@ class TestTierFirstMatchInKey:
         assert set(entries()) == {key, old_key}
         moved = _moved(before)
         assert moved.get("miss", 0) >= 1 and moved.get("compiles", 0) >= 1
+
+
+class TestTargetAllowsInKey:
+    """The form of the target-allows contraction (kernel.TARGET_ALLOWS,
+    the port cases on the one-hot's side) rides in the plan of every
+    program that can trace tiled._precompute_cases: every engine plan,
+    packed or not, tiered or not, and the sharded ring's.  The
+    executable of a build that built peer_allow [P, N, Q] lies under
+    another key and is never adopted."""
+
+    @pytest.mark.parametrize("pack", ["1", "0"])
+    @pytest.mark.parametrize("tiered", [True, False], ids=["tiered", "untiered"])
+    def test_engine_plan_names_the_form(self, monkeypatch, pack, tiered):
+        from cyclonus_tpu.engine import TpuPolicyEngine, kernel
+        from cyclonus_tpu.synthetic import tiers_lattice
+
+        monkeypatch.setenv("CYCLONUS_PACK", pack)
+        policy, pods, namespaces, _cases = _small_problem()
+        engine = TpuPolicyEngine(
+            policy, pods, namespaces, tiers=tiers_lattice() if tiered else None
+        )
+        plan = engine._aot_plan()
+        assert plan.endswith(f";{kernel.TARGET_ALLOWS}")
+        assert engine._aot_plan("x") == plan + ";x"
+        form = kernel.TARGET_ALLOWS
+        monkeypatch.setattr(kernel, "TARGET_ALLOWS", "tallow=peer_allow")
+        other = engine._aot_plan()
+        assert other == plan.replace(form, "tallow=peer_allow") != plan
+        keys = {
+            aot_cache.make_key("counts.cases", "sig", plan=p) for p in (plan, other)
+        }
+        assert len(keys) == 2
+
+    @pytest.mark.parametrize("schedule", ["ring", "allgather"])
+    def test_sharded_plan_names_the_form_where_the_program_traces_it(
+        self, monkeypatch, schedule
+    ):
+        """sharded.grid's ring traces tiled._precompute; its allgather
+        schedule (direction_precompute a shard) does not, and keeps its
+        plan."""
+        import jax
+        import numpy as np
+        from jax.sharding import Mesh, PartitionSpec as P
+
+        from cyclonus_tpu.engine import kernel, sharded
+
+        mesh = Mesh(np.array(jax.devices()[:2]), ("x",))
+        plans = []
+        monkeypatch.setattr(
+            aot_cache, "AotProgram",
+            lambda name, fn, schedule, plan: plans.append(plan) or fn,
+        )
+        monkeypatch.setattr(sharded, "_SHARDED_PROGRAMS", {})
+        sharded._sharded_program(mesh, schedule, 8, {"q_port": P()})
+        (plan,) = plans
+        assert (f"{kernel.TARGET_ALLOWS};" in plan) == (schedule == "ring")
 
 
 def _outcomes():

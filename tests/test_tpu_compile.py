@@ -216,3 +216,69 @@ def test_the_tier_scan_keeps_two_keys_and_no_match(one_chip):
     assert " while(" not in text
     key_reduce = re.compile(rf"= s32\[{a},{b},{q}\]\{{[^}}]*\}} reduce\(")
     assert len(key_reduce.findall(text)) == 2
+
+
+#: `cidr-10k-5k`'s request (`counts.cases`): each direction's peer rows,
+#: the targets, the padded pods, the port cases, a port spec's items and
+#: ranges
+CASES_CELL = dict(p={"ingress": 13_056, "egress": 11_520}, t=895, n=10_240, q=2,
+                  items=2, ranges=1)
+#: the parent's `_precompute_cases` (peer_allow [P, N * Q] and its
+#: relayout) compiled at CASES_CELL with pack on for a described v5e,
+#: read once: memory_analysis().temp_size_in_bytes, by tile form
+PARENT_CASES_TEMP_BYTES = {"bnq": 803_450_880, "bqn": 803_354_112}
+
+
+@pytest.mark.parametrize("tile", ["bnq", "bqn"])
+def test_the_case_precompute_builds_no_peer_allow(one_chip, tile):
+    """tiled._precompute_cases at that request (kernel.TARGET_ALLOWS):
+    the port cases ride the one-hot, so no boolean array of P x N x Q
+    elements exists, the resident peer_match is read where it lies (no
+    copy of a pred[P, N] table into another layout), and the program's
+    temporaries are no more than the parent's."""
+    import re
+
+    from cyclonus_tpu.engine import tiled
+    from cyclonus_tpu.engine.encoding import packed_words
+
+    t, n, q = CASES_CELL["t"], CASES_CELL["n"], CASES_CELL["q"]
+    i, r = CASES_CELL["items"], CASES_CELL["ranges"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def direction(p):
+        spec = {k: sds((p, i), jnp.int32)
+                for k in ("item_kind", "item_port", "item_name", "item_proto")}
+        spec.update({k: sds((p, r), jnp.int32)
+                     for k in ("rng_from", "rng_to", "rng_proto")})
+        spec["spec_all"] = sds((p,), jnp.bool_)
+        return {
+            "peer_match": sds((p, n), jnp.bool_),
+            "tmatch": sds((t, n), jnp.bool_),
+            "has_target": sds((n,), jnp.bool_),
+            "peer_target": sds((p,), jnp.int32),
+            "target_ns": sds((t,), jnp.int32),
+            "port_spec": spec,
+            "tmatch_pk": sds((packed_words(t), n), jnp.int32),
+        }
+
+    static = {d: direction(p) for d, p in CASES_CELL["p"].items()}
+    case = sds((q,), jnp.int32)
+    compiled = (
+        jax.jit(lambda s, a, b, c: tiled._precompute_cases(s, a, b, c, True, tile))
+        .lower(static, case, case, case)
+        .compile()
+    )
+    text = compiled.as_text()
+    op = re.compile(r"= pred\[([\d,]+)\]\{[^}]*\} ([\w-]+)\(")
+    ops = [
+        (tuple(int(d) for d in m.group(1).split(",")), m.group(2))
+        for m in op.finditer(text)
+    ]
+    least = min(CASES_CELL["p"].values()) * n * q
+    assert [o for o in ops if np.prod(o[0]) >= least] == []
+    tables = {(p, n) for p in CASES_CELL["p"].values()}
+    assert [o for o in ops if o[0] in tables and o[1] == "copy"] == []
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= PARENT_CASES_TEMP_BYTES[tile]
