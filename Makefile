@@ -98,7 +98,7 @@ keyharness:
 # entry points, and assert the recorded routes equal what the PathSpec
 # registry predicts — including the compatibility matrix's exact raise
 # messages.  The quick slice runs in tier-1 via tests/test_planlint.py;
-# this is the full sweep (adds the slow ring-pipeline leg).
+# this is the full sweep (adds the scenarios marked slow).
 planharness:
 	JAX_PLATFORMS=cpu python -m tests.planharness --full --verbose
 

@@ -11,8 +11,8 @@ references (the scalar oracle, the XLA tile loop, the single-device kernel):
           full [Q, N, N] tables at 10,000 x 1,000 with their sums tied to the
           counts kernels; every Pallas variant the default shapes do not
           reach; `analyze --mode probe` and a slice of `generate --mock`
-  mesh    on more than one device, in the same JAX process: sharded and ring
-          counts at 100,000 x 10,000 and both grid schedules at 10,000 x
+  mesh    on more than one device, in the same JAX process: the mesh counts
+          entry at 100,000 x 10,000 and both grid schedules at 10,000 x
           1,000 against the single-device results, and the CLI slice under
           `--engine tpu-sharded`
   serve   `python -m cyclonus_tpu serve` over 100,000 pods and the 10,000
@@ -549,7 +549,6 @@ def mesh_phase(state: dict) -> None:
     for label, fn in (
         ("sharded default", lambda: state["default"].evaluate_grid_counts_sharded(cases)),
         ("sharded dense", lambda: state["dense"].evaluate_grid_counts_sharded(cases)),
-        ("ring dense", lambda: state["dense"].evaluate_grid_counts_ring(cases)),
     ):
         got, r = routes_of(fn)
         say(f"   counts {label} {r}: {got}")

@@ -3391,69 +3391,6 @@ class TpuPolicyEngine:
             "cells": q * n * n,
         }
 
-    def evaluate_grid_counts_ring(
-        self, cases: Sequence[PortCase], block: int = 1024, mesh=None
-    ) -> Dict[str, int]:
-        """Ring-rotation counts: both pod axes stay sharded and the
-        dst-side precompute rotates around the mesh with ppermute —
-        per-device memory O(N / mesh size), the path for clusters whose
-        precompute exceeds one device (engine/tiled.py)."""
-        self._check_ips()
-        n = self.encoding.cluster.n_pods
-        if not cases or n == 0:
-            return {"ingress": 0, "egress": 0, "combined": 0, "cells": 0}
-        planspec.record("counts.ring")
-        from .tiled import evaluate_grid_counts_ring
-
-        return evaluate_grid_counts_ring(
-            self._tensors_with_cases(cases), n, block=block, mesh=mesh
-        )
-
-    def mesh_counts_pipelined_eval_s(
-        self,
-        cases: Sequence[PortCase],
-        reps: int = 10,
-        block: int = 1024,
-        mesh=None,
-    ):
-        """Steady-state DEVICE-side seconds per MESH counts evaluation —
-        counts_pipelined_eval_s's twin for the overlapped ring path:
-        one seed dispatch pins the sharded tensors + per-shard
-        precompute on the mesh, then `reps` ring sweeps run back to
-        back with the rotating peer bundle DONATED and fed forward
-        (engine/tiled.py ring_counts_pipeline), one readback at the
-        end.  Returns (seconds_per_eval, counts), or None for an empty
-        problem."""
-        self._check_ips()
-        n = self.encoding.cluster.n_pods
-        if not cases or n == 0:
-            return None
-        planspec.record("counts.ring.pipelined")
-        from .tiled import evaluate_grid_counts_ring_pipelined
-
-        return evaluate_grid_counts_ring_pipelined(
-            self._tensors_with_cases(cases), n, reps=reps, block=block,
-            mesh=mesh,
-        )
-
-    def evaluate_grid_counts_ring2d(
-        self, cases: Sequence[PortCase], block: int = 1024, mesh=None
-    ) -> Dict[str, int]:
-        """Hierarchical multi-host ring counts over a ("dcn", "ici") mesh:
-        ring hops ride the intra-host ICI ring and cross the DCN host
-        boundary once per round (engine/tiled.py ring2d).  The multi-host
-        scale-out path."""
-        self._check_ips()
-        n = self.encoding.cluster.n_pods
-        if not cases or n == 0:
-            return {"ingress": 0, "egress": 0, "combined": 0, "cells": 0}
-        planspec.record("counts.ring2d")
-        from .tiled import evaluate_grid_counts_ring2d
-
-        return evaluate_grid_counts_ring2d(
-            self._tensors_with_cases(cases), n, block=block, mesh=mesh
-        )
-
     def iter_grid_blocks(self, cases: Sequence[PortCase], block: int = 1024):
         """Stream verdict blocks of source rows to the host:
         yields (start, ingress_rows, egress, combined), arrays [b, N, Q]

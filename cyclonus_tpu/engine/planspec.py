@@ -217,31 +217,16 @@ PATHS: Tuple[PathSpec, ...] = (
         gate="tests/test_engine_sharded.py",
         when={"classes": False, "kernel": "xla"},
     ),
-    # --- ring family ---------------------------------------------------------
     PathSpec(
-        "counts.ring", "counts_ring",
-        stages=("pack", "contract", "epilogue"),
+        "counts.ring", "counts_sharded",
+        stages=("pack", "contract", "tier-resolve", "epilogue"),
         flags=("CYCLONUS_PACK",),
+        ctor_args=("tiers",),
         cache_key_family="counts_ring",
-        gate="tests/test_engine_tiled.py",
-        when={},
-    ),
-    PathSpec(
-        "counts.ring.pipelined", "counts_ring_pipelined",
-        stages=("pack", "contract", "epilogue"),
-        flags=("CYCLONUS_PACK",),
-        cache_key_family="counts_ring",
-        gate="tests/test_engine_tiled.py",
-        coverage="slow",  # the donation/feed-forward sweep is bench-scale
-        when={},
-    ),
-    PathSpec(
-        "counts.ring2d", "counts_ring2d",
-        stages=("pack", "contract", "epilogue"),
-        flags=("CYCLONUS_PACK",),
-        cache_key_family="counts_ring2d",
-        gate="tests/test_engine_tiled.py",
-        when={},
+        gate="tests/test_cidr_counts_mesh.py",
+        # predict() picks it before the kernel pair: the route decision
+        # (api._mesh_counts_route) comes first, whatever the kernel
+        when={"classes": False, "replicated_fits": False},
     ),
     # --- point / streaming / analysis ---------------------------------------
     PathSpec(
@@ -523,14 +508,15 @@ def predict(entry: str, features: Mapping[str, object]) -> str:
         f["backend"] = backend
     elif entry == "counts_sharded":
         if not f.get("classes", False):
-            # the mesh counts entry's own decision (api._mesh_counts_route):
-            # a dense precompute that no chip holds replicated keeps both
-            # pod axes sharded, whatever the kernel
-            if f.get("replicated_fits") is False:
-                return "counts.ring"
+            # the live entry resolves the kernel (and raises) first
             kernel = resolve_sharded_counts_kernel(
                 kernel=f.get("kernel"), tiers=bool(f.get("tiers", False))
             )
+            # then its own route decision (api._mesh_counts_route): a
+            # dense precompute that no chip holds replicated keeps both
+            # pod axes sharded, whatever the kernel
+            if f.get("replicated_fits") is False:
+                return "counts.ring"
             if kernel is None:
                 kernel = "pallas" if f.get("platform") == "tpu" else "xla"
             f["kernel"] = kernel

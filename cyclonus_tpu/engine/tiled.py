@@ -41,7 +41,6 @@ _slab_lock to stay off the LK002 cycle graph).
 
 from __future__ import annotations
 
-import math
 from functools import partial
 from typing import Dict, Iterator, Tuple
 
@@ -54,7 +53,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..telemetry import instruments as ti
-from ..utils import cachekeys
 from ..utils.tracing import detail, phase
 from .encoding import PACK_BITS, TIER_KEY_NONE, pack_enabled, packed_words
 from .kernel import (
@@ -292,8 +290,8 @@ def _dst_bundle_keys(ring: Dict) -> Tuple[str, ...]:
 
 
 def _ring_sweep(n_dev: int, ring: Dict, init, body):
-    """THE double-buffered ring loop every 1-D ring path shares — the
-    sync ring counts, the pipelined twin, and the sharded grid ring
+    """THE double-buffered ring loop both 1-D ring paths share — the
+    ring counts (_ring_counts) and the sharded grid ring
     (sharded._ring_grid_eval) — so the schedule can never diverge
     between them.  One ppermute hop per step, ISSUED BEFORE the step's
     compute: the transfer and the compute both only read the current
@@ -302,9 +300,7 @@ def _ring_sweep(n_dev: int, ring: Dict, init, body):
     consumes the bundle currently held.  All n_dev hops run — the final
     rotation returns every bundle to its origin; it is kept rather than
     guarded out because collectives under lax.cond don't lower
-    reliably, it is one ICI transfer, and the pipelined twin RELIES on
-    it to hand the bundle back for the next eval's donation.  Returns
-    (acc, ring-at-origin)."""
+    reliably and it is one ICI transfer.  Returns (acc, ring-at-origin)."""
     perm = [(d, (d + 1) % n_dev) for d in range(n_dev)]
 
     def ring_step(step, carry):
@@ -1028,41 +1024,6 @@ def mesh_counts_kernel(kernel: str = None) -> str:
     return kernel
 
 
-def _run_mesh_counts(
-    per_device, mesh, in_specs, tensors: Dict, q: int, n_pods: int,
-    path: str = "counts.mesh", xla_tile: bool = True,
-) -> Dict[str, int]:
-    """Shared tail of every mesh count path: one shard_map execution,
-    then the int64 host sum of the [*, 3] int32 partials (device-side
-    int64 silently truncates without jax_enable_x64).  `path` labels the
-    telemetry flight entry with the calling mesh strategy; `xla_tile`
-    (the program runs the XLA counts tile body) puts the tile's form
-    there."""
-    from jax.sharding import PartitionSpec as P
-
-    from .sharded import mesh_device_context, shard_map_no_check
-
-    fn = jax.jit(
-        shard_map_no_check(
-            per_device, mesh=mesh, in_specs=(in_specs,), out_specs=P()
-        )
-    )
-    form = {"tile": counts_tile_form(tensors)} if xla_tile else {}
-    with ti.eval_flight(
-        path, n_pods, q, devices=int(mesh.devices.size), **form,
-        **tier_attrs(tensors),
-    ) as fl:
-        with mesh_device_context(mesh):
-            counts = np.asarray(fn(tensors), dtype=np.int64).sum(axis=0)
-        fl.set(cells=q * n_pods * n_pods)
-    return {
-        "ingress": int(counts[0]),
-        "egress": int(counts[1]),
-        "combined": int(counts[2]),
-        "cells": q * n_pods * n_pods,
-    }
-
-
 def _ring_counts(pre: Dict, n_pods, n_dev: int, shard: int, block: int):
     """The pod-sharded ring's per-device body from the shard's `pre` on:
     the src view stays local, the dst view (and its validity mask)
@@ -1070,8 +1031,7 @@ def _ring_counts(pre: Dict, n_pods, n_dev: int, shard: int, block: int):
     of the [n_dev * tiles, 3] int32 partials.  `pre` is built with
     tile=TILE_BQN.  With packing on the rotating bundle carries the
     packed words (~16x fewer bytes a hop).
-    `n_pods` may be traced.  Shared by the per-call ring
-    (evaluate_grid_counts_ring) and the held program
+    `n_pods` may be traced.  The ring route's body in the held program
     (mesh_counts_programs)."""
     tiles_per_shard = shard // block
     row0 = jax.lax.axis_index("x") * shard
@@ -1104,9 +1064,8 @@ def _row_counts(
     rectangular Pallas kernel (kernel="pallas") or the XLA tile loop,
     then the ONE gather of the int32 partials.  `n_pods` may be traced.
     `pre` is built with tile=TILE_BQN for the tile loop and at the
-    default for the Pallas kernel.  Shared by the per-call program
-    (evaluate_grid_counts_sharded) and the held one
-    (mesh_counts_programs)."""
+    default for the Pallas kernel.  The rows route's body in the held
+    program (mesh_counts_programs)."""
     shard = n_padded // n_dev
     tiles_per_dev = shard // block
     row0 = jax.lax.axis_index("x") * shard
@@ -1215,9 +1174,8 @@ def mesh_counts_programs(
 ):
     """(static, cases): the held pair over `mesh` for the case-free
     `tensors`, padded to whole tiles a device.  `route` "ring"
-    keeps both pod axes sharded (evaluate_grid_counts_ring's program),
-    "rows" replicates the precompute and splits the source rows
-    (evaluate_grid_counts_sharded's, under `kernel`).
+    keeps both pod axes sharded (_ring_counts), "rows" replicates the
+    precompute and splits the source rows (_row_counts, under `kernel`).
 
       static(tensors)               -> _precompute_static a device
       cases(static, cases, n_pods)  -> [*, 3] int32 partials, gathered
@@ -1287,425 +1245,6 @@ def mesh_counts_programs(
         plan=plan,  # cache-key: static_specs
     )
     return static_fn, cases_fn
-
-
-def evaluate_grid_counts_ring(
-    tensors: Dict, n_pods: int, block: int = 1024, mesh=None
-) -> Dict[str, int]:
-    """Ring-rotation counts: BOTH pod axes stay sharded.
-
-    evaluate_grid_counts_sharded replicates the dst-side precompute
-    (tallow is [T, N, Q] bf16 — the memory ceiling at large N); here each
-    device keeps only its OWN pod shard's precompute, and the dst-side
-    block rotates around the ring with jax.lax.ppermute, one hop per
-    step — structurally the ring-attention/blockwise pattern from
-    SURVEY.md §5 with verdict tiles in place of attention blocks:
-
-        for step in range(n_dev):
-            counts += local_src_rows x current_dst_block   (MXU tiles)
-            dst_block <- left neighbor                      (ICI ppermute)
-
-    Per-device memory is O(N/n_dev) instead of O(N), so max cluster size
-    scales linearly with the mesh.  The rotating state is the
-    (tallow_e, tmatch_i, has_i, tallow_i, tmatch_e-free) dst bundle; the
-    ppermute overlaps with the next step's tile matmuls under XLA's
-    scheduler."""
-    from .sharded import pod_sharded_in_specs
-
-    mesh, n_dev, q, block, tensors, n_padded = _mesh_counts_setup(
-        tensors, n_pods, block, mesh
-    )
-    pack = pack_enabled()
-    shard = n_padded // n_dev
-
-    def per_device(t):
-        # local precompute over THIS device's pod shard only (t's pod
-        # arrays arrive shard-sharded via in_specs)
-        return _ring_counts(
-            _precompute(t, pack, TILE_BQN), n_pods, n_dev, shard, block
-        )
-
-    return _run_mesh_counts(
-        per_device, mesh, pod_sharded_in_specs(tensors), tensors, q, n_pods,
-        path="counts.ring",
-    )
-
-
-# --- double-buffered pipelined ring counts --------------------------------
-#
-# The sync ring path re-transfers the host tensors and re-derives the
-# peer-side bundle every eval; at N chips the per-dispatch overhead is
-# what the single-chip pipelined path already amortizes away (its size
-# on the mesh is not measured: no cell runs this route yet).  This twin
-# splits the program in two:
-#
-#   seed(tensors) -> (src, ring)   one host->device transfer + the
-#                                  per-shard precompute, device-resident
-#   step(src, ring) -> (partials, ring)   the full n_dev-hop ring sweep;
-#                                  the `ring` argument is DONATED, and
-#                                  the final hop returns every bundle to
-#                                  its origin, so the output ring aliases
-#                                  the input's buffers — the rotating
-#                                  peer slabs stream in place, no fresh
-#                                  HBM per eval
-#
-# so steady-state mesh evals dispatch only `step`, back to back, with one
-# readback (counts_pipelined_eval_s's discipline, on the mesh).
-
-#: shard_map specs of the src-side (local, non-rotating) precompute
-#: view.  Shape patterns are representation-independent: the packed
-#: plan carries int32 word slabs ([W, N]/[W, N, Q]) under the same
-#: keys and axis layout (_split_pre).
-_SRC_SPECS = {
-    "tmatch_e": P(None, "x"),  # shape: (T_e, N) bool | (W_e, N) int32
-    "has_e": P("x"),  # shape: (N,) bool
-    "tallow_i": P(None, "x", None),  # (T_i, N, Q) bf16 | (W_i, N, Q) int32
-    "tier_subj_e": P(None, "x"),  # shape: (G_e, N) bool
-    "tier_peerq_i": P(None, "x", None),  # shape: (G_i, N, Q) bool
-    "tier_keys_e": P(),  # shape: (2, G_e) int32 (replicated)
-    "tier_keys_i": P(),  # shape: (2, G_i) int32 (replicated)
-}
-#: shard_map specs of the rotating peer-side ring bundle (the arrays a
-#: ppermute hop moves; donated by the step program)
-_RING_SPECS = {
-    # (T_e, N, Q) bf16 | (W_e, N, Q) int32 on a tiered set; the
-    # counts tile's (W_e, Q, N) otherwise (ring_counts_pipeline)
-    "tallow_e": P(None, "x", None),
-    "tmatch_i": P(None, "x"),  # shape: (T_i, N) bool | (W_i, N) int32
-    "has_i": P("x"),  # shape: (N,) bool
-    "tier_peerq_e": P(None, "x", None),  # shape: (G_e, N, Q) bool
-    "tier_subj_i": P(None, "x"),  # shape: (G_i, N) bool
-    "valid": P("x"),  # shape: (N,) bool
-}
-
-_RING_PIPELINES: Dict = {}  # cache-key: mesh, shard, block, n_pods, tiered, pack, specs
-_RING_PIPELINES_MAX = 32
-
-
-def ring_counts_pipeline(tensors: Dict, n_pods: int, block: int, mesh):
-    """(mesh, seed_fn, step_fn, meta) for the double-buffered ring
-    counts pipeline over `tensors` (already padded by the caller via
-    _mesh_counts_setup).  Programs are cached per (mesh, shapes,
-    tiered) so repeat case sets and same-bucket resizes reuse the
-    compiled pair."""
-    from .sharded import pod_sharded_in_specs, shard_map_no_check
-
-    pack = pack_enabled()
-    n_dev = int(mesh.devices.size)
-    n_padded = int(tensors["pod_ns_id"].shape[0])
-    shard = n_padded // n_dev
-    tiles_per_shard = shard // block
-    tiered = "tiers" in tensors
-    in_specs = pod_sharded_in_specs(tensors)
-    leaves, treedef = jax.tree_util.tree_flatten(in_specs)
-    key = (
-        tuple(mesh.devices.flat),
-        tuple(mesh.axis_names),
-        shard,
-        block,
-        n_pods,
-        tiered,
-        pack,
-        treedef,
-        tuple(leaves),
-    )
-    cached = _RING_PIPELINES.get(key)
-    if cached is not None:
-        return cached
-
-    def seed_device(t):
-        pre = _precompute(t, pack, TILE_BQN)
-        src, dst0 = _split_pre(pre)
-        dev = jax.lax.axis_index("x")
-        valid = (jnp.arange(shard) + dev * shard) < n_pods
-        return src, dict(dst0, valid=valid)
-
-    def step_device(src, ring):
-        dev = jax.lax.axis_index("x")
-        valid_local = (jnp.arange(shard) + dev * shard) < n_pods
-
-        def body(step, ring, counts):
-            dst = {k: ring[k] for k in _dst_bundle_keys(ring)}
-
-            def tile(i, counts):
-                row = _tile_counts_split(
-                    src, dst, valid_local, ring["valid"], i * block, block
-                )
-                return counts.at[step * tiles_per_shard + i].set(row)
-
-            return jax.lax.fori_loop(0, tiles_per_shard, tile, counts)
-
-        counts = jnp.zeros((n_dev * tiles_per_shard, 3), dtype=jnp.int32)
-        # the sweep's final hop returns every bundle to its origin,
-        # which is what lets the caller feed the returned ring straight
-        # back into the next (donated) step dispatch
-        counts, ring = _ring_sweep(n_dev, ring, counts, body)
-        return (
-            jax.lax.all_gather(counts, "x", axis=0, tiled=True),
-            ring,
-        )
-
-    src_specs = {
-        k: v for k, v in _SRC_SPECS.items() if tiered or not k.startswith("tier")
-    }
-    ring_specs = {
-        k: v
-        for k, v in _RING_SPECS.items()
-        if tiered or not k.startswith("tier")
-    }
-    if counts_tile_form(tensors) == TILE_BQN:
-        ring_specs["tallow_e"] = P(None, None, "x")  # (W_e, Q, N)
-    seed_fn = jax.jit(
-        shard_map_no_check(
-            seed_device,
-            mesh=mesh,
-            in_specs=(in_specs,),
-            out_specs=(src_specs, ring_specs),
-        )
-    )
-    step_fn = jax.jit(
-        shard_map_no_check(
-            step_device,
-            mesh=mesh,
-            in_specs=(src_specs, ring_specs),
-            out_specs=(P(), ring_specs),
-        ),
-        # the rotating peer buffers are DONATED: the returned (origin-
-        # restored) bundle reuses their storage, so back-to-back step
-        # dispatches stream the peer slabs through one double-buffered
-        # allocation instead of allocating a bundle per eval
-        donate_argnums=(1,),
-    )
-    out = (seed_fn, step_fn, {"shard": shard, "tiles": tiles_per_shard})
-    if cachekeys.ACTIVE:
-        cachekeys.register(
-            "ring.pipelines",
-            kind="program",
-            components=cachekeys.program(
-                "mesh", "shard", "block", "n_pods", "tiered", "pack", "specs"
-            ),
-        )
-    if len(_RING_PIPELINES) >= _RING_PIPELINES_MAX:
-        _RING_PIPELINES.clear()
-    _RING_PIPELINES[key] = out
-    return out
-
-
-def evaluate_grid_counts_ring_pipelined(
-    tensors: Dict,
-    n_pods: int,
-    reps: int = 10,
-    block: int = 1024,
-    mesh=None,
-) -> Tuple[float, Dict[str, int]]:
-    """Steady-state DEVICE-side seconds per ring-counts evaluation: one
-    seed (transfer + precompute), then `reps` back-to-back step
-    dispatches — the rotating peer bundle donated and fed forward — with
-    ONE readback at the end, so per-eval cost excludes the per-dispatch
-    host round trip (counts_pipelined_eval_s's discipline, on the mesh).
-    Returns (seconds_per_eval, counts)."""
-    import time as _time
-
-    from .sharded import mesh_device_context
-
-    mesh, n_dev, q, block, tensors, n_padded = _mesh_counts_setup(
-        tensors, n_pods, block, mesh
-    )
-    seed_fn, step_fn, _meta = ring_counts_pipeline(
-        tensors, n_pods, block, mesh
-    )
-    with ti.eval_flight(
-        "counts.ring.pipelined", n_pods, q, devices=int(n_dev), reps=reps,
-        tile=counts_tile_form(tensors), **tier_attrs(tensors),
-    ) as fl:
-        with mesh_device_context(mesh):
-            src, ring = seed_fn(tensors)
-            partials, ring = step_fn(src, ring)  # warm: compile + run
-            np.asarray(partials)
-            t0 = _time.perf_counter()
-            for _ in range(max(reps, 1)):
-                partials, ring = step_fn(src, ring)
-            counts_np = np.asarray(partials)  # in-order stream: one barrier
-            dt = (_time.perf_counter() - t0) / max(reps, 1)
-        totals = counts_np.astype(np.int64).sum(axis=0)
-        cells = q * n_pods * n_pods
-        fl.set(cells=cells)
-    counts = {
-        "ingress": int(totals[0]),
-        "egress": int(totals[1]),
-        "combined": int(totals[2]),
-        "cells": cells,
-    }
-    if dt > 0:
-        ti.MESH_RING_STEP_SECONDS.set(dt / max(n_dev, 1))
-    return dt, counts
-
-
-def evaluate_grid_counts_ring2d(
-    tensors: Dict, n_pods: int, block: int = 1024, mesh=None
-) -> Dict[str, int]:
-    """Hierarchical multi-host ring counts over a 2-D ("dcn", "ici") mesh.
-
-    Same math as evaluate_grid_counts_ring — both pod axes sharded, the
-    dst-side precompute bundle rotating — but the rotation is laid out
-    for multi-host topology: of every `n_dev` hops, all but one ride the
-    intra-host ICI ring; the bundle crosses the slow DCN boundary exactly
-    once per host round.  Device (h, c) still sees every shard exactly
-    once: at step j of round o it holds shard (h - o, c + o - j mod
-    n_ici) — j sweeps the host's chips within a round, o sweeps the
-    hosts — which enumerates the full (host, chip) torus.  The program
-    is a lax.fori_loop over the n_dcn rounds with only the n_ici-step
-    round body unrolled (collectives need static axis/perm, and a
-    full-ring unroll would scale trace/compile size with total device
-    count).
-
-    This is the scale-out story the reference's slot map (SURVEY.md
-    section 2.7/5) assigns to NCCL-style backends: XLA collectives over
-    ICI within a host, DCN across hosts, no host-side communication
-    code at all."""
-    from jax.sharding import Mesh
-    from jax.sharding import PartitionSpec as P
-
-    from .sharded import default_mesh, pod_sharded_in_specs
-
-    if mesh is None:
-        # default: factor the flat device list into 2 "hosts" when even
-        # (so the DCN axis actually exercises on a virtual mesh)
-        devs = default_mesh().devices.reshape(-1)
-        n_hosts = 2 if devs.size % 2 == 0 and devs.size > 1 else 1
-        mesh = Mesh(devs.reshape(n_hosts, -1), ("dcn", "ici"))
-    if set(mesh.axis_names) != {"dcn", "ici"}:
-        raise ValueError(
-            f"ring2d needs a ('dcn', 'ici') mesh, got {mesh.axis_names}"
-        )
-    mesh, n_dev, q, block, tensors, n_padded = _mesh_counts_setup(
-        tensors, n_pods, block, mesh
-    )
-    n_dcn, n_ici = (
-        mesh.shape["dcn"],
-        mesh.shape["ici"],
-    )
-    pack = pack_enabled()
-    shard = n_padded // n_dev
-    tiles_per_shard = shard // block
-
-    def per_device(t):
-        pre = _precompute(t, pack, TILE_BQN)
-        dev = jax.lax.axis_index("dcn") * n_ici + jax.lax.axis_index("ici")
-        row0 = dev * shard
-        valid_local = (jnp.arange(shard) + row0) < n_pods
-
-        src, dst0 = _split_pre(pre)
-        ring = dict(dst0, valid=valid_local)
-        counts = jnp.zeros((n_dev * tiles_per_shard, 3), dtype=jnp.int32)
-
-        def _hop(ring, axis, size):
-            perm = [(d, (d + 1) % size) for d in range(size)]
-            return jax.tree_util.tree_map(
-                lambda x: jax.lax.ppermute(x, axis, perm), ring
-            )
-
-        def round_body(o, carry):
-            counts, ring = carry
-            # only the n_ici-step round body is traced; rounds ride the
-            # fori_loop so program size is independent of the host count
-            for j in range(n_ici):
-                dst = {k: ring[k] for k in _dst_bundle_keys(ring)}
-
-                def tile(i, counts, _dst=dst, _rv=ring["valid"], _j=j):
-                    row = _tile_counts_split(
-                        src, _dst, valid_local, _rv, i * block, block
-                    )
-                    return counts.at[
-                        (o * n_ici + _j) * tiles_per_shard + i
-                    ].set(row)
-
-                counts = jax.lax.fori_loop(0, tiles_per_shard, tile, counts)
-                # all-but-one hop per round stays on ICI; the bundle
-                # crosses DCN once per round.  The last round's DCN hop
-                # is wasted work but kept unconditional: collectives
-                # under lax.cond don't lower reliably, and it is one
-                # transfer per run.
-                if j < n_ici - 1:
-                    ring = _hop(ring, "ici", n_ici)
-                else:
-                    ring = _hop(ring, "dcn", n_dcn)
-            return counts, ring
-
-        counts, _ = jax.lax.fori_loop(0, n_dcn, round_body, (counts, ring))
-        return jax.lax.all_gather(
-            jax.lax.all_gather(counts, "ici", axis=0, tiled=True),
-            "dcn",
-            axis=0,
-            tiled=True,
-        )
-
-    # pod arrays shard over the flattened (dcn, ici) device order
-    in_specs = pod_sharded_in_specs(tensors)
-
-    def _flatten_spec(spec):
-        if spec and spec != P():
-            parts = tuple(
-                ("dcn", "ici") if p == "x" else p for p in spec
-            )
-            return P(*parts)
-        return spec
-
-    in_specs = jax.tree_util.tree_map(
-        _flatten_spec, in_specs, is_leaf=lambda x: isinstance(x, P)
-    )
-    return _run_mesh_counts(
-        per_device, mesh, in_specs, tensors, q, n_pods, path="counts.ring2d"
-    )
-
-
-def evaluate_grid_counts_sharded(
-    tensors: Dict, n_pods: int, block: int = 1024, mesh=None, kernel: str = None
-) -> Dict[str, int]:
-    """Mesh-parallel tiled counts: the SOURCE-ROW axis is split over the
-    mesh; each device evaluates its own row shard against the full
-    (replicated) per-direction precompute, and the per-device partials
-    are combined with one all-gather.  Combines the two scale axes:
-    tiling lifts the per-device HBM ceiling, sharding divides wall-clock
-    by the mesh size (tiles are embarrassingly parallel across source
-    rows).
-
-    kernel="pallas" runs the fused rectangular verdict+count kernel per
-    device (src = the device's row shard, dst = the full axis) — the
-    same program the single-chip fast path uses, so its measured
-    per-device rates carry over; kernel="xla" runs the lax.fori_loop
-    tile loop.  The default picks pallas on TPU, xla elsewhere (where
-    pallas would run in slow interpret mode), mirroring
-    api.evaluate_grid_counts.  Identical counts by construction; the
-    mesh tests pin all of them against the single-device kernel.
-
-    The per-pod precompute (selector matches, tallow) is evaluated
-    replicated — it is O(N), negligible next to the O(N^2) grid."""
-    kernel = mesh_counts_kernel(kernel)
-    from . import planspec
-
-    if kernel == "pallas":
-        planspec.record("counts.sharded.pallas")
-    else:
-        planspec.record("counts.sharded.xla")
-    mesh, n_dev, q, block, tensors, n_padded = _mesh_counts_setup(
-        tensors, n_pods, block, mesh
-    )
-    pack = pack_enabled()
-
-    tile = TILE_BQN if kernel == "xla" else TILE_BNQ
-
-    def per_device(t):
-        return _row_counts(
-            _precompute(t, pack, tile), n_pods, n_dev, n_padded, block,
-            kernel, pack,
-        )
-
-    in_specs = jax.tree_util.tree_map(lambda _: P(), tensors)
-    return _run_mesh_counts(
-        per_device, mesh, in_specs, tensors, q, n_pods,
-        path="counts.sharded", xla_tile=kernel == "xla",
-    )
 
 
 @jax.jit

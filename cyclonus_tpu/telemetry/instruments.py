@@ -116,12 +116,6 @@ GRID_HOST_BUFFER = REGISTRY.counter(
     "(np.empty: every page is faulted in while the shards are laid).",
     labelnames=("outcome",),
 )
-MESH_RING_STEP_SECONDS = REGISTRY.gauge(
-    "cyclonus_tpu_mesh_ring_step_seconds",
-    "Per-hop seconds of the last pipelined ring-counts eval "
-    "(pipelined eval seconds / device count): the overlapped ICI-hop "
-    "budget.",
-)
 
 DEVICE_BYTES = REGISTRY.gauge(
     "cyclonus_tpu_device_bytes",

@@ -79,8 +79,8 @@ _FLAGS = [
          "Pallas slab materialization: 'auto' (TPU only), '1', '0'.",
          choices=("auto", "0", "1")),
     Flag("CYCLONUS_MESH_SCHEDULE", "enum", "ring", "engine",
-         "Sharded counts schedule (sharded.mesh_schedule).",
-         choices=("ring", "allgather", "ring2d", "ring-pipelined")),
+         "Sharded grid peer exchange (sharded.mesh_schedule).",
+         choices=("ring", "allgather")),
     Flag("CYCLONUS_AUTOTUNE", "enum", "auto", "engine",
          "Steady-state kernel autotune: 'auto' (TPU only), '1' "
          "(force, interpret ok), '0' (off).",

@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """The evidence for `chips: 4` of `cidr-100k-10k-x4` (ISSUE 38, step 1), and
-the parent's ring beside the held pair on the chips.
+the held pair's requests on the chips.
 
     python3 hack/mesh_counts_evidence.py compile     # here or there: no chip needed
     chiprun --chips 4 -- python3 hack/mesh_counts_evidence.py run
@@ -15,15 +15,15 @@ could take, and prints each one's `memory_analysis()` a chip:
              and its precompute alone (`counts.pre`)
   rows       `evaluate_grid_counts_sharded` as it was until PR 38: the
              precompute REPLICATED on every chip, the source rows split
-  ring       `evaluate_grid_counts_ring` as it was: both pod axes sharded,
+  ring       the ring route as a per-call program: both pod axes sharded,
              everything recomputed and re-sent a request
   held       PR 38's pair on the ring route: `counts.mesh.static` (once per
              engine state) and `counts.mesh.cases` (a request)
 
 A program the TPU compiler refuses (it does not fit 16 GB) prints the
 compiler's message, which names the bytes it wanted.  `run` (four chips)
-calls the per-call ring twice and the mesh counts entry four times, and
-prints what each took and whether a request compiled.  BENCH_REHEARSE=1 runs
+calls the mesh counts entry four times, and prints what each took and
+whether a request compiled.  BENCH_REHEARSE=1 runs
 either at the rehearsal sizes on four CPU devices.
 """
 
@@ -199,15 +199,11 @@ def run(rehearse: bool):
               f"{compiles() - before:.0f}, {got}", flush=True)
         return got
 
-    want = [timed("held entry", eng.evaluate_grid_counts_sharded, p)
-            for p in PAIRS + PAIRS[:1]]
+    for p in PAIRS + PAIRS[:1]:
+        timed("held entry", eng.evaluate_grid_counts_sharded, p)
     stats = [d.memory_stats() or {} for d in jax.devices()]
     print("peak bytes a chip after the held entry:",
           [s.get("peak_bytes_in_use") for s in stats], flush=True)
-    eng._mesh_static = None  # the per-call ring needs the chips' memory
-    got = [timed("per-call ring", eng.evaluate_grid_counts_ring, p)
-           for p in PAIRS[:2]]
-    print("agree:", got == want[:2], flush=True)
 
 
 if __name__ == "__main__":

@@ -397,22 +397,25 @@ def scenario_counts_sharded_routes(ctx: Ctx) -> Dict:
 
 
 def scenario_ring_family_routes(ctx: Ctx) -> Dict:
-    """The ring-rotation counts family: single-axis ring and the
-    hierarchical 2D ring (the pipelined leg is the slow scenario)."""
-    from cyclonus_tpu.engine import planspec
+    """The mesh counts entry's ring route: both pod axes sharded, taken
+    where no chip holds the replicated precompute (the ceiling is
+    lowered to 0 for the scenario)."""
+    from cyclonus_tpu.engine import api, planspec
 
     eng = ctx.engine()
     cases = ctx.cases(1)
-    ctx.drain()
-    eng.evaluate_grid_counts_ring(cases)
+    ceiling = api._MESH_REPLICATED_MAX_BYTES
+    api._MESH_REPLICATED_MAX_BYTES = 0
+    try:
+        ctx.drain()
+        eng.evaluate_grid_counts_sharded(cases)
+    finally:
+        api._MESH_REPLICATED_MAX_BYTES = ceiling
     _expect(
-        "counts.ring", ctx.drain(), [planspec.predict("counts_ring", {})]
+        "counts.ring", ctx.drain(),
+        [planspec.predict("counts_sharded", {"replicated_fits": False})],
     )
-    eng.evaluate_grid_counts_ring2d(cases)
-    _expect(
-        "counts.ring2d", ctx.drain(), [planspec.predict("counts_ring2d", {})]
-    )
-    return {"routes": 2}
+    return {"routes": 1}
 
 
 def scenario_analysis_routes(ctx: Ctx) -> Dict:
@@ -515,23 +518,6 @@ def scenario_audit_routes(ctx: Ctx) -> Dict:
     return {"routes": 1}
 
 
-def scenario_ring_pipelined_route(ctx: Ctx) -> Dict:
-    """The donation/feed-forward ring pipeline (coverage: slow — the
-    sweep is bench-scale, the route proof is not)."""
-    from cyclonus_tpu.engine import planspec
-
-    eng = ctx.engine()
-    ctx.drain()
-    eng.mesh_counts_pipelined_eval_s(ctx.cases(1), reps=2)
-    routes = ctx.drain()
-    _check(
-        routes[:1] == [planspec.predict("counts_ring_pipelined", {})],
-        "counts.ring.pipelined",
-        f"pipelined ring routed {routes}",
-    )
-    return {"routes": 1}
-
-
 #: (name, fn, in_quick_slice)
 SCENARIOS: List[Tuple[str, Callable[[Ctx], Dict], bool]] = [
     ("grid_routes", scenario_grid_routes, True),
@@ -543,7 +529,6 @@ SCENARIOS: List[Tuple[str, Callable[[Ctx], Dict], bool]] = [
     ("analysis_routes", scenario_analysis_routes, True),
     ("serve_routes", scenario_serve_routes, True),
     ("audit_routes", scenario_audit_routes, True),
-    ("ring_pipelined_route", scenario_ring_pipelined_route, False),
 ]
 
 

@@ -153,14 +153,17 @@ def test_the_mesh_entry_one_chip_and_the_reference_count_the_same(
 @pytest.mark.parametrize("pair", range(3), ids=IDS)
 def test_at_the_defaults_the_mesh_is_every_device(engine, pair, monkeypatch):
     """No mesh given: `sharded.default_mesh`, all the default backend's
-    devices (eight here), and the per-call ring the tests pin counts the
+    devices (eight here), and the ring route over four of them counts the
     same."""
     monkeypatch.setattr(api, "_MESH_REPLICATED_MAX_BYTES", 0)
     spans.REGISTRY.reset()
     got = engine.evaluate_grid_counts_sharded(PAIRS[pair])
     (root,) = spans_named("engine.eval")
     assert root["devices"] == sharded_mod.default_mesh().devices.size
-    assert got == engine.evaluate_grid_counts_ring(PAIRS[pair], mesh=cpu_mesh(4))
+    assert root["route"] == "counts.ring"
+    assert got == engine.evaluate_grid_counts_sharded(
+        PAIRS[pair], mesh=cpu_mesh(4)
+    )
 
 
 @both_routes

@@ -2,19 +2,17 @@
 against the single-device kernel and the all-gather reference schedule
 at 1/2/4/8 virtual devices (uneven pod/device divisions included), the
 tiered and class-compressed routes, the peer-buffer HBM watermark claim
-(ring < allgather), the double-buffered pipelined counts twin, the
-min-of-5 overlapped-vs-allgather throughput differential, and the
-zero-recompile elastic-resize contract (same-bucket cluster resizes
-reuse every compiled sharded program)."""
+(ring < allgather), the mesh counts entry's ring route on a tiered
+engine, and the zero-recompile elastic-resize contract (same-bucket
+cluster resizes reuse every compiled sharded program)."""
 
 import random
-import time
 
 import numpy as np
 import pytest
 from jax.sharding import Mesh
 
-from cyclonus_tpu.engine import PortCase, TpuPolicyEngine
+from cyclonus_tpu.engine import TpuPolicyEngine
 from cyclonus_tpu.engine import sharded as sharded_mod
 from cyclonus_tpu.engine.api import _bucket_down, _bucket_pods, _bucket_up
 from cyclonus_tpu.matcher import build_network_policies
@@ -234,24 +232,11 @@ class TestRingParity:
 
 
 class TestMeshCounts:
-    def test_pipelined_twin_matches_counts(self):
-        """The double-buffered pipelined mesh twin must return the same
-        counts as the sync ring path and the single-device engine."""
-        engine, _policy, _pods = synthetic_engine(13)
-        want = engine.evaluate_grid_counts(CASES, block=4, backend="xla")
-        mesh = cpu_mesh(8)
-        sync = engine.evaluate_grid_counts_ring(CASES, block=4, mesh=mesh)
-        assert sync == want
-        dt, counts = engine.mesh_counts_pipelined_eval_s(
-            CASES, reps=3, block=4, mesh=mesh
-        )
-        assert counts == want
-        assert dt > 0
-        assert ti.MESH_RING_STEP_SECONDS.value() > 0
-
-    def test_pipelined_twin_tiered(self):
-        """Tier slabs rotate with the bundle: the pipelined twin on a
-        tiered engine equals the tiered counts engine."""
+    def test_ring_route_tiered_matches_counts(self, monkeypatch):
+        """Tier slabs rotate with the bundle: the mesh counts entry on its
+        ring route, on a tiered engine, equals the tiered one-chip
+        counts."""
+        import cyclonus_tpu.engine.api as api
         from cyclonus_tpu.kube.netpol import LabelSelector
         from cyclonus_tpu.tiers.model import (
             AdminNetworkPolicy,
@@ -260,6 +245,7 @@ class TestMeshCounts:
             TierSet,
         )
 
+        monkeypatch.setattr(api, "_MESH_REPLICATED_MAX_BYTES", 0)
         policy, pods, namespaces = fuzz_problem(9, n_extra_pods=4)
         tiers = TierSet(
             anps=[
@@ -280,47 +266,10 @@ class TestMeshCounts:
         )
         engine = TpuPolicyEngine(policy, pods, namespaces, tiers=tiers)
         want = engine.evaluate_grid_counts(CASES, block=4)
-        dt, counts = engine.mesh_counts_pipelined_eval_s(
-            CASES, reps=2, block=4, mesh=cpu_mesh(4)
+        counts = engine.evaluate_grid_counts_sharded(
+            CASES, block=4, mesh=cpu_mesh(4)
         )
         assert counts == want
-
-    def test_overlapped_beats_allgather_throughput_min_of_5(self):
-        """The min-of-5 throughput differential: the OVERLAPPED path —
-        pipelined ring counts, peer bundle double-buffered and donated,
-        per-eval transfer/precompute amortized away — must sustain at
-        least the all-gather-style path's throughput (the replicated
-        sharded counts as a PER-CALL program, which re-transfers and
-        replicates the full peer-side precompute per eval:
-        tiled.evaluate_grid_counts_sharded; the engine's entry holds
-        its program and static since PR 38 and pays neither) on the
-        virtual 8-device mesh.
-        min-of-5 per leg absorbs scheduler noise; the measured gap is
-        several-fold, so the bound has real margin."""
-        from cyclonus_tpu.engine import tiled
-
-        engine, _policy, pods = synthetic_engine(512, n_pols=48, seed=11)
-        mesh = cpu_mesh(8)
-
-        def run_allgather():
-            return tiled.evaluate_grid_counts_sharded(
-                engine._tensors_with_cases(CASES), len(pods), block=256,
-                mesh=mesh, kernel="xla",
-            )
-
-        want = run_allgather()  # compile outside the timing
-        ag_s = float("inf")
-        for _ in range(5):
-            t0 = time.perf_counter()
-            counts = run_allgather()
-            ag_s = min(ag_s, time.perf_counter() - t0)
-        # the pipelined twin is already a min-style amortization: reps
-        # back-to-back dispatches, one barrier
-        ring_s, ring_counts = engine.mesh_counts_pipelined_eval_s(
-            CASES, reps=5, block=256, mesh=mesh
-        )
-        assert ring_counts == want
-        assert ring_s <= ag_s, (ring_s, ag_s)
 
 
 class TestPeerBufferWatermark:

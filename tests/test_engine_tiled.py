@@ -10,6 +10,7 @@ import random
 import numpy as np
 import pytest
 
+import cyclonus_tpu.engine.api as api
 from cyclonus_tpu.engine import PortCase, TpuPolicyEngine
 from cyclonus_tpu.matcher import build_network_policies
 
@@ -85,28 +86,18 @@ class TestTiledCounts:
             "cells": 0,
         }
 
-    @pytest.mark.parametrize("seed,block", [(20, 2), (21, 8)])
-    def test_counts_ring_match_kernel(self, seed, block):
-        """Ring-rotation counts (both axes sharded, ppermute per step)
-        must equal the single-device kernel's sums."""
+    @pytest.mark.parametrize(
+        "seed,block", [(20, 2), (21, 8), (22, 2), (23, 8)]
+    )
+    def test_counts_ring_match_kernel(self, seed, block, monkeypatch):
+        """Ring-rotation counts (both axes sharded, ppermute per step):
+        the mesh counts entry on its ring route must equal the
+        single-device kernel's sums."""
+        monkeypatch.setattr(api, "_MESH_REPLICATED_MAX_BYTES", 0)
         policy, pods, namespaces = fuzz_problem(seed, n_extra_pods=13)
         engine = TpuPolicyEngine(policy, pods, namespaces)
         ing, egr, comb = full_grids(engine, CASES)
-        counts = engine.evaluate_grid_counts_ring(CASES, block=block)
-        assert counts["ingress"] == int(ing.sum())
-        assert counts["egress"] == int(egr.sum())
-        assert counts["combined"] == int(comb.sum())
-
-    @pytest.mark.parametrize("seed,block", [(22, 2), (23, 8)])
-    def test_counts_ring2d_match_kernel(self, seed, block):
-        """Hierarchical (dcn, ici) ring counts — ICI hops within a host
-        round, one DCN hop per round — must equal the single-device
-        kernel's sums.  On the virtual 8-device CPU mesh the default
-        factoring is 2 hosts x 4 chips, so both axes actually rotate."""
-        policy, pods, namespaces = fuzz_problem(seed, n_extra_pods=13)
-        engine = TpuPolicyEngine(policy, pods, namespaces)
-        ing, egr, comb = full_grids(engine, CASES)
-        counts = engine.evaluate_grid_counts_ring2d(CASES, block=block)
+        counts = engine.evaluate_grid_counts_sharded(CASES, block=block)
         assert counts["ingress"] == int(ing.sum())
         assert counts["egress"] == int(egr.sum())
         assert counts["combined"] == int(comb.sum())
@@ -116,13 +107,17 @@ class TestTiledCounts:
         """The ring path's O(N / n_dev) per-device memory claim, asserted
         structurally: inside the shard_map'd per-device function, the pod
         arrays must arrive with exactly n_padded / n_dev rows.  Recorded
-        by intercepting the per-device _precompute during tracing."""
+        by intercepting the per-device _precompute_static while the held
+        ring's `counts.mesh.static` traces (the AOT cache off, so that no
+        adopted executable skips the trace)."""
         import jax
         import numpy as np
         from jax.sharding import Mesh
 
         import cyclonus_tpu.engine.tiled as tiled
 
+        monkeypatch.setenv("CYCLONUS_AOT_CACHE", "0")
+        monkeypatch.setattr(api, "_MESH_REPLICATED_MAX_BYTES", 0)
         policy, pods, namespaces = fuzz_problem(25, n_extra_pods=13)
         engine = TpuPolicyEngine(policy, pods, namespaces)
         cpu = jax.devices("cpu")
@@ -131,26 +126,26 @@ class TestTiledCounts:
         mesh = Mesh(np.array(cpu[:n_dev]), ("x",))
 
         seen = []
-        real_precompute = tiled._precompute
+        real_precompute = tiled._precompute_static
 
         def recording_precompute(tensors, *args, **kwargs):
             seen.append(int(tensors["pod_kv"].shape[0]))
             return real_precompute(tensors, *args, **kwargs)
 
-        monkeypatch.setattr(tiled, "_precompute", recording_precompute)
+        monkeypatch.setattr(tiled, "_precompute_static", recording_precompute)
         block = 4
         want = engine.evaluate_grid_counts(CASES, block=block, backend="xla")
-        got = engine.evaluate_grid_counts_ring(CASES, block=block, mesh=mesh)
+        seen.clear()  # only the ring's trace is asserted
+        got = engine.evaluate_grid_counts_sharded(
+            CASES, block=block, mesh=mesh
+        )
         assert got == want
         # ring path: per-device pod rows = n_padded / n_dev exactly.
         # The engine's tensors arrive shape-BUCKETED (api._bucket_pods),
         # so mesh padding starts from the bucketed axis, not n_pods.
-        # (Only the ring call's trace is asserted — the single-device
-        # reference's module-level jit may be cached from earlier tests
-        # and then never calls the recorder.)
         n_bucketed = engine._tensors["pod_ns_id"].shape[0]
-        # mirror _mesh_counts_setup's block clamp: the engine shrinks the
-        # tile height so every device gets at least one tile
+        # mirror _counts_mesh's block clamp: the engine shrinks the tile
+        # height so every device gets at least one tile
         n = engine.encoding.cluster.n_pods
         block_eff = min(block, max(n // n_dev, 1))
         granule = n_dev * block_eff
@@ -159,25 +154,7 @@ class TestTiledCounts:
         assert seen[-1] == n_padded // n_dev
         assert seen[-1] < n_bucketed  # strictly smaller than the full axis
 
-    def test_counts_ring2d_explicit_mesh(self):
-        """A caller-provided 4x2 mesh (4 'hosts' x 2 'chips') exercises a
-        DCN axis longer than the ICI axis."""
-        import jax
-        import numpy as np
-        from jax.sharding import Mesh
-
-        policy, pods, namespaces = fuzz_problem(24, n_extra_pods=9)
-        engine = TpuPolicyEngine(policy, pods, namespaces)
-        cpu = jax.devices("cpu")
-        if len(cpu) < 8:
-            pytest.skip(f"needs an 8-device CPU mesh, have {len(cpu)}")
-        devs = np.array(cpu[:8]).reshape(4, 2)
-        mesh = Mesh(devs, ("dcn", "ici"))
-        want = engine.evaluate_grid_counts(CASES, block=4, backend="xla")
-        got = engine.evaluate_grid_counts_ring2d(CASES, block=4, mesh=mesh)
-        assert got == want
-
-    def test_counts_ring_ipv6_host_rows(self):
+    def test_counts_ring_ipv6_host_rows(self, monkeypatch):
         """host_ip_match rows are pod-axis sharded in the ring path — on
         BOTH sides: the ingress policy patches the local (peer) view, the
         egress policy's patched rows are baked into the tallow bundle
@@ -192,6 +169,7 @@ class TestTiledCounts:
         from cyclonus_tpu.matcher import build_network_policies
         from test_engine_parity import default_cluster, mkpol
 
+        monkeypatch.setattr(api, "_MESH_REPLICATED_MAX_BYTES", 0)
         pods, namespaces = default_cluster()
         pods = [
             (ns, name, labels, ip if i % 2 else f"2001:db8::{i + 1}")
@@ -215,7 +193,7 @@ class TestTiledCounts:
         policy = build_network_policies(True, [pol_i, pol_e])
         engine = TpuPolicyEngine(policy, pods, namespaces)
         ing, egr, comb = full_grids(engine, CASES)
-        counts = engine.evaluate_grid_counts_ring(CASES, block=2)
+        counts = engine.evaluate_grid_counts_sharded(CASES, block=2)
         assert counts["combined"] == int(comb.sum())
         assert counts["ingress"] == int(ing.sum())
 

@@ -963,12 +963,16 @@ class TestServeTiers:
 
 
 class TestMeshTieredCounts:
-    """The mesh-parallel counts paths (sharded all-gather, ring /
-    ring2d ppermute rotation of the dst-side tier arrays) carry the
-    same resolution epilogue — differentially gated here against the
-    tiered oracle on the CPU 8-virtual-device mesh."""
+    """The mesh-parallel counts routes (replicated source rows, ring
+    ppermute rotation of the dst-side tier arrays) carry the same
+    resolution epilogue — differentially gated here against the tiered
+    oracle on the CPU 8-virtual-device mesh."""
 
-    def test_sharded_and_ring_counts_match_oracle_under_tiers(self):
+    def test_sharded_and_ring_counts_match_oracle_under_tiers(
+        self, monkeypatch
+    ):
+        import cyclonus_tpu.engine.api as api
+
         pods, namespaces = mk_cluster()
         netpol = NetworkPolicy(
             name="allow-80",
@@ -1023,9 +1027,11 @@ class TestMeshTieredCounts:
             "combined": int(want[..., 2].sum()),
         }
         engine = TpuPolicyEngine(policy, pods, namespaces, tiers=ts)
-        for name in ("sharded", "ring", "ring2d"):
-            fn = getattr(engine, f"evaluate_grid_counts_{name}")
-            counts = fn(CASES, block=4)
+        # the entry's default route, then the ring forced by the ceiling
+        for name in ("default", "ring"):
+            if name == "ring":
+                monkeypatch.setattr(api, "_MESH_REPLICATED_MAX_BYTES", 0)
+            counts = engine.evaluate_grid_counts_sharded(CASES, block=4)
             assert {k: counts[k] for k in sums} == sums, name
         # the mesh-sharded GRID path too (shard_map tier all-gathers):
         # full truth table bit-identical to the tiered oracle

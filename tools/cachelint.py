@@ -2,7 +2,7 @@
 """Cache-coherence static lint: AST checks for the two contracts every
 compiled/persisted cache in this repo leans on (docs/DESIGN.md "Cache
 discipline").  PRs 11-12 made per-shape autotune winners, the module
-program caches (_SHARDED_PROGRAMS, _RING_PIPELINES), and the persisted
+program cache (_SHARDED_PROGRAMS), and the persisted
 AOT executable cache the backbone of both perf and restart survival —
 and a single value baked into a compiled program but missing from its
 cache key silently serves STALE VERDICTS after a delta or a restart,
