@@ -23,7 +23,7 @@ from ..telemetry import instruments as ti
 from ..telemetry.spans import evaluation
 from ..utils import guards
 from ..utils.tracing import detail, phase
-from . import aot_cache, planspec
+from . import aot_cache, planspec, selectors
 from .encoding import (
     PEER_IP,
     PolicyEncoding,
@@ -35,6 +35,7 @@ from .encoding import (
     pack_enabled,
     packed_words,
 )
+from .selectors import selector_match_np as _selector_match_np  # noqa: F401
 
 
 @dataclass(frozen=True)
@@ -385,85 +386,14 @@ def _tier_tensors(tenc) -> Dict:
     }
 
 
-def _selector_match_np(
-    sel_req_kv: np.ndarray,  # [S, R]
-    sel_exp_op: np.ndarray,  # [S, E]
-    sel_exp_key: np.ndarray,  # [S, E]
-    sel_exp_vals: np.ndarray,  # [S, E, V]
-    kv: np.ndarray,  # [N, L]
-    key: np.ndarray,  # [N, L]
-) -> np.ndarray:
-    """[S, N] bool — numpy twin of kernel.selector_match, op for op.
-
-    Pure numpy on purpose: the device twin would be routed to CPU with
-    jax.devices("cpu"), and that call initialises the backend — encode
-    must stay host-only.  Twin equality is pinned by
-    tests/test_engine_pallas.py::test_selector_match_np_twin."""
-    from .encoding import EXP_EXISTS, EXP_IN, EXP_NONE, EXP_NOT_IN
-
-    present = np.any(
-        kv[None, :, None, :] == sel_req_kv[:, None, :, None], axis=-1
-    )
-    req_ok = np.all((sel_req_kv[:, None, :] == -1) | present, axis=-1)  # [S, N]
-
-    has_key = np.any(
-        key[None, :, None, :] == sel_exp_key[:, None, :, None], axis=-1
-    )  # [S, N, E]
-    val_hit = np.any(
-        (sel_exp_vals[:, None, :, :, None] != -1)
-        & (kv[None, :, None, None, :] == sel_exp_vals[:, None, :, :, None]),
-        axis=(-1, -2),
-    )  # [S, N, E]
-    op = sel_exp_op[:, None, :]  # [S, 1, E]
-    exp_ok = np.where(
-        op == EXP_NONE,
-        True,
-        np.where(
-            op == EXP_IN,
-            has_key & val_hit,
-            np.where(
-                op == EXP_NOT_IN,
-                has_key & ~val_hit,
-                np.where(op == EXP_EXISTS, has_key, ~has_key),
-            ),
-        ),
-    )  # [S, N, E]
-    return req_ok & np.all(exp_ok, axis=-1)
-
-
 def _selector_pod_matches_host(tensors: Dict, chunk: int = 0) -> np.ndarray:
-    """[S, N] bool selector-vs-pod matches, evaluated host-side in pod
-    chunks so the result is available at encode time without touching any
-    device.  The chunk scales inversely with the selector count so the
-    [S, chunk, ...] broadcast intermediates stay bounded in BOTH axes —
-    a fixed pod chunk would let a large selector table OOM the encode."""
-    n = tensors["pod_kv"].shape[0]
-    s = tensors["sel_req_kv"].shape[0]
-    if not chunk:
-        # budget the [S, chunk, R, L] and [S, chunk, E, V, L] broadcast
-        # intermediates of _selector_match_np, not just S * chunk: a
-        # label-heavy cluster (large R/E/V/L) scales the temporaries by
-        # the trailing dims too
-        r = tensors["sel_req_kv"].shape[1]
-        e, v = tensors["sel_exp_vals"].shape[1:3]
-        l = tensors["pod_kv"].shape[1]
-        per_pod = max(s, 1) * max(r * l, e * v * l, 1)
-        chunk = max(64, (1 << 24) // per_pod)
-    outs = []
-    for lo in range(0, n, chunk):
-        outs.append(
-            _selector_match_np(
-                tensors["sel_req_kv"],
-                tensors["sel_exp_op"],
-                tensors["sel_exp_key"],
-                tensors["sel_exp_vals"],
-                tensors["pod_kv"][lo : lo + chunk],
-                tensors["pod_key"][lo : lo + chunk],
-            )
-        )
-    if not outs:
-        return np.zeros((s, 0), dtype=bool)
-    return np.concatenate(outs, axis=1)
+    """[S, N] bool selector-vs-pod matches over EVERY pod row, host-side
+    (selectors.match_rows_dense): the serve path's delta patches read
+    one pod's column of it.  The engine's own pass runs over the distinct
+    label rows (selectors.run) and never builds this matrix."""
+    return selectors.match_rows_dense(
+        tensors, tensors["pod_kv"], tensors["pod_key"], chunk
+    )
 
 
 # port_spec arrays are [P, ...]-shaped like the flat peer arrays
@@ -484,7 +414,7 @@ _PEER_KEYS = (
 )
 
 
-def _compact_dead_targets(tensors: Dict, selpod: Optional[np.ndarray] = None) -> Dict:
+def _compact_dead_targets(tensors: Dict, selpod: selectors.SelectorRows) -> Dict:
     """Drop targets that match no pod of this cluster (and their peers).
 
     Verdicts are exactly invariant: a dead target's tmatch row is all
@@ -493,29 +423,11 @@ def _compact_dead_targets(tensors: Dict, selpod: Optional[np.ndarray] = None) ->
     flops multiplier of every grid kernel — and in namespace-local policy
     sets most compiled targets are dead ((ns, selector) combos with no
     matching pods), so compaction shrinks the dominant matmuls by the
-    dead fraction.  Deadness is decided with the real selector kernel
-    (no heuristics), evaluated once on CPU at encode time: O(S * N),
-    noise next to the O(N^2 * T) evaluation it shrinks."""
+    dead fraction.  Deadness is decided with the selector pass (no
+    heuristics: selectors.SelectorRows.live_targets over the label rows
+    present in each target's namespace), evaluated once on the host at
+    encode time."""
     pod_ns_id = tensors["pod_ns_id"]
-    if selpod is None:
-        selpod = _selector_pod_matches_host(tensors)
-    s = selpod.shape[0]
-    # rows: any ns id referenced by pods or targets (vocab ns ids can
-    # exceed the cluster's ns table when policies name pod-less namespaces)
-    n_rows = int(tensors["ns_kv"].shape[0])
-    for direction in ("ingress", "egress"):
-        t_ns = tensors[direction]["target_ns"]
-        if t_ns.size:
-            n_rows = max(n_rows, int(t_ns.max()) + 1)
-    if pod_ns_id.size:
-        n_rows = max(n_rows, int(pod_ns_id.max()) + 1)
-    # live_by_sel_ns[s, ns] = selector s matches >= 1 pod in namespace ns
-    live_by_sel_ns = np.zeros((s, max(n_rows, 1)), dtype=bool)
-    for si in range(s):
-        ids = pod_ns_id[selpod[si]]
-        if ids.size:
-            live_by_sel_ns[si, ids[ids >= 0]] = True
-
     out = dict(tensors)
     for direction in ("ingress", "egress"):
         d = tensors[direction]
@@ -523,7 +435,7 @@ def _compact_dead_targets(tensors: Dict, selpod: Optional[np.ndarray] = None) ->
         t = t_ns.shape[0]
         if t == 0:
             continue
-        live = (t_ns >= 0) & live_by_sel_ns[t_sel, np.maximum(t_ns, 0)]
+        live = selpod.live_targets(pod_ns_id, t_ns, t_sel)
         keep = np.flatnonzero(live)
         if keep.size == t:
             continue
@@ -840,11 +752,12 @@ def _pre_cache_enabled() -> bool:
     return os.environ.get("CYCLONUS_PRE_CACHE", "1") != "0"
 
 
-def _compaction_enabled(tensors: Dict) -> bool:
+def _compaction_enabled(plan: selectors.Plan) -> bool:
     """Compaction is on by default (CYCLONUS_COMPACT=0 opts out), guarded
-    by a host-work budget: the CPU selector pass is O(S * N) with small
-    per-element constants — cap S * N so a pathological selector count
-    can't stall encode."""
+    by a host-work budget on the selector pass (selectors.plan: its word
+    operations over the distinct label rows) — past it the compaction
+    win is dwarfed by its own cost, so skip it (CYCLONUS_COMPACT=1 forces
+    it back on)."""
     import os
 
     setting = os.environ.get("CYCLONUS_COMPACT", "")
@@ -852,30 +765,26 @@ def _compaction_enabled(tensors: Dict) -> bool:
         return False
     if setting == "1":
         return True  # explicit opt-in overrides the work budget
-    s = int(tensors["sel_req_kv"].shape[0])
-    n = int(tensors["pod_ns_id"].shape[0])
-    r = int(tensors["sel_req_kv"].shape[1])
-    e, v = (int(x) for x in tensors["sel_exp_vals"].shape[1:3])
-    l = int(tensors["pod_kv"].shape[1])
-    # budget ELEMENT OPS of the host selector pass (S * N * the trailing
-    # broadcast dims of _selector_match_np), not just S * N: 2^32 ops is
-    # ~seconds-to-a-minute of single-threaded numpy.  The old flat S * N
-    # cap bounded memory but let a label-heavy cluster stall encode for
-    # minutes — past this budget the compaction win is dwarfed by its
-    # own cost, so skip it (CYCLONUS_COMPACT=1 forces it back on).
-    ops = s * n * max(r * l, e * v * l, 1)
-    if ops > 1 << 32:
+    if not plan.fits:
         import logging
 
         logging.getLogger(__name__).info(
-            "skipping dead-target compaction: host selector pass would "
-            "cost ~%.1e element ops (budget 2^32); set CYCLONUS_COMPACT=1 "
-            "to force it",
-            float(ops),
+            "skipping dead-target compaction: the selector pass would "
+            "cost ~%.1e word operations; set CYCLONUS_COMPACT=1 to force it",
+            float(plan.ops),
         )
         return False
     return True
 
+
+#: the class counts route's selector matches: the program recomputes them
+#: over the class axis every request (kernel.selector_match) while that
+#: pass's temporaries, two [S, Cb, E] boolean planes by the TPU compiler's
+#: buffer assignment, stay within this many bytes; past it the engine
+#: holds the matches its constructor's pass already made, [S, Cb] bool on
+#: the device (_class_selpod_host).  k8s-envelope-150k's 41,088 selectors
+#: over 17,408 class rows held 5.7 GB of them a request
+_CLASS_SELPOD_DEVICE_MAX_BYTES = 1 << 30
 
 #: below this pod count the auto mode leaves the legacy paths untouched:
 #: the compressed path's win is quadratic in cluster size, and tiny
@@ -1084,23 +993,20 @@ class TpuPolicyEngine:
                     )
                 with phase("engine.build_tensors"):
                     self._tensors = self._build_tensors()
-                # one O(S*N) host selector pass serves both consumers: dead-
-                # target compaction here and the slab-window plan later
-                # (selector and pod axes are unchanged by compaction, only
-                # padded by bucketing)
+                # one host selector pass (over the distinct label rows,
+                # selectors.py) serves every consumer: dead-target
+                # compaction here, the class signature and the slab-window
+                # plan later (selector and pod axes are unchanged by
+                # compaction, only padded by bucketing)
                 self._selpod_prebucket = None
+                plan = selectors.plan(self._tensors) if compact is None else None
                 compact_on = (
-                    _compaction_enabled(self._tensors)
-                    if compact is None
-                    else bool(compact)
+                    _compaction_enabled(plan) if compact is None else bool(compact)
                 )
                 if compact_on:
                     with phase("engine.compact"):
-                        self._selpod_prebucket = _selector_pod_matches_host(
-                            self._tensors
-                        )
                         self._tensors = _compact_dead_targets(
-                            self._tensors, selpod=self._selpod_prebucket
+                            self._tensors, selpod=self._selector_pass(plan)
                         )
                 # equivalence-class grid compression (docs/DESIGN.md "Grid
                 # compression"): tuple-space partition compression of the
@@ -1128,6 +1034,8 @@ class TpuPolicyEngine:
                     class_route = self._maybe_build_class_state(mode)
                 # which side of the class-route hand-off this engine is on
                 ti.CLASS_ROUTE.inc(outcome=class_route)
+                if self._selpod_prebucket is None:
+                    ti.SELECTOR_PASS.inc(outcome="skipped")
                 with phase("engine.class_tensors"):
                     self._tensors = _bucket_tensors(
                         _sort_targets_by_ns(self._tensors),
@@ -1153,11 +1061,13 @@ class TpuPolicyEngine:
                     cidr_bytes = (
                         st["cidr"].nbytes() if st.get("cidr") is not None else 0
                     )
+                    st["selpod"] = self._class_selpod_host(st)
                     st["aux_bytes"] = int(
                         self.encoding.cluster.n_pods * 4
                         + cb * 4
                         + sum(a.nbytes for a in _np_leaves(st["ctensors"]))
                         + cidr_bytes
+                        + (st["selpod"].nbytes if st["selpod"] is not None else 0)
                     )
                     ti.CLASS_AUX_BYTES.set(st["aux_bytes"])
             # wall-clock of the last tiered grid evaluation's dispatch
@@ -1188,6 +1098,9 @@ class TpuPolicyEngine:
             # the serve layer mutates class_size in place, so a kept copy is
             # a wrong count unless every reset of _class_of_dev resets it too
             self._class_w_dev = None  # derived-from: classes
+            # the class counts route's resident selector matches
+            # (_class_selpod_resident), where the class state holds them
+            self._class_selpod_dev = None  # derived-from: classes
             self._class_grid_jit = None  # derived-from: shapes
             self._pod_perm_dev = None  # derived-from: pod-rows (ns-order perm)
             self._pod_perm_host = None  # derived-from: pod-rows
@@ -1297,6 +1210,11 @@ class TpuPolicyEngine:
         self._class_device_tensors = None
         self._class_of_dev = None
         self._class_w_dev = None
+        # a patch may change what a selector matches: the program's own
+        # pass takes the resident matches' place from here on
+        self._class_selpod_dev = None
+        if self._class_state is not None:
+            self._class_state["selpod"] = None
         self._pre_cache = None
         self._pre_cache_misses = 0
         self._pre_cache_declined = None
@@ -1426,9 +1344,7 @@ class TpuPolicyEngine:
         if selpod is None:
             if mode != "1":
                 return "no_selector_pass"
-            selpod = self._selpod_prebucket = _selector_pod_matches_host(
-                self._tensors
-            )
+            selpod = self._selector_pass()
         # TSS/LPM CIDR pre-classification (engine/cidrspace.py): when the
         # stage resolves (CYCLONUS_CIDR_TSS gate + distinct-spec floor +
         # HBM budget), the class signature's CIDR dimension rides the
@@ -1472,11 +1388,64 @@ class TpuPolicyEngine:
             "aux_bytes": 0,  # finalized after bucketing (engine __init__)
             "last_gather_s": None,
             "cidr": space,
+            "selpod": None,  # finalized after bucketing (engine __init__)
         }
         ti.CLASS_PODS.set(n)
         ti.CLASS_COUNT.set(pc.n_classes)
         ti.CLASS_RATIO.set(self._class_state["ratio"])
         return "kept"
+
+    def _selector_pass(
+        self, plan: Optional[selectors.Plan] = None
+    ) -> selectors.SelectorRows:
+        """The selector pass over the distinct label rows of the
+        (pre-bucket) tensors (`plan`, where the caller has it), kept for
+        every consumer: span engine.selector_pass (selectors, pods, rows,
+        how=host: it runs on the host, ops: its word operations), counter
+        cyclonus_tpu_selector_pass_total{outcome="ran"}."""
+        n = int(self._tensors["pod_ns_id"].shape[0])
+        with phase("engine.selector_pass") as sp:
+            plan = plan or selectors.plan(self._tensors)
+            sp.set(
+                selectors=int(self._tensors["sel_req_kv"].shape[0]),
+                pods=n,
+                rows=int(plan.kv.shape[0]),
+                how="host",
+                ops=int(plan.ops),
+            )
+            self._selpod_prebucket = selectors.run(self._tensors, plan)
+        ti.SELECTOR_PASS.inc(outcome="ran")
+        return self._selpod_prebucket
+
+    def _class_selpod_host(self, st: Dict) -> Optional[np.ndarray]:
+        """The class counts route's selector matches, [S, Cb] bool, taken
+        from the constructor's pass (selectors.class_matches) where the
+        device's own pass over the class axis would hold more than
+        _CLASS_SELPOD_DEVICE_MAX_BYTES of temporaries a request; None
+        below it (the program computes them, as it always has)."""
+        ct = st["ctensors"]
+        s_b, e_b = (int(x) for x in ct["sel_exp_op"].shape)
+        cb = int(ct["pod_ns_id"].shape[0])
+        if self._selpod_prebucket is None or (
+            2 * s_b * cb * e_b <= _CLASS_SELPOD_DEVICE_MAX_BYTES
+        ):
+            return None
+        return selectors.class_matches(
+            self._selpod_prebucket, ct, st["classes"].class_rep
+        )
+
+    def _class_selpod_resident(self):
+        """The class selector matches on the device (sent once, kept), or
+        None where the engine holds none (_class_selpod_host)."""
+        import jax
+
+        st = self._class_state
+        if st.get("selpod") is None:
+            return None
+        if self._class_selpod_dev is None:
+            with phase("engine.device_put", bytes=st["selpod"].nbytes):
+                self._class_selpod_dev = jax.device_put(st["selpod"])
+        return self._class_selpod_dev
 
     def pod_classes(self):
         """The PodClasses of the active compression state, or None when
@@ -1656,36 +1625,51 @@ class TpuPolicyEngine:
         )
 
     def _class_counts_bytes(self, q: int) -> int:
-        """Host-side estimate of the compressed counts route's HBM:
-        aux/index tensors + the class precompute + row sums, and on a
-        tiered set the tier precompute and a class tile's scan state."""
+        """Host-side estimate of the compressed counts route's HBM, laid
+        out as the TPU compiler's buffer assignment lays out a request
+        (k8s-envelope-150k's, read at its full shapes): what the engine
+        keeps (aux_bytes: the class map, the weights, the class tensor
+        set and, where held, its selector matches) and what lives
+        through the whole request — the selector matches of the classes
+        [S, Cb] bool where the program computes them, a direction's
+        peer_match [P, Cb] and tmatch [T, Cb] bool and target words
+        (packed: tallow_pk [W, Cb, Q] and tmatch_pk [W, Cb] int32; else
+        tallow bf16 [T, Cb, Q]), the row sums [Cb, Q, 3] f32 — plus the
+        larger of two stages that never overlap: the program's own
+        selector pass, two [S, Cb, E] boolean planes, and one direction's
+        target-allows contraction (kernel.target_allows_q: the
+        port-folded one-hot [Q·T', P] and its [Q·T', Cb] result, both
+        bool, T' the target axis in whole words; the bf16 operand and
+        accumulator fuse into the matmul).  A tiered set adds its tier
+        precompute and a class tile's first-match."""
         from .tiled import _class_tiling
 
         st = self._class_state
         ct = st["ctensors"]
         cb = int(ct["pod_ns_id"].shape[0])
-        t = sum(
-            int(ct[d]["target_ns"].shape[0]) for d in ("ingress", "egress")
-        )
-        if self._pack:
-            # packed plan: tallow_pk int32 [W, Cb, Q] + tmatch_pk
-            # [W, Cb] + the bool tmatch — ~16x below the bf16 estimate
-            # (the _pre_bytes_estimate twin; overstating it here would
-            # silently decline the compressed route at exactly the
-            # watch-scale sizes it exists for)
-            w = sum(
-                packed_words(int(ct[d]["target_ns"].shape[0]))
-                for d in ("ingress", "egress")
-            )
-            est = st["aux_bytes"] + cb * (4 * w * (q + 1) + t) + cb * q * 12
-        else:
-            # tallow bf16 [T, Cb, Q] per direction + tmatch + f32 row sums
-            est = st["aux_bytes"] + t * cb * (2 * q + 1) + cb * q * 12
+        s_b, e_b = (int(x) for x in ct["sel_exp_op"].shape)
+        held = st["aux_bytes"] + cb * q * 12
+        stages = [0]
+        if st.get("selpod") is None:
+            held += s_b * cb
+            stages.append(2 * s_b * cb * e_b)
+        for d in ("ingress", "egress"):
+            t = int(ct[d]["target_ns"].shape[0])
+            p = int(ct[d]["peer_kind"].shape[0])
+            w = packed_words(t)
+            held += (p + t) * cb
+            if self._pack:
+                held += 4 * w * cb * (q + 1)
+                t_words = w * 32
+            else:
+                held += 2 * t * cb * q
+                t_words = t
+            stages.append(q * t_words * (p + cb))
         # a tiered set: its precompute and, on the XLA tile loop past the
         # fused kernel's row ceiling, a class tile's first-match
         block, _ = _class_tiling(cb, 1024)
-        return est + _tier_pre_bytes(ct, cb, q) + _tier_first_match_bytes(
-            ct, block, cb, q
+        return held + max(stages) + _tier_pre_bytes(ct, cb, q) + (
+            _tier_first_match_bytes(ct, block, cb, q)
         )
 
     def _counts_classes(
@@ -1729,6 +1713,7 @@ class TpuPolicyEngine:
                     pc.class_size,
                     n,
                     pack=self._pack,
+                    selpod=self._class_selpod_resident(),
                 )
         st["last_gather_s"] = gather_s
         ti.CLASS_EVALS.inc(path="sharded" if sharded else "counts")
@@ -2153,7 +2138,7 @@ class TpuPolicyEngine:
             # selector rows match nothing; pad pod columns diverge from
             # the device (empty selectors match pads there) but every
             # pad column is force-masked below, so False is safe
-            pre = self._selpod_prebucket
+            pre = self._selpod_prebucket.pods()
             selpod = np.zeros(
                 (self._tensors["sel_req_kv"].shape[0], n_b), dtype=bool
             )

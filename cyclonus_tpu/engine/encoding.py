@@ -1168,34 +1168,46 @@ def pod_signatures(
     on the selector count and the ip-peer spec/partition structure, so
     it is stable across pod-only deltas."""
     n = int(tensors["pod_ns_id"].shape[0])
-    blocks = [
-        np.ascontiguousarray(
-            tensors["pod_ns_id"].astype(np.int32, copy=False).reshape(n, 1)
-        ).view(np.uint8).reshape(n, 4)
-    ]
+    blocks = [_ns_block(tensors)]
     if selpod.shape[0]:
         if selpod.shape[1] != n:
             raise ValueError(
                 f"selpod covers {selpod.shape[1]} pods but tensors hold {n}"
             )
         blocks.append(np.packbits(selpod, axis=0).T)  # [N, ceil(S/8)]
+    ip = _ip_block(tensors, cidr)
+    if ip is not None:
+        blocks.append(ip)
+    return np.ascontiguousarray(np.concatenate(blocks, axis=1))
+
+
+def _ns_block(tensors: Dict) -> np.ndarray:
+    """[N, 4] uint8: each pod's namespace id, its int32 bytes."""
+    n = int(tensors["pod_ns_id"].shape[0])
+    return np.ascontiguousarray(
+        tensors["pod_ns_id"].astype(np.int32, copy=False).reshape(n, 1)
+    ).view(np.uint8).reshape(n, 4)
+
+
+def _ip_block(tensors: Dict, cidr) -> Optional[np.ndarray]:
+    """The signature's IP-observability block in the form `cidr` names
+    (pod_signatures), or None where no rule observes pod IPs."""
     if cidr is CIDR_AUTO:
         from .cidrspace import resolve as _resolve_cidr
 
         cidr = _resolve_cidr(tensors)
     if cidr is not None:
-        blocks.append(_ip_signature_tss(tensors, cidr))
-    else:
-        ip_bits = _ip_signature_bits(tensors)
-        if ip_bits is not None:
-            blocks.append(ip_bits)
-    return np.ascontiguousarray(np.concatenate(blocks, axis=1))
+        return _ip_signature_tss(tensors, cidr)
+    return _ip_signature_bits(tensors)
 
 
-def classes_from_signatures(buf: np.ndarray) -> PodClasses:
+def classes_from_signatures(
+    buf: np.ndarray, signature_bytes: Optional[int] = None
+) -> PodClasses:
     """PodClasses from a [N, K] signature matrix: one np.unique over the
     void row view (shared by the build-time classify and the delta
-    path's class rebuild)."""
+    path's class rebuild).  `signature_bytes` (default K) is the width
+    the classes say they were derived from."""
     n = int(buf.shape[0])
     if n == 0:
         z = np.zeros((0,), dtype=np.int32)
@@ -1213,26 +1225,48 @@ def classes_from_signatures(buf: np.ndarray) -> PodClasses:
         class_of_pod=inv.astype(np.int32).reshape(n),
         class_rep=rep.astype(np.int32).reshape(-1),
         class_size=counts.astype(np.int32).reshape(-1),
-        signature_bytes=int(buf.shape[1]),
+        signature_bytes=int(
+            buf.shape[1] if signature_bytes is None else signature_bytes
+        ),
     )
 
 
-def compute_pod_classes(
-    tensors: Dict, selpod: np.ndarray, cidr=CIDR_AUTO
-) -> PodClasses:
+def compute_pod_classes(tensors: Dict, selpod, cidr=CIDR_AUTO) -> PodClasses:
     """Bucket pods into label-equivalence classes.
 
     `tensors` is the engine tensor dict BEFORE shape bucketing (real pod
-    rows only); `selpod` the [S, N] host selector-match matrix over the
-    same rows (api._selector_pod_matches_host — the identical pass that
-    feeds dead-target compaction); `cidr` a resolved cidrspace.CidrSpace
-    / None / CIDR_AUTO exactly as pod_signatures takes it.  Numpy plus
-    the optional device LPM stage: one packed signature matrix, one
-    np.unique over its void view."""
+    rows only); `selpod` the host selector pass over the same pods
+    (selectors.SelectorRows: match [S, U] over the distinct label rows
+    and each pod's row); `cidr` a resolved cidrspace.CidrSpace / None /
+    CIDR_AUTO exactly as pod_signatures takes it.  Numpy plus the
+    optional device LPM stage: one packed signature matrix, one
+    np.unique over its void view.
+
+    The selector block of a pod's signature is the rank of its row's
+    packed match column among the distinct columns, as four big-endian
+    bytes, in place of the column pod_signatures packs: ranks order as
+    the columns' bytes do and are equal exactly where they are, so the
+    rows sort as pod_signatures' would and np.unique hands back the
+    classes, ids, representatives and sizes the serve path's
+    classes_from_signatures(pod_signatures(...)) gives, from
+    N x (4 + 4 + ip) bytes in place of N x (4 + S/8 + ip).
+    `signature_bytes` stays the width of pod_signatures' row."""
     n = int(tensors["pod_ns_id"].shape[0])
     if n == 0:
         return classes_from_signatures(np.zeros((0, 1), dtype=np.uint8))
-    return classes_from_signatures(pod_signatures(tensors, selpod, cidr=cidr))
+    blocks, width = [_ns_block(tensors)], 4
+    s = int(selpod.match.shape[0])
+    if s:
+        rank = selpod.pattern_rank()[selpod.row_of]
+        blocks.append(rank.astype(">u4").view(np.uint8).reshape(n, 4))
+        width += (s + 7) // 8
+    ip = _ip_block(tensors, cidr)
+    if ip is not None:
+        blocks.append(ip)
+        width += int(ip.shape[1])
+    return classes_from_signatures(
+        np.ascontiguousarray(np.concatenate(blocks, axis=1)), width
+    )
 
 
 def gather_class_pod_rows(tensors: Dict, class_rep: np.ndarray) -> Dict:

@@ -680,6 +680,15 @@ PACKED_TILE_CANDIDATES = ((512, 512), (1024, 512), (2048, 1024))
 PACKED_TIER_MAX_ROWS = 1024
 
 
+#: the packed kernel's word loops unroll statically, one [BS, BD] AND/OR
+#: a packed target word a direction, and the TPU compiler's time grows
+#: with them (a described v5e, the weighted class kernel over 17,408
+#: rows: 6.3 s at 24 words a direction, 26.3 s at 64; k8s-envelope-150k's
+#: 516 had not finished after six minutes); past this many words in
+#: either direction the class counts route runs the XLA tile loop
+PACKED_MAX_WORDS = 64
+
+
 def _sub8(n: int) -> int:
     """Round up to the int32/f32 sublane tile (8)."""
     return -(-max(int(n), 1) // 8) * 8
@@ -925,6 +934,20 @@ def packed_tier_eligible(tensors: Dict) -> bool:
     from .kernel import tier_rows
 
     return tier_rows(tensors) <= PACKED_TIER_MAX_ROWS
+
+
+def packed_words_eligible(tensors: Dict) -> bool:
+    """The host-side gate on the fused kernel's target words: both
+    directions' packed words (encoding.packed_words of the bucketed
+    target axis) within PACKED_MAX_WORDS.  The class counts route
+    (tiled._class_counts_kernel_choice) consults it beside
+    packed_tier_eligible."""
+    from .encoding import packed_words
+
+    return all(
+        packed_words(int(tensors[d]["target_ns"].shape[0])) <= PACKED_MAX_WORDS
+        for d in ("ingress", "egress")
+    )
 
 
 def verdict_counts_pallas_packed(

@@ -80,7 +80,9 @@ def _apply_host_ip(enc: Dict, pre: Dict) -> Dict:
     return pre
 
 
-def _precompute_static(tensors: Dict, pack: bool = False) -> Dict:
+def _precompute_static(
+    tensors: Dict, pack: bool = False, selpod: jnp.ndarray = None
+) -> Dict:
     """The half of _precompute that the port cases do not touch: a
     function of the cluster and the policy set alone, so a holder
     (api.TpuPolicyEngine's dense counts route) computes it once per
@@ -95,15 +97,19 @@ def _precompute_static(tensors: Dict, pack: bool = False) -> Dict:
     peer_target [P], target_ns [T] (for its length) and port_spec.  A
     tiered tensor set carries its tier encodings and the two selector
     matches under "tiers": tier_direction_arrays takes the cases, so
-    it stays whole in the second half."""
-    selpod = selector_match(
-        tensors["sel_req_kv"],
-        tensors["sel_exp_op"],
-        tensors["sel_exp_key"],
-        tensors["sel_exp_vals"],
-        tensors["pod_kv"],
-        tensors["pod_key"],
-    )
+    it stays whole in the second half.  `selpod`, where the caller holds
+    it (the class counts route past its device-pass ceiling:
+    api._CLASS_SELPOD_DEVICE_MAX_BYTES), is kernel.selector_match over
+    the pods as given, and the pass is not run again here."""
+    if selpod is None:
+        selpod = selector_match(
+            tensors["sel_req_kv"],
+            tensors["sel_exp_op"],
+            tensors["sel_exp_key"],
+            tensors["sel_exp_vals"],
+            tensors["pod_kv"],
+            tensors["pod_key"],
+        )
     selns = selector_match(
         tensors["sel_req_kv"],
         tensors["sel_exp_op"],
@@ -183,6 +189,16 @@ def tier_attrs(tensors: Dict, kernel: str = TIER_XLA) -> Dict:
     return out
 
 
+def target_words(tensors: Dict) -> int:
+    """The packed target words of a tensor set, ingress + egress: the depth
+    of every packed contraction over it (encoding.packed_words of each
+    direction's bucketed target axis)."""
+    return sum(
+        packed_words(int(tensors[d]["target_ns"].shape[0]))
+        for d in ("ingress", "egress")
+    )
+
+
 def _precompute_cases(
     static: Dict,
     q_port: jnp.ndarray,
@@ -243,7 +259,8 @@ def _precompute_cases(
 
 
 def _precompute(
-    tensors: Dict, pack: bool = False, tile: str = TILE_BNQ
+    tensors: Dict, pack: bool = False, tile: str = TILE_BNQ,
+    selpod: jnp.ndarray = None,
 ) -> Dict[str, Dict[str, jnp.ndarray]]:
     """Per-direction, port-resolved precompute shared by every tile:
 
@@ -263,9 +280,10 @@ def _precompute(
 
     The composition of its two halves, cut where q_port first appears:
     every caller but the dense counts route's resident request traces
-    both in one program, as before the cut.  `tile`: _precompute_cases."""
+    both in one program, as before the cut.  `tile`: _precompute_cases;
+    `selpod`: _precompute_static."""
     return _precompute_cases(
-        _precompute_static(tensors, pack),
+        _precompute_static(tensors, pack, selpod),
         tensors["q_port"],
         tensors["q_name"],
         tensors["q_proto"],
@@ -678,11 +696,14 @@ def _class_rowsums_kernel(
     block: int,
     n_tiles: int,
     pack: bool = False,
+    selpod: jnp.ndarray = None,
 ) -> jnp.ndarray:
     """[n_tiles * block, Q, 3] f32 weighted row sums over the class grid,
     one device execution (fori_loop over class tiles).  `tensors` carries
-    no port cases: they come as `cases` (_with_case_rows)."""
-    pre = _precompute(_with_case_rows(tensors, cases), pack)
+    no port cases: they come as `cases` (_with_case_rows); `selpod`, the
+    resident selector matches of the classes where the engine holds them
+    (_precompute_static)."""
+    pre = _precompute(_with_case_rows(tensors, cases), pack, selpod=selpod)
     src, dst = _split_pre(pre)
     q = cases.shape[1]
 
@@ -749,7 +770,8 @@ def class_counts_finish(
 
 @partial(jax.jit, static_argnames=("interpret",))
 def _class_rowsums_fused_kernel(
-    tensors: Dict, w: jnp.ndarray, cases: jnp.ndarray, interpret: bool = False
+    tensors: Dict, w: jnp.ndarray, cases: jnp.ndarray, interpret: bool = False,
+    selpod: jnp.ndarray = None,
 ) -> jnp.ndarray:
     """Fused-epilogue twin of _class_rowsums_kernel: packed precompute +
     the packed Pallas kernel whose EPILOGUE computes the dst-weighted
@@ -760,7 +782,7 @@ def _class_rowsums_fused_kernel(
     test."""
     from .pallas_kernel import verdict_counts_pallas_packed
 
-    pre = _precompute(_with_case_rows(tensors, cases), True)
+    pre = _precompute(_with_case_rows(tensors, cases), True, selpod=selpod)
     tier = {
         d: pre[d]["tier"] for d in ("ingress", "egress")
     } if "tier" in pre["egress"] else None
@@ -785,18 +807,21 @@ def _class_counts_kernel_choice(tensors: Dict, pack: bool, kernel: str) -> str:
     `pack` is on) is the FUSED packed kernel — contraction + tier lattice
     + the dst-weighted gather epilogue in one Pallas program; "xla" keeps
     the fori_loop tile body.  Identical row sums by construction (the
-    fused-vs-split parity test pins them)."""
-    from .pallas_kernel import packed_tier_eligible
+    fused-vs-split parity test pins them).  The fused kernel unrolls over
+    the tier rule rows and the packed target words: past either ceiling
+    (packed_tier_eligible, packed_words_eligible) the XLA tile runs."""
+    from .pallas_kernel import packed_tier_eligible, packed_words_eligible
 
     if kernel is None:
-        # the same static-unroll ceiling the dense counts route
-        # enforces (api._packed_tier_ok): an oversized tier rule axis
-        # routes the class counts to the XLA tile loop too
+        # the tier rule ceiling the dense counts route enforces too
+        # (api._packed_tier_ok), and the target word ceiling: past
+        # either, the class counts run the XLA tile loop
         kernel = (
             "pallas"
             if pack
             and jax.default_backend() == "tpu"
             and packed_tier_eligible(tensors)
+            and packed_words_eligible(tensors)
             else "xla"
         )
     if kernel not in ("pallas", "xla"):
@@ -807,6 +832,12 @@ def _class_counts_kernel_choice(tensors: Dict, pack: bool, kernel: str) -> str:
         raise ValueError(
             "class counts kernel 'pallas' cannot fuse a tier rule axis "
             "past the static-unroll ceiling; use kernel='xla' or "
+            "kernel=None (auto)"
+        )
+    if kernel == "pallas" and not packed_words_eligible(tensors):
+        raise ValueError(
+            "class counts kernel 'pallas' cannot unroll a target word axis "
+            "past pallas_kernel.PACKED_MAX_WORDS; use kernel='xla' or "
             "kernel=None (auto)"
         )
     return kernel
@@ -823,6 +854,7 @@ def evaluate_grid_counts_classes(
     block: int = 1024,
     pack: bool = None,
     kernel: str = None,
+    selpod=None,
 ) -> Tuple[Dict[str, int], float]:
     """Allow counts over the FULL N x N x Q grid, evaluated on the
     compressed C x C class grid and weighted back exactly, inside the
@@ -838,7 +870,10 @@ def evaluate_grid_counts_classes(
     and `host_bytes` count the host arrays among all three.  Returns
     (counts, gather_s) where gather_s is the finish (the host weighting)
     — the cheap gather the compression trades the dense grid for.
-    `kernel`: see _class_counts_kernel_choice."""
+    `kernel`: see _class_counts_kernel_choice.  `selpod`: the engine's
+    resident selector matches of the classes, [S, Cb] on its device, or
+    None, where the program computes them (_precompute_static); the
+    flight says which (`class_sel` host / device)."""
     import time as _time
 
     if pack is None:
@@ -851,6 +886,9 @@ def evaluate_grid_counts_classes(
     q = int(cases.shape[1])
     fl.set(
         block=block,
+        class_kernel=kernel,
+        class_sel="device" if selpod is None else "host",
+        target_words=target_words(tensors),
         **tier_attrs(tensors, TIER_FUSED if kernel == "pallas" else TIER_XLA),
     )
     with phase("engine.dispatch") as sp:
@@ -859,17 +897,17 @@ def evaluate_grid_counts_classes(
             from .pallas_kernel import _should_interpret
 
             out = _class_rowsums_fused_kernel(
-                tensors, w, sent, interpret=_should_interpret()
+                tensors, w, sent, interpret=_should_interpret(), selpod=selpod
             )
         else:
             out = _class_rowsums_kernel(
-                tensors, w, sent, block, n_tiles, pack
+                tensors, w, sent, block, n_tiles, pack, selpod=selpod
             )
         # counted once the program is enqueued: the device is running,
         # so walking the operands costs the request nothing
         host = [
             a
-            for a in jax.tree_util.tree_leaves((tensors, w, cases))
+            for a in jax.tree_util.tree_leaves((tensors, w, cases, selpod))
             if isinstance(a, np.ndarray)
         ]
         sp.set(

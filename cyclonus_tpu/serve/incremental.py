@@ -625,6 +625,10 @@ class IncrementalEngine:
         eng._class_device_tensors = None
         eng._class_of_dev = None
         eng._class_w_dev = None
+        # the resident class selector matches were made for the old class
+        # set: the program's own pass takes their place
+        st["selpod"] = None
+        eng._class_selpod_dev = None
         ti.CLASS_PODS.set(n)
         ti.CLASS_COUNT.set(pc.n_classes)
         ti.CLASS_RATIO.set(st["ratio"])

@@ -24,6 +24,14 @@ cidr_cluster and tiers_lattice are the two special-purpose shapes over
 the same label scheme: an ipBlock-heavy cluster for the TSS/LPM CIDR
 stage, and a fixed ANP/BANP lattice for the precedence tiers.
 
+k8s_envelope is the Kubernetes scale envelope (5,000 nodes, 150,000 pods)
+as ClusterLoader2's load test lays it out, under one NetworkPolicy a
+Deployment: a cluster whose classes track its workloads, with
+matchExpressions, endPort ranges and SCTP in its policies.
+`benchmarks/generators_envelope.py` is the benchmark's own copy
+(configuration `k8s-envelope-150k`), held equal to it in
+tests/test_synthetic.py.
+
 cidr_allowlists is the cluster whose IP structure cuts across its labels
 (node /24s, labels drawn a pod, allowlists kept by CIDR with except
 lists): the one that auto class compression REFUSES, so the dense routes
@@ -34,6 +42,7 @@ CIDR_ALLOWLISTS below), held equal to it in tests/test_synthetic.py.
 
 from __future__ import annotations
 
+import bisect
 import random
 from ipaddress import IPv4Address
 from typing import Optional
@@ -559,3 +568,239 @@ def anp_tenancy(n_ns: int, gen: Optional[dict] = None) -> TierSet:
     )
     tiers.validate()
     return tiers
+
+
+#: the shapes of k8s_envelope; benchmarks/configs/k8s-envelope-150k.json's
+#: `generator` block holds the same values (tests/test_synthetic.py).  The
+#: ClusterLoader2 defaults and the shares are recalled or assumed (the
+#: configuration's `assumed` says which)
+K8S_ENVELOPE = {
+    "structure_seed": 20261018,
+    "pods_per_node": 30,  # ClusterLoader2's PODS_PER_NODE
+    "host_first": 2,
+    # [replicas, Deployments a namespace]: big / medium / small
+    "deployments": [[250, 3], [30, 25], [5, 300]],
+    "tiers": ["frontend", "backend", "cache", "db"],
+    "teams": 7,
+    "callers": [1, 4],
+    "zipf": 1.1,
+    "cross_namespace_share": 0.1,
+    "expression_share": 0.05,
+    "ports": [
+        [{"protocol": "TCP", "port": 80}, 60],
+        [{"protocol": "TCP", "port": 80, "endPort": 81}, 15],
+        [{"protocol": "UDP", "port": "serve-81-udp"}, 15],
+        [{"protocol": "SCTP", "port": 81}, 10],
+    ],
+}
+_HASH_CHARS = "bcdfghjklmnpqrstvwxz2456789"
+_ENVELOPE_SYSTEM = (
+    ("kube-system", "coredns", {"k8s-app": "kube-dns"}, 2),
+    ("monitoring", "prometheus", {"app": "prometheus"}, 2),
+)
+_NOT_A_DB = {"matchExpressions": [
+    {"key": "tier", "operator": "NotIn", "values": ["db"]},
+    {"key": "version", "operator": "Exists"},
+    {"key": "k8s-app", "operator": "DoesNotExist"},
+]}
+
+
+def _node_addr(node: int, host: int) -> str:
+    return str(IPv4Address(
+        (10 << 24) | ((node // 256) << 16) | ((node % 256) << 8) | host
+    ))
+
+
+def _template_hash(rng: random.Random, seen: set) -> str:
+    while True:
+        h = "".join(rng.choice(_HASH_CHARS) for _ in range(10))
+        if h not in seen:
+            seen.add(h)
+            return h
+
+
+def _envelope_deployments(n_ns: int, gen: dict) -> list:
+    rng = random.Random(f"{gen['structure_seed']}/deployments")
+    seen, out = set(), []
+    for i in range(n_ns):
+        ns, k = f"load-{i}", 0
+        for replicas, count in gen["deployments"]:
+            for _ in range(count):
+                app = f"{ns}-d{k}"
+                labels = {
+                    "app": app,
+                    "pod-template-hash": _template_hash(rng, seen),
+                    "tier": rng.choice(gen["tiers"]),
+                    "version": "v1",
+                }
+                out.append((ns, app, replicas, labels))
+                k += 1
+    return out
+
+
+def _envelope_callers(apps: list, gen: dict, rng: random.Random) -> dict:
+    ranked = list(apps)
+    rng.shuffle(ranked)
+    cum, total = [], 0.0
+    for r in range(len(ranked)):
+        total += (r + 1) ** -gen["zipf"]
+        cum.append(total)
+    lo, hi = gen["callers"]
+    callers = {}
+    for app in apps:
+        want = min(rng.randint(lo, hi), len(apps) - 1)
+        chosen: list = []
+        while len(chosen) < want:
+            pick = ranked[bisect.bisect_right(cum, rng.random() * total)]
+            if pick != app and pick not in chosen:
+                chosen.append(pick)
+        callers[app] = chosen
+    return callers
+
+
+def _netpol(ns: str, name: str, spec: dict) -> dict:
+    return {
+        "apiVersion": "networking.k8s.io/v1",
+        "kind": "NetworkPolicy",
+        "metadata": {"name": name, "namespace": ns},
+        "spec": spec,
+    }
+
+
+def _envelope_namespace_policies(ns: str) -> list:
+    def sel(name: str, value: str) -> dict:
+        return {"matchLabels": {name: value}}
+
+    return [
+        _netpol(ns, "default-deny-ingress", {"podSelector": {}, "policyTypes": ["Ingress"]}),
+        _netpol(ns, "default-deny-egress", {"podSelector": {}, "policyTypes": ["Egress"]}),
+        _netpol(ns, "allow-dns", {
+            "podSelector": {}, "policyTypes": ["Egress"],
+            "egress": [{
+                "to": [{
+                    "namespaceSelector": sel("kubernetes.io/metadata.name", "kube-system"),
+                    "podSelector": sel("k8s-app", "kube-dns"),
+                }],
+                "ports": [{"protocol": "UDP", "port": 53}, {"protocol": "TCP", "port": 53}],
+            }],
+        }),
+        _netpol(ns, "allow-monitoring", {
+            "podSelector": {}, "policyTypes": ["Ingress"],
+            "ingress": [{
+                "from": [{
+                    "namespaceSelector": sel("kubernetes.io/metadata.name", "monitoring"),
+                    "podSelector": sel("app", "prometheus"),
+                }],
+                "ports": [{"protocol": "TCP", "port": 80}],
+            }],
+        }),
+        _netpol(ns, "allow-external-egress", {
+            "podSelector": sel("tier", "frontend"), "policyTypes": ["Egress"],
+            "egress": [{
+                "to": [{"ipBlock": {"cidr": "0.0.0.0/0", "except": ["10.0.0.0/8"]}}],
+                "ports": [{"protocol": "TCP", "port": 80}, {"protocol": "TCP", "port": 443}],
+            }],
+        }),
+    ]
+
+
+def _apps_in(apps: list) -> dict:
+    return {"matchExpressions": [{"key": "app", "operator": "In", "values": list(apps)}]}
+
+
+def k8s_envelope_objects(
+    n_ns: int, n_nodes: int, gen: Optional[dict] = None
+) -> tuple:
+    """(pods, namespaces, NetworkPolicy dicts) of the scale envelope, in the
+    order they are drawn: `n_ns` load namespaces `load-<i>` of the
+    Deployments `gen["deployments"]` lists ([replicas, how many]), each
+    replica labelled app / pod-template-hash / tier / version, spread round
+    robin over `n_nodes` nodes (node k owns 10.(k // 256).(k % 256).0/24),
+    then kube-system's CoreDNS and monitoring's Prometheus (two pods each).
+    Per load namespace five policies (default deny in and out, DNS out,
+    monitoring in, the frontends' external egress), and per Deployment one:
+    ingress from its 1 - 4 Zipf-drawn callers as `app In (...)`, egress to
+    its callees as `app In (...)`, one rule a serving port (TCP 80, TCP
+    80 - 81, UDP serve-81-udp, SCTP 81); a share admits its team's
+    frontends across namespaces, a share every non-db pod of its namespace
+    (NotIn / Exists / DoesNotExist).  Every draw comes from
+    gen["structure_seed"]."""
+    gen = gen or K8S_ENVELOPE
+    rng = random.Random(f"{gen['structure_seed']}/system")
+    deployments = _envelope_deployments(n_ns, gen)
+    seen: set = set()
+    pods, g = [], 0
+    for ns, app, replicas, labels in deployments:
+        for j in range(replicas):
+            ip = _node_addr(g % n_nodes, gen["host_first"] + g // n_nodes)
+            pods.append((ns, f"{app}-{labels['pod-template-hash']}-{j}", labels, ip))
+            g += 1
+    node = 0
+    for ns, name, base, replicas in _ENVELOPE_SYSTEM:
+        labels = dict(base, **{"pod-template-hash": _template_hash(rng, seen)})
+        for j in range(replicas):
+            ip = _node_addr(node, gen["host_first"] + gen["pods_per_node"])
+            pods.append((ns, f"{name}-{labels['pod-template-hash']}-{j}", labels, ip))
+            node += 1
+    namespaces = {
+        f"load-{i}": {
+            "kubernetes.io/metadata.name": f"load-{i}",
+            "team": f"team{i % gen['teams']}",
+            "env": "prod" if i % 2 == 0 else "staging",
+        }
+        for i in range(n_ns)
+    }
+    for ns, _, _, _ in _ENVELOPE_SYSTEM:
+        namespaces[ns] = {"kubernetes.io/metadata.name": ns}
+
+    rng = random.Random(f"{gen['structure_seed']}/policies")
+    terms, weights = zip(*gen["ports"])
+    by_ns: dict = {}
+    for ns, app, _, _ in deployments:
+        by_ns.setdefault(ns, []).append(app)
+    policies = []
+    for i, (ns, apps) in enumerate(by_ns.items()):
+        policies.extend(_envelope_namespace_policies(ns))
+        callers = _envelope_callers(apps, gen, rng)
+        serves = {app: rng.choices(range(len(terms)), weights)[0] for app in apps}
+        callees: dict = {app: [] for app in apps}
+        for app in apps:
+            for caller in callers[app]:
+                callees[caller].append(app)
+        team = f"team{i % gen['teams']}"
+        for app in apps:
+            if rng.random() < gen["expression_share"]:
+                peers = [{"podSelector": _NOT_A_DB}]
+            else:
+                peers = [{"podSelector": _apps_in(callers[app])}]
+            if rng.random() < gen["cross_namespace_share"]:
+                peers.append({
+                    "namespaceSelector": {"matchLabels": {"team": team}},
+                    "podSelector": {"matchLabels": {"tier": "frontend"}},
+                })
+            spec = {
+                "podSelector": {"matchLabels": {"app": app}},
+                "policyTypes": ["Ingress", "Egress"],
+                "ingress": [{"from": peers, "ports": [dict(terms[serves[app]])]}],
+            }
+            egress = []
+            for term in range(len(terms)):
+                to = [c for c in callees[app] if serves[c] == term]
+                if to:
+                    egress.append({
+                        "to": [{"podSelector": _apps_in(to)}],
+                        "ports": [dict(terms[term])],
+                    })
+            if egress:
+                spec["egress"] = egress
+            policies.append(_netpol(ns, f"allow-{app}", spec))
+    return pods, namespaces, policies
+
+
+def k8s_envelope(n_ns: int, n_nodes: int, gen: Optional[dict] = None) -> tuple:
+    """(pods, namespaces, NetworkPolicies) of k8s_envelope_objects, the
+    policies parsed by the program's own parser."""
+    from .kube.yaml_io import parse_policy_dict
+
+    pods, namespaces, policies = k8s_envelope_objects(n_ns, n_nodes, gen)
+    return pods, namespaces, [parse_policy_dict(d) for d in policies]

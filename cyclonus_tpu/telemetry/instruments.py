@@ -186,6 +186,15 @@ CLASS_ROUTE = REGISTRY.counter(
     "2^24 pods exact counts allow).",
     labelnames=("outcome",),
 )
+SELECTOR_PASS = REGISTRY.counter(
+    "cyclonus_tpu_selector_pass_total",
+    "The host selector pass over the distinct pod label rows "
+    "(engine/selectors.py), one an engine: ran (for dead-target compaction "
+    "or a forced class state; span engine.selector_pass says its form and "
+    "rows) or skipped (compaction off or past the pass's work budget, and "
+    "no class state forced).",
+    labelnames=("outcome",),
+)
 CLASS_EVALS = REGISTRY.counter(
     "cyclonus_tpu_class_evals_total",
     "Evaluations served by the compressed class path, by path "
@@ -770,6 +779,16 @@ def eval_flight(path: str, n_pods: int, q: int, **attrs: Any) -> Iterator[Flight
             if "tile" in flight.data:
                 sp.set(tile=flight.data["tile"])
                 COUNTS_TILE.inc(form=flight.data["tile"])
+            # and, on the class counts route, the classes, the row-sum
+            # program that ran over them, where their selector matches
+            # came from and the packed target words
+            if "class_kernel" in flight.data:
+                sp.set(
+                    classes=flight.data.get("classes"),
+                    class_kernel=flight.data["class_kernel"],
+                    class_sel=flight.data["class_sel"],
+                    target_words=flight.data["target_words"],
+                )
             # and, over a tiered set, its tier rule rows and the epilogue
             # that resolved them (tiled.tier_attrs), counted a kernel
             if "tier_kernel" in flight.data:

@@ -30,10 +30,13 @@ from cyclonus_tpu.matcher import build_network_policies
 from cyclonus_tpu.synthetic import (
     ANP_TENANCY,
     CIDR_ALLOWLISTS,
+    K8S_ENVELOPE,
     anp_tenancy,
     build_synthetic,
     cidr_allowlists,
     cidr_cluster,
+    k8s_envelope,
+    k8s_envelope_objects,
     synthetic_cluster,
     tiers_lattice,
 )
@@ -368,6 +371,111 @@ class TestAnpTenancy:
         rows = [sum(len(o.rule.peers) for o in t.ordered_rules(ing, tier))
                 for ing in (True, False) for tier in ("anp", "banp")]
         assert sum(rows) > 2 * 1024
+
+
+@pytest.fixture(scope="module")
+def generators_envelope():
+    """benchmarks/generators_envelope.py by path, as `generators` above."""
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_generators_envelope",
+        os.path.join(REPO, "benchmarks", "generators_envelope.py"),
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def envelope_config():
+    with open(os.path.join(REPO, "benchmarks", "configs", "k8s-envelope-150k.json")) as f:
+        return json.load(f)
+
+
+class TestK8sEnvelope:
+    """The envelope's generator: pinned, and the benchmark's copy
+    (`benchmarks/generators_envelope.py`, configuration `k8s-envelope-150k`)
+    held to the program's."""
+
+    @pytest.mark.parametrize(
+        "n_ns, n_nodes, want",
+        [
+            (1, 100, "0f5248158c1a5d637666d44309c4984cc40051ec44caeccef4533ce3cd827e69"),
+            (2, 40, "6aa7a1c762d24edd7a920e4a757b15910108edf6ef9db2e69ee345f7ced227ec"),
+        ],
+    )
+    def test_pinned(self, n_ns, n_nodes, want):
+        assert digest(*k8s_envelope_objects(n_ns, n_nodes)) == want
+
+    def test_the_configuration_holds_the_programs_shapes(self, envelope_config):
+        gen = dict(envelope_config["generator"])
+        assert gen.pop("module") == "generators_envelope"
+        assert gen == K8S_ENVELOPE
+        assert envelope_config["reduced"] == []
+        sizes, rehearsal = envelope_config["sizes"], envelope_config["rehearsal"]
+        assert (sizes["pods"], sizes["policies"]) == (150_004, 16_650)
+        assert (rehearsal["pods"], rehearsal["policies"]) == (3_004, 333)
+
+    @pytest.mark.parametrize("n_ns, n_nodes", [(1, 100), (2, 40)])
+    def test_the_benchmarks_copy_draws_the_same_deployment(
+        self, generators_envelope, envelope_config, n_ns, n_nodes
+    ):
+        gen = envelope_config["generator"]
+        sizes = {"namespaces": n_ns, "nodes": n_nodes}
+        pods, namespaces, policies = k8s_envelope_objects(n_ns, n_nodes)
+        assert generators_envelope.cluster(sizes, gen) == (pods, namespaces)
+        assert generators_envelope.policies(sizes, gen) == policies
+        # and the program's parser reads them back to the same policies
+        _, _, parsed = k8s_envelope(n_ns, n_nodes)
+        assert policy_dicts(parsed) == policy_dicts(
+            parse_policy_dict(d) for d in policies
+        )
+
+    def test_every_shape_the_configuration_names_is_drawn(
+        self, generators_envelope, envelope_config
+    ):
+        """All three Deployment sizes, one label set a Deployment, every
+        selector operator, every port form, the ipBlock with its except, a
+        cross-namespace peer, and the counts the configuration states."""
+        pods, namespaces, policies = generators_envelope.build(
+            envelope_config["rehearsal"], envelope_config["generator"], 1
+        )
+        replicas = {}
+        for ns, _, labels, _ in pods:
+            replicas.setdefault(json.dumps(labels, sort_keys=True), []).append(ns)
+        assert sorted({len(v) for v in replicas.values()}) == [2, 5, 30, 250]
+        assert len(replicas) == 330  # 328 Deployments + CoreDNS + Prometheus
+        assert {"kube-system", "monitoring", "load-0"} == set(namespaces)
+        ops, ports, peers = set(), set(), []
+        for p in policies:
+            for rules, key in ((p["spec"].get("ingress", []), "from"),
+                               (p["spec"].get("egress", []), "to")):
+                for rule in rules:
+                    ports |= {json.dumps(t, sort_keys=True) for t in rule.get("ports", [])}
+                    for peer in rule[key]:
+                        peers.append(peer)
+                        for e in (peer.get("podSelector") or {}).get("matchExpressions", []):
+                            ops.add(e["operator"])
+        assert ops == {"In", "NotIn", "Exists", "DoesNotExist"}
+        assert {json.dumps(t, sort_keys=True) for t, _ in K8S_ENVELOPE["ports"]} <= ports
+        assert {"ipBlock": {"cidr": "0.0.0.0/0", "except": ["10.0.0.0/8"]}} in peers
+        assert any("team" in (peer.get("namespaceSelector") or {}).get("matchLabels", {})
+                   for peer in peers)
+        # every load pod lies on a node's /24 at .2 - .31, inside the except
+        hosts = {int(ip.rsplit(".", 1)[1]) for ns, _, _, ip in pods if ns == "load-0"}
+        assert hosts == set(range(2, 32))
+        assert all(ipaddress.ip_address(ip) in ipaddress.ip_network("10.0.0.0/8")
+                   for *_, ip in pods)
+
+    def test_the_seed_reorders_and_changes_no_count(
+        self, generators_envelope, envelope_config
+    ):
+        gen, sizes = envelope_config["generator"], envelope_config["rehearsal"]
+        a = generators_envelope.build(sizes, gen, 1)
+        b = generators_envelope.build(sizes, gen, 3000000019)
+        assert a[0] != b[0] and a[2] != b[2] and a[1] == b[1]
+        assert sorted(a[0]) == sorted(b[0])
+        by_name = lambda ps: sorted(ps, key=lambda p: p["metadata"]["name"])
+        assert by_name(a[2]) == by_name(b[2])
 
 
 class Flipped:

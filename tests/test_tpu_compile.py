@@ -282,3 +282,54 @@ def test_the_case_precompute_builds_no_peer_allow(one_chip, tile):
     assert [o for o in ops if o[0] in tables and o[1] == "copy"] == []
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp <= PARENT_CASES_TEMP_BYTES[tile]
+
+
+def _envelope_class_set(one_chip):
+    """(ct, w, cases, selpod) as ShapeDtypeStructs: the class tensor set of
+    k8s-envelope-150k's rehearsal (one load namespace, 330 classes), built
+    on the host, and its resident selector matches [S, Cb]."""
+    from cyclonus_tpu.engine import TpuPolicyEngine
+    from cyclonus_tpu.kube.yaml_io import parse_policy_dict
+    from cyclonus_tpu.matcher import build_network_policies
+    from cyclonus_tpu.synthetic import k8s_envelope_objects
+
+    pods, namespaces, policies = k8s_envelope_objects(1, 100)
+    policy = build_network_policies(True, [parse_policy_dict(d) for d in policies])
+    eng = TpuPolicyEngine(policy, pods, namespaces, class_compress="1")
+    ct = eng._class_state["ctensors"]
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    cb = ct["pod_ns_id"].shape[0]
+    return (
+        jax.tree_util.tree_map(sds, ct),
+        jax.ShapeDtypeStruct((cb,), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((3, 2), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((ct["sel_req_kv"].shape[0], cb), jnp.bool_, sharding=one_chip),
+    )
+
+
+def test_the_class_program_handed_its_matches_runs_no_selector_pass(one_chip):
+    """tiled._class_rowsums_kernel (the XLA class tile, what
+    k8s-envelope-150k runs past PACKED_MAX_WORDS): handed the resident
+    selector matches it builds no [S, Cb, E] plane of the selector pass
+    (the 5.7 GB of two such planes at the envelope's full shapes), and
+    holds no more temporaries than the same program without them."""
+    import re
+
+    from cyclonus_tpu.engine import tiled
+
+    ct, w, cases, selpod = _envelope_class_set(one_chip)
+    s, e = ct["sel_exp_op"].shape
+    cb = ct["pod_ns_id"].shape[0]
+    block, n_tiles = tiled._class_tiling(cb, 1024)
+    plane = re.compile(rf"pred\[{s},{cb},{e}\]|pred\[{s},{e},{cb}\]")
+    temps = {}
+    for held in (False, True):
+        compiled = tiled._class_rowsums_kernel.lower(
+            ct, w, cases, block, n_tiles, True, selpod=selpod if held else None
+        ).compile()
+        assert bool(plane.search(compiled.as_text())) is not held
+        temps[held] = compiled.memory_analysis().temp_size_in_bytes
+    assert temps[True] <= temps[False]

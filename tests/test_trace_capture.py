@@ -661,7 +661,10 @@ class TestEvaluationSpans:
             sp["name"] for sp in found["spans"]
             if sp["path"].startswith("engine.new/engine.encode/")
         ]
-        want = ["engine.encode_policy", "engine.build_tensors", "engine.compact"]
+        want = [
+            "engine.encode_policy", "engine.build_tensors", "engine.compact",
+            "engine.selector_pass",  # inside engine.compact
+        ]
         if class_compress == "1":
             want += [
                 "engine.partition", "engine.cidrspace", "engine.classify",
