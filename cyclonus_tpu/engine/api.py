@@ -1632,17 +1632,27 @@ class TpuPolicyEngine:
         set and, where held, its selector matches) and what lives
         through the whole request — the selector matches of the classes
         [S, Cb] bool where the program computes them, a direction's
-        peer_match [P, Cb] and tmatch [T, Cb] bool and target words
-        (packed: tallow_pk [W, Cb, Q] and tmatch_pk [W, Cb] int32; else
-        tallow bf16 [T, Cb, Q]), the row sums [Cb, Q, 3] f32 — plus the
-        larger of two stages that never overlap: the program's own
-        selector pass, two [S, Cb, E] boolean planes, and one direction's
-        target-allows contraction (kernel.target_allows_q: the
-        port-folded one-hot [Q·T', P] and its [Q·T', Cb] result, both
-        bool, T' the target axis in whole words; the bf16 operand and
-        accumulator fuse into the matmul).  A tiered set adds its tier
-        precompute and a class tile's first-match."""
-        from .tiled import _class_tiling
+        peer_match [P, Cb] and tmatch [T, Cb] bool and target words in
+        the form the program takes (tiled.class_counts_pack and
+        class_tile_form; packed: tallow_pk [W, Cb, Q] and tmatch_pk
+        [W, Cb] int32; the MXU form: tallow [Q, 32W, Cb] in
+        tiled.MXU_DTYPE; a tiered set unpacked: tallow bf16 [T, Cb, Q]),
+        the row sums [Cb, Q, 3] f32 — plus the larger of two stages that
+        never overlap: the program's own selector pass, two [S, Cb, E]
+        boolean planes, and one direction's target-allows contraction
+        (kernel.target_allows_q: the port-folded one-hot [Q·T', P] and,
+        but in the MXU form, whose held tallow it is, its [Q·T', Cb]
+        result, both bool, T' the target axis in whole words; the bf16
+        operand and accumulator fuse into the matmul).  A tiered set adds
+        its tier precompute and a class tile's first-match."""
+        from .tiled import (
+            MXU_DTYPE,
+            TILE_QTN,
+            _class_counts_kernel_choice,
+            _class_tiling,
+            class_counts_pack,
+            class_tile_form,
+        )
 
         st = self._class_state
         ct = st["ctensors"]
@@ -1653,18 +1663,28 @@ class TpuPolicyEngine:
         if st.get("selpod") is None:
             held += s_b * cb
             stages.append(2 * s_b * cb * e_b)
+        pack = class_counts_pack(
+            ct, self._pack, _class_counts_kernel_choice(ct, self._pack, None)
+        )
+        mxu = class_tile_form(ct, pack) == TILE_QTN
         for d in ("ingress", "egress"):
             t = int(ct[d]["target_ns"].shape[0])
             p = int(ct[d]["peer_kind"].shape[0])
             w = packed_words(t)
             held += (p + t) * cb
-            if self._pack:
+            if pack:
                 held += 4 * w * cb * (q + 1)
-                t_words = w * 32
+                stages.append(q * w * 32 * (p + cb))
+            elif mxu:
+                # the int8 tallow IS the contraction's result (its compare
+                # and cast fuse), so the stage holds the one-hot alone; the
+                # dst ingress tmatch's int8 copy, a byte a cell as its
+                # bool, takes the bool's place
+                held += np.dtype(MXU_DTYPE).itemsize * q * w * 32 * cb
+                stages.append(q * w * 32 * p)
             else:
                 held += 2 * t * cb * q
-                t_words = t
-            stages.append(q * t_words * (p + cb))
+                stages.append(q * t * (p + cb))
         # a tiered set: its precompute and, on the XLA tile loop past the
         # fused kernel's row ceiling, a class tile's first-match
         block, _ = _class_tiling(cb, 1024)
@@ -1806,6 +1826,7 @@ class TpuPolicyEngine:
         from .tiled import (
             _class_rowsums_kernel,
             class_counts_finish,
+            class_counts_pack,
             class_rowsums_plan,
         )
 
@@ -1817,7 +1838,8 @@ class TpuPolicyEngine:
         w, block, n_tiles = class_rowsums_plan(
             tensors, pc.n_classes, pc.class_size
         )
-        args = (tensors, w, q_cases, block, n_tiles, self._pack)
+        pack = class_counts_pack(tensors, self._pack, "xla")
+        args = (tensors, w, q_cases, block, n_tiles, pack)
         out = _class_rowsums_kernel(*args)
         np.asarray(out)  # warm barrier
         t0 = _time.perf_counter()

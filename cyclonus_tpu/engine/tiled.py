@@ -158,6 +158,14 @@ def _precompute_static(
 #: minor (lane) axis
 TILE_BNQ = "bnq"
 TILE_BQN = "bqn"
+#: the class tile's MXU form (class_tile_form): the target axis unpacked,
+#: each direction's tallow [Q, T, N] as target_allows_q leaves it, a
+#: [T, N] slab a port case, in MXU_DTYPE
+TILE_QTN = "qtn"
+
+#: the MXU form's operand dtype: 0/1 products summed in int32 are exact,
+#: and an int8 operand holds half the bytes of a bf16 one
+MXU_DTYPE = jnp.int8
 
 
 def counts_tile_form(tensors: Dict) -> str:
@@ -216,15 +224,21 @@ def _precompute_cases(
     kernel.target_allows_q, [Q, T, N], the port cases on the one-hot's
     rows against the resident peer_match: no [P, N, Q] operand exists,
     and T is packed where it lies (the one-hot padded to whole words)
-    before the small words take their order."""
+    before the small words take their order.  `tile` TILE_QTN (unpacked
+    alone) keeps each direction's tallow [Q, T, N] in MXU_DTYPE, cast in
+    the compare that ends the contraction, for the class tile's MXU form,
+    with T in whole words as packing pads it and tmatch padded to match
+    (an odd T took the TPU compiler 383 s over the envelope's one-hot,
+    whole words 2 s, compiled for a v5e: PERF.md section 6)."""
     out = {}
     qn = tile == TILE_BQN and counts_tile_form(static) == TILE_BQN
     for direction in ("ingress", "egress"):
         st = static[direction]
         pport = port_spec_allows(st["port_spec"], q_port, q_name, q_proto)
         t = st["target_ns"].shape[0]
+        rows = packed_words(t) * PACK_BITS if pack or tile == TILE_QTN else t
         tallow = target_allows_q(
-            st, st["peer_match"], pport, packed_words(t) * PACK_BITS if pack else t
+            st, st["peer_match"], pport, rows
         )  # shape: (Q, T, N) | (Q, 32W, N)
         q_axis = 1 if qn and direction == "egress" else 2
         out[direction] = {
@@ -237,6 +251,11 @@ def _precompute_cases(
                 words, 0, q_axis
             )  # shape: (W, N, Q) | (W, Q, N) int32
             out[direction]["tmatch_pk"] = st["tmatch_pk"]
+        elif tile == TILE_QTN:
+            out[direction]["tallow_q"] = tallow.astype(MXU_DTYPE)  # shape: (Q, 32W, N)
+            out[direction]["tmatch"] = jnp.pad(
+                st["tmatch"], ((0, rows - t), (0, 0))
+            )  # shape: (32W, N) bool
         else:
             out[direction]["tallow_bf"] = jnp.moveaxis(
                 tallow.astype(jnp.bfloat16), 0, q_axis
@@ -346,8 +365,22 @@ def _split_pre(pre: Dict) -> Tuple[Dict, Dict]:
     the int32 packed words — the bundle specs and ring schedules are
     shape-pattern-identical, and _tile_verdicts_split picks the
     contraction by dtype.  The packed bundle is what rides the ppermute
-    ring: ~16x fewer peer bytes per hop than the bf16 tallow."""
-    if "tallow_pk" in pre["egress"]:
+    ring: ~16x fewer peer bytes per hop than the bf16 tallow.  The MXU
+    form's (tallow_q, TILE_QTN) dst tmatch_i is cast to MXU_DTYPE here,
+    once a request, outside any tile loop; the src tmatch_e stays bool,
+    since a tile converts only its own slice."""
+    if "tallow_q" in pre["egress"]:
+        src = {
+            "tmatch_e": pre["egress"]["tmatch"],
+            "has_e": pre["egress"]["has_target"],
+            "tallow_i": pre["ingress"]["tallow_q"],
+        }
+        dst = {
+            "tallow_e": pre["egress"]["tallow_q"],
+            "tmatch_i": pre["ingress"]["tmatch"].astype(MXU_DTYPE),
+            "has_i": pre["ingress"]["has_target"],
+        }
+    elif "tallow_pk" in pre["egress"]:
         src = {
             "tmatch_e": pre["egress"]["tmatch_pk"],
             "has_e": pre["egress"]["has_target"],
@@ -392,6 +425,47 @@ def _any_bf16(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
         )
         > 0
     )
+
+
+def _any_mxu(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """[A, B] bool: OR_t (a[t, a] AND b[t, b]) over 0/1 [T, A] and [T, B],
+    b in MXU_DTYPE and `a` in it or bool: one int8 dot over T as both
+    lie, with no transpose of either, summed exactly in int32, then
+    `> 0`."""
+    return (
+        jax.lax.dot_general(
+            a.astype(MXU_DTYPE), b, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        )
+        > 0
+    )
+
+
+def _tile_verdicts_mxu(src: Dict, dst: Dict, start, block: int) -> list:
+    """The class tile's verdicts in the MXU form (TILE_QTN views,
+    _split_pre): for each port case q, (ingress, egress, combined) [B, Nd]
+    bool of the tile's source rows against the dst pods.  Both directions
+    contract the unpacked target axis on the MXU (_any_mxu): egress the
+    tile's tmatch_e slice against the dst tallow_e[q], ingress the tile's
+    tallow_i[q] slice against the dst tmatch_i.  A [T, N] slab is a major
+    slice of [Q, T, N], so no operand is copied inside the loop, and both
+    outputs put the dst pods on the lanes, so nothing is swapped."""
+    q, t_i, _ = src["tallow_i"].shape
+    t_e = src["tmatch_e"].shape[0]
+    tme = jax.lax.dynamic_slice(src["tmatch_e"], (0, start), (t_e, block))
+    no_e = ~jax.lax.dynamic_slice(src["has_e"], (start,), (block,))[:, None]
+    no_i = ~dst["has_i"][None, :]
+    out = []
+    for k in range(q):
+        # one slice of the 3-D operand: a [k] first would lay both [T, N]
+        # slabs into HBM again every tile
+        tli = jax.lax.dynamic_slice(
+            src["tallow_i"], (k, 0, start), (1, t_i, block)
+        )[0]
+        egress = no_e | _any_mxu(tme, dst["tallow_e"][k])  # [B, Nd]
+        ingress = no_i | _any_mxu(tli, dst["tmatch_i"])  # [B, Nd]
+        out.append((ingress, egress, egress & ingress))
+    return out
 
 
 def _tile_verdicts_split(
@@ -660,8 +734,9 @@ def _class_tile_rowsums(
     """[block, Q, 3] f32 dst-weighted verdict row sums for src-view rows
     [start, start+block): rs[b, q, k] = sum_dst grid_k * w_dst.  Pad
     classes carry weight 0 on the dst side and are zeroed by the host
-    weighting on the src side, so no validity mask is needed."""
-    ingress_rows, egress, combined = _tile_verdicts_split(src, dst, start, block)
+    weighting on the src side, so no validity mask is needed.  The views'
+    form picks the body: the MXU form's [B, Nd] slabs a case
+    (_tile_verdicts_mxu), else the tables' [B, Nd, Q] verdicts."""
 
     def rs(a: jnp.ndarray) -> jnp.ndarray:
         # HIGHEST precision is load-bearing: TPU's default f32 matmul
@@ -671,12 +746,21 @@ def _class_tile_rowsums(
         # (where the parity suites run) is exact either way — only the
         # TPU mega shapes would see the corruption.
         return jnp.einsum(
-            "bdq,d->bq",
+            "bd...,d->b...",
             a.astype(jnp.float32),
             w_dst,
             precision=jax.lax.Precision.HIGHEST,
         )
 
+    if dst["tmatch_i"].dtype == MXU_DTYPE:
+        return jnp.stack(
+            [
+                jnp.stack([rs(v) for v in case], axis=-1)
+                for case in _tile_verdicts_mxu(src, dst, start, block)
+            ],
+            axis=1,
+        )  # [B, Q, 3]
+    ingress_rows, egress, combined = _tile_verdicts_split(src, dst, start, block)
     return jnp.stack([rs(ingress_rows), rs(egress), rs(combined)], axis=-1)
 
 
@@ -702,8 +786,12 @@ def _class_rowsums_kernel(
     one device execution (fori_loop over class tiles).  `tensors` carries
     no port cases: they come as `cases` (_with_case_rows); `selpod`, the
     resident selector matches of the classes where the engine holds them
-    (_precompute_static)."""
-    pre = _precompute(_with_case_rows(tensors, cases), pack, selpod=selpod)
+    (_precompute_static).  `pack`: class_counts_pack's, and with it the
+    tile's layout (class_tile_form)."""
+    pre = _precompute(
+        _with_case_rows(tensors, cases), pack, class_tile_form(tensors, pack),
+        selpod=selpod,
+    )
     src, dst = _split_pre(pre)
     q = cases.shape[1]
 
@@ -843,6 +931,33 @@ def _class_counts_kernel_choice(tensors: Dict, pack: bool, kernel: str) -> str:
     return kernel
 
 
+#: the forms of the class tile's contraction (`class_contract` on the
+#: evaluation): the packed target words, on the VPU (kernel.packed_any, or
+#: the fused Pallas kernel), or the unpacked target axis on the MXU
+CONTRACT_PACKED = "packed"
+CONTRACT_MXU = "mxu"
+
+
+def class_counts_pack(tensors: Dict, pack: bool, kernel: str) -> bool:
+    """Whether the class row-sum program over `tensors` packs its target
+    axis.  The fused Pallas kernel (`kernel` "pallas") always does.  On
+    the XLA tile a tiered set keeps `pack` (its tier epilogue reads the
+    [B, Nd, Q] verdicts), and an untiered set never packs: its two
+    contractions run on the MXU (TILE_QTN, _tile_verdicts_mxu), where
+    packing would only move them to the VPU.  Decided from the shapes
+    and the kernel, outside any jit; the class counts routes and their
+    HBM estimate (api._class_counts_bytes) all ask here."""
+    return kernel == "pallas" or (bool(pack) and "tiers" in tensors)
+
+
+def class_tile_form(tensors: Dict, pack: bool) -> str:
+    """The layout of the class row-sum tile over `tensors`: TILE_BNQ for
+    packed words and for a tiered set (the tier epilogue is written for
+    [B, Nd, Q] verdicts, as counts_tile_form), else the MXU form,
+    TILE_QTN."""
+    return TILE_BNQ if pack or "tiers" in tensors else TILE_QTN
+
+
 def evaluate_grid_counts_classes(
     fl,
     tensors: Dict,
@@ -873,13 +988,15 @@ def evaluate_grid_counts_classes(
     `kernel`: see _class_counts_kernel_choice.  `selpod`: the engine's
     resident selector matches of the classes, [S, Cb] on its device, or
     None, where the program computes them (_precompute_static); the
-    flight says which (`class_sel` host / device)."""
+    flight says which (`class_sel` host / device), and which form the
+    contraction took (`class_contract`, class_counts_pack)."""
     import time as _time
 
     if pack is None:
         pack = pack_enabled()
     with detail("engine.plan"):
         kernel = _class_counts_kernel_choice(tensors, pack, kernel)
+        pack = class_counts_pack(tensors, pack, kernel)
         block, n_tiles = _class_tiling(
             int(tensors["pod_ns_id"].shape[0]), block
         )
@@ -888,6 +1005,7 @@ def evaluate_grid_counts_classes(
         block=block,
         class_kernel=kernel,
         class_sel="device" if selpod is None else "host",
+        class_contract=CONTRACT_PACKED if pack else CONTRACT_MXU,
         target_words=target_words(tensors),
         **tier_attrs(tensors, TIER_FUSED if kernel == "pallas" else TIER_XLA),
     )
@@ -946,7 +1064,8 @@ def evaluate_grid_counts_classes_sharded(
     mesh, n_dev, q, block, tensors, n_padded = _mesh_counts_setup(
         tensors, n_classes, block, mesh
     )
-    pack = pack_enabled()
+    pack = class_counts_pack(tensors, pack_enabled(), "xla")
+    tile = class_tile_form(tensors, pack)
     shard = n_padded // n_dev
     tiles_per_shard = shard // block
     t = dict(tensors)
@@ -955,7 +1074,7 @@ def evaluate_grid_counts_classes_sharded(
     def per_device(td):
         w_all = td["class_w"]
         pre = _precompute(
-            {k: v for k, v in td.items() if k != "class_w"}, pack
+            {k: v for k, v in td.items() if k != "class_w"}, pack, tile
         )
         src, dst = _split_pre(pre)
         dev = jax.lax.axis_index("x")
@@ -985,6 +1104,7 @@ def evaluate_grid_counts_classes_sharded(
         q,
         classes=n_classes,
         devices=int(n_dev),
+        class_contract=CONTRACT_PACKED if pack else CONTRACT_MXU,
         **tier_attrs(tensors),
     ) as fl:
         with mesh_device_context(mesh):

@@ -108,6 +108,15 @@ COUNTS_TILE = REGISTRY.counter(
     "`engine.eval` span, counted.",
     labelnames=("form",),
 )
+CLASS_CONTRACT = REGISTRY.counter(
+    "cyclonus_tpu_class_contract_total",
+    "Class counts evaluations by the form of their tile contraction "
+    "(engine/tiled.py class_counts_pack): packed (the target words on the "
+    "VPU, in the fused Pallas kernel or the XLA tile over a tiered set) or "
+    "mxu (the XLA tile's unpacked target axis on the MXU).  The "
+    "`class_contract` of the evaluation's `engine.eval` span, counted.",
+    labelnames=("form",),
+)
 GRID_HOST_BUFFER = REGISTRY.counter(
     "cyclonus_tpu_grid_host_buffer_total",
     "Host buffers handed to sharded tables' readbacks "
@@ -789,6 +798,11 @@ def eval_flight(path: str, n_pods: int, q: int, **attrs: Any) -> Iterator[Flight
                     class_sel=flight.data["class_sel"],
                     target_words=flight.data["target_words"],
                 )
+            # and the form its tile contraction took (tiled.class_counts_pack),
+            # counted a form
+            if "class_contract" in flight.data:
+                sp.set(class_contract=flight.data["class_contract"])
+                CLASS_CONTRACT.inc(form=flight.data["class_contract"])
             # and, over a tiered set, its tier rule rows and the epilogue
             # that resolved them (tiled.tier_attrs), counted a kernel
             if "tier_kernel" in flight.data:

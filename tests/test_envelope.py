@@ -196,6 +196,85 @@ def test_the_class_kernel_leaves_a_deep_word_axis_to_the_xla_tile(engines, monke
         tiled._class_counts_kernel_choice(ct, True, "pallas")
 
 
+@pytest.fixture(scope="module")
+def tiered_engines():
+    """A tiered set on the class route, and its dense twin (`counts.xla`)
+    for the counts."""
+    pods, namespaces, policy, tiers = _tiers()
+    return {
+        compress: TpuPolicyEngine(
+            policy, pods, namespaces, class_compress=compress, tiers=tiers
+        )
+        for compress in ("1", "0")
+    }
+
+
+@pytest.mark.parametrize(
+    "deployment, pack, contract",
+    [
+        ("envelope", True, tiled.CONTRACT_MXU),
+        ("envelope", False, tiled.CONTRACT_MXU),
+        ("tiered", True, tiled.CONTRACT_PACKED),
+        ("tiered", False, tiled.CONTRACT_MXU),
+    ],
+    ids=["envelope-mxu", "envelope-unpacked", "tiered-packed", "tiered-unpacked"],
+)
+def test_the_class_tile_contracts_in_the_form_of_its_words(
+    engines, tiered_engines, reference, monkeypatch, deployment, pack, contract
+):
+    """On the XLA tile an untiered set contracts the unpacked target axis
+    on the MXU whether or not packing is on, and its counts are the
+    reference's; a tiered set keeps the packed form where packing is on
+    and the tables' unpacked bf16 body where it is off, and its counts are
+    the dense route's.  The flight entry, the `engine.eval` span and
+    cyclonus_tpu_class_contract_total say which."""
+    if deployment == "envelope":
+        eng = engines["classes-host"]
+        want = {p: reference.counts(cases_of(PAIRS[p])) for p in range(3)}
+    else:
+        eng = tiered_engines["1"]
+        want = {
+            p: tiered_engines["0"].evaluate_grid_counts(port_cases(PAIRS[p]))
+            for p in range(3)
+        }
+    monkeypatch.setattr(eng, "_pack", pack)
+    before = ti.CLASS_CONTRACT.value(form=contract)
+    for p in range(3):
+        spans.REGISTRY.reset()
+        got = eng.evaluate_grid_counts(port_cases(PAIRS[p]))
+        assert {k: int(got[k]) for k in want[p]} == want[p]
+        (root,) = spans_named("engine.eval")
+        assert (root["route"], root["class_contract"]) == ("counts.classes", contract)
+        assert recorder.entries()[-1]["class_contract"] == contract
+    assert ti.CLASS_CONTRACT.value(form=contract) == before + 3
+
+
+def test_the_estimate_counts_the_form_of_the_target_axis(engines, monkeypatch):
+    """The class route's HBM estimate holds the MXU form's tallow
+    [Q, T, Cb] in tiled.MXU_DTYPE a direction where the XLA tile runs an
+    untiered set, its contraction's result counted once, and the packed
+    words [W, Cb, Q] and [W, Cb] int32 a direction beside that result
+    where the fused kernel runs, T in whole words either way."""
+    eng = engines["classes-host"]
+    ct = eng._class_state["ctensors"]
+    cb, q = int(ct["pod_ns_id"].shape[0]), 2
+    dims = {
+        d: (32 * packed_words(int(ct[d]["target_ns"].shape[0])), int(ct[d]["peer_kind"].shape[0]))
+        for d in ("ingress", "egress")
+    }
+    mxu = eng._class_counts_bytes(q)
+    monkeypatch.setattr(tiled, "_class_counts_kernel_choice", lambda *a: "pallas")
+    packed = eng._class_counts_bytes(q)
+    size = np.dtype(tiled.MXU_DTYPE).itemsize
+    words = sum(4 * t // 32 * cb * (q + 1) for t, _ in dims.values())
+    unpacked = sum(size * q * t * cb for t, _ in dims.values())
+    stage = {
+        form: max(q * t * (p + cb * (form == "packed")) for t, p in dims.values())
+        for form in ("packed", "mxu")
+    }
+    assert packed - mxu == (words + stage["packed"]) - (unpacked + stage["mxu"])
+
+
 def test_the_estimate_counts_the_selector_stage_and_the_one_hot(engines):
     """The program's own pass over the class axis holds two [S, Cb, E]
     boolean planes; the resident matches hold [S, Cb] instead, in the

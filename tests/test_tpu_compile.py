@@ -284,10 +284,22 @@ def test_the_case_precompute_builds_no_peer_allow(one_chip, tile):
     assert temp <= PARENT_CASES_TEMP_BYTES[tile]
 
 
-def _envelope_class_set(one_chip):
-    """(ct, w, cases, selpod) as ShapeDtypeStructs: the class tensor set of
-    k8s-envelope-150k's rehearsal (one load namespace, 330 classes), built
-    on the host, and its resident selector matches [S, Cb]."""
+#: k8s-envelope-150k's class set at its full size (PERF.md section 4, the
+#: draw on a TPU host): pods, classes and selectors bucketed,
+#: and a direction's (peer rows bucketed, target rows bucketed)
+ENVELOPE_FULL = dict(
+    pods=150_004, cb=17_408, s=41_472,
+    ingress=(18_048, 16_511), egress=(14_848, 16_511),
+)
+
+
+def _envelope_class_set(one_chip, full=False):
+    """(ct, w, cases, selpod, eng) with the first four as ShapeDtypeStructs:
+    the class tensor set of k8s-envelope-150k's rehearsal (one load
+    namespace, 330 classes), built on the host, and its resident selector
+    matches [S, Cb]; `full` lays the same leaves at the full deployment's
+    axes (ENVELOPE_FULL), and `eng`, the rehearsal's engine, then holds
+    them as its class state, for its estimate alone."""
     from cyclonus_tpu.engine import TpuPolicyEngine
     from cyclonus_tpu.kube.yaml_io import parse_policy_dict
     from cyclonus_tpu.matcher import build_network_policies
@@ -297,17 +309,82 @@ def _envelope_class_set(one_chip):
     policy = build_network_policies(True, [parse_policy_dict(d) for d in policies])
     eng = TpuPolicyEngine(policy, pods, namespaces, class_compress="1")
     ct = eng._class_state["ctensors"]
+    cb, s = ct["pod_ns_id"].shape[0], ct["sel_req_kv"].shape[0]
+    if full:
+        cb, s = ENVELOPE_FULL["cb"], ENVELOPE_FULL["s"]
 
-    def sds(a):
-        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    def sds(path, a):
+        shape = list(a.shape)
+        key = [k.key for k in path]
+        if full and key[0] in ("ingress", "egress"):
+            peers, targets = ENVELOPE_FULL[key[0]]
+            shape[0] = targets if key[-1] in ("target_ns", "target_sel") else peers
+        elif full and key[0].startswith("pod_"):
+            shape[0] = cb
+        elif full and key[0].startswith("sel_"):
+            shape[0] = s
+        return jax.ShapeDtypeStruct(tuple(shape), a.dtype, sharding=one_chip)
 
-    cb = ct["pod_ns_id"].shape[0]
+    ct = jax.tree_util.tree_map_with_path(sds, ct)
+    selpod = jax.ShapeDtypeStruct((s, cb), jnp.bool_, sharding=one_chip)
+    if full:
+        leaves = jax.tree_util.tree_leaves(ct) + [selpod]
+        eng._class_state = dict(
+            eng._class_state,
+            ctensors=ct,
+            selpod=selpod,
+            aux_bytes=ENVELOPE_FULL["pods"] * 4 + cb * 4 + sum(
+                int(np.prod(a.shape)) * a.dtype.itemsize for a in leaves
+            ),
+        )
     return (
-        jax.tree_util.tree_map(sds, ct),
+        ct,
         jax.ShapeDtypeStruct((cb,), jnp.float32, sharding=one_chip),
         jax.ShapeDtypeStruct((3, 2), jnp.int32, sharding=one_chip),
-        jax.ShapeDtypeStruct((ct["sel_req_kv"].shape[0], cb), jnp.bool_, sharding=one_chip),
+        selpod,
+        eng,
     )
+
+
+def _while_body_ops(text):
+    """What a compiled module's while loops do a trip: ([(opcode, largest
+    array it writes, in elements)] of each body's own instructions, the
+    buffers a trip materializes; [(opcode, element type, elements)] of
+    every instruction of the bodies' fusions and the computations they
+    call)."""
+    import math
+    import re
+
+    comps = {}
+    for block in re.split(r"\n(?=%|ENTRY )", text):
+        m = re.match(r"(?:ENTRY )?%(\S+) ", block)
+        if m:
+            comps[m.group(1)] = block
+    bodies = re.findall(r"\bwhile\(.*?body=%([\w.\-]+)", text)
+    written, todo = [], []
+    for body in bodies:
+        for line in comps[body].split("\n")[1:]:
+            m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([\w\-]+)\(", line)
+            if m and m.group(2) not in ("parameter", "get-tuple-element", "tuple"):
+                sizes = [
+                    math.prod(int(d) for d in dims.split(",") if d)
+                    for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(1))
+                ]
+                written.append((m.group(2), max(sizes, default=0)))
+            todo += re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", line)
+    seen, inner = set(), []
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name].split("\n")[1:]:
+            m = re.match(r"\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(", line)
+            if m:
+                dims = [int(d) for d in m.group(2).split(",") if d]
+                inner.append((m.group(3), m.group(1), math.prod(dims)))
+            todo += re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", line)
+    return written, inner
 
 
 def test_the_class_program_handed_its_matches_runs_no_selector_pass(one_chip):
@@ -320,7 +397,7 @@ def test_the_class_program_handed_its_matches_runs_no_selector_pass(one_chip):
 
     from cyclonus_tpu.engine import tiled
 
-    ct, w, cases, selpod = _envelope_class_set(one_chip)
+    ct, w, cases, selpod, _ = _envelope_class_set(one_chip)
     s, e = ct["sel_exp_op"].shape
     cb = ct["pod_ns_id"].shape[0]
     block, n_tiles = tiled._class_tiling(cb, 1024)
@@ -333,3 +410,35 @@ def test_the_class_program_handed_its_matches_runs_no_selector_pass(one_chip):
         assert bool(plane.search(compiled.as_text())) is not held
         temps[held] = compiled.memory_analysis().temp_size_in_bytes
     assert temps[True] <= temps[False]
+
+
+@pytest.mark.parametrize("full", [False, True], ids=["rehearsal", "envelope"])
+def test_the_untiered_class_program_contracts_on_the_mxu(one_chip, full):
+    """The XLA class program over an untiered set (k8s-envelope-150k's past
+    PACKED_MAX_WORDS, 516 words a direction at full size) runs
+    the MXU form: its tile loop holds one dot a direction and port case
+    over the unpacked target axis, in tiled.MXU_DTYPE, and writes,
+    converts, copies or transposes no [T, Cb]-sized array a trip (the dst
+    ingress tmatch is cast once, before the loop, and a tile's [T, block]
+    slab is sliced where it lies); the class route's estimate holds its
+    temporaries and arguments, and at full size by at most 15 % more."""
+    from cyclonus_tpu.engine import tiled
+
+    ct, w, cases, selpod, eng = _envelope_class_set(one_chip, full)
+    assert not tiled.class_counts_pack(ct, True, "xla")
+    cb = ct["pod_ns_id"].shape[0]
+    t = min(ct[d]["target_ns"].shape[0] for d in ("ingress", "egress"))
+    # two tiles or more, so that the loop is a loop
+    block, n_tiles = tiled._class_tiling(cb, 1024 if full else 128)
+    compiled = tiled._class_rowsums_kernel.lower(
+        ct, w, cases, block, n_tiles, False, selpod=selpod
+    ).compile()
+    written, inner = _while_body_ops(compiled.as_text())
+    assert [o for o in inner if o[0] == "convolution"] == [("convolution", "s32", block * cb)] * 4
+    assert [o for o in written if o[1] >= t * cb] == []
+    assert [o for o in inner if o[0] in ("convert", "copy", "transpose") and o[2] >= t * cb] == []
+    mem = compiled.memory_analysis()
+    used = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+    assert used <= eng._class_counts_bytes(2)
+    if full:
+        assert eng._class_counts_bytes(2) <= 1.15 * used
