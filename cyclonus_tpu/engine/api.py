@@ -691,7 +691,30 @@ def _bucket_tensors(tensors: Dict, headroom: int = 0) -> Dict:
     # pod axis last: the inert-row scheme lives in _pad_pod_arrays
     n = t["pod_ns_id"].shape[0]
     t, _ = _pad_pod_arrays(t, n, _bucket_pods(n))
-    return t
+    return _band_tensors(t, headroom)
+
+
+def _band_tensors(tensors: Dict, headroom: int = 0) -> Dict:
+    """Each direction's band plan for the target-allows contraction
+    (kernel.target_allows_q), from its bucketed shapes and peer_target
+    alone: band_rows [tiles, PSPAN] (kernel.band_rows; `headroom` steps
+    PSPAN as _bucket_tensors steps the peer axis) where the rule engages
+    (kernel.band_engages: the band is at most a quarter of the dense
+    one-hot), and no leaf elsewhere, so that the programs of such a set
+    are the dense form's.  A plan the set carried already is laid anew:
+    every tensor set passes here last (_bucket_tensors, the serve slab
+    patch after its fit), so a delta never keeps a stale one."""
+    from .kernel import band_engages, band_rows
+
+    out = dict(tensors)
+    for direction in ("ingress", "egress"):
+        d = {k: v for k, v in tensors[direction].items() if k != "band_rows"}
+        t = int(d["target_ns"].shape[0])
+        band = band_rows(d["peer_target"], t, headroom)
+        if band_engages(band, t, int(d["peer_target"].shape[0])):
+            d["band_rows"] = band
+        out[direction] = d
+    return out
 
 
 # device-resident precompute cache ceiling (the tallow tensors are
@@ -1036,11 +1059,14 @@ class TpuPolicyEngine:
                 ti.CLASS_ROUTE.inc(outcome=class_route)
                 if self._selpod_prebucket is None:
                     ti.SELECTOR_PASS.inc(outcome="skipped")
-                with phase("engine.class_tensors"):
+                from .kernel import tallow_attrs
+
+                with phase("engine.class_tensors") as sp:
                     self._tensors = _bucket_tensors(
                         _sort_targets_by_ns(self._tensors),
                         headroom=self._slab_headroom,
                     )
+                    sp.set(**tallow_attrs(self._tensors))
                     if self._class_state is not None:
                         self._class_state["ctensors"] = _bucket_tensors(
                             _sort_targets_by_ns(
@@ -1048,6 +1074,10 @@ class TpuPolicyEngine:
                             ),
                             headroom=self._slab_headroom,
                         )
+                        sp.set(**{
+                            f"class_{k}": v for k, v in
+                            tallow_attrs(self._class_state["ctensors"]).items()
+                        })
                 if self._class_state is not None:
                     st = self._class_state
                     # the gather/index tensors the compressed path pins on
@@ -1250,13 +1280,16 @@ class TpuPolicyEngine:
         dense operand dtype, plus the tier flag and, on a tiered engine
         alone, the form of the tier first-match (kernel.TIER_FIRST_MATCH),
         then the form of the target-allows contraction
-        (kernel.TARGET_ALLOWS: nearly every program traces
-        tiled._precompute_cases, through _precompute).  Programs whose trace
+        (kernel.target_allows_form: nearly every program traces
+        tiled._precompute_cases, through _precompute; the banded form
+        where the engine's tensors or its class tensors carry band_rows,
+        so that an engine whose sets carry none keeps the dense form's
+        key).  Programs whose trace
         bakes per-engine constants (the unpack closures' leaf layout)
         append a metas digest via `extra` — two engines with equal
         buffer lengths but different leaf layouts must never share an
         executable."""
-        from .kernel import PACKED_CONTRACTION, TARGET_ALLOWS, TIER_FIRST_MATCH
+        from .kernel import PACKED_CONTRACTION, TIER_FIRST_MATCH, target_allows_form
         from .pallas_kernel import _resolve_operand_dtype
 
         dtype = (
@@ -1267,7 +1300,8 @@ class TpuPolicyEngine:
         plan = f"{dtype};tiered={self.tiers is not None}"
         if self.tiers is not None:
             plan += f";{TIER_FIRST_MATCH}"
-        plan += f";{TARGET_ALLOWS}"
+        st = self._class_state or {}
+        plan += ";" + target_allows_form(self._tensors, st.get("ctensors", {}))
         return plan + (";" + extra if extra else "")
 
     @staticmethod
@@ -1632,7 +1666,8 @@ class TpuPolicyEngine:
         set and, where held, its selector matches) and what lives
         through the whole request — the selector matches of the classes
         [S, Cb] bool where the program computes them, a direction's
-        peer_match [P, Cb] and tmatch [T, Cb] bool and target words in
+        peer_match [P, Cb] (but where the direction carries band_rows)
+        and tmatch [T, Cb] bool and target words in
         the form the program takes (tiled.class_counts_pack and
         class_tile_form; packed: tallow_pk [W, Cb, Q] and tmatch_pk
         [W, Cb] int32; the MXU form: tallow [Q, 32W, Cb] in
@@ -1640,11 +1675,15 @@ class TpuPolicyEngine:
         the row sums [Cb, Q, 3] f32 — plus the larger of two stages that
         never overlap: the program's own selector pass, two [S, Cb, E]
         boolean planes, and one direction's target-allows contraction
-        (kernel.target_allows_q: the port-folded one-hot [Q·T', P] and,
-        but in the MXU form, whose held tallow it is, its [Q·T', Cb]
-        result, both bool, T' the target axis in whole words; the bf16
-        operand and accumulator fuse into the matmul).  A tiered set adds
-        its tier precompute and a class tile's first-match."""
+        (kernel.target_allows_q: the port-folded one-hot [Q·T', P], or
+        where the direction carries band_rows the tile one-hots
+        [tiles, Q·TB, PSPAN] and the peer rows gathered to them
+        [tiles, PSPAN, Cb], and, but in the MXU form, whose held tallow
+        it is, its [Q·T', Cb] result, all bool, T' the target axis in
+        whole words; the bf16 operand and accumulator fuse into the
+        matmul).  A tiered set adds its tier precompute and a class
+        tile's first-match."""
+        from .kernel import TARGET_BAND
         from .tiled import (
             MXU_DTYPE,
             TILE_QTN,
@@ -1671,20 +1710,30 @@ class TpuPolicyEngine:
             t = int(ct[d]["target_ns"].shape[0])
             p = int(ct[d]["peer_kind"].shape[0])
             w = packed_words(t)
-            held += (p + t) * cb
+            rows = w * 32 if pack or mxu else t
+            band = ct[d].get("band_rows")
+            if band is None:
+                held += (p + t) * cb
+                onehot = q * rows * p
+            else:
+                # the peer rows live gathered to the band, for the
+                # contraction alone, beside the tile one-hots
+                held += t * cb
+                tiles, span = (int(x) for x in band.shape)
+                onehot = tiles * span * (q * TARGET_BAND + cb)
             if pack:
                 held += 4 * w * cb * (q + 1)
-                stages.append(q * w * 32 * (p + cb))
+                stages.append(onehot + q * rows * cb)
             elif mxu:
                 # the int8 tallow IS the contraction's result (its compare
                 # and cast fuse), so the stage holds the one-hot alone; the
                 # dst ingress tmatch's int8 copy, a byte a cell as its
                 # bool, takes the bool's place
-                held += np.dtype(MXU_DTYPE).itemsize * q * w * 32 * cb
-                stages.append(q * w * 32 * p)
+                held += np.dtype(MXU_DTYPE).itemsize * q * rows * cb
+                stages.append(onehot)
             else:
                 held += 2 * t * cb * q
-                stages.append(q * t * (p + cb))
+                stages.append(onehot + q * rows * cb)
         # a tiered set: its precompute and, on the XLA tile loop past the
         # fused kernel's row ceiling, a class tile's first-match
         block, _ = _class_tiling(cb, 1024)
@@ -2844,6 +2893,7 @@ class TpuPolicyEngine:
         """Telemetry shell around the pallas counts path: one flight-
         recorder entry + latency/throughput instruments per evaluation
         (host-side only — the timed body below never syncs for it)."""
+        from .kernel import tallow_attrs
         from .tiled import TIER_FUSED, tier_attrs
 
         # a tiered set reaches the Pallas kernel only where its epilogue
@@ -2851,6 +2901,7 @@ class TpuPolicyEngine:
         with ti.eval_flight(
             "counts.pallas", n, len(cases),
             **tier_attrs(self._tensors, TIER_FUSED),
+            **tallow_attrs(self._tensors),
         ) as fl:
             counts = self._counts_pallas_dispatch(cases, n, fl)
             fl.set(cells=counts["cells"])
@@ -3012,18 +3063,23 @@ class TpuPolicyEngine:
     def _static_pre_bytes(self) -> int:
         """The bytes tiled._precompute_static's result holds, from the
         shapes alone, before any program exists: per direction the
-        [P, N] peer matches, the [T, N] target matches (and their packed
-        words), has_target, and the encoding leaves that ride along; a
-        tiered set also keeps both selector matches."""
+        [P, N] peer matches (where band_rows rides, the [tiles, PSPAN,
+        N] rows laid to them), the [T, N] target matches (and their
+        packed words), has_target, and the encoding leaves that ride
+        along; a tiered set also keeps both selector matches."""
         t = self._tensors
         n = int(t["pod_ns_id"].shape[0])
         total = 0
         for d in ("ingress", "egress"):
             enc = t[d]
             n_t = int(enc["target_ns"].shape[0])
-            total += n * (int(enc["peer_target"].shape[0]) + n_t + 1)
+            band = enc.get("band_rows")
+            peer_rows = int(enc["peer_target"].shape[0] if band is None else band.size)
+            total += n * (peer_rows + n_t + 1)
             if self._pack:
                 total += 4 * n * packed_words(n_t)
+            if band is not None:
+                total += band.nbytes
             total += enc["peer_target"].nbytes + enc["target_ns"].nbytes
             total += sum(x.nbytes for x in enc["port_spec"].values())
         if "tiers" in t:
@@ -3306,6 +3362,7 @@ class TpuPolicyEngine:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec
 
+        from .kernel import tallow_attrs
         from .sharded import _pad_pod_arrays, default_mesh, mesh_device_context
         from .tiled import (
             _int32_safe_block,
@@ -3346,7 +3403,7 @@ class TpuPolicyEngine:
         with ti.eval_flight(
             path, n, q, devices=n_dev, mode="held",
             replicated_bytes=need, ceiling_bytes=ceiling, **tile,
-            **tier_attrs(self._tensors),
+            **tier_attrs(self._tensors), **tallow_attrs(self._tensors),
         ) as fl, mesh_device_context(mesh):
             held = self._mesh_static
             if key not in self._mesh_counts_jits or held is None or held[0] != key:

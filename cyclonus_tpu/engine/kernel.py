@@ -344,6 +344,124 @@ def m_tp_onehot(enc: Dict, rows: Optional[int] = None) -> jnp.ndarray:
 #: peer_allow [P, N, Q] and relaid it, lies under another key
 TARGET_ALLOWS = "tallow=qonehot"
 
+#: targets a tile of the banded contraction (band_rows, target_allows_q):
+#: a multiple of 32, so that every tile boundary is a packed word's, and
+#: Q * TARGET_BAND = 256 one-hot rows a tile at Q = 2
+TARGET_BAND = 128
+
+#: the plan's name of the banded form, in place of TARGET_ALLOWS where a
+#: program's tensors carry band_rows (target_allows_form): a program whose
+#: tensors carry none keeps the dense form's key and executable
+TARGET_ALLOWS_BAND = f"tallow=band{TARGET_BAND}"
+
+#: the granule of a band's span (its peer rows a tile): the peer axis's
+#: bucket granule past 128 (api._bucket_dim), so that a delta that moves a
+#: span by a few rows keeps its shapes
+BAND_SPAN_GRANULE = 128
+
+
+def band_rows(peer_target: np.ndarray, t: int, headroom: int = 0) -> np.ndarray:
+    """[ceil(t / TARGET_BAND), PSPAN] int32, on the host: tile k's peer
+    rows (those whose peer_target lies in [k * TARGET_BAND, (k + 1) *
+    TARGET_BAND)), in target order, stable, -1 past them.  PSPAN is the
+    largest tile's rows rounded up to BAND_SPAN_GRANULE, `headroom`
+    granules more (the serve slab reservation, as api._bucket_up steps
+    the peer axis).  Pad rows (peer_target -1) lie in no tile."""
+    pt = np.asarray(peer_target).reshape(-1)
+    tiles = max(-(-t // TARGET_BAND), 1)
+    real = np.flatnonzero((pt >= 0) & (pt < t))
+    order = real[np.argsort(pt[real], kind="stable")]
+    tile = pt[order] // TARGET_BAND
+    count = np.bincount(tile, minlength=tiles)
+    span = -(-max(int(count.max()), 1) // BAND_SPAN_GRANULE) * BAND_SPAN_GRANULE
+    span += max(0, headroom) * BAND_SPAN_GRANULE
+    out = np.full((tiles, span), -1, np.int32)
+    start = np.concatenate([[0], np.cumsum(count)[:-1]])
+    out[tile, np.arange(order.size) - start[tile]] = order
+    return out
+
+
+def band_engages(band: np.ndarray, t: int, p: int) -> bool:
+    """The rule that decides the banded form, from the shapes: the band's
+    one-hot, tiles x TARGET_BAND x PSPAN, is at most a quarter of the
+    dense one's T x P (the MXU work of either contraction is that times
+    Q x N)."""
+    tiles, span = band.shape
+    return 4 * tiles * TARGET_BAND * span <= t * p
+
+
+def target_allows_form(*tensor_sets: Dict) -> str:
+    """TARGET_ALLOWS_BAND where a direction of one of `tensor_sets` (tensor
+    sets, or the shard_map specs of one) carries band_rows, else
+    TARGET_ALLOWS."""
+    banded = any(
+        "band_rows" in ts.get(d, {})
+        for ts in tensor_sets
+        for d in ("ingress", "egress")
+    )
+    return TARGET_ALLOWS_BAND if banded else TARGET_ALLOWS
+
+
+def tallow_attrs(tensors: Dict) -> Dict:
+    """What an evaluation whose program traces _precompute_cases over the
+    tensor set `tensors` says of its target-allows contraction
+    (kernel.target_allows_q) in its flight entry and on its `engine.eval`
+    span: tallow, the form a direction, ingress first ("band": the
+    direction carries band_rows; "dense"), and tallow_span, the peer rows
+    a target reads in the contraction, ingress + egress (a band's PSPAN;
+    a dense direction's every row, P)."""
+    forms, span = [], 0
+    for direction in ("ingress", "egress"):
+        enc = tensors[direction]
+        banded = "band_rows" in enc
+        forms.append("band" if banded else "dense")
+        span += int(
+            enc["band_rows"].shape[1] if banded else enc["peer_target"].shape[0]
+        )
+    return {"tallow": ",".join(forms), "tallow_span": span}
+
+
+def band_peer_match(band: jnp.ndarray, peer_match: jnp.ndarray) -> jnp.ndarray:
+    """[tiles, PSPAN, N] bool: the rows of peer_match [P, N] as `band`
+    (band_rows) lays them, its -1 pads false rows."""
+    real = band >= 0
+    return jnp.take(peer_match, jnp.maximum(band, 0), axis=0) & real[:, :, None]
+
+
+def _target_allows_band(
+    enc: Dict, peer_band: jnp.ndarray, pport: jnp.ndarray, rows: int
+) -> jnp.ndarray:
+    """target_allows_q over a banded direction: each tile of TARGET_BAND
+    targets against its own peer rows only.  The tile's one-hot, its
+    peer rows' targets against the tile's target ids masked by each
+    case's column of their `pport`, [tiles, Q * TB, PSPAN], runs against
+    peer_band [tiles, PSPAN, N] in ONE batched MXU matmul; the result is
+    laid [Q, tiles * TB, N] and cut or padded to `rows`."""
+    band = enc["band_rows"]
+    tiles, span = band.shape
+    tb = TARGET_BAND
+    q, n = pport.shape[1], peer_band.shape[2]
+    idx = jnp.maximum(band, 0)
+    target = jnp.where(band >= 0, jnp.take(enc["peer_target"], idx), -1)
+    local = target - jnp.arange(tiles, dtype=target.dtype)[:, None] * tb
+    hot = local[:, None, :] == jnp.arange(tb, dtype=target.dtype)[None, :, None]
+    ports = jnp.moveaxis(jnp.take(pport, idx, axis=0), 2, 1)  # [tiles, Q, S]
+    folded = (ports[:, :, None, :] & hot[:, None, :, :]).reshape(
+        tiles, q * tb, span
+    )
+    tallow = jax.lax.dot_general(
+        folded.astype(jnp.bfloat16),
+        peer_band.astype(jnp.bfloat16),
+        (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.bfloat16,
+    )  # [tiles, Q * TB, N]
+    out = jnp.moveaxis((tallow > 0).reshape(tiles, q, tb, n), 1, 0).reshape(
+        q, tiles * tb, n
+    )
+    if rows <= tiles * tb:
+        return out[:, :rows]
+    return jnp.pad(out, ((0, 0), (0, rows - tiles * tb), (0, 0)))
+
 
 def target_allows_q(
     enc: Dict,
@@ -359,7 +477,16 @@ def target_allows_q(
     `m_tp @ (peer_match & pport)` and no [P, N, Q] operand or relayout
     of one.  `rows` (m_tp_onehot's) pads T with false rows, so that a
     packer of T finds nothing to pad.  bf16 accumulation of 0/1 terms:
-    `> 0` is exact (tiled.py's memory note)."""
+    `> 0` is exact (tiled.py's memory note).
+
+    Where `enc` carries band_rows (api._band_tensors laid them), every
+    peer row belongs to one target, so the one-hot's ones lie in a band:
+    `peer_match` is then band_peer_match's [tiles, PSPAN, N] and each
+    tile of TARGET_BAND targets contracts its own rows alone
+    (_target_allows_band), the same OR over the same rows."""
+    if "band_rows" in enc:
+        t = enc["target_ns"].shape[0] if rows is None else rows
+        return _target_allows_band(enc, peer_match, pport, t)
     m_tp = m_tp_onehot(enc, rows)  # [T, P]
     q, t = pport.shape[1], m_tp.shape[0]
     folded = (pport.T[:, None, :] & m_tp[None, :, :]).reshape(q * t, -1)

@@ -55,7 +55,6 @@ from ..utils.tracing import phase
 
 from .kernel import (
     PACKED_CONTRACTION,
-    TARGET_ALLOWS,
     TIER_FIRST_MATCH,
     WORD_CELLS,
     WORD_FORMAT,
@@ -70,6 +69,8 @@ from .kernel import (
     port_spec_allows,
     resolve_tier_lattice,
     selector_match,
+    tallow_attrs,
+    target_allows_form,
     tier_direction_arrays,
     tier_first_match_keys,
 )
@@ -552,7 +553,8 @@ def _sharded_program(
         # statics, the form of the packed contraction, that of the tier
         # first-match (a tiered set alone: an untiered plan stays as it
         # was), that of the target-allows contraction (the ring alone
-        # traces tiled._precompute), the epilogue and the result's form
+        # traces tiled._precompute; banded where the specs carry
+        # band_rows), the epilogue and the result's form
         # are program identity the arg shapes can't see, so they ride in
         # the plan.
         from . import aot_cache
@@ -565,7 +567,7 @@ def _sharded_program(
             fn,
             schedule=schedule,
             plan=(
-                (f"{TARGET_ALLOWS};" if schedule == "ring" else "")
+                (f"{target_allows_form(in_specs)};" if schedule == "ring" else "")
                 + f"shard={shard};pack={pack};"
                 + (f"{PACKED_CONTRACTION};" if pack else "")
                 + (f"{TIER_FIRST_MATCH};" if "tiers" in in_specs else "")
@@ -646,6 +648,8 @@ def evaluate_grid_sharded(
         "grid.sharded", n_pods, int(tensors["q_port"].shape[0]),
         devices=n_dev, schedule=schedule, classes=classes,
         dispatch_only=True, **tier_attrs(tensors),
+        # the ring alone traces tiled._precompute
+        **(tallow_attrs(tensors) if schedule == "ring" else {}),
     ) as fl:
         with mesh_device_context(mesh):
             fn.resolve(*args)

@@ -56,6 +56,7 @@ from ..telemetry import instruments as ti
 from ..utils.tracing import detail, phase
 from .encoding import PACK_BITS, TIER_KEY_NONE, pack_enabled, packed_words
 from .kernel import (
+    band_peer_match,
     direction_precompute,
     m_tp_onehot,
     pack_bool_words_jnp,
@@ -63,6 +64,7 @@ from .kernel import (
     port_spec_allows,
     resolve_tier_lattice,
     selector_match,
+    tallow_attrs,
     target_allows_q,
     tier_direction_arrays,
     tier_first_match_keys,
@@ -89,12 +91,16 @@ def _precompute_static(
     engine state and keeps it on the device.  Per direction:
 
       peer_match [P, N] bool — peer row p matches pod n (host-IP rows
-                               applied)
+                               applied); where the direction carries
+                               band_rows [tiles, PSPAN], its rows as
+                               they lie there, [tiles, PSPAN, N]
+                               (kernel.band_peer_match)
       tmatch     [T, N] bool, has_target [N] bool, and with pack=True
       tmatch_pk  [W, N] int32
 
     and the leaves _precompute_cases still reads from the encoding:
-    peer_target [P], target_ns [T] (for its length) and port_spec.  A
+    peer_target [P], target_ns [T] (for its length), port_spec and
+    band_rows where it rides.  A
     tiered tensor set carries its tier encodings and the two selector
     matches under "tiers": tier_direction_arrays takes the cases, so
     it stays whole in the second half.  `selpod`, where the caller holds
@@ -138,6 +144,11 @@ def _precompute_static(
             "target_ns": enc["target_ns"],
             "port_spec": enc["port_spec"],
         }
+        if "band_rows" in enc:
+            out[direction]["band_rows"] = enc["band_rows"]
+            out[direction]["peer_match"] = band_peer_match(
+                enc["band_rows"], pre["peer_match"]
+            )  # shape: (tiles, PSPAN, N) bool
         if pack:
             out[direction]["tmatch_pk"] = pack_bool_words_jnp(
                 pre["tmatch"]
@@ -222,7 +233,9 @@ def _precompute_cases(
     (_tile_counts_split), the same bytes in another order; a tiered
     static keeps [T, N, Q] (counts_tile_form).  The contraction is
     kernel.target_allows_q, [Q, T, N], the port cases on the one-hot's
-    rows against the resident peer_match: no [P, N, Q] operand exists,
+    rows against the resident peer_match (where the static carries
+    band_rows, each tile of kernel.TARGET_BAND targets against its own
+    banded rows): no [P, N, Q] operand exists,
     and T is packed where it lies (the one-hot padded to whole words)
     before the small words take their order.  `tile` TILE_QTN (unpacked
     alone) keeps each direction's tallow [Q, T, N] in MXU_DTYPE, cast in
@@ -691,7 +704,7 @@ def evaluate_grid_counts(
     block = _int32_safe_block(min(block, max(n_pods, 1)), n_pods, q)
     with ti.eval_flight(
         "counts.xla", n_pods, q, block=block, tile=counts_tile_form(tensors),
-        **tier_attrs(tensors),
+        **tier_attrs(tensors), **tallow_attrs(tensors),
     ) as fl:
         tensors, n_tiles = _pad_pod_axis(tensors, n_pods, block)
         with phase("engine.dispatch"):
@@ -1008,6 +1021,7 @@ def evaluate_grid_counts_classes(
         class_contract=CONTRACT_PACKED if pack else CONTRACT_MXU,
         target_words=target_words(tensors),
         **tier_attrs(tensors, TIER_FUSED if kernel == "pallas" else TIER_XLA),
+        **tallow_attrs(tensors),
     )
     with phase("engine.dispatch") as sp:
         sent = jax.device_put(cases)
@@ -1106,6 +1120,7 @@ def evaluate_grid_counts_classes_sharded(
         devices=int(n_dev),
         class_contract=CONTRACT_PACKED if pack else CONTRACT_MXU,
         **tier_attrs(tensors),
+        **tallow_attrs(tensors),
     ) as fl:
         with mesh_device_context(mesh):
             rs = np.asarray(fn(t))
@@ -1297,8 +1312,8 @@ MESH_COUNTS_HELD = "mesh-counts=held"
 
 def _mesh_static_specs(tensors: Dict, pack: bool, ring: bool) -> Dict:
     """shard_map specs of _precompute_static's result: on the ring route
-    every leaf with a pod axis is sharded over it, on the rows route all
-    of it is replicated."""
+    every leaf with a pod axis is sharded over it (a banded peer_match on
+    its last), on the rows route all of it is replicated."""
 
     def pod(*lead):
         return P(*lead, "x") if ring else P()
@@ -1313,6 +1328,9 @@ def _mesh_static_specs(tensors: Dict, pack: bool, ring: bool) -> Dict:
             "target_ns": P(),
             "port_spec": {k: P() for k in tensors[direction]["port_spec"]},
         }
+        if "band_rows" in tensors[direction]:
+            spec["band_rows"] = P()
+            spec["peer_match"] = pod(None, None)
         if pack:
             spec["tmatch_pk"] = pod(None)
         out[direction] = spec

@@ -819,11 +819,15 @@ class IncrementalEngine:
         )
         for k in _SEL_LEAVES:
             merged[k] = _fit(k, merged[k], old[k], engine_api._SEL_PADS[k])
+        def no_band(d: Dict) -> Dict:
+            # the band plan follows the FITTED shapes: laid anew below
+            return {k: v for k, v in d.items() if k != "band_rows"}
+
         for direction in ("ingress", "egress"):
             merged[direction] = _fit_slab_dict(
                 direction,
-                old[direction],
-                merged[direction],
+                no_band(old[direction]),
+                no_band(merged[direction]),
                 engine_api._DIRECTION_PADS,
             )
             if had_tiers:
@@ -833,6 +837,22 @@ class IncrementalEngine:
                     old["tiers"][direction],
                     merged["tiers"][direction],
                     engine_api._TIER_PADS,
+                )
+        # the target-allows band plan of the fitted slabs
+        # (api._band_tensors): it rides where the live one rides, its
+        # span padded up to the allocated one, or the patch is a rebuild
+        merged = engine_api._band_tensors(merged, headroom)
+        for direction in ("ingress", "egress"):
+            od, nd = old[direction], merged[direction]
+            if ("band_rows" in od) != ("band_rows" in nd):
+                raise Ineligible(
+                    f"{direction} target-allows band plan appears or goes "
+                    "(tensor structure change)"
+                )
+            if "band_rows" in nd:
+                nd["band_rows"] = _fit(
+                    f"{direction}.band_rows", nd["band_rows"],
+                    od["band_rows"], -1,
                 )
         patch = self.main_patchset()
 

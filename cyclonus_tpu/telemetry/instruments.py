@@ -117,6 +117,16 @@ CLASS_CONTRACT = REGISTRY.counter(
     "`class_contract` of the evaluation's `engine.eval` span, counted.",
     labelnames=("form",),
 )
+TARGET_ALLOWS = REGISTRY.counter(
+    "cyclonus_tpu_target_allows_total",
+    "Target-allows contractions (kernel.target_allows_q) of evaluations "
+    "whose program traces it, one a direction, by form "
+    "(engine/kernel.py tallow_attrs): band (each tile of targets against "
+    "its own peer rows, where the direction carries band_rows) or dense "
+    "(the one-hot over every peer row).  The `tallow` of the "
+    "evaluation's `engine.eval` span, counted.",
+    labelnames=("form",),
+)
 GRID_HOST_BUFFER = REGISTRY.counter(
     "cyclonus_tpu_grid_host_buffer_total",
     "Host buffers handed to sharded tables' readbacks "
@@ -803,6 +813,16 @@ def eval_flight(path: str, n_pods: int, q: int, **attrs: Any) -> Iterator[Flight
             if "class_contract" in flight.data:
                 sp.set(class_contract=flight.data["class_contract"])
                 CLASS_CONTRACT.inc(form=flight.data["class_contract"])
+            # and the form of its target-allows contraction a direction
+            # and the peer rows a target reads (kernel.tallow_attrs),
+            # counted a direction
+            if "tallow" in flight.data:
+                sp.set(
+                    tallow=flight.data["tallow"],
+                    tallow_span=flight.data["tallow_span"],
+                )
+                for form in flight.data["tallow"].split(","):
+                    TARGET_ALLOWS.inc(form=form)
             # and, over a tiered set, its tier rule rows and the epilogue
             # that resolved them (tiled.tier_attrs), counted a kernel
             if "tier_kernel" in flight.data:

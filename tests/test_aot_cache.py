@@ -566,6 +566,66 @@ class TestTargetAllowsInKey:
         (plan,) = plans
         assert (f"{kernel.TARGET_ALLOWS};" in plan) == (schedule == "ring")
 
+    @pytest.mark.parametrize("schedule", ["ring", "allgather"])
+    def test_sharded_plan_names_the_band_where_the_specs_carry_it(
+        self, monkeypatch, schedule
+    ):
+        import jax
+        import numpy as np
+        from jax.sharding import Mesh, PartitionSpec as P
+
+        from cyclonus_tpu.engine import kernel, sharded
+
+        mesh = Mesh(np.array(jax.devices()[:2]), ("x",))
+        plans = []
+        monkeypatch.setattr(
+            aot_cache, "AotProgram",
+            lambda name, fn, schedule, plan: plans.append(plan) or fn,
+        )
+        monkeypatch.setattr(sharded, "_SHARDED_PROGRAMS", {})
+        specs = {"q_port": P(), "ingress": {"band_rows": P()}, "egress": {}}
+        sharded._sharded_program(mesh, schedule, 8, specs)
+        (plan,) = plans
+        assert (f"{kernel.TARGET_ALLOWS_BAND};" in plan) == (schedule == "ring")
+        assert f"{kernel.TARGET_ALLOWS};" not in plan
+
+    @pytest.mark.parametrize("tiered", [True, False], ids=["tiered", "untiered"])
+    def test_band_form_rides_only_in_plans_whose_tensors_carry_band_rows(
+        self, monkeypatch, tiered
+    ):
+        """An engine below the band rule (kernel.band_engages) keeps the
+        parent's plan string, letter for letter; the same engine with
+        band_rows laid names tallow=band128 in their place, and nothing
+        else of the plan moves."""
+        from cyclonus_tpu.engine import TpuPolicyEngine, kernel
+        from cyclonus_tpu.synthetic import tiers_lattice
+
+        monkeypatch.setenv("CYCLONUS_PACK", "1")
+        policy, pods, namespaces, _cases = _small_problem()
+
+        def engine():
+            return TpuPolicyEngine(
+                policy, pods, namespaces, tiers=tiers_lattice() if tiered else None
+            )
+
+        plain = engine()
+        assert all(
+            "band_rows" not in plain._tensors[d] for d in ("ingress", "egress")
+        )
+        parent = (
+            f"packed32;any=reduce;tiered={tiered}"
+            + (";first=words" if tiered else "")
+            + ";tallow=qonehot"
+        )
+        assert plain._aot_plan() == parent
+        monkeypatch.setattr(kernel, "band_engages", lambda *a: True)
+        banded = engine()
+        assert "band_rows" in banded._tensors["ingress"]
+        assert kernel.TARGET_ALLOWS_BAND == "tallow=band128"
+        assert banded._aot_plan() == parent.replace(
+            "tallow=qonehot", "tallow=band128"
+        )
+
 
 def _outcomes():
     from cyclonus_tpu.telemetry import instruments as ti

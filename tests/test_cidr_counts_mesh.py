@@ -30,12 +30,12 @@ import pytest
 import cyclonus_tpu.engine.api as api
 from cyclonus_tpu.engine import PortCase, TpuPolicyEngine, planspec
 from cyclonus_tpu.engine import sharded as sharded_mod
-from cyclonus_tpu.engine import tiled
+from cyclonus_tpu.engine import kernel, tiled
 from cyclonus_tpu.kube.yaml_io import policy_to_dict
 from cyclonus_tpu.matcher import build_network_policies
 from cyclonus_tpu.synthetic import CIDR_ALLOWLISTS, cidr_allowlists
 from cyclonus_tpu.telemetry import instruments as ti
-from cyclonus_tpu.telemetry import spans
+from cyclonus_tpu.telemetry import recorder, spans
 
 from test_engine_sharded import cpu_mesh
 
@@ -150,6 +150,30 @@ def test_the_mesh_entry_one_chip_and_the_reference_count_the_same(
     assert all(isinstance(got[k], int) for k in KINDS)
 
 
+@both_routes
+def test_the_band_form_counts_what_the_reference_counts(
+    cluster, reference, route, monkeypatch
+):
+    """The same cluster with the target-allows band laid (forced: at this
+    size the rule declines; tiles of 8 targets, so several a direction):
+    the held pair keeps peer_match banded (on the ring pod-sharded on its
+    last axis), the key names the band, and every count is the
+    reference's."""
+    monkeypatch.setattr(kernel, "band_engages", lambda *a: True)
+    monkeypatch.setattr(kernel, "TARGET_BAND", 8)
+    eng = new_engine(*cluster)
+    bands = [eng._tensors[d]["band_rows"] for d in ("ingress", "egress")]
+    assert all(b.shape[0] > 1 for b in bands)
+    assert eng._aot_plan().endswith(f";{kernel.TARGET_ALLOWS_BAND}")
+    for cases in PAIRS:
+        got = eng.evaluate_grid_counts_sharded(cases, mesh=cpu_mesh(4))
+        said = reference.counts([(c.port, c.port_name, c.protocol) for c in cases])
+        assert {k: got[k] for k in KINDS} == said
+        assert recorder.entries()[-1]["tallow"] == "band,band"
+    static = eng._mesh_static[1]
+    assert static["ingress"]["peer_match"].shape[:2] == bands[0].shape
+
+
 @pytest.mark.parametrize("pair", range(3), ids=IDS)
 def test_at_the_defaults_the_mesh_is_every_device(engine, pair, monkeypatch):
     """No mesh given: `sharded.default_mesh`, all the default backend's
@@ -184,6 +208,7 @@ def test_the_route_its_bytes_and_its_ceiling_are_on_the_evaluation(
         "replicated_bytes": need,
         "ceiling_bytes": api._MESH_REPLICATED_MAX_BYTES,
         "tile": tiled.TILE_BQN,
+        **kernel.tallow_attrs(engine._tensors),
     }
     assert ti.COUNTS_TILE.value(form=tiled.TILE_BQN) == tiles + 1
     # from the shapes: the static half and a direction's peer_allow

@@ -26,7 +26,7 @@ import re
 import numpy as np
 import pytest
 
-from cyclonus_tpu.engine import PortCase, TpuPolicyEngine, planspec
+from cyclonus_tpu.engine import PortCase, TpuPolicyEngine, kernel, planspec, tiled
 from cyclonus_tpu.engine import sharded as sharded_mod
 from cyclonus_tpu.kube.yaml_io import policy_to_dict
 from cyclonus_tpu.matcher import build_network_policies
@@ -102,6 +102,25 @@ def test_the_dense_mesh_route_against_the_scalar_oracle(
     assert np.array_equal(_table_from_grid(grid), want[case:case + 1])
 
 
+@pytest.mark.parametrize("n_dev", [2, 4])
+def test_the_ring_over_the_band_against_the_scalar_oracle(cluster, n_dev, monkeypatch):
+    """The ring (`grid.sharded.ring`, which traces tiled._precompute a
+    device) over the cluster with the target-allows band laid (forced: at
+    this size the rule declines; tiles of 8 targets): every table of
+    every case is the oracle's, and the evaluation says band."""
+    from cyclonus_tpu.telemetry import recorder
+
+    policy, pods, namespaces, _, want = cluster
+    monkeypatch.setattr(kernel, "band_engages", lambda *a: True)
+    monkeypatch.setattr(kernel, "TARGET_BAND", 8)
+    monkeypatch.setenv("CYCLONUS_CLASS_MIN_PODS", "32")
+    eng = TpuPolicyEngine(policy, pods, namespaces)
+    assert eng._tensors["ingress"]["band_rows"].shape[0] > 1
+    grid = eng.evaluate_grid_sharded(SIX, mesh=cpu_mesh(n_dev), schedule="ring")
+    assert np.array_equal(_table_from_grid(grid), want)
+    assert recorder.entries()[-1]["tallow"] == "band,band"
+
+
 @pytest.mark.parametrize("case", range(len(SIX)), ids=[f"{c.port}-{c.protocol}" for c in SIX])
 def test_one_chip_and_the_benchmarks_reference_say_the_same(cluster, engine, case):
     """`evaluate_grid` on one device, and `benchmarks/reference.py` (read
@@ -142,7 +161,11 @@ def test_the_recorded_route_is_the_dense_leaf_and_nothing_else(
         rec["attrs"] for path, rec in spans.REGISTRY.tree().items()
         if path == "engine.eval"
     ]
-    assert root == {"route": "grid.sharded", "schedule": schedule, "classes": False}
+    # the ring traces the target-allows contraction: its form a direction
+    tallow = kernel.tallow_attrs(engine._tensors) if schedule == "ring" else {}
+    assert root == {
+        "route": "grid.sharded", "schedule": schedule, "classes": False, **tallow,
+    }
 
 
 @pytest.mark.parametrize("n_dev", [2, 4, 8])

@@ -8,6 +8,8 @@ fixture of this file, never while a module is imported (xdist hands a
 file to one worker).  Where no topology can be described the tests
 skip."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -442,3 +444,39 @@ def test_the_untiered_class_program_contracts_on_the_mxu(one_chip, full):
     assert used <= eng._class_counts_bytes(2)
     if full:
         assert eng._class_counts_bytes(2) <= 1.15 * used
+
+
+def test_the_banded_class_program_contracts_target_tiles(one_chip):
+    """The class program at k8s-envelope-150k's full shapes with the band
+    plan its constructor lays (api._band_tensors: 129 tiles of 128
+    targets, PSPAN 256 a direction): the target-allows contraction is ONE
+    batched matmul a direction, [129, 256, 256] x [129, 256, Cb], no
+    one-hot over every peer row ([Q·T', P]) exists, the tile loop is the
+    MXU form's, and the class route's estimate holds the program's
+    temporaries and arguments by at most 15 % more."""
+    from cyclonus_tpu.engine import kernel, tiled
+
+    ct, w, cases, selpod, eng = _envelope_class_set(one_chip, True)
+    band = jax.ShapeDtypeStruct((129, 256), jnp.int32, sharding=one_chip)
+    for d in ("ingress", "egress"):
+        ct[d] = dict(ct[d], band_rows=band)
+    eng._class_state = dict(eng._class_state, ctensors=ct)
+    assert kernel.target_allows_form(ct) == kernel.TARGET_ALLOWS_BAND
+    cb = ct["pod_ns_id"].shape[0]
+    block, n_tiles = tiled._class_tiling(cb, 1024)
+    compiled = tiled._class_rowsums_kernel.lower(
+        ct, w, cases, block, n_tiles, False, selpod=selpod
+    ).compile()
+    text = compiled.as_text()
+    dots = re.findall(r"= (\w+\[[\d,]+\])\{[^}]*\} convolution\(", text)
+    assert sorted(dots) == sorted(
+        [f"bf16[129,{2 * kernel.TARGET_BAND},{cb}]"] * 2 + [f"s32[{block},{cb}]"] * 4
+    )
+    t = ENVELOPE_FULL["ingress"][1] + 1  # whole words
+    for p in (ENVELOPE_FULL["ingress"][0], ENVELOPE_FULL["egress"][0]):
+        assert f"[{2 * t},{p}]" not in text
+    written, inner = _while_body_ops(text)
+    assert [o for o in inner if o[0] == "convolution"] == [("convolution", "s32", block * cb)] * 4
+    mem = compiled.memory_analysis()
+    used = mem.temp_size_in_bytes + mem.argument_size_in_bytes
+    assert used <= eng._class_counts_bytes(2) <= 1.15 * used

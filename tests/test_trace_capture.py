@@ -878,9 +878,14 @@ class TestMeshSpans:
             == attrs["host_bytes"]
         )
         (root,) = by_name(found, "engine.eval")
+        from cyclonus_tpu.engine import kernel
+
+        # the ring traces the target-allows contraction: its form a direction
+        ring = (schedule or "ring") == "ring"
         assert root["attrs"] == {
             "route": "grid.sharded", "schedule": schedule or "ring",
             "classes": route == "classes",
+            **(kernel.tallow_attrs(tensors) if ring else {}),
         }
 
     def test_outside_a_capture_a_shard_copy_is_no_span(self, cluster):
